@@ -337,10 +337,13 @@ _ranks_cache: Dict[str, Tuple[int, ...]] = {}
 
 
 def autotune_ranks(mod_path: str) -> Tuple[int, ...]:
-    """The rank grid ``vmem-overbudget`` evaluates: the ``r<N>``
-    buckets declared by ``gram_autotune_defaults.json`` next to the
-    scanned module (falling back to the packaged table), so the
-    checker and the autotuner always argue over the same ranks."""
+    """The rank grid ``vmem-overbudget`` evaluates: the autotuner's
+    rank buckets (32, 64, 128 — ``gram_autotune._rank_bucket``) plus
+    any further ``r<N>`` bucket declared by
+    ``gram_autotune_defaults.json`` next to the scanned module (falling
+    back to the packaged table), so the checker and the autotuner
+    always argue over the same ranks however few entries the table
+    holds."""
     for candidate in (
             os.path.join(os.path.dirname(mod_path) or ".",
                          "gram_autotune_defaults.json"),
@@ -359,7 +362,7 @@ def autotune_ranks(mod_path: str) -> Tuple[int, ...]:
                         for key in doc
                         for m in [re.search(r"\|r(\d+)\|", key)]
                         if m})
-        out = tuple(ranks) or (32, 64, 128)
+        out = tuple(sorted({32, 64, 128, *ranks}))
         _ranks_cache[candidate] = out
         return out
     return (32, 64, 128)

@@ -94,7 +94,7 @@ def _is_low(dtype: str) -> bool:
 
 def _sub_jaxprs(params: dict):
     """Inner jaxprs of one equation (pjit/scan/cond/shard_map/…)."""
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     def _as_jaxpr(v):
         if isinstance(v, jcore.ClosedJaxpr):
@@ -159,7 +159,7 @@ def census_jaxpr(closed) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# entry-point builders (each returns a jax.core.ClosedJaxpr)
+# entry-point builders (each returns a jax.extend.core.ClosedJaxpr)
 # ---------------------------------------------------------------------------
 
 def _training_mesh():

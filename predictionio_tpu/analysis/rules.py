@@ -21,7 +21,7 @@ The JAX rules:
   ``.block_until_ready()``, ``float(jnp...)``) inside functions of the
   hot packages (``server/``, ``ops/``). Each is a synchronous transfer
   that stalls the dispatch pipeline; on the query path one stray sync
-  caps throughput at the PCIe/tunnel round-trip rate.
+  caps throughput at the host↔device round-trip rate.
 - ``recompile-hazard`` — jit call sites that re-trace or re-compile
   silently: unhashable values passed for static args, jitted closures
   capturing ``jnp`` arrays built in an enclosing scope (the captured

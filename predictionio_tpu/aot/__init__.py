@@ -193,8 +193,14 @@ class ArtifactStore:
             return cached
         compiled = fn.lower(*dyn_args, **(statics or {})).compile()
         blob, in_tree, out_tree = se.serialize(compiled)
+        # the executable runs on exactly the devices it was compiled
+        # for: record them so load() binds it back to those, not to
+        # every device of the backend (the deserializer's default)
+        devices = [d.id for d in
+                   compiled.runtime_executable().local_devices()]
         payload = pickle.dumps(
-            {"blob": blob, "in_tree": in_tree, "out_tree": out_tree},
+            {"blob": blob, "in_tree": in_tree, "out_tree": out_tree,
+             "devices": devices},
             protocol=pickle.HIGHEST_PROTOCOL)
         os.makedirs(self.path, exist_ok=True)
         fname = ekey + _EXT
@@ -270,8 +276,12 @@ class ArtifactStore:
             if hashlib.sha256(payload).hexdigest() != meta.get("sha256"):
                 raise ValueError("artifact checksum mismatch")
             doc = pickle.loads(payload)
+            import jax
+
+            by_id = {d.id: d for d in jax.devices()}
             executable = se.deserialize_and_load(
-                doc["blob"], doc["in_tree"], doc["out_tree"])
+                doc["blob"], doc["in_tree"], doc["out_tree"],
+                execution_devices=[by_id[i] for i in doc["devices"]])
         except Exception:  # noqa: BLE001 — any bad artifact ⇒ compile
             _bump("corrupt_entries")
             with self._lock:

@@ -264,6 +264,10 @@ def cmd_train(args, storage: Storage) -> int:
     else:
         if ctx.stage_timings:
             _out(f"Train stages: {json.dumps(ctx.stage_timings)}")
+        for label, key in (("Train build info", "train_build_info"),
+                           ("Train kernels", "train_kernels")):
+            if ctx.extra.get(key):
+                _out(f"{label}: {json.dumps(ctx.extra[key])}")
         _out(f"Training completed. Engine instance ID: {instance_id}")
     return 0
 
@@ -461,6 +465,10 @@ def cmd_deploy(args, storage: Storage) -> int:
         server.serve_forever()
     except KeyboardInterrupt:
         _out("Shutting down.")
+    warm_error = server.query_server.warm_error
+    if warm_error is not None:
+        _err(f"Deploy failed: serving warm-up failed: {warm_error}")
+        return 1
     return 0
 
 

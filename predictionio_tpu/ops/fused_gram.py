@@ -33,13 +33,15 @@ Entry points:
 
 - :func:`fused_gram` — the kernel itself (``interpret=True`` runs it
   on any backend for tests/debugging);
-- :func:`fused_gram_dispatch` — backend-aware: compiled kernel on TPU,
-  interpret-mode kernel elsewhere (explicit ``gram_mode="fused"`` on a
-  CPU is a debugging run), XLA reference on TPUs whose Mosaic can't
-  lower the kernel;
-- :func:`fused_gram_reference` — the jnp mirror used for fallback and
-  accuracy tests;
-- :func:`fused_gram_supported` — one-shot lowering probe.
+- :func:`fused_gram_dispatch` — backend-aware: the compiled kernel on
+  TPU (a kernel the compiler refuses RAISES with the compiler's
+  message — nothing stands in for it), the interpret-mode kernel
+  elsewhere (explicit ``gram_mode="fused"`` on a CPU is a debugging
+  run);
+- :func:`fused_gram_reference` — the jnp mirror the accuracy tests
+  hold the kernel against (never a fallback);
+- :func:`fused_gram_refusal` — compile probe at the shapes about to
+  run; returns the compiler's message when it refuses.
 
 Wired as ``ALSParams(gram_mode="fused")`` through
 ``models/als.py::_lhs_fn`` (which owns the only gather) and picked by
@@ -54,14 +56,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover — pallas not in this jax build
-    _HAVE_PALLAS = False
+from . import _probe
 
 #: rows of A/b produced per grid step. Small on purpose: each row's
 #: history chunks pipeline through the double buffer, so the block size
@@ -206,7 +204,6 @@ def fused_gram(table: jax.Array, idx: jax.Array, wa: jax.Array,
     Padding slots must carry w=0 (idx may point at any valid row);
     B and L are padded to block multiples internally and sliced back —
     ragged tails are the caller's normal case, not an error."""
-    assert _HAVE_PALLAS, "pallas unavailable in this jax build"
     B, L = idx.shape
     m, r = table.shape
     Lc = min(chunk or _L_CHUNK, L)
@@ -239,7 +236,7 @@ def fused_gram(table: jax.Array, idx: jax.Array, wa: jax.Array,
             # the factor table STAYS in HBM — rows are DMA'd on demand;
             # this is the whole point (a VMEM-resident BlockSpec would
             # cap m·r at the ~16MB core budget)
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((block_rows, r, r), lambda i: (i, 0, 0),
@@ -268,51 +265,44 @@ def fused_gram_reference(table: jax.Array, idx: jax.Array,
                          wa: jax.Array, wb: jax.Array
                          ) -> Tuple[jax.Array, jax.Array]:
     """jnp mirror of the kernel (gather, upcast, f32 contraction) —
-    the fallback on TPUs whose Mosaic can't lower the kernel, and the
-    oracle for the accuracy tests. Materializes the gather temp: this
-    is the baseline the kernel exists to beat."""
+    the oracle for the accuracy tests, never a fallback. Materializes
+    the gather temp: this is the baseline the kernel exists to beat."""
     F = table[idx].astype(jnp.float32)  # [B, L, r]
     A = jnp.einsum("blr,bls,bl->brs", F, F, wa.astype(jnp.float32))
     b = jnp.einsum("blr,bl->br", F, wb.astype(jnp.float32))
     return A, b
 
 
-def _tpu_attached() -> bool:
-    try:
-        dev = jax.devices()[0]
-        return dev.platform == "tpu" or dev.device_kind.startswith("TPU")
-    except Exception:  # noqa: BLE001 — no backend at all
-        return False
+_probes = _probe.CompileProbes()
 
 
-_support: dict = {}
+def fused_gram_refusal(rank: int, wire_dtype="float32",
+                       hist_len: int = _L_CHUNK) -> Optional[str]:
+    """Why the fused kernel will NOT run at ``(rank, wire_dtype,
+    hist_len)`` on the attached backend — None when it compiles.
+
+    The probe compiles the kernel with the block, chunk and scratch
+    shapes the real call at that history length gets (one
+    ``[_BLOCK_ROWS, hist_len]`` grid step; the table's row count and
+    the batch only set the grid extent, not what Mosaic compiles) and
+    keeps the compiler's message verbatim. ``gram_mode="auto"``
+    consumers report that message and train on einsum; an explicit
+    ``gram_mode="fused"`` never consults this — it compiles the kernel
+    or raises. Without a TPU attached the answer is
+    ``_probe.NO_TPU`` (interpret mode is for CPU tests only)."""
+    dt = jnp.dtype(wire_dtype)
+    tab = jax.ShapeDtypeStruct((_BLOCK_ROWS, int(rank)), dt)
+    idx = jax.ShapeDtypeStruct((_BLOCK_ROWS, int(hist_len)), jnp.int32)
+    w = jax.ShapeDtypeStruct((_BLOCK_ROWS, int(hist_len)), jnp.float32)
+    return _probes.refusal(
+        (f"r{int(rank)}", dt.name, f"L{int(hist_len)}"),
+        lambda: fused_gram.lower(tab, idx, w, w).compile())
 
 
-def fused_gram_supported() -> bool:
-    """Probe ONCE whether the fused kernel lowers+compiles on the
-    attached backend. True only on a TPU whose Mosaic build accepts the
-    kernel (per-row dynamic-index DMA support is version-dependent);
-    ``gram_mode="auto"`` consumers use this to fall back to einsum
-    instead of raising mid-train."""
-    if not _HAVE_PALLAS or not _tpu_attached():
-        return False
-    cached = _support.get("tpu")
-    if cached is not None:
-        return cached
-    try:
-        tab = jnp.zeros((256, 64), jnp.float32)
-        idx = jnp.zeros((_BLOCK_ROWS, 128), jnp.int32)
-        w = jnp.zeros((_BLOCK_ROWS, 128), jnp.float32)
-        jax.jit(fused_gram).lower(tab, idx, w, w).compile()
-        ok = True
-    except Exception:  # noqa: BLE001 — lowering not supported
-        ok = False
-    _support["tpu"] = ok
-    return ok
-
-
-def reset_support_cache_for_tests() -> None:
-    _support.clear()
+#: every refusal probed so far, ``r<rank>/<dtype>/L<hist_len>`` →
+#: compiler message
+refusals = _probes.refusals
+reset_support_cache_for_tests = _probes.clear
 
 
 def fused_gram_dispatch(table: jax.Array, idx: jax.Array, wa: jax.Array,
@@ -320,19 +310,15 @@ def fused_gram_dispatch(table: jax.Array, idx: jax.Array, wa: jax.Array,
     """Backend-aware fused entry (the ``gram_mode="fused"`` realization
     ``models/als.py::_lhs_fn`` calls):
 
-    - TPU with Mosaic support → the compiled kernel; a CPU lowering of
-      the same trace (virtual-mesh dryruns) runs it interpreted, so the
-      numbers match the device run;
-    - TPU without support → the XLA reference (graceful, not fatal);
+    - TPU → the compiled kernel, or the compiler's error if it refuses
+      the shapes: a request for this kernel never runs anything else. A
+      CPU lowering of the same trace (virtual-mesh dryruns) runs it
+      interpreted, so the numbers match the device run;
     - no TPU → interpret-mode kernel: an explicit ``gram_mode="fused"``
       on CPU is a debugging run and should exercise the REAL kernel
       (this is what tier-1 covers without a TPU).
     """
-    if not _HAVE_PALLAS:
-        return fused_gram_reference(table, idx, wa, wb)
-    if _tpu_attached():
-        if not fused_gram_supported():
-            return fused_gram_reference(table, idx, wa, wb)
+    if _probe.tpu_attached():
         return jax.lax.platform_dependent(
             table, idx, wa, wb,
             tpu=lambda t, i, a, b: fused_gram(t, i, a, b),

@@ -30,11 +30,20 @@ where the bound lands).
 Entry points mirror fused_gram's contract:
 
 - :func:`fused_topk` — the kernel (``interpret=True`` runs anywhere);
-- :func:`fused_topk_dispatch` — compiled on TPU, interpret-mode kernel
-  elsewhere (explicit ``serving topk="fused"`` on CPU is a debugging
-  run), XLA reference on TPUs whose Mosaic can't lower it;
-- :func:`fused_topk_reference` — the jnp mirror (fallback + oracle);
-- :func:`fused_topk_supported` — one-shot lowering probe.
+- :func:`fused_topk_dispatch` — the compiled kernel on TPU (a kernel
+  the compiler refuses RAISES with the compiler's message — nothing
+  stands in for it), the interpret-mode kernel elsewhere (explicit
+  ``serving topk="fused"`` on CPU is a debugging run);
+- :func:`fused_topk_reference` — the jnp mirror the parity tests hold
+  the kernel against (never a fallback);
+- :func:`fused_topk_refusal` — compile probe at the shapes about to
+  run; returns the compiler's message when it refuses.
+
+On the installed JAX (0.9.0) the kernel does NOT lower for TPU at any
+shape: ``lax.top_k`` has no Pallas TPU lowering and the ``(1, block_q)``
+index block is rejected for every batch above 8. It needs an in-kernel
+top-k that Mosaic lowers; until then no autotune entry names it, and an
+explicit ``--serving-topk fused`` fails the deploy on a TPU backend.
 
 Routed through ``models/als.py::_device_topk`` (single + replicated
 lanes + pinned hot tier) and ``_sharded_rank_fn`` (per-shard local
@@ -51,14 +60,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover — pallas not in this jax build
-    _HAVE_PALLAS = False
+from . import _probe
 
 #: query rows scored per grid step — bounds the user tile and the
 #: running top-k carry; the item-chunk sweep, not the block size, sets
@@ -240,7 +245,6 @@ def fused_topk(user_table: jax.Array, idx: jax.Array,
     int8-quantized tables (both or neither — bf16/f32 tables carry
     none). B pads to the block multiple and the catalog to the chunk
     multiple internally; ragged tails are the normal case."""
-    assert _HAVE_PALLAS, "pallas unavailable in this jax build"
     assert (user_scale is None) == (item_scale is None), \
         "int8 tables quantize both sides (scales come in pairs)"
     B = idx.shape[0]
@@ -292,14 +296,14 @@ def fused_topk(user_table: jax.Array, idx: jax.Array,
     # item chunks stream through the double buffer; a VMEM-resident
     # BlockSpec would cap the catalog at the ~16MB core budget
     inputs.append(user_table)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(itab)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     if has_scale:
         isc = _pad_rows_to(item_scale.reshape(-1).astype(jnp.float32),
                            Ipad, fill=1.0).reshape(n_chunks, c)
         inputs.append(isc)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     scratch = [
         pltpu.VMEM((block_q, r), user_table.dtype),   # gathered rows
@@ -345,9 +349,9 @@ def fused_topk_reference(user_table: jax.Array, idx: jax.Array,
                          base: Optional[jax.Array] = None, *, k: int,
                          n_items: int) -> Tuple[jax.Array, jax.Array]:
     """jnp mirror of the kernel (gather, dequantize, full [B, I] score
-    matrix, top_k) — the fallback on TPUs whose Mosaic can't lower the
-    kernel and the oracle for the parity tests. Materializes the score
-    matrix: this is the baseline the kernel exists to beat."""
+    matrix, top_k) — the oracle for the parity tests, never a
+    fallback. Materializes the score matrix: this is the baseline the
+    kernel exists to beat."""
     # ptpu: allow[materialized-gather] — [B, r] serving row fetch
     # bounded by the dispatch batch, mirroring _serve_topk
     vecs = user_table[idx].astype(jnp.float32)
@@ -372,52 +376,37 @@ def fused_topk_reference(user_table: jax.Array, idx: jax.Array,
     return s, ids
 
 
-#: compiled wrapper for the dispatch fallback lanes: without jit the
-#: reference runs op-by-op and `item_table.astype(f32)` materializes a
-#: full-width copy of the serving table in HBM — exactly the 4×
-#: footprint the quantized tables exist to avoid. Compiled, the upcast
-#: fuses into the score matmul.
-_reference_compiled = jax.jit(fused_topk_reference,
-                              static_argnames=("k", "n_items"))
+_probes = _probe.CompileProbes()
 
 
-def _tpu_attached() -> bool:
-    try:
-        dev = jax.devices()[0]
-        return dev.platform == "tpu" or dev.device_kind.startswith("TPU")
-    except Exception:  # noqa: BLE001 — no backend at all
-        return False
+def fused_topk_refusal(batch: int, rank: int, n_rows: int, k: int,
+                       wire_dtype="float32") -> Optional[str]:
+    """Why the fused serving kernel will NOT run for a ``[batch]``
+    dispatch of top-``k`` over an ``[n_rows, rank]`` item table of
+    ``wire_dtype`` on the attached backend — None when it compiles at
+    exactly those shapes. Keeps the compiler's message verbatim; the
+    deploy-time bind raises it for an explicit ``serving_topk="fused"``
+    and ``/status.json`` carries it for a kernel ``auto`` skipped.
+    Without a TPU attached the answer is ``_probe.NO_TPU`` (interpret
+    mode is for CPU tests only)."""
+    dt = jnp.dtype(wire_dtype)
+    batch, rank, n_rows, k = int(batch), int(rank), int(n_rows), int(k)
+    utab = jax.ShapeDtypeStruct((max(batch, _BLOCK_Q), rank), dt)
+    itab = jax.ShapeDtypeStruct((n_rows, rank), dt)
+    idx = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    scale = (jax.ShapeDtypeStruct((utab.shape[0], 1), jnp.float32),
+             jax.ShapeDtypeStruct((n_rows, 1), jnp.float32)) \
+        if dt == jnp.int8 else (None, None)
+    return _probes.refusal(
+        (f"B{batch}", f"r{rank}", f"I{n_rows}", f"k{k}", dt.name),
+        lambda: fused_topk.lower(utab, idx, itab, *scale, k=k,
+                                 n_items=n_rows).compile())
 
 
-_support: dict = {}
-
-
-def fused_topk_supported() -> bool:
-    """Probe ONCE whether the fused serving kernel lowers+compiles on
-    the attached backend. True only on a TPU whose Mosaic build accepts
-    it (dynamic-index row DMAs and the in-kernel top_k merge are both
-    version-dependent); the autotune table uses this to degrade to the
-    einsum lane instead of raising mid-serve."""
-    if not _HAVE_PALLAS or not _tpu_attached():
-        return False
-    cached = _support.get("tpu")
-    if cached is not None:
-        return cached
-    try:
-        utab = jnp.zeros((256, 64), jnp.float32)
-        itab = jnp.zeros((1024, 64), jnp.float32)
-        idx = jnp.zeros((_BLOCK_Q,), jnp.int32)
-        jax.jit(functools.partial(fused_topk, k=8, n_items=1000)
-                ).lower(utab, idx, itab).compile()
-        ok = True
-    except Exception:  # noqa: BLE001 — lowering not supported
-        ok = False
-    _support["tpu"] = ok
-    return ok
-
-
-def reset_support_cache_for_tests() -> None:
-    _support.clear()
+#: every refusal probed so far,
+#: ``B<batch>/r<rank>/I<rows>/k<k>/<dtype>`` → compiler message
+refusals = _probes.refusals
+reset_support_cache_for_tests = _probes.clear
 
 
 def fused_topk_dispatch(user_table: jax.Array, idx: jax.Array,
@@ -429,23 +418,12 @@ def fused_topk_dispatch(user_table: jax.Array, idx: jax.Array,
     """Backend-aware fused entry (what ``models/als.py::_device_topk``
     calls when the serving top-k resolves to "fused"):
 
-    - TPU with Mosaic support → the compiled kernel;
-    - TPU without support → the XLA reference (graceful, not fatal);
+    - TPU → the compiled kernel, or the compiler's error if it refuses:
+      a request for this kernel never runs anything else;
     - no TPU → interpret-mode kernel: an explicit topk="fused" on CPU
       is a debugging run and should exercise the REAL kernel (this is
       what tier-1 covers without a TPU).
     """
-    if not _HAVE_PALLAS:
-        return _reference_compiled(user_table, idx, item_table,
-                                   user_scale, item_scale, base,
-                                   k=k, n_items=n_items)
-    if _tpu_attached():
-        if not fused_topk_supported():
-            return _reference_compiled(user_table, idx, item_table,
-                                       user_scale, item_scale, base,
-                                       k=k, n_items=n_items)
-        return fused_topk(user_table, idx, item_table, user_scale,
-                          item_scale, base, k=k, n_items=n_items)
     return fused_topk(user_table, idx, item_table, user_scale,
                       item_scale, base, k=k, n_items=n_items,
-                      interpret=True)
+                      interpret=not _probe.tpu_attached())

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def gram_weighted(F: jax.Array, w: jax.Array,
@@ -132,14 +134,6 @@ def gram_dispatch(F: jax.Array, w: jax.Array, mode: str,
 # ``gram_table_supported()`` probes lowering once so callers can fall
 # back to the XLA paths.
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
 #: rows of A/b produced per kernel invocation step (must be even: the
 #: MXU contraction packs two rows per 128-wide tile)
 _BLOCK_ROWS = 16
@@ -186,7 +180,6 @@ def gram_table_pallas(table: jax.Array, idx: jax.Array, wa: jax.Array,
     ``A[i] = Σ_l wa[i,l]·f fᵀ`` and ``b[i] = Σ_l wb[i,l]·f`` over
     ``f = table[idx[i,l]]``. Pad slots carry w=0 (idx may point
     anywhere valid). B is padded to the block size internally."""
-    assert _HAVE_PALLAS, "pallas unavailable"
     B, L = idx.shape
     m, r = table.shape
     Bp = -(-B // _BLOCK_ROWS) * _BLOCK_ROWS
@@ -229,8 +222,6 @@ _table_support: dict = {}
 def gram_table_supported() -> bool:
     """Probe once whether the fused table kernel LOWERS on the attached
     backend (Mosaic's vector-gather support is version-dependent)."""
-    if not _HAVE_PALLAS:
-        return False
     try:
         dev = jax.devices()[0]
         if not (dev.platform == "tpu"

@@ -1,18 +1,22 @@
-"""Persistent, shape-keyed gram-mode selection (VERDICT r3 task 2).
+"""Shape-keyed gram-mode and serving top-k selection for ``"auto"``.
 
 ``ALSParams(gram_mode="auto")`` needs a concrete realization (baseline
-einsum vs. the pair-packed MXU tiling, ``ops/gram.py``) at trace time.
-Round 3 raced the candidates at *bench* time only; this module makes the
-choice persistent and shape-keyed so every trainer entry benefits:
+einsum, the pair-packed MXU tiling of ``ops/gram.py``, or the fused
+Pallas kernel of ``ops/fused_gram.py``) at trace time. This module is
+the TABLE half of that choice: it reads what was measured and says
+which realization the table names. Whether a named kernel compiles at
+the shapes about to run is decided where those shapes are known
+(``models/als.py``), never here.
 
 resolution order for ``best_mode(rank, bf16)``:
 
-1. the user cache file (``PIO_GRAM_AUTOTUNE_CACHE``, default
-   ``~/.cache/predictionio_tpu/gram_autotune.json``) — written by
-   ``record()`` whenever a measured race runs (bench.py's gram race,
-   ``benchmarks/gram_profile.py --record``);
+1. the file ``PIO_GRAM_AUTOTUNE_CACHE`` names, when that variable is
+   set — written by ``record()`` whenever a measured race runs
+   (bench.py's gram race, ``benchmarks/gram_profile.py --record``).
+   With the variable unset nothing outside the checkout is read and
+   ``record()`` writes nothing;
 2. the packaged defaults (``gram_autotune_defaults.json`` next to this
-   file) — the committed table measured on real hardware;
+   file) — the committed table;
 3. a hardware heuristic: on TPU, "pair" below rank 128 (two rank<128
    systems share one 128-wide MXU tile; a full-rank system doesn't),
    "einsum" otherwise and on every non-TPU backend.
@@ -36,11 +40,11 @@ _DEFAULTS_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _cache_mem: dict | None = None
 
 
-def _cache_path() -> str:
-    return os.environ.get(
-        "PIO_GRAM_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     "predictionio_tpu", "gram_autotune.json"))
+def _cache_path() -> str | None:
+    """The measured-race overlay file, or None: only an explicit
+    ``PIO_GRAM_AUTOTUNE_CACHE`` names one (state outside the checkout
+    would let one run change what another compiles)."""
+    return os.environ.get("PIO_GRAM_AUTOTUNE_CACHE") or None
 
 
 def device_family(kind: str | None = None) -> str:
@@ -85,13 +89,16 @@ def _load(path: str) -> dict:
 
 
 def _table() -> dict:
-    """defaults overlaid by the user cache (cache wins: it's measured on
+    """The committed defaults, overlaid by the measured-race file when
+    ``PIO_GRAM_AUTOTUNE_CACHE`` names one (it wins: it was measured on
     THIS machine)."""
     global _cache_mem
     with _LOCK:
         if _cache_mem is None:
             t = _load(_DEFAULTS_PATH)
-            t.update(_load(_cache_path()))
+            path = _cache_path()
+            if path:
+                t.update(_load(path))
             _cache_mem = t
         return dict(_cache_mem)
 
@@ -102,33 +109,17 @@ def _table() -> dict:
 MODES = ("einsum", "pair", "fused")
 
 
-def _fused_lowers() -> bool:
-    """Whether the fused Pallas kernel can actually lower on the
-    attached backend — a tuning table measured on one machine may name
-    "fused" on a host whose jax/Mosaic build can't compile it (or with
-    no accelerator at all); resolution must DEGRADE, never raise."""
-    try:
-        from .fused_gram import fused_gram_supported
-
-        return fused_gram_supported()
-    except Exception:  # noqa: BLE001 — probe failure = unsupported
-        return False
-
-
 def best_mode(rank: int, bf16: bool = False,
               device_kind: str | None = None) -> str:
-    """Concrete gram mode ("einsum" | "pair" | "fused") for
-    ``gram_mode="auto"``. A table entry naming "fused" is honored only
-    where the Pallas kernel lowers (:func:`_fused_lowers`); everywhere
-    else it falls back to the baseline einsum instead of raising —
-    the tuning table describes a *preference*, not a capability."""
+    """The gram mode ("einsum" | "pair" | "fused") the table names for
+    ``gram_mode="auto"``. A pure lookup: an entry naming "fused" is
+    returned as such, and the caller that knows the shapes about to
+    run (``models/als.py::_resolve_gram``) compiles the kernel there
+    and reports the compiler's message if it refuses."""
     fam = device_family(device_kind)
     ent = _table().get(_key(fam, rank, bf16))
     if isinstance(ent, dict) and ent.get("mode") in MODES:
-        mode = ent["mode"]
-        if mode == "fused" and not _fused_lowers():
-            return "einsum"
-        return mode
+        return ent["mode"]
     # heuristic: pair-packing helps exactly when two systems fit one
     # 128-wide MXU tile; CPUs/GPUs gain nothing from the extra flops
     if fam.startswith("TPU") and _rank_bucket(rank) < 128:
@@ -155,38 +146,18 @@ def _topk_key(family: str, rank: int, quant: str) -> str:
     return f"{family}|topk|r{_rank_bucket(rank)}|{quant}"
 
 
-def _topk_lowers() -> bool:
-    """Whether the fused serving kernel can lower on the attached
-    backend — like :func:`_fused_lowers`, resolution must DEGRADE to
-    the einsum lane, never raise mid-serve."""
-    try:
-        from .fused_topk import fused_topk_supported
-
-        return fused_topk_supported()
-    except Exception:  # noqa: BLE001 — probe failure = unsupported
-        return False
-
-
 def best_topk_mode(rank: int, quant: str = "f32",
                    device_kind: str | None = None) -> str:
-    """Concrete serving top-k mode ("einsum" | "fused") for the
-    batched lane, support-gated exactly like :func:`best_mode`: a
-    table entry naming "fused" is honored only where the Pallas kernel
-    lowers; everywhere else the einsum lane serves. The heuristic
-    (no table entry) prefers the fused kernel wherever it lowers — it
-    exists to beat the [B, I] HBM round trip — and einsum on every
-    backend without it."""
+    """The serving top-k mode ("einsum" | "fused") the table names for
+    the batched lane — a pure lookup like :func:`best_mode`. With no
+    entry the einsum lane serves: a kernel is chosen only by an entry
+    that names it."""
     if quant not in TOPK_QUANTS:
         quant = "f32"
     fam = device_family(device_kind)
     ent = _table().get(_topk_key(fam, rank, quant))
     if isinstance(ent, dict) and ent.get("mode") in TOPK_MODES:
-        mode = ent["mode"]
-        if mode == "fused" and not _topk_lowers():
-            return "einsum"
-        return mode
-    if fam.startswith("TPU") and _topk_lowers():
-        return "fused"
+        return ent["mode"]
     return "einsum"
 
 
@@ -212,11 +183,14 @@ def _persist(key: str, ent: dict) -> bool:
     measurement-source priority (shared by :func:`record` and
     :func:`record_topk`)."""
     path = _cache_path()
+    if not path:
+        return False  # nothing outside the checkout is written
     global _cache_mem
     prio = {"bench_race": 2, "serving_bench": 2, "gram_profile": 1}
     with _LOCK:
         try:
-            os.makedirs(os.path.dirname(path), exist_ok=True)
+            os.makedirs(os.path.dirname(os.path.abspath(path)),
+                        exist_ok=True)
             cur = _load(path)
             old = cur.get(key)
             if (isinstance(old, dict)

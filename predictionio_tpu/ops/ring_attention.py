@@ -48,19 +48,9 @@ def _ring_attention_local(q, k, v, kmask, *, axis_name: str,
 
     # the accumulators join a carry with device-varying k/v —
     # shard_map's varying-axis typing requires the whole carry to agree
-    # (pcast replaces the deprecated pvary; keep a fallback for older
-    # jax)
-    if hasattr(jax.lax, "pcast"):
-        def _vary(x):
-            return jax.lax.pcast(x, (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        def _vary(x):
-            return jax.lax.pvary(x, (axis_name,))
-    else:
-        def _vary(x):
-            # pre-varying-type jax (check_rep-era shard_map): there is
-            # no per-axis replication typing to satisfy — identity
-            return x
+    def _vary(x):
+        return jax.lax.pcast(x, (axis_name,), to="varying")
+
     m0 = _vary(jnp.full((B, H, S_loc), -jnp.inf, jnp.float32))
     l0 = _vary(jnp.zeros((B, H, S_loc), jnp.float32))
     acc0 = _vary(jnp.zeros((B, S_loc, H, D), jnp.float32))
@@ -165,15 +155,13 @@ def _compiled(mesh, axis: str, causal: bool, scale: float):
                     key_valid=key_valid)
             fn = jax.jit(nodist)
         else:
-            from ..parallel.collectives import shard_map_compat
-
             spec = P(None, axis, None, None)
             km_spec = P(None, axis)
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 functools.partial(_ring_attention_local, axis_name=axis,
                                   causal=causal, scale=scale),
-                mesh, in_specs=(spec, spec, spec, km_spec),
-                out_specs=spec))
+                mesh=mesh, in_specs=(spec, spec, spec, km_spec),
+                out_specs=spec, check_vma=False))
         _fn_cache[key] = fn
     return fn
 

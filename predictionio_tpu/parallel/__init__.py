@@ -5,7 +5,6 @@ from .collectives import (
     all_reduce_sum,
     reduce_scatter,
     ring_permute,
-    shard_map_compat,
     sharded,
     sharded_top_k,
 )
@@ -56,6 +55,5 @@ __all__ = [
     "replicated",
     "resolve_serving_mode",
     "rows_spec",
-    "shard_map_compat",
     "single_device_mesh",
 ]

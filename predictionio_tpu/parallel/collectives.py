@@ -23,21 +23,6 @@ from .mesh import MODEL_AXIS
 Axis = Union[str, Sequence[str]]
 
 
-def shard_map_compat(fn: Callable, mesh: Mesh, in_specs, out_specs,
-                     check: bool = False) -> Callable:
-    """``shard_map`` across jax versions: new jaxes expose
-    ``jax.shard_map(..., check_vma=)``, older ones only
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` — the
-    replication-check knob was renamed along the way."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check)
-
-
 def all_reduce_sum(x: jax.Array, axis: Axis = MODEL_AXIS) -> jax.Array:
     """``lax.psum`` — the Gramian/gradient all-reduce (NCCL allreduce
     role)."""
@@ -68,8 +53,8 @@ def gramian_allreduce(x: jax.Array, mesh: Mesh) -> jax.Array:
                                 preferred_element_type=jnp.float32),
             axes)
 
-    return shard_map_compat(part, mesh, in_specs=P(axes),
-                            out_specs=P(), check=False)(x)
+    return jax.shard_map(part, mesh=mesh, in_specs=P(axes),
+                         out_specs=P(), check_vma=False)(x)
 
 
 def all_gather(x: jax.Array, axis: Axis = MODEL_AXIS,
@@ -88,10 +73,7 @@ def ring_permute(x: jax.Array, axis: Axis = MODEL_AXIS,
     """Send each shard to its ring neighbor (``lax.ppermute``) — the
     building block for ring-structured algorithms (ring all-reduce,
     ring attention) on ICI."""
-    # psum of a python 1 folds to the static axis size on every jax
-    # this repo supports (lax.axis_size only exists on newer ones)
-    n = lax.psum(1, axis) if not hasattr(lax, "axis_size") \
-        else lax.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 
@@ -110,8 +92,8 @@ def sharded(mesh: Mesh, in_specs, out_specs,
     """
 
     def deco(fn):
-        return shard_map_compat(fn, mesh, in_specs, out_specs,
-                                check=check_vma)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check_vma)
 
     return deco
 
@@ -137,8 +119,8 @@ def sharded_top_k(scores: jax.Array, k: int, mesh: Mesh,
         mvals, mpos = lax.top_k(all_vals, k)
         return mpos, mvals, all_idx
 
-    fn = shard_map_compat(local_then_merge, mesh,
-                          in_specs=P(axis), out_specs=(P(), P(), P()),
-                          check=False)
+    fn = jax.shard_map(local_then_merge, mesh=mesh,
+                       in_specs=P(axis), out_specs=(P(), P(), P()),
+                       check_vma=False)
     mpos, mvals, all_idx = fn(scores)
     return jnp.take(all_idx, mpos), mvals

@@ -1,0 +1,314 @@
+"""CPU-side checks of the chip bring-up rules (PR 21): where the compile
+cache lives, that nothing stands in for a requested kernel on a TPU
+backend, that a failed warm-up fails the deploy, that ``chip_smoke.py``
+has no CPU mode, and that the autotuner keeps no state outside git."""
+
+import json
+import os
+import subprocess
+import sys
+import time
+from datetime import datetime, timezone
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.controller.context import Context
+from predictionio_tpu.data.storage.base import App
+from predictionio_tpu.data.storage.registry import Storage
+from predictionio_tpu.models.als import (
+    ALSModel,
+    ALSParams,
+    resolved_gram_mode,
+    set_serving_topk_mode,
+)
+from predictionio_tpu.ops import _probe, fused_gram, fused_topk
+from predictionio_tpu.ops import gram_autotune as ga
+from predictionio_tpu.utils import platform
+
+REPO = Path(__file__).resolve().parent.parent
+
+
+# -- compile cache placement -------------------------------------------------
+
+@pytest.fixture()
+def tpu_backend(monkeypatch):
+    """A process whose resolved backend is not the CPU, with every
+    ``jax.config.update`` recorded instead of applied."""
+    import jax
+
+    updates = {}
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(platform, "_cache_enabled", False)
+    return updates
+
+
+class TestCompileCachePlacement:
+    def test_variable_set_means_no_directory_set_in_code(
+            self, tpu_backend, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+        monkeypatch.setattr(platform, "COMPILE_CACHE_DIR",
+                            str(tmp_path / "in_checkout"))
+        platform.enable_compilation_cache()
+        assert "jax_compilation_cache_dir" not in tpu_backend
+        assert not (tmp_path / "in_checkout").exists()
+        # every compile is cached either way: a second run adds nothing
+        assert tpu_backend[
+            "jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+    def test_unset_means_the_fixed_in_checkout_directory(
+            self, tpu_backend, monkeypatch, tmp_path):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("PIO_HOME", str(tmp_path / "home"))
+        monkeypatch.setenv("PIO_COMPILE_CACHE", str(tmp_path / "old"))
+        monkeypatch.setattr(platform, "COMPILE_CACHE_DIR",
+                            str(tmp_path / "in_checkout"))
+        platform.enable_compilation_cache()
+        assert tpu_backend["jax_compilation_cache_dir"] \
+            == str(tmp_path / "in_checkout")
+        assert (tmp_path / "in_checkout").is_dir()
+        assert not (tmp_path / "home").exists()
+        assert not (tmp_path / "old").exists()  # PIO_COMPILE_CACHE is gone
+
+    def test_path_is_in_the_checkout_whatever_the_process_or_home(
+            self, tmp_path):
+        assert Path(platform.COMPILE_CACHE_DIR) == REPO / ".jax_cache"
+        seen = set()
+        for home in ("a", "b"):
+            env = dict(os.environ, PIO_HOME=str(tmp_path / home),
+                       HOME=str(tmp_path / home), PYTHONPATH=str(REPO))
+            env.pop("JAX_COMPILATION_CACHE_DIR", None)
+            seen.add(subprocess.run(
+                [sys.executable, "-c",
+                 "from predictionio_tpu.utils.platform import "
+                 "COMPILE_CACHE_DIR; print(COMPILE_CACHE_DIR)"],
+                env=env, cwd=str(tmp_path), capture_output=True,
+                text=True, check=True).stdout.strip())
+        assert seen == {str(REPO / ".jax_cache")}
+        ignored = (REPO / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored
+
+    def test_failure_to_enable_on_an_accelerator_is_an_error(
+            self, tpu_backend, monkeypatch, tmp_path):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        blocker = tmp_path / "file"
+        blocker.write_text("not a directory")
+        monkeypatch.setattr(platform, "COMPILE_CACHE_DIR",
+                            str(blocker / "cache"))
+        with pytest.raises(OSError):
+            platform.enable_compilation_cache()
+
+    def test_cpu_backend_is_left_alone(self, monkeypatch):
+        import jax
+
+        updates = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
+        monkeypatch.setattr(platform, "_cache_enabled", False)
+        platform.enable_compilation_cache()  # conftest pins the CPU
+        assert updates == {}
+
+
+# -- no stand-in for a kernel ------------------------------------------------
+
+@pytest.fixture()
+def fake_tpu_attach(monkeypatch):
+    """The kernel modules believe a TPU is attached; their compile
+    probes then really run the TPU lowering, which this CPU process
+    refuses — a stand-in for a kernel the chip's compiler refuses."""
+    monkeypatch.setattr(_probe, "tpu_attached", lambda: True)
+    fused_gram.reset_support_cache_for_tests()
+    fused_topk.reset_support_cache_for_tests()
+    yield
+    set_serving_topk_mode(None)
+    fused_gram.reset_support_cache_for_tests()
+    fused_topk.reset_support_cache_for_tests()
+
+
+def _server(cfg):
+    from predictionio_tpu.data.storage.base import (
+        STATUS_COMPLETED,
+        EngineInstance,
+    )
+    from predictionio_tpu.server.engineserver import QueryServer
+    from predictionio_tpu.templates.recommendation import (
+        default_engine_params,
+        recommendation_engine,
+    )
+
+    rng = np.random.default_rng(0)
+    from predictionio_tpu import BiMap
+
+    model = ALSModel(
+        user_factors=rng.normal(size=(12, 8)).astype(np.float32),
+        item_factors=rng.normal(size=(20, 8)).astype(np.float32),
+        n_users=12, n_items=20,
+        user_ids=BiMap({f"u{i}": i for i in range(12)}),
+        item_ids=BiMap({f"i{i}": i for i in range(20)}),
+        params=ALSParams(rank=8))
+    storage = Storage(env={"PIO_STORAGE_SOURCES_MEM_TYPE": "memory"})
+    storage.apps().insert(App(0, "bringup"))
+    ctx = Context(app_name="bringup", _storage=storage)
+    now = datetime.now(timezone.utc)
+    inst = EngineInstance(
+        id="b", status=STATUS_COMPLETED, start_time=now, end_time=now,
+        engine_id="b", engine_version="1", engine_variant="e.json",
+        engine_factory="f")
+    return QueryServer(ctx, recommendation_engine(),
+                       default_engine_params("bringup", rank=8), [model],
+                       inst, cfg)
+
+
+class TestNoStandInForAKernel:
+    def test_explicit_topk_request_fails_the_deploy(self, fake_tpu_attach):
+        from predictionio_tpu.server.engineserver import ServerConfig
+
+        with pytest.raises(RuntimeError) as err:
+            _server(ServerConfig(serving_topk="fused", warm_start=False))
+        msg = str(err.value)
+        assert "serving_topk='fused' does not compile" in msg
+        # the compiler's own words ride along
+        assert fused_topk.refusals()
+        assert next(iter(fused_topk.refusals().values())) in msg
+
+    def test_auto_skips_a_named_kernel_and_says_why(
+            self, fake_tpu_attach, monkeypatch, tmp_path):
+        from predictionio_tpu.server.engineserver import ServerConfig
+
+        table = tmp_path / "tune.json"
+        table.write_text(json.dumps(
+            {"cpu|topk|r32|f32": {"mode": "fused", "source": "test"}}))
+        monkeypatch.setenv("PIO_GRAM_AUTOTUNE_CACHE", str(table))
+        ga.reset_for_tests()
+        try:
+            server = _server(ServerConfig(warm_start=False))
+            kern = server.serving_kernel_status()
+            assert kern["mode"] == "einsum"
+            assert kern["configuredTopk"] == "auto"
+            assert kern["refused"]  # the compiler's message, by shape
+            rendered = server.metrics.render()
+            assert 'pio_serving_kernel{mode="einsum",quant="off"} 1' \
+                in rendered
+            assert 'mode="fused"' not in rendered
+        finally:
+            ga.reset_for_tests()
+
+    def test_dispatch_on_a_tpu_never_runs_the_reference(
+            self, fake_tpu_attach, monkeypatch):
+        import jax.numpy as jnp
+
+        def boom(*a, **k):
+            raise AssertionError("a reference stood in for the kernel")
+
+        monkeypatch.setattr(fused_topk, "fused_topk_reference", boom)
+        monkeypatch.setattr(fused_gram, "fused_gram_reference", boom)
+        tab = jnp.zeros((16, 8), jnp.float32)
+        with pytest.raises(Exception) as err:
+            fused_topk.fused_topk_dispatch(
+                tab, jnp.zeros((8,), jnp.int32), tab, k=4, n_items=16)
+        assert "reference stood in" not in str(err.value)
+
+    def test_explicit_gram_request_raises_and_gauge_stays_off_fused(
+            self, fake_tpu_attach):
+        from predictionio_tpu.server.engineserver import ServerConfig
+
+        with pytest.raises(RuntimeError, match="gram_mode='fused' does "
+                                               "not compile"):
+            resolved_gram_mode(ALSParams(rank=8, gram_mode="fused"))
+        server = _server(ServerConfig(warm_start=False))
+        server.algorithms[0].params = ALSParams(rank=8, gram_mode="fused")
+        server._record_gram_mode()
+        assert 'pio_gram_mode{mode="fused"} 1' \
+            not in server.metrics.render()
+
+    def test_auto_gram_skips_a_named_kernel_and_keeps_the_message(
+            self, fake_tpu_attach, monkeypatch, tmp_path):
+        table = tmp_path / "tune.json"
+        table.write_text(json.dumps(
+            {"cpu|r32|f32": {"mode": "fused", "source": "test"}}))
+        monkeypatch.setenv("PIO_GRAM_AUTOTUNE_CACHE", str(table))
+        ga.reset_for_tests()
+        try:
+            assert resolved_gram_mode(ALSParams(rank=8), (32, 64)) \
+                == "einsum"
+            # the first refusal decides; its message is kept by shape
+            assert set(fused_gram.refusals()) == {"r8/float32/L32"}
+        finally:
+            ga.reset_for_tests()
+
+
+class TestFailedWarmupFailsTheDeploy:
+    def test_warm_error_is_recorded_and_the_listener_stops(self):
+        from predictionio_tpu.server.engineserver import (
+            ServerConfig,
+            create_engine_server,
+        )
+
+        server = _server(ServerConfig(warm_start=False))
+
+        def broken(model, max_batch):
+            raise RuntimeError("shape does not compile")
+
+        server.algorithms[0].warm_serving = broken
+        server.warm_done.clear()
+        srv = create_engine_server(server, "127.0.0.1", 0)
+        srv.start_background()
+        server._warm_serving(server._warm_gen)
+        assert not server.warm_done.is_set()
+        assert "shape does not compile" in server.warm_error
+        assert "shape does not compile" in server._warm_report["error"]
+        deadline = time.monotonic() + 10
+        while srv._thread.is_alive() and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert not srv._thread.is_alive()  # the deploy is over
+
+
+# -- chip_smoke.py has no CPU mode -------------------------------------------
+
+def test_chip_smoke_fails_at_once_without_a_tpu():
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=str(REPO),
+        capture_output=True, text=True, timeout=60)
+    assert proc.returncode != 0
+    assert time.monotonic() - t0 < 20
+    assert "no TPU" in proc.stderr
+    assert '"ok"' not in proc.stdout  # no result line
+
+
+def test_chip_smoke_result_line_has_the_contract_keys_and_no_others():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", REPO / "chip_smoke.py")
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    line = smoke.result_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
+# -- nothing from outside git ------------------------------------------------
+
+def test_autotune_touches_nothing_under_home(monkeypatch, tmp_path):
+    monkeypatch.delenv("PIO_GRAM_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / ".cache"))
+    ga.reset_for_tests()
+    try:
+        assert ga.best_mode(64, device_kind="TPU v5 lite0") == "einsum"
+        assert ga.best_topk_mode(64, device_kind="TPU v5 lite0") \
+            == "einsum"
+        assert not ga.record(64, "pair", device_kind="TPU v5 lite0",
+                             measured={"source": "bench_race"})
+        assert not ga.record_topk(64, "einsum", "f32",
+                                  device_kind="TPU v5 lite0")
+        assert list(tmp_path.iterdir()) == []
+    finally:
+        ga.reset_for_tests()

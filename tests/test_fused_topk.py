@@ -28,12 +28,13 @@ from predictionio_tpu.models.als import (
     resolved_topk_mode,
     set_serving_topk_mode,
 )
+from predictionio_tpu.ops._probe import NO_TPU
 from predictionio_tpu.ops.fused_topk import (
     TOPK_MAX_K,
     fused_topk,
     fused_topk_dispatch,
     fused_topk_reference,
-    fused_topk_supported,
+    fused_topk_refusal,
     fused_topk_vmem_bytes,
 )
 
@@ -157,7 +158,8 @@ class TestKernelInterpret:
     def test_dispatch_runs_kernel_on_cpu(self):
         """No TPU attached → dispatch runs the interpret-mode kernel
         (the debugging contract), not the reference fallback."""
-        assert not fused_topk_supported()  # CPU host
+        assert fused_topk_refusal(5, 16, 64, 8) \
+            == NO_TPU  # CPU host
         U, V = make_tables()
         idx = np.arange(5, dtype=np.int32)
         s, i = fused_topk_dispatch(jnp.asarray(U), jnp.asarray(idx),
@@ -360,8 +362,9 @@ class TestStagedPipelineEndToEnd:
 
 
 class TestTopkAutotune:
-    """Satellite: the gram_autotune-style serving top-k mode table —
-    support-gated exactly like best_mode."""
+    """Satellite: the gram_autotune-style serving top-k mode table — a
+    pure lookup like best_mode; ``auto`` skips a named kernel that
+    cannot run at the dispatch shapes."""
 
     def test_fused_entry_falls_back_on_cpu(self, tmp_path, monkeypatch):
         from predictionio_tpu.ops import gram_autotune as ga
@@ -372,8 +375,9 @@ class TestTopkAutotune:
         monkeypatch.setenv("PIO_GRAM_AUTOTUNE_CACHE", str(cache))
         ga.reset_for_tests()
         try:
-            assert not fused_topk_supported()  # no TPU here
-            assert ga.best_topk_mode(64, device_kind="cpu") == "einsum"
+            assert ga.best_topk_mode(64, device_kind="cpu") == "fused"
+            assert resolved_topk_mode(64, "off", batch=8, n_rows=100,
+                                      k=8) == "einsum"  # no TPU here
         finally:
             ga.reset_for_tests()
 
@@ -409,19 +413,23 @@ class TestTopkAutotune:
         finally:
             ga.reset_for_tests()
 
-    def test_defaults_carry_fused_for_all_quants(self):
-        from predictionio_tpu.ops.gram_autotune import _DEFAULTS_PATH
+    def test_defaults_name_no_topk_kernel(self):
+        """fused_topk does not lower on the installed JAX at any shape
+        (``lax.top_k`` has no Pallas TPU lowering), so the committed
+        table carries no top-k entry and ``auto`` serves einsum."""
+        from predictionio_tpu.ops import gram_autotune as ga
 
-        table = json.loads(open(_DEFAULTS_PATH).read())
-        for r in (32, 64, 128):
-            for q in ("f32", "bf16", "int8"):
-                assert table[f"TPU v5 lite|topk|r{r}|{q}"]["mode"] \
-                    == "fused"
+        table = json.loads(open(ga._DEFAULTS_PATH).read())
+        assert not [k for k in table if "|topk|" in k]
+        for q in ("f32", "bf16", "int8"):
+            assert ga.best_topk_mode(
+                64, q, device_kind="TPU v5 lite0") == "einsum"
 
     def test_resolved_topk_mode_override_and_validation(self):
+        shapes = dict(batch=8, n_rows=100, k=8)
         set_serving_topk_mode("fused")
-        assert resolved_topk_mode(64, "int8") == "fused"
+        assert resolved_topk_mode(64, "int8", **shapes) == "fused"
         set_serving_topk_mode("auto")
-        assert resolved_topk_mode(64, "off") == "einsum"  # CPU host
+        assert resolved_topk_mode(64, "off", **shapes) == "einsum"
         with pytest.raises(ValueError, match="serving topk"):
             set_serving_topk_mode("fusion")
