@@ -750,11 +750,10 @@ def build_fleet_app(agg: FleetAggregator) -> HTTPApp:
     MERGED registry (a fleet aggregator is itself scrapeable — fleets
     of fleets compose), plus the fleet-only routes."""
     app = HTTPApp(name="fleet")
-    # runtime=False: pio_build_info / HBM / span collectors describe
-    # ONE process — the aggregator's own would shadow nothing useful,
-    # and the merged pio_span_seconds from replicas must stay the only
-    # source of that family. tracer=False: the aggregator's requests
-    # are not the traffic worth flight-recording.
+    # runtime=False: pio_build_info / HBM collectors describe ONE
+    # process — the aggregator's own would shadow nothing useful.
+    # tracer=False: the aggregator's requests are not the traffic
+    # worth flight-recording.
     mount_metrics(app, agg.registry, server_name="fleet",
                   status=agg.fleet_status, runtime=False, tracer=False)
     _auth = make_key_auth(agg.config.accesskey)

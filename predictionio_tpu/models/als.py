@@ -1825,26 +1825,32 @@ def _serve_topk(user_factors, item_factors, idx: jax.Array, *, k: int,
     kernel (``ops/fused_topk.py``) is held exact against."""
     ud, us = _table_leaves(user_factors)
     vd, vs = _table_leaves(item_factors)
-    # ptpu: allow[materialized-gather] — a [B, r] serving row fetch
-    # (no history axis): bounded by the micro-batcher's pow2 batch cap
-    vecs = ud[idx]
-    if vecs.dtype != jnp.float32:
-        vecs = vecs.astype(jnp.float32)
-    if us is not None:
-        # ptpu: allow[materialized-gather] — [B]-bounded scale fetch
-        vecs = vecs * us.reshape(-1)[idx][:, None]
-    if vd.dtype != jnp.float32:
-        vd = vd.astype(jnp.float32)
-    scores = vecs @ vd.T
-    if vs is not None:
-        # per-row item scales factor out of the dot: score[b,i] =
-        # (vec·q_i)·s_i — applied to the [B, n_pad] product, never as
-        # a dequantized f32 copy of the table
-        scores = scores * vs.reshape(1, -1)
-    n_pad = vd.shape[0]
-    mask = jnp.arange(n_pad) < n_items
-    scores = jnp.where(mask[None, :], scores, -jnp.inf)
-    return jax.lax.top_k(scores, k)
+    # the three scopes are metadata only (each operation's op_name in
+    # a profile: docs/tracing.md); the compiled work is unchanged
+    with jax.named_scope("pio_gather"):
+        # ptpu: allow[materialized-gather] — a [B, r] serving row fetch
+        # (no history axis): bounded by the micro-batcher's pow2 batch
+        # cap
+        vecs = ud[idx]
+        if vecs.dtype != jnp.float32:
+            vecs = vecs.astype(jnp.float32)
+        if us is not None:
+            # ptpu: allow[materialized-gather] — [B]-bounded scale fetch
+            vecs = vecs * us.reshape(-1)[idx][:, None]
+    with jax.named_scope("pio_score"):
+        if vd.dtype != jnp.float32:
+            vd = vd.astype(jnp.float32)
+        scores = vecs @ vd.T
+        if vs is not None:
+            # per-row item scales factor out of the dot: score[b,i] =
+            # (vec·q_i)·s_i — applied to the [B, n_pad] product, never
+            # as a dequantized f32 copy of the table
+            scores = scores * vs.reshape(1, -1)
+        n_pad = vd.shape[0]
+        mask = jnp.arange(n_pad) < n_items
+        scores = jnp.where(mask[None, :], scores, -jnp.inf)
+    with jax.named_scope("pio_select"):
+        return jax.lax.top_k(scores, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_items"))

@@ -38,6 +38,7 @@ from .trace import (
     activate_traces,
     add_stage_spans,
     mark_active_traces,
+    stage_span,
 )
 
 __all__ = [
@@ -62,38 +63,11 @@ __all__ = [
     "linear_bounds",
     "mark_active_traces",
     "mount_hot_key_metrics",
-    "mount_span_metrics",
     "process_stats",
     "register_process_metrics",
     "register_runtime_metrics",
     "render_histogram_lines",
+    "stage_span",
     "window_quantile",
 ]
 
-
-def mount_span_metrics(reg: MetricsRegistry, span_registry=None,
-                       metric_name: str = "pio_span_seconds") -> None:
-    """Expose a :class:`..utils.tracing.SpanRegistry`'s bounded
-    histograms as one labeled histogram family on ``reg`` (collector:
-    spans are recorded outside the registry's family machinery)."""
-    from ..utils.tracing import spans as default_spans
-
-    sr = span_registry if span_registry is not None else default_spans
-    mounted = getattr(reg, "_span_registries", None)
-    if mounted is None:
-        mounted = reg._span_registries = set()  # type: ignore[attr-defined]
-    if id(sr) in mounted:  # idempotent: no duplicate series on remount
-        return
-    mounted.add(id(sr))
-
-    def collect():
-        lines = [f"# HELP {metric_name} Wall-clock spans recorded via "
-                 f"utils.tracing.timed(name)",
-                 f"# TYPE {metric_name} histogram"]
-        for name, hist in sorted(sr.histograms().items()):
-            items = (("span", name),)
-            lines.extend(render_histogram_lines(metric_name, items,
-                                                hist))
-        return lines if len(lines) > 2 else []
-
-    reg.register_collector(collect)

@@ -1,9 +1,9 @@
 """Streaming fixed-bucket histograms: O(1) record, bounded memory.
 
-The round-1 ``SpanRegistry`` kept every observation in a raw per-name
-list — unbounded memory on a server that lives for weeks, and no
-percentiles without a sort over the whole history. A fixed-log-bucket
-histogram replaces it: ``record`` is one bisect plus one increment,
+A raw list of observations per series is unbounded memory on a server
+that lives for weeks, and gives no percentile without a sort over the
+whole history. A fixed-log-bucket histogram replaces it: ``record`` is
+one bisect plus one increment,
 memory is ``len(bounds) + 1`` integers forever, and p50/p90/p99/max are
 derivable at read time by linear interpolation inside the target bucket
 (the same estimator Prometheus' ``histogram_quantile`` applies to the

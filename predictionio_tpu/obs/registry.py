@@ -72,7 +72,7 @@ def render_histogram_lines(name: str, items: LabelItems,
                            hist: StreamingHistogram,
                            openmetrics: bool = False) -> List[str]:
     """One labeled histogram child → its ``_bucket``/``_sum``/``_count``
-    exposition lines (shared by the registry and the span collector).
+    exposition lines.
     Under OpenMetrics, buckets carrying an exemplar (last retained
     trace id per bucket) render it as ``# {trace_id="…"} value ts`` —
     the grammar Prometheus scrapes exemplars from (exemplars are
@@ -102,13 +102,16 @@ def _labels_key(labels: Dict[str, str]) -> LabelItems:
 
 
 class Counter:
-    """Monotonic counter child."""
+    """Monotonic counter child: ``inc()`` it, or back it with a
+    callable whose owner does the accumulating (a total the owner
+    already keeps under its own lock, read at scrape time)."""
 
-    __slots__ = ("_value", "_lock")
+    __slots__ = ("_value", "_lock", "_fn")
 
     def __init__(self) -> None:
         self._value = 0.0
         self._lock = threading.Lock()
+        self._fn: Optional[Callable[[], float]] = None
 
     def inc(self, amount: float = 1.0) -> None:
         if amount < 0:
@@ -116,8 +119,16 @@ class Counter:
         with self._lock:
             self._value += amount
 
+    def set_fn(self, fn: Callable[[], float]) -> None:
+        self._fn = fn
+
     @property
     def value(self) -> float:
+        if self._fn is not None:
+            try:
+                return float(self._fn())
+            except Exception:  # noqa: BLE001 — as a broken gauge: 0,
+                return 0.0     # never a broken scrape
         with self._lock:
             return self._value
 
@@ -325,8 +336,8 @@ class MetricsRegistry:
 
     def register_collector(
             self, fn: Callable[[], Iterable[str]]) -> None:
-        """Append raw (already escaped) exposition lines at render time —
-        the hook the span-registry bridge uses."""
+        """Append raw (already escaped) exposition lines at render time
+        (build info, per-device HBM, hot keys, lock metrics)."""
         with self._lock:
             self._collectors.append(fn)
 

@@ -11,7 +11,9 @@ profiling-driven triage dominate aggregate dashboards):
   W3C ``traceparent`` and hands back a :class:`Trace` that handlers and
   pipeline stages append :class:`Span` rows to. Cost per request is a
   handful of small allocations — no I/O, no locks on the span path
-  beyond one list append.
+  beyond one list append. The hot path does not even build its rows:
+  :meth:`Trace.defer` keeps the stamps, and the rows are built from
+  them only if the trace is retained.
 - **Almost every trace is dropped.** :meth:`Tracer.finish` applies the
   tail-sampling policy: a trace is retained only when it was *slow*
   (adaptive threshold riding the live p99 of the tracer's own duration
@@ -25,13 +27,19 @@ profiling-driven triage dominate aggregate dashboards):
   stage timeline (queue_wait → assemble → supplement → dispatch →
   device_wait → readback → serve).
 
-Batch-stage spans are *reconstructed* timelines: the pipeline records
-per-stage durations plus a few wall anchors (enqueue, pickup,
-dispatch), and :func:`add_stage_spans` lays the stages out
-sequentially from each anchor. Stages really do run sequentially
-within a stage-thread, so the reconstruction is faithful to within the
-inter-stage queue hops (which appear as gaps — exactly what you want
-to see).
+The staged pipeline's batch spans are the batch's own stamps
+(``server/engineserver.py::_AssembledBatch``): each stage's end is the
+next one's start, so the hand-off waits between stage threads are
+spans of their own (``dispatch_q``, ``readback_q``), not gaps. The
+serial paths (``query``, ``query_batch``) run their stages back to
+back on one thread and still lay durations out from one anchor with
+:func:`add_stage_spans`.
+
+The same sites open a :func:`stage_span`, a
+``jax.profiler.TraceAnnotation`` named ``pio:<stage>``: whenever a
+profile is being captured the program's stages lie on the host plane
+of the same trace as the device's operations, on the profiler's clock
+(docs/tracing.md).
 
 On-demand device profiling rides along: :class:`DeviceProfiler` wraps
 ``jax.profiler`` start/stop for a bounded window into a served
@@ -49,6 +57,7 @@ import time
 from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from ..utils.tracing import annotate
 from .histogram import StreamingHistogram
 
 __all__ = [
@@ -58,6 +67,7 @@ __all__ = [
     "Tracer",
     "DeviceProfiler",
     "add_stage_spans",
+    "stage_span",
     "activate_traces",
     "mark_active_traces",
     "parse_traceparent",
@@ -133,7 +143,7 @@ class Trace:
     __slots__ = ("trace_id", "name", "root_span_id", "parent_span_id",
                  "request_id", "t_mono", "t_wall", "t_end", "status",
                  "marks", "attrs", "spans", "pending_exemplars",
-                 "retained_reason", "_lock")
+                 "retained_reason", "_lock", "_deferred")
 
     def __init__(self, name: str, trace_id: Optional[str] = None,
                  parent_span_id: Optional[str] = None,
@@ -157,6 +167,7 @@ class Trace:
         #: that ``/trace.json?id=`` can actually serve
         self.pending_exemplars: List[Tuple[Any, float]] = []
         self._lock = threading.Lock()
+        self._deferred: List[Tuple[Any, tuple]] = []
 
     # -- span recording ----------------------------------------------------
     def add_span(self, name: str, t_start: float, t_end: float,
@@ -173,6 +184,19 @@ class Trace:
     def span(self, name: str, **attrs: Any):
         """Context manager recording a span around a block."""
         return _SpanCtx(self, name, attrs)
+
+    def defer(self, fn: Any, *args: Any) -> None:
+        """Keep what ``fn(trace, *args)`` would record (spans,
+        attributes) until the trace is retained. Almost every trace is
+        dropped, so a hot path hands over its stamps here and almost
+        never pays for the rows; :meth:`Tracer.finish` runs the
+        deferred calls once for a trace it keeps."""
+        self._deferred.append((fn, args))
+
+    def materialize(self) -> None:
+        deferred, self._deferred = self._deferred, []
+        for fn, args in deferred:
+            fn(self, *args)
 
     def mark(self, reason: str) -> None:
         """Flag the trace for retention (``fault``, ``stream``, …)."""
@@ -301,6 +325,17 @@ def add_stage_spans(trace: Optional[Trace], anchor: float,
             continue
         trace.add_span(name, t, t + dur, parent_id=parent_id, **attrs)
         t += dur
+
+
+def stage_span(stage: str, **stats: Any):
+    """A ``pio:<stage>`` annotation on the profiler's host plane, with
+    ``batch=<seq>`` and ``n=<queries>`` as its stats. A host line of
+    the trace is an OS thread, which Python's thread names do not
+    reach, so the name and ``batch`` carry the identity. A blocking
+    wait learns its batch only when it ends: enter with no stats and
+    ``set_metadata(batch=..., n=...)`` before leaving. Inert with no
+    capture running."""
+    return annotate("pio:" + stage, **stats)
 
 
 # -- thread-local activation (fault attribution) ---------------------------
@@ -490,6 +525,7 @@ class Tracer:
         if reason is None:
             return False, None
         trace.retained_reason = reason
+        trace.materialize()
         self.recorder.add(trace)
         with self._count_lock:
             self._retained[reason] = self._retained.get(reason, 0) + 1

@@ -18,13 +18,14 @@ hot path never recompiles.
 from __future__ import annotations
 
 import html
+import itertools
 import json
 import logging
 import secrets
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, List, Optional
+from typing import Any, Callable, List, NamedTuple, Optional
 
 from ..concurrency import (
     instrument_locks,
@@ -49,10 +50,12 @@ from ..obs import (
     hbm_stats,
 )
 from ..obs import numerics as numerics_sentinel
+from ..obs.overlap import STATES
 from ..obs.trace import (
     activate_traces,
     add_stage_spans,
     mark_active_traces,
+    stage_span,
 )
 from ..rollout.registry import ReleaseRegistry
 from ..rollout.splitter import ARM_CANDIDATE, ARM_STABLE
@@ -413,21 +416,29 @@ class QueryServer:
         self.metrics = MetricsRegistry()
         self._phase_hist = self.metrics.histogram(
             "pio_query_phase_seconds",
-            "Per-phase query-path wall time (queue_wait, assemble, "
-            "supplement, dispatch, serve, readback, feedback)",
+            "Per-phase query-path wall time. A staged query's "
+            "residence in pipeline order: admit, queue_wait, assemble, "
+            "supplement, dispatch_q, dispatch, readback_q, device_wait, "
+            "serve, finish (of which readback and feedback are parts), "
+            "wake, respond",
             bounds=DEFAULT_LATENCY_BOUNDS)
+        #: bound children by phase: ``labels()`` validates and sorts
+        #: its keywords under a lock on every call
+        self._phase_children: dict = {}
+        # the three below have one, unlabeled child each: bound here,
+        # for a family's own observe() looks its child up under a lock
         self._latency_hist = self.metrics.histogram(
             "pio_query_latency_seconds",
             "End-to-end serving wall time per query",
-            bounds=DEFAULT_LATENCY_BOUNDS)
+            bounds=DEFAULT_LATENCY_BOUNDS).labels()
         self._batch_occupancy = self.metrics.histogram(
             "pio_batch_occupancy",
             "Queries coalesced per micro-batch dispatch",
-            bounds=POW2_COUNT_BOUNDS)
+            bounds=POW2_COUNT_BOUNDS).labels()
         self._queue_depth = self.metrics.histogram(
             "pio_queue_depth",
             "Batcher queue depth observed at each batch pickup",
-            bounds=POW2_COUNT_BOUNDS)
+            bounds=POW2_COUNT_BOUNDS).labels()
         self._query_errors = self.metrics.counter(
             "pio_query_errors_total", "Failed queries by status class")
         # staged serving pipeline series (ISSUE 9,
@@ -455,6 +466,17 @@ class QueryServer:
             "Batch launches that found an earlier batch still in "
             "flight on the device — direct evidence of stage overlap")
         self.overlap = OverlapTracker()
+        state_seconds = self.metrics.counter(
+            "pio_pipeline_state_seconds_total",
+            "The starvation clock: wall seconds since the first batch, "
+            "each in exactly ONE state, the first that holds a batch: "
+            "enqueued (queued on the device, results not back) > "
+            "launching (a dispatch call in progress) > staged (waits "
+            "for the dispatch thread) > assembling (parse + supplement) "
+            "> empty (no batch anywhere in the pipeline)")
+        for state in STATES:
+            state_seconds.labels(state=state).set_fn(
+                lambda state=state: self.overlap.state_seconds(state))
         self.metrics.gauge(
             "pio_pipeline_device_idle_fraction",
             "Fraction of wall time (since first batch) with NO batch "
@@ -1346,9 +1368,17 @@ class QueryServer:
                         "back: %s", e)
         return self._predict_all(algorithms, models, supplemented)
 
+    def _phase(self, phase: str):
+        """The bound ``pio_query_phase_seconds`` child of ``phase``."""
+        child = self._phase_children.get(phase)
+        if child is None:
+            child = self._phase_children[phase] = \
+                self._phase_hist.labels(phase=phase)
+        return child
+
     def _record_phases(self, phases: dict) -> None:
         for phase, sec in phases.items():
-            self._phase_hist.labels(phase=phase).observe(sec)
+            self._phase(phase).observe(sec)
 
     def _observe_release(self, arm: str, seconds: float,
                          error: bool) -> None:
@@ -1565,10 +1595,9 @@ class QueryServer:
             r = row(child)
             if r is not None:
                 out["phase:" + dict(items).get("phase", "?")] = r
-        for items, child in self._latency_hist.children():
-            r = row(child)
-            if r is not None:
-                out["query (end-to-end)"] = r
+        r = row(self._latency_hist)
+        if r is not None:
+            out["query (end-to-end)"] = r
         return out
 
     # -- cached serving entrypoints (ISSUE 4) --------------------------------
@@ -1598,7 +1627,7 @@ class QueryServer:
                 tr.set_attr("arm", arm)
                 tr.set_attr("cacheTier", "query")
                 tr.add_span("cache_hit", t0, t0 + dt, tier="query")
-                tr.exemplar(self._latency_hist.labels(), dt)
+                tr.exemplar(self._latency_hist, dt)
         with self._lock:
             self.last_serving_sec = dt
             self.avg_serving_sec = (
@@ -1813,7 +1842,7 @@ class QueryServer:
                 add_stage_spans(tr, t0, phases,
                                 parent_id=parent.span_id,
                                 skip=("queue_wait",))
-                tr.exemplar(self._latency_hist.labels(), dt)
+                tr.exemplar(self._latency_hist, dt)
             if obs_list is not None and i < len(obs_list) \
                     and obs_list[i] is not None:
                 obs_list[i].update(batch_obs)
@@ -1835,10 +1864,10 @@ class QueryServer:
         feedback, output plugins, metric recording, caller wake. The
         staged twin of :meth:`query_batch`'s post-dispatch section;
         ``results`` is the resolved :class:`PendingBatch` output,
-        aligned with ``ab.entries``."""
+        aligned with ``ab.entries``. Stamps ``ab.t_done`` and derives
+        everything it records from the batch's stamps."""
         cfg = self.config
-        phases = ab.phases
-        per_query_ms: List[dict] = [{} for _ in ab.entries]
+        readback = feedback = None
         final: List[Any] = [None] * len(ab.entries)
         for i, (entry, result) in enumerate(zip(ab.entries, results)):
             if isinstance(result, HTTPError):
@@ -1850,33 +1879,35 @@ class QueryServer:
             try:
                 tr0 = time.monotonic()
                 jsonable = to_jsonable(result)
-                tr1 = time.monotonic()
+                tr1 = tf = time.monotonic()
                 # max-not-sum: the batch phase reports the worst
                 # query's serialization (see query_batch)
-                phases["readback"] = max(phases.get("readback", 0.0),
-                                         tr1 - tr0)
-                per_query_ms[i]["readbackMs"] = round(
-                    (tr1 - tr0) * 1000, 3)
+                readback = max(readback or 0.0, tr1 - tr0)
                 if cfg.feedback:
                     jsonable = self._feedback(
                         ab.queries[i], entry.query_json, jsonable,
                         ab.instance_id)
-                    tf = time.monotonic() - tr1
-                    phases["feedback"] = (phases.get("feedback", 0.0)
-                                          + tf)
-                    per_query_ms[i]["feedbackMs"] = round(tf * 1000, 3)
+                    tf = time.monotonic()
+                    feedback = (feedback or 0.0) + (tf - tr1)
+                entry.own = (tr0, tr1, tf)
                 final[i] = self.plugins.process_output(entry.query_json,
                                                        jsonable)
             except Exception as e:  # noqa: BLE001 — per-query slot
                 final[i] = HTTPError(500, str(e))
-        now = time.monotonic()
+        ab.t_done = now = time.monotonic()
+        timeline = ab.timeline()
+        phases = {name: t1 - t0 for name, t0, t1 in timeline.spans}
+        # parts of ``finish``, as documented
+        if readback is not None:
+            phases["readback"] = readback
+        if feedback is not None:
+            phases["feedback"] = feedback
         self._record_phases(phases)
         self._batch_occupancy.observe(len(ab.entries))
-        if ab.lane is not None and ab.t_dispatched is not None:
+        if ab.lane is not None:
             self._lane_latency.labels(lane=str(ab.lane)).observe(
-                now - ab.t_dispatched)
+                now - ab.t_dispatch_pick)
             self._lane_dispatches.labels(lane=str(ab.lane)).inc()
-        self._trace_pipeline_batch(ab, now)
         batch_obs = {"batchSize": len(ab.entries), "pipeline": "staged"}
         if ab.lane is not None:
             batch_obs["lane"] = ab.lane
@@ -1898,7 +1929,15 @@ class QueryServer:
                     status=str(result.status)).inc()
             if entry.obs is not None:
                 entry.obs.update(batch_obs)
-                entry.obs.update(per_query_ms[i])
+                if entry.own is not None:
+                    tr0, tr1, tf = entry.own
+                    entry.obs["readbackMs"] = round(
+                        (tr1 - tr0) * 1000, 3)
+                    if cfg.feedback:
+                        entry.obs["feedbackMs"] = round(
+                            (tf - tr1) * 1000, 3)
+            entry.t_done = now
+            entry.batch = timeline
             entry.slot[0] = result
             entry.done.set()
         n_q = len(ab.entries)
@@ -1910,52 +1949,29 @@ class QueryServer:
                                          + total_dt) / (n + n_q))
                 self.request_count += n_q
 
-    def _trace_pipeline_batch(self, ab: "_AssembledBatch",
-                              now: float) -> None:
-        """Reconstruct the staged-pipeline timeline onto every traced
-        query of the batch (ISSUE 12): a ``batch`` parent span plus
-        stage children — ``queue_wait`` from each entry's own enqueue
-        time, host stages (assemble/supplement) from the pickup, and
-        device stages (dispatch/device_wait/serve/readback/feedback)
-        anchored at the REAL dispatch time, so the inter-stage queue
-        hops show up as gaps on the Perfetto timeline instead of being
-        smeared into the stages."""
-        if self.tracer is None:
+    def _stamp_wake(self, e: "_Submit", obs: Optional[dict]) -> None:
+        """The handler thread runs again after its staged query: stamp
+        ``t_wake``, observe ``wake`` (and ``admit``, which needs the
+        request's ``t_enter``), copy the query's stamps onto the
+        request's record for the HTTP layer's closing stamps, and hand
+        the stamps to the query's trace. A serial drainer's or a shed
+        query's entry has no ``t_done``: nothing to derive."""
+        if e.t_done is None:
             return
-        phases = ab.phases
-        host = {k: phases[k] for k in ("assemble", "supplement")
-                if k in phases}
-        device = {k: phases[k]
-                  for k in ("dispatch", "device_wait", "serve",
-                            "readback", "feedback") if k in phases}
-        for entry in ab.entries:
-            tr = self._trace_of(entry.obs)
-            if tr is None:
-                continue
-            tr.set_attr("engineInstanceId", ab.instance_id)
-            tr.set_attr("arm", ARM_STABLE)
-            tr.set_attr("pipeline", "staged")
-            if ab.lane is not None:
-                tr.set_attr("lane", ab.lane)
-            wait = ((entry.obs or {}).get("queueWaitMs", 0.0)) / 1000.0
-            t_pick = entry.t_enq + wait
-            parent = tr.add_span(
-                "batch", t_pick, now, batchSize=len(ab.entries),
-                **({"lane": ab.lane} if ab.lane is not None else {}))
-            if wait > 0:
-                tr.add_span("queue_wait", entry.t_enq, t_pick,
-                            parent_id=parent.span_id)
-            add_stage_spans(tr, t_pick, host,
-                            order=("assemble", "supplement"),
-                            parent_id=parent.span_id)
-            add_stage_spans(
-                tr, ab.t_dispatched if ab.t_dispatched is not None
-                else t_pick, device,
-                order=("dispatch", "device_wait", "serve", "readback",
-                       "feedback"),
-                parent_id=parent.span_id)
-            tr.exemplar(self._latency_hist.labels(),
-                        now - entry.t_enq)
+        t_wake = time.monotonic()
+        self._phase("wake").observe(t_wake - e.t_done)
+        st = obs.get("_stamps") if obs is not None else None
+        t_enter = None
+        if st is not None and st.t_enter is not None:
+            t_enter = st.t_enter
+            st.t_enq, st.t_done, st.t_wake = e.t_enq, e.t_done, t_wake
+            st.batch = e.batch
+            self._phase("admit").observe(e.t_enq - t_enter)
+        tr = self._trace_of(obs)
+        if tr is not None and e.batch is not None:
+            tr.defer(_staged_spans, e.batch, t_enter, e.t_enq, e.own,
+                     t_wake)
+            tr.exemplar(self._latency_hist, e.t_done - e.t_enq)
 
     def pipeline_status(self) -> dict:
         """Serving batch-path state for ``/status.json`` and the status
@@ -2056,7 +2072,7 @@ class QueryServer:
             # back-to-back on this thread, so the sequential layout
             # from t0 IS the real timeline
             add_stage_spans(trace, t0, phases)
-            trace.exemplar(self._latency_hist.labels(), dt)
+            trace.exemplar(self._latency_hist, dt)
         if obs is not None:
             obs.update({f"{k}Ms": round(v * 1000, 3)
                         for k, v in phases.items()})
@@ -3186,6 +3202,16 @@ def build_app(server: QueryServer) -> HTTPApp:
                           else False))
     app.access_log_sample = cfg.access_log_sample
 
+    def _respond_phase(req: Request) -> None:
+        # the last hole of a staged query's residence: the handler
+        # woke -> handle() returned (response built, route metrics,
+        # trace finish, access log)
+        st = req.stamps
+        if st.t_wake is not None:
+            server._phase("respond").observe(st.t_return - st.t_wake)
+
+    app.on_sent = _respond_phase
+
     app_server_ref: List[AppServer] = []
     app._server_ref = app_server_ref  # type: ignore[attr-defined]
     return app
@@ -3198,8 +3224,8 @@ class _Submit:
     when the submitter's deadline expired — later stages skip the
     corpse instead of doing device work nobody will read."""
 
-    __slots__ = ("query_json", "done", "slot", "t_enq", "deadline",
-                 "obs", "abandoned")
+    __slots__ = ("query_json", "done", "slot", "t_enq", "t_done",
+                 "batch", "own", "deadline", "obs", "abandoned")
 
     def __init__(self, query_json: Any, obs: Optional[dict],
                  deadline_sec: float):
@@ -3207,6 +3233,13 @@ class _Submit:
         self.done = threading.Event()
         self.slot: List[Any] = [None]
         self.t_enq = time.monotonic()
+        #: stamped by the staged pipeline where it completes the entry,
+        #: with the timeline of the batch that carried it and the
+        #: query's own ``(start, serialized, fed back)`` inside its
+        #: ``finish``
+        self.t_done: Optional[float] = None
+        self.batch: Optional["BatchTimeline"] = None
+        self.own: Optional[tuple] = None
         self.deadline = (self.t_enq + deadline_sec) if deadline_sec > 0 \
             else None
         self.obs = obs
@@ -3229,8 +3262,10 @@ def _deadline_submit(batcher, server: QueryServer, query_json: Any,
     batcher._q.put(e)
     if e.deadline is None:
         e.done.wait()
+        server._stamp_wake(e, obs)
         return e.slot[0]
     if e.done.wait(timeout=batcher.deadline_sec):
+        server._stamp_wake(e, obs)
         return e.slot[0]
     e.abandoned = True
     server._deadline_exceeded.inc()
@@ -3370,7 +3405,7 @@ class MicroBatcher:
             if not batch:
                 continue
             t_pick = time.monotonic()
-            phase = self.server._phase_hist.labels(phase="queue_wait")
+            phase = self.server._phase("queue_wait")
             obs_list: List[Optional[dict]] = []
             for e in batch:
                 wait = t_pick - e.t_enq
@@ -3409,20 +3444,90 @@ class MicroBatcher:
                 e.done.set()
 
 
+#: process-wide batch sequence number: names a batch on the profiler's
+#: host plane (``pio:<stage>`` annotations) and in the flight recorder
+_batch_seq = itertools.count(1)
+
+
+class BatchTimeline(NamedTuple):
+    """A finished batch's stamps as its queries carry them away: the
+    batch's number and size, and ``(phase, start, end)`` of its eight
+    consecutive phases from ``t_pick`` to ``t_done``. With a query's
+    own ``queue_wait`` before them they cover its whole stay in the
+    pipeline. Floats only: a query's record holds no reference back
+    into the batch."""
+
+    seq: int
+    n: int
+    lane: Optional[int]
+    instance_id: str
+    spans: tuple
+
+
+def _staged_spans(tr, timeline: BatchTimeline, t_enter: Optional[float],
+                  t_enq: float, own: Optional[tuple],
+                  t_wake: float) -> None:
+    """A staged query's stamps as the rows of its RETAINED trace
+    (ISSUE 12, ISSUE 24; deferred: :meth:`Trace.defer`): ``admit``,
+    then a ``batch`` parent (pickup → finish) with ``queue_wait`` from
+    the query's own enqueue and the batch's eight consecutive phases as
+    children — the hand-off waits between the stage threads are spans
+    (``dispatch_q``, ``readback_q``), and the query's own
+    ``readback``/``feedback`` (``own``) sit inside ``finish`` where
+    they ran — then ``wake``. Nothing is reconstructed from
+    durations."""
+    spans = timeline.spans
+    t_pick, t_done = spans[0][1], spans[-1][2]
+    tr.set_attr("engineInstanceId", timeline.instance_id)
+    tr.set_attr("arm", ARM_STABLE)
+    tr.set_attr("pipeline", "staged")
+    lane = {} if timeline.lane is None else {"lane": timeline.lane}
+    tr.attrs.update(lane)
+    if t_enter is not None:
+        tr.add_span("admit", t_enter, t_enq)
+    pid = tr.add_span("batch", t_pick, t_done, batch=timeline.seq,
+                      batchSize=timeline.n, **lane).span_id
+    tr.add_span("queue_wait", t_enq, t_pick, parent_id=pid)
+    for name, t0, t1 in spans:
+        tr.add_span(name, t0, t1, parent_id=pid)
+    if own is not None:
+        tr0, tr1, tf = own
+        tr.add_span("readback", tr0, tr1, parent_id=pid)
+        if tf > tr1:
+            tr.add_span("feedback", tr1, tf, parent_id=pid)
+    tr.add_span("wake", t_done, t_wake)
+
+
 class _AssembledBatch:
     """A batch between pipeline stages: the parse/supplement output
     plus the binding SNAPSHOT it was assembled against. Every stage
     uses the carried snapshot — a reload/promote mid-flight serves
-    either the old or the new binding in full, never a mix."""
+    either the old or the new binding in full, never a mix.
+
+    It is also the batch's STAMP RECORD (ISSUE 24,
+    docs/serving-pipeline.md): nine ``time.monotonic`` stamps taken
+    where the work happens, each stage's end being the next one's
+    start. Every per-batch series, the tracker's transitions and the
+    flight recorder's spans are read off this record; no stage reads
+    the clock a second time for the same instant.
+
+    ``t_pick`` (the batch is formed) → ``t_parsed`` → ``t_assembled``
+    (supplemented; handed to ``_dispatch_q``) → ``t_dispatch_pick``
+    (taken off it) → ``t_enqueued`` (``dispatch_batch`` returned: the
+    executable is queued on the device) → ``t_readback_pick`` (taken
+    off ``_readback_q``) → ``t_ready`` (the resolvers returned) →
+    ``t_served`` → ``t_done`` (serialized, recorded; callers wake)."""
 
     __slots__ = ("entries", "queries", "out", "live", "supplemented",
                  "algorithms", "models", "lane_models", "serving",
-                 "instance_id", "phases", "pending", "lane",
-                 "t_dispatched")
+                 "instance_id", "pending", "lane", "seq",
+                 "t_pick", "t_parsed", "t_assembled", "t_dispatch_pick",
+                 "t_enqueued", "t_readback_pick", "t_ready", "t_served",
+                 "t_done")
 
     def __init__(self, entries, queries, out, live, supplemented,
                  algorithms, models, lane_models, serving, instance_id,
-                 phases):
+                 seq, t_pick, t_parsed):
         self.entries = entries
         self.queries = queries
         self.out = out
@@ -3433,10 +3538,26 @@ class _AssembledBatch:
         self.lane_models = lane_models
         self.serving = serving
         self.instance_id = instance_id
-        self.phases = phases
         self.pending = None
         self.lane: Optional[int] = None
-        self.t_dispatched: Optional[float] = None
+        self.seq = seq
+        self.t_pick = t_pick
+        self.t_parsed = t_parsed
+        self.t_assembled = self.t_dispatch_pick = self.t_enqueued = None
+        self.t_readback_pick = self.t_ready = self.t_served = None
+        self.t_done: Optional[float] = None
+
+    def timeline(self) -> BatchTimeline:
+        return BatchTimeline(self.seq, len(self.entries), self.lane,
+                             self.instance_id, (
+            ("assemble", self.t_pick, self.t_parsed),
+            ("supplement", self.t_parsed, self.t_assembled),
+            ("dispatch_q", self.t_assembled, self.t_dispatch_pick),
+            ("dispatch", self.t_dispatch_pick, self.t_enqueued),
+            ("readback_q", self.t_enqueued, self.t_readback_pick),
+            ("device_wait", self.t_readback_pick, self.t_ready),
+            ("serve", self.t_ready, self.t_served),
+            ("finish", self.t_served, self.t_done)))
 
 
 class StagedPipeline:
@@ -3517,6 +3638,12 @@ class StagedPipeline:
         # mean occupancy 1.7 vs the serial drainer's 4.8 at the same
         # load — and device efficiency scales with occupancy).
         self._inflight = threading.BoundedSemaphore(depth * self.lanes)
+        # bound children: labels() validates and sorts its keywords
+        # under a lock on every call
+        self._stage = {st: server._pipeline_stage_hist.labels(stage=st)
+                       for st in ("assemble", "dispatch", "readback")}
+        self._qdepth = {q: server._pipeline_qdepth.labels(queue=q)
+                        for q in ("submit", "dispatch", "readback")}
         # per-stage rosters so close() can stop the stages in pipeline
         # order (assemble first, readback last)
         self._assemble_threads: List[threading.Thread] = []
@@ -3579,185 +3706,234 @@ class StagedPipeline:
     # -- stage 1: assemble ---------------------------------------------------
     def _assemble_loop(self) -> None:
         server = self.server
+        tracker = server.overlap
         while True:
-            # take an in-flight slot FIRST (see __init__): while the
-            # pipeline is full, arrivals pool in the submit queue and
-            # the eventual pickup coalesces them — adaptive batching
-            self._inflight.acquire()
-            handed_off = False
-            try:
-                first = self._q.get()
-                if first is _CLOSE:
-                    return  # the finally releases our in-flight slot
-                depth = self._q.qsize() + 1
-                server._queue_depth.observe(depth)
-                server._pipeline_qdepth.labels(
-                    queue="submit").observe(depth)
-                batch = _form_batch(self._q, first, self.max_batch,
-                                    self.window)
+            batch = None
+            with stage_span("wait_submit") as waiting:
+                # take an in-flight slot FIRST (see __init__): while
+                # the pipeline is full, arrivals pool in the submit
+                # queue and the eventual pickup coalesces them —
+                # adaptive batching
+                self._inflight.acquire()
+                try:
+                    first = self._q.get()
+                    if first is _CLOSE:
+                        return  # the finally releases our slot
+                    depth = self._q.qsize() + 1
+                    server._queue_depth.observe(depth)
+                    self._qdepth["submit"].observe(depth)
+                    batch = _form_batch(self._q, first, self.max_batch,
+                                        self.window)
+                finally:
+                    if not batch:
+                        self._inflight.release()
                 if not batch:
                     continue
-                t0 = time.monotonic()
-                server.overlap.enter("assemble")
-                try:
-                    ab = self._assemble(batch)
-                except Exception as e:  # noqa: BLE001 — isolate batch
-                    server.remote_log(str(e))
-                    err = HTTPError(500, str(e))
-                    err._remote_logged = True
-                    for entry in batch:
-                        entry.slot[0] = err
-                        entry.done.set()
-                    ab = None
-                finally:
-                    server.overlap.exit("assemble")
-                    server._pipeline_stage_hist.labels(
-                        stage="assemble").observe(time.monotonic() - t0)
-                if ab is not None and ab.entries:
-                    self._dispatch_q.put(ab)
-                    handed_off = True  # slot rides with the batch; the
-                    # readback stage releases it after resolve
+                seq = next(_batch_seq)
+                waiting.set_metadata(batch=seq, n=len(batch))
+                t_pick = time.monotonic()
+            tracker.step(t_pick, enter="assemble", join="assembling")
+            ab = None
+            try:
+                ab = self._assemble(batch, seq, t_pick)
+            except Exception as e:  # noqa: BLE001 — isolate batch
+                server.remote_log(str(e))
+                err = HTTPError(500, str(e))
+                err._remote_logged = True
+                for entry in batch:
+                    entry.slot[0] = err
+                    entry.done.set()
             finally:
-                if not handed_off:
+                staged = ab is not None and bool(ab.entries)
+                t_out = ab.t_assembled if ab is not None \
+                    else time.monotonic()
+                tracker.step(t_out, exit="assemble", leave="assembling",
+                             join="staged" if staged else None)
+                self._stage["assemble"].observe(t_out - t_pick)
+                if staged:
+                    # the slot rides with the batch; the readback
+                    # stage releases it after resolve
+                    self._dispatch_q.put(ab)
+                else:
                     self._inflight.release()
 
-    def _assemble(self, batch: List[_Submit]) -> _AssembledBatch:
+    def _assemble(self, batch: List[_Submit], seq: int = 0,
+                  t_pick: Optional[float] = None) -> _AssembledBatch:
+        """Parse and supplement a formed batch against ONE snapshot of
+        the binding. The loop passes the batch's number and the
+        ``t_pick`` it stamped; alone (tests) the pickup is now."""
         from ..workflow.batch_predict import supplement_batch
 
         server = self.server
-        with server._lock:
-            algorithms = server.algorithms
-            models = server.models
-            lane_models = list(server.lane_models)
-            serving = server.serving
-            instance_id = server.instance.id
-        t_pick = time.monotonic()
-        qwait = server._phase_hist.labels(phase="queue_wait")
-        for e in batch:
-            wait = t_pick - e.t_enq
-            qwait.observe(wait)
-            if e.obs is not None:
-                e.obs["queueWaitMs"] = round(wait * 1000, 3)
-        query_cls = algorithms[0].query_class
-        entries: List[_Submit] = []
-        queries: List[Any] = []
-        t0 = time.monotonic()
-        for e in batch:
-            try:
-                queries.append(from_jsonable(query_cls, e.query_json))
-                entries.append(e)
-            except (TypeError, ValueError) as err:
-                # a malformed query completes HERE: its 400 never
-                # rides the batch through the device
-                server._query_errors.labels(status="400").inc()
-                server._latency_hist.observe(time.monotonic() - e.t_enq)
-                e.slot[0] = HTTPError(400, str(err))
-                e.done.set()
-        phases: dict = {"assemble": time.monotonic() - t0}
-        out: List[Any] = [None] * len(entries)
-        live: List[int] = []
-        supplemented: List[Any] = []
-        if entries:
-            with server._transfer_guard():
-                supplemented, live = supplement_batch(
-                    serving, queries, out, timings=phases)
-        return _AssembledBatch(
-            entries=entries, queries=queries, out=out, live=live,
-            supplemented=supplemented, algorithms=algorithms,
-            models=models, lane_models=lane_models, serving=serving,
-            instance_id=instance_id, phases=phases)
+        if t_pick is None:
+            t_pick = time.monotonic()
+        with stage_span("assemble", batch=seq, n=len(batch)):
+            with server._lock:
+                algorithms = server.algorithms
+                models = server.models
+                lane_models = list(server.lane_models)
+                serving = server.serving
+                instance_id = server.instance.id
+            qwait = server._phase("queue_wait")
+            for e in batch:
+                wait = t_pick - e.t_enq
+                qwait.observe(wait)
+                if e.obs is not None:
+                    e.obs["queueWaitMs"] = round(wait * 1000, 3)
+            query_cls = algorithms[0].query_class
+            entries: List[_Submit] = []
+            queries: List[Any] = []
+            for e in batch:
+                try:
+                    queries.append(from_jsonable(query_cls, e.query_json))
+                    entries.append(e)
+                except (TypeError, ValueError) as err:
+                    # a malformed query completes HERE: its 400 never
+                    # rides the batch through the device
+                    server._query_errors.labels(status="400").inc()
+                    e.t_done = time.monotonic()
+                    server._latency_hist.observe(e.t_done - e.t_enq)
+                    e.slot[0] = HTTPError(400, str(err))
+                    e.done.set()
+            t_parsed = time.monotonic()
+        with stage_span("supplement", batch=seq, n=len(entries)):
+            out: List[Any] = [None] * len(entries)
+            live: List[int] = []
+            supplemented: List[Any] = []
+            if entries:
+                with server._transfer_guard():
+                    supplemented, live = supplement_batch(
+                        serving, queries, out)
+            ab = _AssembledBatch(
+                entries=entries, queries=queries, out=out, live=live,
+                supplemented=supplemented, algorithms=algorithms,
+                models=models, lane_models=lane_models, serving=serving,
+                instance_id=instance_id, seq=seq, t_pick=t_pick,
+                t_parsed=t_parsed)
+            ab.t_assembled = time.monotonic()
+        return ab
 
     # -- stage 2: dispatch ---------------------------------------------------
     def _dispatch_loop(self, lane: Optional[int] = None) -> None:
         from ..workflow.batch_predict import PendingBatch, dispatch_batch
 
         server = self.server
+        tracker = server.overlap
         while True:
-            ab = self._dispatch_q.get()
-            if ab is _CLOSE:
-                return
-            server._pipeline_qdepth.labels(queue="dispatch").observe(
-                self._dispatch_q.qsize() + 1)
-            if lane is not None and ab.lane_models:
-                # lane supervision (ISSUE 11): a dead lane's batches
-                # redistribute across survivors at pickup, and a
-                # dispatch failure fails over to the other lanes
-                # before it is allowed to fail the batch — during
-                # detection no caller sees an error as long as one
-                # lane still serves
-                attempts = server.lane_attempt_order(lane)
-                ab.lane = attempts[0]
-                models = ab.lane_models[ab.lane]
-                server._lane_depth.labels(lane=str(ab.lane)).observe(
+            with stage_span("wait_dispatch_q") as waiting:
+                ab = self._dispatch_q.get()
+                if ab is _CLOSE:
+                    return
+                n = len(ab.entries)
+                waiting.set_metadata(batch=ab.seq, n=n)
+                ab.t_dispatch_pick = time.monotonic()
+            # the ``device`` track opens BEFORE the launch (as it always
+            # has: what pio_pipeline_device_idle_fraction means); the
+            # starvation clock's ``enqueued`` waits for t_enqueued
+            in_flight_before = tracker.step(
+                ab.t_dispatch_pick, enter="device", leave="staged",
+                join="launching")
+            with stage_span("dispatch", batch=ab.seq, n=n):
+                self._qdepth["dispatch"].observe(
                     self._dispatch_q.qsize() + 1)
-            else:
-                attempts = [None]
-                models = ab.models
-            t0 = time.monotonic()
-            in_flight_before = server.overlap.enter("device")
-            # fault attribution (ISSUE 12): an injection delivered on
-            # this dispatch thread flags exactly this batch's traces
-            batch_traces = [server._trace_of(e.obs)
-                            for e in ab.entries]
-            for n_try, eff in enumerate(attempts):
-                if eff is not None:
-                    ab.lane = eff
-                    models = ab.lane_models[eff]
-                try:
-                    with activate_traces(batch_traces):
-                        if eff is not None:
-                            fire(F_LANE, lane=str(eff))
-                        fire(F_DISPATCH)
-                        with server._transfer_guard():
-                            resolvers = dispatch_batch(
-                                ab.algorithms, models, ab.supplemented,
-                                timings=ab.phases) if ab.live else []
-                    ab.pending = PendingBatch(ab.queries, ab.serving,
-                                              ab.out, ab.live, resolvers)
+                if lane is not None and ab.lane_models:
+                    # lane supervision (ISSUE 11): a dead lane's batches
+                    # redistribute across survivors at pickup, and a
+                    # dispatch failure fails over to the other lanes
+                    # before it is allowed to fail the batch — during
+                    # detection no caller sees an error as long as one
+                    # lane still serves
+                    attempts = server.lane_attempt_order(lane)
+                    ab.lane = attempts[0]
+                    models = ab.lane_models[ab.lane]
+                    server._lane_depth.labels(
+                        lane=str(ab.lane)).observe(
+                        self._dispatch_q.qsize() + 1)
+                else:
+                    attempts = [None]
+                    models = ab.models
+                # fault attribution (ISSUE 12): an injection delivered
+                # on this dispatch thread flags exactly this batch's
+                # traces
+                batch_traces = [server._trace_of(e.obs)
+                                for e in ab.entries]
+                for n_try, eff in enumerate(attempts):
                     if eff is not None:
-                        server._lane_ok(eff)
-                    break
-                except Exception as e:  # noqa: BLE001 — one dispatch,
-                    if eff is not None:  # count + maybe fail over
-                        server._lane_error(eff, e)
-                    if n_try + 1 < len(attempts):
-                        continue
-                    for i in ab.live:   # whole batch, no lane left
-                        ab.out[i] = e
-                    ab.pending = PendingBatch(ab.queries, ab.serving,
-                                              ab.out, [], [])
+                        ab.lane = eff
+                        models = ab.lane_models[eff]
+                    try:
+                        with activate_traces(batch_traces):
+                            if eff is not None:
+                                fire(F_LANE, lane=str(eff))
+                            fire(F_DISPATCH)
+                            with server._transfer_guard():
+                                resolvers = dispatch_batch(
+                                    ab.algorithms, models,
+                                    ab.supplemented) if ab.live else []
+                        ab.pending = PendingBatch(
+                            ab.queries, ab.serving, ab.out, ab.live,
+                            resolvers)
+                        if eff is not None:
+                            server._lane_ok(eff)
+                        break
+                    except Exception as e:  # noqa: BLE001 — one
+                        if eff is not None:  # dispatch, count + maybe
+                            server._lane_error(eff, e)  # fail over
+                        if n_try + 1 < len(attempts):
+                            continue
+                        for i in ab.live:   # whole batch, no lane left
+                            ab.out[i] = e
+                        ab.pending = PendingBatch(
+                            ab.queries, ab.serving, ab.out, [], [])
+                ab.t_enqueued = time.monotonic()
+            tracker.step(ab.t_enqueued, leave="launching",
+                         join="enqueued")
             if in_flight_before > 0:
                 # launched while an earlier batch was still on the
                 # device: the continuous-batching overlap, counted
                 server._pipeline_overlapped.inc()
-            ab.t_dispatched = t0
-            server._pipeline_stage_hist.labels(stage="dispatch").observe(
-                time.monotonic() - t0)
+            self._stage["dispatch"].observe(
+                ab.t_enqueued - ab.t_dispatch_pick)
             self._readback_q.put(ab)
 
     # -- stage 3: readback ---------------------------------------------------
     def _readback_loop(self) -> None:
         server = self.server
+        tracker = server.overlap
         while True:
-            ab = self._readback_q.get()
-            if ab is _CLOSE:
-                return
-            server._pipeline_qdepth.labels(queue="readback").observe(
+            with stage_span("wait_readback_q") as waiting:
+                ab = self._readback_q.get()
+                if ab is _CLOSE:
+                    return
+                n = len(ab.entries)
+                waiting.set_metadata(batch=ab.seq, n=n)
+                ab.t_readback_pick = time.monotonic()
+            self._qdepth["readback"].observe(
                 self._readback_q.qsize() + 1)
-            t0 = time.monotonic()
+            pending = ab.pending
             try:
-                results = ab.pending.resolve(ab.phases)
+                with stage_span("device_wait", batch=ab.seq, n=n):
+                    fetched = pending.wait()
+                    ab.t_ready = time.monotonic()
+                tracker.step(ab.t_ready, leave="enqueued")
+                with stage_span("serve", batch=ab.seq, n=n):
+                    results = pending.serve(fetched)
             except Exception as e:  # noqa: BLE001 — resolve isolates
-                results = [e] * len(ab.entries)  # internally; belt +
-            finally:                             # braces for the rest
-                server.overlap.exit("device")
+                results = [e] * n   # internally; belt + braces for
+                if ab.t_ready is None:  # the rest
+                    ab.t_ready = time.monotonic()
+                    tracker.step(ab.t_ready, leave="enqueued")
+            finally:
+                ab.t_served = time.monotonic()
+                tracker.step(ab.t_served, exit="device",
+                             enter="readback")
                 # the batch is off the device: free its in-flight slot
                 # so assemble picks up the pooled backlog while WE are
                 # still serializing results (that is the overlap)
                 self._inflight.release()
-            server.overlap.enter("readback")
             try:
-                server._finish_pipeline_batch(ab, results)
+                with stage_span("finish", batch=ab.seq, n=n):
+                    server._finish_pipeline_batch(ab, results)
             except Exception as e:  # noqa: BLE001 — isolate to batch
                 server.remote_log(str(e))
                 err = HTTPError(500, str(e))
@@ -3767,9 +3943,11 @@ class StagedPipeline:
                         entry.slot[0] = err
                         entry.done.set()
             finally:
-                server.overlap.exit("readback")
-                server._pipeline_stage_hist.labels(
-                    stage="readback").observe(time.monotonic() - t0)
+                if ab.t_done is None:  # the finish never got there
+                    ab.t_done = time.monotonic()
+                tracker.step(ab.t_done, exit="readback")
+                self._stage["readback"].observe(
+                    ab.t_done - ab.t_readback_pick)
 
 
 def create_engine_server(server: QueryServer, host: str = "0.0.0.0",

@@ -25,9 +25,10 @@ from urllib.parse import parse_qs, urlparse
 from ..concurrency import new_lock
 from ..data.storage.base import StorageError
 from ..faults import FaultError
+from ..obs.trace import stage_span
 
-__all__ = ["Request", "Response", "HTTPApp", "AppServer", "json_response",
-           "mount_metrics", "mount_trace_routes"]
+__all__ = ["Request", "RequestStamps", "Response", "HTTPApp", "AppServer",
+           "json_response", "mount_metrics", "mount_trace_routes"]
 
 #: Retry-After seconds on a 503 caused by an unavailable backing store
 #: (docs/reliability.md): short enough that a recovered store is back
@@ -39,6 +40,48 @@ RETRY_AFTER_SECONDS = 1
 #: and any per-phase timings the handler attached (``Request.obs``).
 #: Quiet unless the operator enables INFO on this logger.
 access_log = logging.getLogger("predictionio_tpu.access")
+
+
+class RequestStamps:
+    """One request's timeline on its handler thread: ``time.monotonic``
+    seconds taken where the work happens, each stage's end being the
+    next one's start, so the parts add up to the residence
+    (``t_recv``..``t_sent``) with nothing unnamed. ``None`` = never
+    reached (a request handed to :meth:`HTTPApp.handle` directly has
+    no ``t_recv``/``t_sent``).
+
+    - ``t_recv``: the request line has arrived (``parse_request``)
+    - ``t_enter``: headers and body read, ids minted, trace begun;
+      routing starts (``pio_http_request_duration_seconds`` starts here)
+    - ``t_enq`` / ``t_done`` / ``t_wake``: written by a handler that
+      queues its work and blocks for it (the engine server's batcher:
+      enqueued, its batch finished, this thread runs again), with the
+      timeline of the ``batch`` that served it
+    - ``t_return``: :meth:`HTTPApp.handle` returns
+    - ``t_sent``: the response is written
+    """
+
+    __slots__ = ("t_recv", "t_enter", "t_enq", "t_done", "t_wake",
+                 "t_return", "t_sent", "batch", "route", "_span")
+
+    def __init__(self, t_recv: Optional[float] = None):
+        self.t_recv = t_recv
+        self.t_enter = self.t_enq = self.t_done = self.t_wake = None
+        self.t_return = self.t_sent = None
+        self.batch: Any = None
+        self.route = ""
+        self._span: Any = None
+
+    def open_span(self, stage: str) -> None:
+        """Open a ``pio:<stage>`` profiler annotation that another
+        method of this thread closes (:meth:`close_span`)."""
+        self._span = stage_span(stage)
+        self._span.__enter__()
+
+    def close_span(self) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
 
 @dataclass
@@ -65,6 +108,9 @@ class Request:
     #: ``obs["_trace"]`` so batcher/pipeline code that only sees the
     #: obs dict can attach stage spans.
     trace: Any = None
+    #: This request's stamps (``obs["_stamps"]`` carries the same
+    #: object to code that only sees the obs dict).
+    stamps: RequestStamps = field(default_factory=RequestStamps)
 
     def header(self, name: str, default: Optional[str] = None
                ) -> Optional[str]:
@@ -242,6 +288,14 @@ class HTTPApp:
         self.metrics = None  # set by mount_metrics
         self._http_hist = None
         self._http_count = None
+        self._io_read = self._io_write = self._http_residence = None
+        #: bound children by label values: ``labels()`` validates and
+        #: sorts its keywords under a lock on every call
+        self._children: Dict[Tuple, Any] = {}
+        #: called with the request once its response is written and
+        #: every stamp is taken (the engine server observes its
+        #: ``respond`` phase here)
+        self.on_sent: Optional[Callable[[Request], None]] = None
         self.tracer = None  # set by mount_metrics (obs.trace.Tracer)
         #: probabilistic sampling of the structured access log
         #: (ISSUE 12 satellite): at high qps the per-request
@@ -266,6 +320,25 @@ class HTTPApp:
         self._http_count = registry.counter(
             "pio_http_requests_total",
             "HTTP requests by route, method, and status code")
+        http_io = registry.histogram(
+            "pio_http_io_seconds",
+            "Handler-thread time outside the app, per request: "
+            "part=read (request line arrived -> routing starts: "
+            "headers, body, ids, trace begin), part=write (handle "
+            "returned -> response written)")
+        self._http_residence = registry.histogram(
+            "pio_http_residence_seconds",
+            "Request line arrived -> response written, by route: all "
+            "the server does for a request")
+        self._io_read = http_io.labels(part="read")
+        self._io_write = http_io.labels(part="write")
+
+    def _child(self, family, **labels: str):
+        key = (family.name, *labels.values())
+        child = self._children.get(key)
+        if child is None:
+            child = self._children[key] = family.labels(**labels)
+        return child
 
     def _dispatch(self, req: Request) -> Tuple[Response, str]:
         """Route + run the handler; returns (response, route pattern —
@@ -315,15 +388,19 @@ class HTTPApp:
                 traceparent=req.header("traceparent"),
                 request_id=req.request_id, server=self.name)
             req.obs["_trace"] = req.trace
-        t0 = time.monotonic()
+        st = req.stamps
+        req.obs["_stamps"] = st
+        t0 = st.t_enter = time.monotonic()
+        st.close_span()  # pio:http_read, where the server opened one
         resp, route = self._dispatch(req)
         dt = time.monotonic() - t0
+        st.route = route
         resp.headers.setdefault("X-Request-ID", req.request_id)
         if self.metrics is not None:
-            hist = self._http_hist.labels(route=route)
+            hist = self._child(self._http_hist, route=route)
             hist.observe(dt)
-            self._http_count.labels(route=route, method=req.method,
-                                    status=str(resp.status)).inc()
+            self._child(self._http_count, route=route, method=req.method,
+                        status=str(resp.status)).inc()
             if req.trace is not None:
                 req.trace.exemplar(hist, dt)
         if req.trace is not None:
@@ -346,7 +423,23 @@ class HTTPApp:
             line.update((k, v) for k, v in req.obs.items()
                         if not k.startswith("_"))
             access_log.info(json.dumps(line))
+        st.t_return = time.monotonic()
         return resp
+
+    def sent(self, req: Request) -> None:
+        """The server wrote ``req``'s response: take the last stamp and
+        observe the request's read, write and residence (after the
+        write, so off the client's path)."""
+        st = req.stamps
+        st.t_sent = time.monotonic()
+        if self.metrics is not None and st.t_recv is not None \
+                and st.t_return is not None:
+            self._io_read.observe(st.t_enter - st.t_recv)
+            self._io_write.observe(st.t_sent - st.t_return)
+            self._child(self._http_residence, route=st.route).observe(
+                st.t_sent - st.t_recv)
+        if self.on_sent is not None:
+            self.on_sent(req)
 
     def _log_this(self, status: int) -> bool:
         """Access-log admission: errors/503s always; successes at the
@@ -384,8 +477,7 @@ def mount_metrics(app: HTTPApp, registry, server_name: Optional[str] = None,
     - instruments the app's request path (latency histogram, status
       counters, request ids, access log) via :meth:`HTTPApp.enable_metrics`
     - registers the standard runtime series (build info, XLA compiles,
-      transfer-guard violations, per-device HBM) and the global
-      ``timed(name)`` span registry
+      transfer-guard violations, per-device HBM)
     - adds ``GET /metrics`` — Prometheus text format 0.0.4, or
       OpenMetrics 1.0 (with bucket exemplars) when the scraper sends
       ``Accept: application/openmetrics-text``
@@ -398,11 +490,10 @@ def mount_metrics(app: HTTPApp, registry, server_name: Optional[str] = None,
       docs/tracing.md). ``tracer=None`` builds a default one;
       ``tracer=False`` disables tracing for this app.
     """
-    from ..obs import Tracer, mount_span_metrics, register_runtime_metrics
+    from ..obs import Tracer, register_runtime_metrics
 
     if runtime:
         register_runtime_metrics(registry, server_name or app.name)
-        mount_span_metrics(registry)
     app.enable_metrics(registry)
     if tracer is None:
         tracer = Tracer()
@@ -502,23 +593,39 @@ class _Handler(BaseHTTPRequestHandler):
     def log_message(self, fmt, *args):  # quiet by default
         pass
 
+    def parse_request(self) -> bool:
+        # the request line has arrived: the request's first stamp
+        self._stamps = st = RequestStamps(time.monotonic())
+        st.open_span("http_read")  # closed where routing starts
+        ok = super().parse_request()
+        if not ok or not hasattr(self, "do_" + self.command):
+            st.close_span()  # answered by the base class, not by us
+        return ok
+
     def _dispatch(self) -> None:
-        parsed = urlparse(self.path)
-        query = {k: v[0] for k, v in parse_qs(parsed.query).items()}
-        length = int(self.headers.get("Content-Length") or 0)
-        body = self.rfile.read(length) if length else b""
-        req = Request(method=self.command, path=parsed.path, query=query,
-                      headers={k: v for k, v in self.headers.items()},
-                      body=body)
-        resp = self.app.handle(req)
-        payload = resp.encoded()
-        self.send_response(resp.status)
-        self.send_header("Content-Type", resp.content_type)
-        self.send_header("Content-Length", str(len(payload)))
-        for k, v in resp.headers.items():
-            self.send_header(k, v)
-        self.end_headers()
-        self.wfile.write(payload)
+        st = self._stamps
+        try:
+            parsed = urlparse(self.path)
+            query = {k: v[0] for k, v in parse_qs(parsed.query).items()}
+            length = int(self.headers.get("Content-Length") or 0)
+            body = self.rfile.read(length) if length else b""
+            req = Request(method=self.command, path=parsed.path,
+                          query=query,
+                          headers={k: v for k, v in self.headers.items()},
+                          body=body, stamps=st)
+            resp = self.app.handle(req)
+        finally:
+            st.close_span()  # a read that failed never reached handle
+        with stage_span("http_write"):
+            payload = resp.encoded()
+            self.send_response(resp.status)
+            self.send_header("Content-Type", resp.content_type)
+            self.send_header("Content-Length", str(len(payload)))
+            for k, v in resp.headers.items():
+                self.send_header(k, v)
+            self.end_headers()
+            self.wfile.write(payload)
+        self.app.sent(req)
 
     do_GET = do_POST = do_DELETE = do_PUT = _dispatch
 

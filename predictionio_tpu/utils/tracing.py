@@ -3,20 +3,16 @@
 The reference has no tracing at all (SURVEY §5: observability = logs +
 the external Spark UI). Here the XLA profiler is wired into the
 workflow: ``trace(dir)`` captures a device trace viewable in
-TensorBoard/XProf/Perfetto, ``annotate(name)`` labels host-side phases so
-they show up on the trace timeline, and ``timed(name)`` collects
-wall-clock spans into an in-process registry the servers can expose.
+TensorBoard/XProf/Perfetto, and ``annotate(name)`` labels host-side
+phases so they show up on the host plane of that same trace, on the
+profiler's clock, beside the device's operations.
 """
 
 from __future__ import annotations
 
 import contextlib
 import logging
-import threading
-import time
-from typing import Dict, Iterator, Optional
-
-from ..obs.histogram import StreamingHistogram
+from typing import Any, ContextManager, Iterator, Optional
 
 log = logging.getLogger(__name__)
 
@@ -40,89 +36,35 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
         log.info("XLA trace written to %s", log_dir)
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Label a host-side phase on the profiler timeline."""
-    try:
-        import jax
+class _NoAnnotation:
+    """Stands in where jax is absent: profiling must never break the
+    workflow."""
 
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    except ImportError:  # profiling must never break the workflow
-        yield
+    def __init__(self, name: str, **stats: Any) -> None:
+        pass
 
+    def __enter__(self) -> "_NoAnnotation":
+        return self
 
-class SpanRegistry:
-    """Thread-safe wall-clock span collection, bounded per name.
+    def __exit__(self, *exc: Any) -> None:
+        pass
 
-    Round-1 kept a raw ``List[float]`` per span — unbounded memory on a
-    long-lived server. Each name is now one fixed-bucket
-    :class:`~predictionio_tpu.obs.histogram.StreamingHistogram`:
-    ``record`` is O(1), memory is constant however many observations
-    arrive, and :meth:`summary` gains p50/p90/p99 while keeping the
-    original ``count/total_sec/mean_sec/max_sec`` keys.
-    """
-
-    #: a runaway caller generating span names per request must not grow
-    #: the registry without bound; past this, records fold into one
-    #: overflow bucket (visible, not silent)
-    MAX_SPAN_NAMES = 1024
-    _OVERFLOW = "(overflow)"
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._spans: Dict[str, StreamingHistogram] = {}
-
-    def record(self, name: str, seconds: float) -> None:
-        with self._lock:
-            hist = self._spans.get(name)
-            if hist is None:
-                if len(self._spans) >= self.MAX_SPAN_NAMES:
-                    name = self._OVERFLOW
-                    hist = self._spans.get(name)
-                if hist is None:
-                    hist = self._spans[name] = StreamingHistogram()
-        hist.record(seconds)
-
-    def histograms(self) -> Dict[str, StreamingHistogram]:
-        """Live per-name histograms (the /metrics exposition bridge)."""
-        with self._lock:
-            return dict(self._spans)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        out: Dict[str, Dict[str, float]] = {}
-        for name, h in self.histograms().items():
-            if not h.count:
-                continue
-            s = h.snapshot()
-            out[name] = {
-                "count": s["count"],
-                "total_sec": s["sum"],
-                "mean_sec": s["mean"],
-                "max_sec": s["max"],
-                "p50": s["p50"],
-                "p90": s["p90"],
-                "p99": s["p99"],
-            }
-        return out
-
-    def reset(self) -> None:
-        with self._lock:
-            self._spans.clear()
+    def set_metadata(self, **stats: Any) -> None:
+        pass
 
 
-#: Process-wide registry; the engine server's status page reads it.
-spans = SpanRegistry()
+_annotation = None  # jax.profiler.TraceAnnotation, resolved on first use
 
 
-@contextlib.contextmanager
-def timed(name: str,
-          registry: Optional[SpanRegistry] = None) -> Iterator[None]:
-    """Time a block into the span registry AND the profiler timeline."""
-    reg = registry if registry is not None else spans
-    t0 = time.monotonic()
-    with annotate(name):
+def annotate(name: str, **stats: Any) -> ContextManager:
+    """Label a host-side phase on the profiler timeline; ``stats``
+    become the event's stats (``set_metadata(**more)`` on the entered
+    object adds what is known only later). With no capture running the
+    annotation is inert (under a microsecond)."""
+    global _annotation
+    if _annotation is None:
         try:
-            yield
-        finally:
-            reg.record(name, time.monotonic() - t0)
+            from jax.profiler import TraceAnnotation as _annotation
+        except ImportError:
+            _annotation = _NoAnnotation
+    return _annotation(name, **stats)

@@ -128,34 +128,52 @@ class PendingBatch:
         self.live = live
         self.resolvers = resolvers
 
-    def resolve(self, timings: Optional[Dict[str, float]] = None
-                ) -> List[Any]:
-        """Block on the device results (``device_wait``), then serve
-        per query (``serve``). Same error contract as the serial path:
-        a per-algorithm readback failure fills every live slot; a
-        per-query serve failure fills only its own."""
-        out, live = self.out, self.live
-        if not live:
-            return out
-        t1 = time.monotonic()
+    def wait(self) -> Optional[List[Any]]:
+        """Block until every algorithm's predictions are host-real and
+        return them, one list per algorithm. ``None`` when nothing is
+        live or a readback failed (a per-algorithm readback failure
+        fills every live slot: it is one dispatch)."""
+        if not self.live:
+            return None
         try:
-            per_algo = [r() for r in self.resolvers]
+            return [r() for r in self.resolvers]
         except Exception as e:  # noqa: BLE001 — one dispatch, whole batch
-            for i in live:
-                out[i] = e
+            for i in self.live:
+                self.out[i] = e
+            return None
+
+    def serve(self, per_algo: Optional[List[Any]]) -> List[Any]:
+        """Serve per query from what :meth:`wait` returned; a per-query
+        serve failure fills only its own slot."""
+        out = self.out
+        if per_algo is None:
             return out
-        finally:
-            t2 = time.monotonic()
-            if timings is not None:
-                timings["device_wait"] = (timings.get("device_wait", 0.0)
-                                          + (t2 - t1))
-        for row, i in enumerate(live):
+        for row, i in enumerate(self.live):
             try:
                 # serve sees the original query (CreateServer.scala:511)
                 out[i] = self.serving.serve(
                     self.queries[i], [preds[row] for preds in per_algo])
             except Exception as e:  # noqa: BLE001
                 out[i] = e
+        return out
+
+    def resolve(self, timings: Optional[Dict[str, float]] = None
+                ) -> List[Any]:
+        """:meth:`wait` (``device_wait``) then :meth:`serve`
+        (``serve``), timed into ``timings`` for the serial paths. The
+        staged pipeline calls the two halves itself and stamps between
+        them (``server/engineserver.py::_AssembledBatch``)."""
+        if not self.live:
+            return self.out
+        t1 = time.monotonic()
+        per_algo = self.wait()
+        t2 = time.monotonic()
+        if timings is not None:
+            timings["device_wait"] = (timings.get("device_wait", 0.0)
+                                      + (t2 - t1))
+        if per_algo is None:
+            return self.out
+        out = self.serve(per_algo)
         if timings is not None:
             timings["serve"] = (timings.get("serve", 0.0)
                                 + (time.monotonic() - t2))

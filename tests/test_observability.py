@@ -23,7 +23,6 @@ from predictionio_tpu.obs import (
     exponential_bounds,
     linear_bounds,
 )
-from predictionio_tpu.utils.tracing import SpanRegistry, timed
 
 
 # ---------------------------------------------------------------------------
@@ -124,48 +123,6 @@ class TestStreamingHistogram:
             t.join()
         assert h.count == n * threads
         assert h.bucket_counts()[0][1] == n * threads
-
-
-# ---------------------------------------------------------------------------
-# span registry: bounded memory + backward-compatible summary
-# ---------------------------------------------------------------------------
-
-class TestSpanRegistry:
-    def test_summary_keys_backward_compatible_plus_percentiles(self):
-        reg = SpanRegistry()
-        with timed("op", registry=reg):
-            pass
-        reg.record("op", 0.5)
-        s = reg.summary()["op"]
-        for key in ("count", "total_sec", "mean_sec", "max_sec",
-                    "p50", "p90", "p99"):
-            assert key in s
-        assert s["count"] == 2
-        assert s["max_sec"] == pytest.approx(0.5, abs=0.01)
-
-    def test_memory_bounded_under_100k_records(self):
-        reg = SpanRegistry()
-        for i in range(100_000):
-            reg.record("hot", 0.001 * (i % 100))
-        hist = reg.histograms()["hot"]
-        # bounded: fixed bucket array, no raw list of 100k floats
-        assert len(hist._counts) == len(hist.bounds) + 1
-        assert reg.summary()["hot"]["count"] == 100_000
-
-    def test_span_name_cardinality_capped(self):
-        reg = SpanRegistry()
-        for i in range(SpanRegistry.MAX_SPAN_NAMES + 50):
-            reg.record(f"span-{i}", 0.001)
-        hists = reg.histograms()
-        assert len(hists) <= SpanRegistry.MAX_SPAN_NAMES + 1
-        assert SpanRegistry._OVERFLOW in hists
-        assert hists[SpanRegistry._OVERFLOW].count == 50
-
-    def test_reset(self):
-        reg = SpanRegistry()
-        reg.record("x", 1.0)
-        reg.reset()
-        assert reg.summary() == {}
 
 
 # ---------------------------------------------------------------------------
@@ -414,13 +371,6 @@ class TestEngineServerPhases:
                 in text
             assert "pio_query_latency_seconds_count 5" in text
             assert "pio_compiles_since_warm" in text
-            # the global timed(name) span registry bridges into the
-            # same exposition once a span exists
-            with timed("obs-bridge-span"):
-                pass
-            status, text, _ = _call(srv.port, "GET", "/metrics")
-            assert 'pio_span_seconds_bucket{span="obs-bridge-span"' \
-                in text
         finally:
             srv.shutdown()
 
