@@ -2174,7 +2174,7 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--pipeline-depth", type=int, default=0,
                    help="staged pipeline: bounded in-flight batches "
                         "per lane (the backpressure knob); 0 = auto "
-                        "(1 on CPU, 4 on accelerators)")
+                        "(2 on every backend)")
     s.add_argument("--cache", action="store_true",
                    help="serving cache hierarchy: query-result + "
                         "feature caches and the device-resident "

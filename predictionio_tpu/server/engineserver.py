@@ -157,17 +157,17 @@ class ServerConfig:
     #: in-flight depth: each worker parks on one batch's readback
     #: while the device runs later batches.
     readback_workers: int = 4
-    #: staged pipeline: bounded in-flight (dispatched-but-unresolved)
-    #: batches per lane — the knob that trades batch size against
-    #: latency hiding. 0 = auto: 2 where the "device" shares the host
-    #: cores (CPU — nothing to hide; maximum occupancy wins, measured
-    #: 1.6× the serial drainer), 4 on real accelerators (readback is
-    #: pipelined behind later batches, like the serial drainer's 4
-    #: concurrent dispatches — but with fatter batches and host work
-    #: off the critical path; the value is not re-decided on the
-    #: current chip). While the pipeline is full, arrivals pool in
-    #: the submit queue (where the deadline sheds them) and the next
-    #: pickup coalesces the backlog into one fat batch.
+    #: staged pipeline: bounded in-flight (picked-up-but-unserved)
+    #: batches per lane — the knob that trades every query's wait
+    #: behind earlier dispatches against latency hiding. 0 = auto: 2
+    #: on every backend, one batch on the device and one behind it.
+    #: On the CPU maximum occupancy wins (measured 1.6× the serial
+    #: drainer); on the v5e 2 against the former 4 took 10 ms off the
+    #: median at 0.8 × the knee and held the saturated rate, with
+    #: fuller, fewer dispatches (PR 26, PERF.md section 6). While the
+    #: pipeline is full, arrivals pool in the submit queue (where the
+    #: deadline sheds them) and the next pickup coalesces the backlog
+    #: into one fat batch.
     pipeline_depth: int = 0
     #: POST query errors to this URL (``remoteLog``,
     #: ``CreateServer.scala:435-446``); never fails the query.
@@ -3607,16 +3607,14 @@ class StagedPipeline:
         self.lanes = max(lanes, 1)
         self.deadline_sec = max(deadline_ms, 0.0) / 1000.0
         if depth <= 0:  # auto (ServerConfig.pipeline_depth = 0):
-            # shallow where the "device" shares the host cores (CPU —
-            # occupancy wins; deep pipelines just shred batch size),
-            # deep where readback pays a real device→host transfer that
-            # must be hidden behind later batches' compute
-            try:
-                import jax
-
-                depth = 2 if jax.default_backend() == "cpu" else 4
-            except Exception:  # noqa: BLE001 — no backend: middle road
-                depth = 2
+            # one batch on the device and one behind it, on every
+            # backend. A batch launched earlier than that only waits on
+            # the device behind the others, and every query in it
+            # waits too: on the v5e 4 in flight cost 10 ms of the
+            # median at 0.8 × the knee and bought no throughput at
+            # saturation, where 2 runs fuller, fewer dispatches
+            # (PR 26, PERF.md section 6)
+            depth = 2
         self.depth = depth
         # ptpu: allow[unbounded-queue] — every entry has an HTTP worker
         # thread blocked on its done-Event, so depth is bounded by the

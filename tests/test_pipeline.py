@@ -340,6 +340,76 @@ class TestPipelineTelemetry:
                                     warm_start=False))
 
 
+class TestInflightDepth:
+    """PR 26: ``pipeline_depth`` 0 resolves to 2 on every backend, and
+    the pipeline never holds more than ``depth`` unserved batches."""
+
+    def test_auto_depth_is_two_whatever_the_backend(self, monkeypatch):
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        qs = _mk_server(ServerConfig(batching=True, warm_start=False))
+        assert qs.batcher.depth == 2
+        assert qs.pipeline_status()["depth"] == 2
+
+    @pytest.mark.parametrize("configured,depth", [(0, 2), (3, 3)])
+    def test_holds_depth_batches_and_close_drains_a_parked_assemble(
+            self, configured, depth):
+        """Resolvers block on an Event: ``depth`` batches are launched
+        and no more, and ``close`` drains with the assemble thread
+        parked on the in-flight semaphore."""
+        qs = _mk_server(ServerConfig(batching=True, max_batch=1,
+                                     batch_window_ms=0.0,
+                                     pipeline_depth=configured,
+                                     warm_start=False))
+        pipe = qs.batcher
+        assert qs.pipeline_status()["depth"] == depth
+        gate = threading.Event()
+        algo = qs.algorithms[0]
+        inner = algo.batch_predict_async
+        launched = []
+
+        def gated(model, supplemented):
+            resolve = inner(model, supplemented)
+            launched.append(gate.is_set())
+
+            def blocked():
+                gate.wait(30)
+                return resolve()
+            return blocked
+
+        algo.batch_predict_async = gated
+        n = depth + 4
+        results = [None] * n
+        callers = [threading.Thread(
+            target=lambda i=i: results.__setitem__(
+                i, pipe.submit({"user": f"u{i}", "num": 3})))
+            for i in range(n)]
+        for t in callers:
+            t.start()
+        deadline = time.monotonic() + 10
+        while len(launched) < depth and time.monotonic() < deadline:
+            time.sleep(0.005)
+        closer = threading.Thread(target=pipe.close)
+        closer.start()  # assemble is parked on the semaphore
+        closer.join(0.1)
+        assert closer.is_alive()
+        assert len(launched) == depth
+        assert qs.overlap.active("device") == depth
+        gate.set()
+        for t in callers + [closer]:
+            t.join(10)
+            assert not t.is_alive()
+        assert not any(t.is_alive() for t in pipe._threads)
+        # every launch before the gate opened was one of the first
+        # ``depth``; the rest waited for a slot
+        assert launched.count(False) == depth and len(launched) == n
+        for r in results:
+            assert len(r["itemScores"]) == 3
+        with pytest.raises(ValueError):
+            pipe._inflight.release()  # every slot was given back
+
+
 class TestOverlapTracker:
     def test_overlap_accounting(self):
         t = [0.0]
