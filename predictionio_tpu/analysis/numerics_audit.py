@@ -376,6 +376,46 @@ def _entry_device_topk(quant: str):
     return fn(u, v, idx)
 
 
+def _gen_small():
+    """The generative programs' inputs at the CPU tests' size, in the
+    served dtype (bfloat16 weights and operands)."""
+    import jax
+    import numpy as np
+
+    from ..models import decoder
+
+    cfg = decoder.DecoderConfig(
+        hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=4,
+        layer_types=("conv", "full_attention", "conv", "conv"),
+        num_dense_layers=1, num_attention_heads=4, num_key_value_heads=2,
+        num_experts=8, num_experts_per_tok=2, vocab_size=256)
+    w = decoder.init_weights(jax.random.key(0), cfg)
+    tokens = np.zeros((4, 16), np.int32)
+    lengths = np.full((4,), 9, np.int32)
+    return decoder, cfg, w, tokens, lengths
+
+
+def _entry_gen_prefill():
+    import jax
+
+    decoder, cfg, w, tokens, lengths = _gen_small()
+    return jax.make_jaxpr(
+        lambda w, t, n: decoder._gen_prefill(w, t, n, cfg=cfg, room=4)
+    )(w, tokens, lengths)
+
+
+def _entry_gen_decode():
+    import jax
+
+    decoder, cfg, w, tokens, lengths = _gen_small()
+    first, state = decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
+                                        room=4)
+    return jax.make_jaxpr(
+        lambda w, s, f: decoder._gen_decode(w, s, f, cfg=cfg, steps=4)
+    )(w, state, first)
+
+
 #: name → (builder, one-line description); ordered — the manifest and
 #: the CI artifact list entries in this order
 ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
@@ -418,6 +458,12 @@ ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
     "device_topk_int8": (
         lambda: _entry_device_topk("int8"),
         "fused serving dispatch, int8+scale tables"),
+    "gen_prefill": (
+        _entry_gen_prefill,
+        "generative prefill (models/decoder._gen_prefill), bf16 weights"),
+    "gen_decode": (
+        _entry_gen_decode,
+        "generative greedy decode (models/decoder._gen_decode)"),
 }
 
 

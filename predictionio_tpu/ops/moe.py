@@ -1,0 +1,147 @@
+"""Sparse experts: sigmoid routing over ALL experts, and the product
+over the experts this chip HOLDS.
+
+The routing is the published one of the ``lfm2_moe`` family (and of the
+sigmoid-routed families before it): scores ``s = sigmoid(W_g z)`` over
+every expert; the top ``k`` are SELECTED by ``s + b`` with a per-expert
+bias ``b`` that balances load, and WEIGHTED by ``s`` without it;
+``norm_topk_prob`` divides the weights by their sum (plus ``1e-6``).
+No token is dropped and there is no capacity limit.
+
+The product (:func:`expert_product`) has two forms and picks by the
+number of tokens, which it can see. MANY tokens (a prefill): the (token,
+expert) assignments are sorted by expert and each weight takes one
+grouped matrix product (``jax.lax.ragged_dot``: a Mosaic grouped-matmul
+kernel on a TPU, ``ragged-dot`` in its trace, and a native op on the
+CPU), so the work is that of the assignments made and not of experts x
+tokens. FEW tokens (a decode step, ``DENSE_MAX_ROWS`` or under): every
+held expert is computed for every token in one batched product and the
+routing weights, zero where an expert was not selected, do the
+selecting; a step's rows touch every expert anyway, each expert's
+weights are read once either way, and the batched product reads them at
+14.2 ms a step of 64 rows where the sorted one took 24.5 (my chip run,
+PR 27). An assignment to an expert that is not held
+here (``held``: the chip's share of an expert-parallel deployment,
+model-configs guide section 4) or from a slot that is padding
+(``valid``) joins no group: it is sorted behind the last group and its
+rows are never computed. What the absent experts would have added is
+left out; four shares of a layer add up to the whole layer
+(``tests/test_decoder.py``).
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+NORM_EPS = 1e-6  # the family's constant in the top-k normalisation
+#: tokens up to which every expert is computed for every token. Reading
+#: one expert's weights takes 27 us at the v5e's 819 GB/s and computing
+#: it for one more token 0.11 us at its 197 TFLOP/s: under about 240
+#: tokens the weights' streaming hides the products nobody selected.
+#: Half of that, for an MXU that is not at its peak at these heights.
+DENSE_MAX_ROWS = 128
+
+
+def route(z: jax.Array, w_gate: jax.Array, bias: Optional[jax.Array], *,
+          top_k: int, norm_topk: bool = True, scale: float = 1.0
+          ) -> Tuple[jax.Array, jax.Array]:
+    """``(selected [T, k] int32, weights [T, k] float32)`` for tokens
+    ``z [T, H]`` over all ``E`` experts of ``w_gate [H, E]``. The scores,
+    the sums and the product itself are float32 (``highest``: the gate
+    is 32 columns wide and decides WHICH 22 MB are read next, so it is
+    not the place to round)."""
+    logits = jnp.dot(z.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision="highest",
+                     preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    pick = s if bias is None else s + bias.astype(jnp.float32)
+    _, sel = jax.lax.top_k(pick, top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + NORM_EPS)
+    return sel.astype(jnp.int32), w * scale
+
+
+def local_index(sel: jax.Array, n_experts: int,
+                held: Optional[Sequence[int]]) -> Tuple[jax.Array, int]:
+    """Expert ids as indices into the held weights; ``n_held`` (one past
+    the last group) for an expert that lives on another chip."""
+    if held is None:
+        return sel, n_experts
+    table = jnp.full((n_experts,), len(held), jnp.int32).at[
+        jnp.asarray(held, jnp.int32)].set(
+        jnp.arange(len(held), dtype=jnp.int32))
+    return table[sel], len(held)
+
+
+def expert_load(sel: jax.Array, n_experts: int,
+                valid: Optional[jax.Array] = None) -> jax.Array:
+    """Tokens per expert ``[E]`` int32 over ALL experts (pad slots
+    count for none): what the counters read."""
+    flat = sel if valid is None else jnp.where(valid[:, None], sel,
+                                               n_experts)
+    return jnp.bincount(flat.reshape(-1), length=n_experts + 1
+                        )[:n_experts].astype(jnp.int32)
+
+
+def expert_product(x: jax.Array, sel: jax.Array, wts: jax.Array,
+                   w1: jax.Array, w3: jax.Array, w2: jax.Array, *,
+                   n_experts: int, held: Optional[Sequence[int]] = None,
+                   valid: Optional[jax.Array] = None) -> jax.Array:
+    """``sum_e w_e W2_e (silu(W1_e x) * W3_e x)`` over the held experts,
+    ``[T, H]`` float32. ``x [T, H]`` in the weights' dtype, ``sel`` /
+    ``wts [T, k]`` from :func:`route`, ``w1`` / ``w3 [E_held, H, F]``,
+    ``w2 [E_held, F, H]``; products accumulate in float32."""
+    T, k = sel.shape
+    local, n_held = local_index(sel, n_experts, held)
+    if valid is not None:
+        local = jnp.where(valid[:, None], local, n_held)
+    if T <= DENSE_MAX_ROWS:
+        return _every_expert(x, local, wts, w1, w3, w2)
+    return _sorted_groups(x, local, wts, w1, w3, w2)
+
+
+def _every_expert(x, local, wts, w1, w3, w2):
+    """Few tokens: one batched product over the held experts, weighted
+    by the dense ``[T, E_held]`` routing matrix (an assignment to an
+    absent expert or from a pad slot has index ``E_held``: no column)."""
+    f32 = jnp.float32
+    dense = jnp.sum(jax.nn.one_hot(local, w1.shape[0], dtype=f32)
+                    * wts[..., None], axis=1)
+    h = jax.nn.silu(jnp.einsum("th,ehf->etf", x, w1,
+                               preferred_element_type=f32)) \
+        * jnp.einsum("th,ehf->etf", x, w3, preferred_element_type=f32)
+    y = jnp.einsum("etf,efh->eth", h.astype(x.dtype), w2,
+                   preferred_element_type=f32)
+    # float32 x float32: at the default precision the MXU would round
+    # the routing weights and the experts' outputs to bfloat16
+    return jnp.einsum("te,eth->th", dense, y, precision="highest")
+
+
+def _sorted_groups(x, local, wts, w1, w3, w2):
+    """Many tokens: assignments sorted by expert, one grouped product
+    per weight; index ``E_held`` sorts behind the last group."""
+    T, k = local.shape
+    n_held = w1.shape[0]
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    xs = jnp.take(x, order // k, axis=0)
+    f32 = jnp.float32
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes,
+                                       preferred_element_type=f32)) \
+        * jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=f32)
+    y = jax.lax.ragged_dot(h.astype(x.dtype), w2, sizes,
+                           preferred_element_type=f32)
+    # rows behind the last group are never written: zero them before
+    # the weights (0 x garbage is not 0)
+    live = (jnp.take(flat, order) < n_held)[:, None]
+    y = jnp.where(live, y * jnp.take(wts.reshape(-1), order)[:, None],
+                  0.0)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * k, dtype=order.dtype))
+    return jnp.take(y, back, axis=0).reshape(T, k, -1).sum(axis=1)

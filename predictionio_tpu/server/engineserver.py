@@ -664,6 +664,8 @@ class QueryServer:
         self._record_gram_mode()
         self._record_serving_kernel()
         self._record_sharding_findings()
+        for algo in self.algorithms:
+            self._bind_algorithm_metrics(algo)
         if self.cache is not None:
             self.cache.register_metrics(self.metrics)
         if locks_instrumented():
@@ -990,6 +992,7 @@ class QueryServer:
             for algo in self.algorithms:
                 algo.bind_serving(self.ctx)
                 self._bind_feature_cache(algo)
+                self._bind_algorithm_metrics(algo)
             # serving fast path knobs (ISSUE 13): pin the batched-lane
             # top-k realization for this deploy (validates the value —
             # a bad config fails the deploy, not the first query) and
@@ -1265,6 +1268,18 @@ class QueryServer:
         bind = getattr(algo, "bind_feature_cache", None)
         if bind is not None:
             bind(self.cache.features)
+
+    def _bind_algorithm_metrics(self, algo: Any) -> None:
+        """Hand the registry to algorithms that keep per-batch series
+        of their own (``register_metrics``; e.g. the generative
+        template's token and expert-load counts). The constructor's
+        initial ``_bind`` runs before the registry exists; ``__init__``
+        binds again right after."""
+        if getattr(self, "metrics", None) is None:
+            return
+        register = getattr(algo, "register_metrics", None)
+        if register is not None:
+            register(self.metrics)
 
     def _make_cache(self):
         cfg = self.config
@@ -2132,6 +2147,7 @@ class QueryServer:
         for algo in algorithms:
             algo.bind_serving(self.ctx)
             self._bind_feature_cache(algo)
+            self._bind_algorithm_metrics(algo)
         # the candidate serves under the same quant policy as stable
         # (an A/B across precision is a config change, not a canary);
         # raw_models stay unquantized so promote re-derives through

@@ -1,0 +1,288 @@
+"""Generative next-item template: the continuation of a session, item
+after item, from a decoder block stack (``models/decoder.py``) driven by
+a published language-model configuration, in the same DASE shape as
+every shipped template.
+
+Query ``{"items": ["i3", "i9", ...], "num": 32}``; answer
+``{"itemScores": [{"item": "i17", "score": 11.2}, ...]}`` with ``num``
+entries: entry ``j`` is the ``j``-th item generated (greedily) and its
+logit. Item ``"i<k>"`` is token ``k`` of the vocabulary; a history keeps
+its last ``max(history_buckets)`` items, and items outside the
+vocabulary are dropped.
+
+One query is a prefill and then ``max_new - 1`` decode steps, and a
+whole batch of them is ONE ``batch_predict_async``: the batch is padded
+to a bucket (rows and history), ``_gen_prefill`` and ``_gen_decode`` are
+enqueued back to back without a host sync, and the resolver blocks on
+the answer: the protocol of ``ALSAlgorithm.batch_predict_async``, so
+``StagedPipeline`` serves this engine as it serves ALS, with the next
+batch's prefill enqueued behind this one's decode. There is no
+per-step scheduler, no paged cache and no prefix reuse (ROADMAP.md).
+
+``train`` materialises the weights from ``params.seed``: importing a
+published checkpoint and training this architecture are not in the
+repository yet. What persists is the configuration and the seed.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass, field, replace
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
+
+from ..controller import (
+    Algorithm,
+    Context,
+    DataSource,
+    Engine,
+    FirstServing,
+    IdentityPreparator,
+)
+from ..utils.tracing import annotate
+from .sequential import ItemScore, PredictedResult
+
+EXPERTS_TOUCHED_BOUNDS = (1, 2, 4, 8, 12, 16, 20, 24, 28, 30, 31, 32)
+IMBALANCE_BOUNDS = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0)
+
+
+@dataclass(frozen=True)
+class Query:
+    items: Tuple[str, ...] = ()
+    num: int = 32
+
+    def __post_init__(self):
+        object.__setattr__(self, "items", tuple(self.items))
+
+
+@dataclass(frozen=True)
+class GenerativeParams:
+    #: the published ``config.json`` keys (``models/decoder.DecoderConfig``)
+    model: Dict[str, Any] = field(default_factory=dict)
+    seed: int = 0
+    #: tokens generated a query (a query's ``num`` is held to it)
+    max_new: int = 32
+    row_buckets: Tuple[int, ...] = (16, 32, 64)
+    history_buckets: Tuple[int, ...] = (128, 256, 512)
+
+    def __post_init__(self):
+        object.__setattr__(self, "row_buckets",
+                           tuple(sorted(int(b) for b in self.row_buckets)))
+        object.__setattr__(
+            self, "history_buckets",
+            tuple(sorted(int(b) for b in self.history_buckets)))
+
+
+@dataclass
+class GenerativeModel:
+    """The configuration, the seed and, once bound, the weights on the
+    device."""
+
+    config: Dict[str, Any]
+    seed: int = 0
+    weights: Optional[dict] = None
+
+    @functools.cached_property
+    def cfg(self):
+        from ..models.decoder import DecoderConfig
+
+        return DecoderConfig.from_dict(self.config)
+
+    def materialise(self) -> "GenerativeModel":
+        if self.weights is not None:
+            return self
+        import jax
+
+        from ..models.decoder import init_weights
+
+        # the hardware generator: threefry takes 70 s for 4.7 B normals
+        # on a v5e (my chip run, PR 27)
+        return replace(self, weights=init_weights(
+            jax.random.key(self.seed, impl="rbg"), self.cfg))
+
+
+@dataclass
+class TrainingData:
+    """Nothing: the vocabulary is the configuration's, the weights the
+    seed's."""
+
+
+@dataclass(frozen=True)
+class DataSourceParams:
+    app_name: str = ""
+
+
+class GenerativeDataSource(DataSource):
+    def __init__(self, params: DataSourceParams = DataSourceParams()):
+        self.params = params
+
+    def read_training(self, ctx: Context) -> TrainingData:
+        return TrainingData()
+
+
+def _bucket(buckets: Sequence[int], n: int) -> int:
+    for b in buckets:
+        if n <= b:
+            return b
+    return buckets[-1]
+
+
+class GenerativeAlgorithm(Algorithm):
+    """DASE wrapper over ``models/decoder``'s two programs."""
+
+    query_class = Query
+
+    def __init__(self, params: GenerativeParams = GenerativeParams()):
+        self.params = params
+        self._tokens = self._touched = self._imbalance = None
+
+    def train(self, ctx: Context, td: TrainingData) -> GenerativeModel:
+        return GenerativeModel(config=dict(self.params.model),
+                               seed=self.params.seed).materialise()
+
+    def make_persistent_model(self, model: GenerativeModel,
+                              engine_instance_id: str, algo_index: int):
+        return replace(model, weights=None)
+
+    def prepare_serving_model(self, model: GenerativeModel,
+                              max_batch: int = 1) -> GenerativeModel:
+        return model.materialise()
+
+    def register_metrics(self, registry) -> None:
+        """The engine's per-batch series on the server's registry
+        (docs/observability.md)."""
+        self._tokens = registry.counter(
+            "pio_gen_tokens_total",
+            "Token slots of generative batches by kind: prompt (real "
+            "history tokens), pad (the rest of rows x history bucket), "
+            "generated")
+        self._touched = registry.histogram(
+            "pio_moe_experts_touched",
+            "Distinct experts a decode step read, mean over the expert "
+            "layers and the steps of a batch",
+            bounds=EXPERTS_TOUCHED_BOUNDS)
+        self._imbalance = registry.histogram(
+            "pio_moe_load_imbalance",
+            "Largest over mean tokens per expert of an expert layer in "
+            "a batch's prefill",
+            bounds=IMBALANCE_BOUNDS)
+
+    def _history(self, model: GenerativeModel, query: Query) -> List[int]:
+        vocab = int(model.config["vocab_size"])
+        out = []
+        for item in query.items:
+            tok = item[1:]
+            if item[:1] == "i" and tok.isdigit() and int(tok) < vocab:
+                out.append(int(tok))
+        return out[-self.params.history_buckets[-1]:]
+
+    def _dispatch(self, model: GenerativeModel, hists: List[List[int]]):
+        """Enqueue one padded batch: ``(device outputs, rows, slots)``."""
+        import jax
+
+        from ..models.decoder import _gen_decode, _gen_prefill
+
+        p = self.params
+        B = _bucket(p.row_buckets, len(hists))
+        L = _bucket(p.history_buckets, max(len(h) for h in hists))
+        tokens = np.zeros((B, L), np.int32)
+        lengths = np.ones((B,), np.int32)  # a pad row is one token long
+        for r, h in enumerate(hists):
+            tokens[r, L - len(h):] = h
+            lengths[r] = len(h)
+        cfg = model.cfg
+        # explicit: the server's transfer guard logs an implicit one
+        tokens, lengths = jax.device_put((tokens, lengths))
+        with annotate("pio:gen_prefill", rows=B, history=L):
+            first, state = _gen_prefill(model.weights, tokens, lengths,
+                                        cfg=cfg, room=p.max_new)
+        with annotate("pio:gen_decode", rows=B, steps=p.max_new):
+            toks, scores, load, _ = _gen_decode(
+                model.weights, state, first, cfg=cfg, steps=p.max_new)
+        return (toks, scores, load), B * L
+
+    def _observe(self, hists, slots: int, load) -> None:
+        """Once a batch, never per query."""
+        if self._tokens is None:
+            return
+        prompt = sum(len(h) for h in hists)
+        self._tokens.labels(kind="prompt").inc(prompt)
+        self._tokens.labels(kind="pad").inc(slots - prompt)
+        self._tokens.labels(kind="generated").inc(
+            len(hists) * self.params.max_new)
+        prefill, decode = (np.asarray(a) for a in load)
+        if decode.size:
+            self._touched.observe(float((decode > 0).sum(axis=-1).mean()))
+        for layer in prefill:
+            if layer.sum() > 0:
+                self._imbalance.observe(float(layer.max() / layer.mean()))
+
+    def warm_serving(self, model: GenerativeModel,
+                     max_batch: int = 1) -> None:
+        """Compile the ladder: every row bucket up to ``max_batch``'s
+        at every history bucket (two programs each)."""
+        p = self.params
+        top = _bucket(p.row_buckets, max(max_batch, 1))
+        for b in p.row_buckets:
+            if b > top:
+                break
+            for L in p.history_buckets:
+                self._dispatch(model, [[0] * L] * b)[0][0].block_until_ready()
+
+    def batch_predict_async(self, model: GenerativeModel,
+                            queries: Sequence[Query]):
+        """Dispatch half of :meth:`batch_predict`: enqueues the prefill
+        and the decode of every row-bucketful of queries and returns a
+        no-arg resolver that blocks on the device arrays and builds the
+        per-query results."""
+        import jax
+
+        hists = [self._history(model, q) for q in queries]
+        live = [i for i, h in enumerate(hists) if h]
+        out: List[PredictedResult] = [PredictedResult()] * len(queries)
+        top = self.params.row_buckets[-1]
+        chunks = [live[s:s + top] for s in range(0, len(live), top)]
+        pending = [(chunk,) + self._dispatch(
+            model, [hists[i] for i in chunk]) for chunk in chunks]
+
+        def resolve() -> List[PredictedResult]:
+            for chunk, arrays, slots in pending:
+                toks, scores, load = jax.device_get(arrays)
+                self._observe([hists[i] for i in chunk], slots, load)
+                for row, i in enumerate(chunk):
+                    n = min(max(queries[i].num, 0), self.params.max_new)
+                    out[i] = PredictedResult(tuple(
+                        ItemScore(item=f"i{int(t)}", score=float(s))
+                        for t, s in zip(toks[row, :n], scores[row, :n])))
+            return out
+
+        return resolve
+
+    def batch_predict(self, model: GenerativeModel,
+                      queries: Sequence[Query]) -> List[PredictedResult]:
+        """Dispatch + immediate readback of
+        :meth:`batch_predict_async`: the two never diverge."""
+        return self.batch_predict_async(model, queries)()
+
+    def predict(self, model: GenerativeModel,
+                query: Query) -> PredictedResult:
+        return self.batch_predict(model, [query])[0]
+
+
+class GenerativeServing(FirstServing):
+    pass
+
+
+def generative_engine() -> Engine:
+    """Engine factory."""
+    return Engine(
+        datasource_classes=GenerativeDataSource,
+        preparator_classes=IdentityPreparator,
+        algorithm_classes={"decoder": GenerativeAlgorithm,
+                           "": GenerativeAlgorithm},
+        serving_classes=GenerativeServing,
+        datasource_params_class=DataSourceParams,
+        algorithm_params_classes={"decoder": GenerativeParams,
+                                  "": GenerativeParams},
+    )

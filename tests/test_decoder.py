@@ -1,0 +1,313 @@
+"""``models/decoder.py`` and ``ops/moe.py`` against the plain reference
+(``models/decoder_reference.py``) at a small size on the CPU: prefill
+and cached decoding, logit by logit.
+
+Tolerances. In float32 the program and the reference differ by the
+order of their sums only: 2e-4 of a logit whose spread is ~0.16
+(readings under 2e-5). The bfloat16 tolerances are written at their
+test.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.models import decoder, decoder_reference as ref
+from predictionio_tpu.ops import moe
+
+SMALL = {
+    "hidden_size": 64, "intermediate_size": 128,
+    "moe_intermediate_size": 32, "num_hidden_layers": 6,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
+                    "conv"],
+    "num_dense_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "num_experts": 8,
+    "num_experts_per_tok": 2, "vocab_size": 256, "conv_L_cache": 3,
+    "conv_bias": False, "norm_eps": 1e-5, "norm_topk_prob": True,
+    "use_expert_bias": True, "routed_scaling_factor": 1.0,
+    "rope_theta": 1000000, "head_dim": 16, "dtype": "float32",
+    "model_type": "lfm2_moe", "max_position_embeddings": 128000,
+}
+TOL32 = 2e-4
+STEPS = 9  # the prefill's token and 8 decode steps
+
+
+def _setup(dtype="float32", seed=0, **over):
+    d = {**SMALL, "dtype": dtype, **over}
+    cfg = decoder.DecoderConfig.from_dict(d)
+    w = decoder.init_weights(jax.random.key(seed), cfg)
+    return d, cfg, w
+
+
+def _ref_weights(w, cfg):
+    """The reference reads the program's tree as it is; a copy, so that
+    a test can put a layer's weights through a round trip."""
+    return {**w, "layers": [dict(lw) for lw in w["layers"]]}
+
+
+def _right_align(hists, L):
+    tokens = np.zeros((len(hists), L), np.int32)
+    for r, h in enumerate(hists):
+        tokens[r, L - len(h):] = h
+    return jnp.asarray(tokens), jnp.asarray([len(h) for h in hists],
+                                            jnp.int32)
+
+
+def _generate(w, cfg, hists, L, steps=STEPS):
+    tokens, lengths = _right_align(hists, L)
+    first, state = decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
+                                        room=steps)
+    first = np.asarray(first)
+    toks, scores, load, _ = decoder._gen_decode(
+        w, state, jnp.asarray(first), cfg=cfg, steps=steps)
+    return first, np.asarray(toks), np.asarray(scores), load
+
+
+def _hists(rng, lengths):
+    return [rng.integers(0, SMALL["vocab_size"], n).tolist()
+            for n in lengths]
+
+
+@pytest.fixture(scope="module")
+def small():
+    return _setup()
+
+
+@pytest.fixture(scope="module")
+def served(small):
+    d, cfg, w = small
+    # 6 x 32 slots: the prefill's experts go through the sorted groups,
+    # the decode's 6 rows through the every-expert product
+    hists = _hists(np.random.default_rng(1), [5, 16, 11, 1, 30, 32])
+    return hists, _generate(w, cfg, hists, 32)
+
+
+def test_config_reads_the_published_keys():
+    _, cfg, _ = _setup()
+    assert cfg.head_dim == 16 and cfg.n_held == 8
+    lfm = dict(SMALL, num_hidden_layers=14, head_dim=None,
+               layer_types=["conv", "conv"] + ["full_attention", "conv",
+                                               "conv", "conv"] * 3)
+    big = decoder.DecoderConfig.from_dict(lfm)
+    assert big.head_dim == 16 and len(big.layer_types) == 14
+    with pytest.raises(ValueError):
+        decoder.DecoderConfig.from_dict(dict(SMALL, conv_bias=True))
+    with pytest.raises(ValueError):
+        decoder.DecoderConfig.from_dict(dict(SMALL, num_hidden_layers=5))
+
+
+def test_prefill_and_cached_decode_match_the_full_forward(small, served):
+    """Every served score is the reference's logit of the served token
+    at that position, from ONE uncached forward over history + served
+    tokens; and greedy took the reference's best."""
+    d, cfg, w = small
+    hists, (first, toks, scores, _) = served
+    rw = _ref_weights(w, cfg)
+    for r, h in enumerate(hists):
+        seq = h + toks[r, :-1].tolist()
+        logits = np.asarray(ref.forward(rw, seq, d))[len(h) - 1:]
+        assert logits.shape[0] == STEPS
+        np.testing.assert_allclose(first[r], logits[0], atol=TOL32)
+        at = logits[np.arange(STEPS), toks[r]]
+        np.testing.assert_allclose(scores[r], at, atol=TOL32)
+        assert np.all(logits.max(axis=1) - at <= TOL32)
+
+
+@pytest.mark.parametrize("window,beside", [(16, []), (32, [30, 32]),
+                                           (64, [64, 40, 3]),
+                                           (128, [3, 128])])
+def test_padding_and_neighbours_do_not_move_a_row(small, served, window,
+                                                  beside):
+    """The same history alone, and left-padded to 2x and 4x its window
+    beside longer rows, gives the same logits and the same tokens."""
+    d, cfg, w = small
+    hists, (first, toks, scores, _) = served
+    rng = np.random.default_rng(2)
+    mix = [hists[1]] + _hists(rng, beside)
+    f2, t2, s2, _ = _generate(w, cfg, mix, window)
+    np.testing.assert_allclose(f2[0], first[1], atol=TOL32)
+    np.testing.assert_array_equal(t2[0], toks[1])
+    np.testing.assert_allclose(s2[0], scores[1], atol=TOL32)
+
+
+def test_row_groups_give_what_one_pass_gives(small, served, monkeypatch):
+    """A batch that goes through the prefill in row groups
+    (``PREFILL_SLOTS``) reads as it does in one pass."""
+    d, cfg, w = small
+    hists, (first, toks, scores, load) = served
+    monkeypatch.setattr(decoder, "PREFILL_SLOTS", 32)
+    jax.clear_caches()
+    f2, t2, s2, l2 = _generate(w, cfg, hists, 32)
+    jax.clear_caches()
+    np.testing.assert_allclose(f2, first, atol=TOL32)
+    np.testing.assert_array_equal(t2, toks)
+    np.testing.assert_array_equal(np.asarray(l2[0]), np.asarray(load[0]))
+
+
+def test_conv_step_equals_its_prefill(small):
+    d, cfg, w = small
+    lw = w["layers"][0]
+    z = jax.random.normal(jax.random.key(3), (2, 7, cfg.hidden_size))
+    valid = jnp.ones((2, 7), bool)
+    full, st = decoder._conv_prefill(lw, z, valid, cfg)
+    part, st6 = decoder._conv_prefill(lw, z[:, :6], valid[:, :6], cfg)
+    step, st7 = decoder._conv_step(lw, z[:, 6], st6, cfg)
+    np.testing.assert_allclose(step, full[:, 6], atol=1e-5)
+    np.testing.assert_allclose(st7["win"], st["win"], atol=1e-6)
+    np.testing.assert_allclose(
+        full[0], ref.conv_op(lw, z[0], d), atol=1e-5)
+
+
+def test_expert_load_counts_real_tokens_only(small, served):
+    d, cfg, w = small
+    hists, (_, _, _, (pre, dec)) = served
+    pre, dec = np.asarray(pre), np.asarray(dec)
+    k = cfg.num_experts_per_tok
+    n_expert = cfg.num_hidden_layers - cfg.num_dense_layers
+    assert pre.shape == (n_expert, cfg.num_experts)
+    assert dec.shape == (STEPS - 1, n_expert, cfg.num_experts)
+    assert (pre.sum(axis=1) == k * sum(len(h) for h in hists)).all()
+    assert (dec.sum(axis=2) == k * len(hists)).all()
+
+
+def test_router_bias_moves_the_selection_not_the_weights():
+    z = jax.random.normal(jax.random.key(4), (32, 64))
+    gate = jax.random.normal(jax.random.key(5), (64, 8)) / 8.0
+    sel0, w0 = moe.route(z, gate, None, top_k=2)
+    bias = jnp.zeros(8).at[3].set(10.0)
+    sel1, w1 = moe.route(z, gate, bias, top_k=2)
+    assert (np.asarray(sel1) == 3).any(axis=1).all()
+    assert not (np.asarray(sel0) == 3).any(axis=1).all()
+    s = np.asarray(jax.nn.sigmoid(jnp.dot(z, gate, precision="highest")))
+    picked = np.take_along_axis(s, np.asarray(sel1), axis=1)
+    # the weights come from s WITHOUT the bias, and sum to
+    # 1 / (1 + 1e-6 / sum)
+    np.testing.assert_allclose(
+        w1, picked / (picked.sum(1, keepdims=True) + 1e-6), rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(w1).sum(1), 1 / (1 + 1e-6 / picked.sum(1)), rtol=1e-5)
+    _, raw = moe.route(z, gate, bias, top_k=2, norm_topk=False, scale=2.0)
+    np.testing.assert_allclose(raw, 2.0 * picked, rtol=1e-5)
+
+
+@pytest.mark.parametrize("tokens", [24, 200])
+def test_both_forms_of_the_product_give_the_reference(small, tokens):
+    """Few tokens (every expert for every token) and many (sorted
+    groups), pad slots and an absent expert among them."""
+    d, cfg, w = small
+    lw = w["layers"][4]
+    z = jax.random.normal(jax.random.key(tokens), (tokens, cfg.hidden_size))
+    valid = jnp.arange(tokens) % 5 != 0
+    sel, wts = moe.route(z, lw["gate"], lw["gate_bias"], top_k=2)
+    held = (0, 1, 2, 4, 5, 6, 7)
+    idx = jnp.asarray(held)
+    args = (z, sel, wts, lw["w1"][idx], lw["w3"][idx], lw["w2"][idx])
+    got = moe.expert_product(*args, n_experts=8, held=held, valid=valid)
+    local, _ = moe.local_index(sel, 8, held)
+    local = jnp.where(valid[:, None], local, len(held))
+    np.testing.assert_allclose(
+        moe._every_expert(z, local, wts, *args[3:]),
+        moe._sorted_groups(z, local, wts, *args[3:]), atol=1e-5)
+    share = {**lw, "w1": args[3], "w3": args[4], "w2": args[5]}
+    want = np.where(np.asarray(valid)[:, None], np.asarray(
+        ref.expert_ff(share, z, {**d, "experts_held": held})), 0.0)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_four_shares_add_up_to_the_uncut_layer(small):
+    """The guide's share test: four chips of 2 experts each route over
+    all 8 and compute their own experts' part; the parts add up to the
+    reference's whole layer (program AND reference given the shares)."""
+    d, cfg, w = small
+    lw = w["layers"][3]
+    z = jax.random.normal(jax.random.key(6), (24, cfg.hidden_size))
+    whole = np.asarray(ref.expert_ff(lw, z, d))
+    full, _ = decoder._feed_forward(lw, z, None, cfg)
+    np.testing.assert_allclose(full, whole, atol=1e-5)
+    got = np.zeros_like(whole)
+    want = np.zeros_like(whole)
+    for held in ((0, 1), (2, 3), (4, 5), (6, 7)):
+        idx = jnp.asarray(held)
+        share = {**lw, "w1": lw["w1"][idx], "w3": lw["w3"][idx],
+                 "w2": lw["w2"][idx]}
+        part, load = decoder._feed_forward(
+            share, z, None, dataclasses.replace(cfg, experts_held=held))
+        assert int(load.sum()) == 24 * cfg.num_experts_per_tok
+        got += np.asarray(part)
+        want += np.asarray(ref.expert_ff(share, z,
+                                         {**d, "experts_held": held}))
+    np.testing.assert_allclose(got, whole, atol=1e-5)
+    np.testing.assert_allclose(want, whole, atol=1e-5)
+
+
+def _int8_round_trip(a):
+    """Symmetric int8 with one scale per output column."""
+    a = np.asarray(a, np.float32)
+    scale = np.abs(a).max(axis=-2, keepdims=True) / 127.0
+    return jnp.asarray(np.round(a / scale) * scale)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("seed", [7, 11])
+def test_bfloat16_stays_inside_the_tolerance_and_int8_does_not(seed):
+    """The expert block with bfloat16 weights, against the reference in
+    float32 on those same weights: 4e-3 of the output's norm (readings
+    1.5e-3 .. 1.8e-3: the rounding of two operands to bfloat16), which
+    the reference does not meet once the expert weights have been
+    through int8 (readings 9.8e-3 .. 1.04e-2). The whole stack is then
+    held at the median position, in units of the position's spread of
+    reference logits (limit 0.05; readings 0.008 .. 0.015): there the
+    rounding of every layer's operands is as large as what int8 adds
+    in the experts, so that limit does not tell the two apart and the
+    layer's does."""
+    d, cfg, w = _setup("bfloat16", seed=seed)
+    lw = w["layers"][3]
+    z = jax.random.normal(jax.random.key(seed), (64, cfg.hidden_size)
+                          ).astype(jnp.bfloat16).astype(jnp.float32)
+    want = ref.expert_ff(lw, z, d)
+    got, _ = decoder._feed_forward(lw, z, None, cfg)
+    assert _rel(got, want) <= 4e-3
+    lossy = {**lw, **{n: _int8_round_trip(lw[n])
+                      for n in ("w1", "w3", "w2")}}
+    assert _rel(ref.expert_ff(lossy, z, d), want) > 4e-3
+
+    hists = _hists(np.random.default_rng(seed + 1), [12, 16, 7, 16])
+    _, toks, scores, _ = _generate(w, cfg, hists, 16)
+    gaps = []
+    for r, h in enumerate(hists):
+        seq = h + toks[r, :-1].tolist()
+        logits = np.asarray(ref.forward(w, seq, d))[len(h) - 1:]
+        at = logits[np.arange(len(logits)), toks[r]]
+        gaps.append(np.abs(scores[r] - at) / logits.std(axis=1))
+    assert np.median(np.concatenate(gaps)) <= 0.05
+
+
+def test_the_benchmarks_copy_of_the_reference_is_the_same(small):
+    """``cellbench/reference_lfm2.py`` imports nothing of the program;
+    it is held to this package's reference output for output."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "cellbench", "reference_lfm2.py")
+    spec = importlib.util.spec_from_file_location("reference_lfm2", path)
+    copy = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(copy)
+    d, cfg, w = small
+    seq = _hists(np.random.default_rng(9), [13])[0]
+    np.testing.assert_array_equal(np.asarray(copy.forward(w, seq, d)),
+                                  np.asarray(ref.forward(w, seq, d)))
+    share = {**d, "experts_held": (2, 5)}
+    lw = w["layers"][4]
+    lw = {**lw, **{n: lw[n][jnp.asarray((2, 5))]
+                   for n in ("w1", "w3", "w2")}}
+    z = jax.random.normal(jax.random.key(10), (5, cfg.hidden_size))
+    np.testing.assert_array_equal(np.asarray(copy.expert_ff(lw, z, share)),
+                                  np.asarray(ref.expert_ff(lw, z, share)))

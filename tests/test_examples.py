@@ -10,7 +10,7 @@ VARIANTS = sorted(glob.glob("examples/*/engine.json"))
 
 
 def test_examples_exist():
-    assert len(VARIANTS) == 5
+    assert len(VARIANTS) == 6
 
 
 @pytest.mark.parametrize("path", VARIANTS)

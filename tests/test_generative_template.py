@@ -1,0 +1,159 @@
+"""The generative template (``templates/generative.py``) on the normal
+path: bound into ``QueryServer`` with ``ServerConfig(batching=True)``,
+queried over HTTP, batches formed and launched by ``StagedPipeline``."""
+
+import http.client
+import json
+import threading
+from datetime import datetime, timezone
+
+import pytest
+
+from predictionio_tpu.controller import Context
+from predictionio_tpu.controller.params import EngineParams
+from predictionio_tpu.data.storage import App, Storage
+from predictionio_tpu.data.storage.base import (
+    STATUS_COMPLETED,
+    EngineInstance,
+)
+from predictionio_tpu.server.engineserver import (
+    QueryServer,
+    ServerConfig,
+    StagedPipeline,
+    create_engine_server,
+)
+from predictionio_tpu.templates.generative import (
+    GenerativeAlgorithm,
+    GenerativeModel,
+    GenerativeParams,
+    Query,
+    generative_engine,
+)
+from test_decoder import SMALL
+
+PARAMS = GenerativeParams(model=SMALL, seed=3, max_new=8,
+                          row_buckets=(4, 8), history_buckets=(16, 32))
+HISTORIES = [[5], [7, 9, 200, 13], list(range(20, 45)),
+             list(range(1, 17)), [255] * 40, [3, 1, 4, 1, 5, 9, 2, 6]]
+
+
+def _query(hist, num=8):
+    return {"items": [f"i{t}" for t in hist], "num": num}
+
+
+@pytest.fixture(scope="module")
+def served():
+    storage = Storage(env={"PIO_STORAGE_SOURCES_MEM_TYPE": "memory"})
+    storage.apps().insert(App(0, "gen"))
+    ctx = Context(app_name="gen", _storage=storage)
+    now = datetime.now(timezone.utc)
+    inst = EngineInstance(
+        id="g0", status=STATUS_COMPLETED, start_time=now, end_time=now,
+        engine_id="gen", engine_version="1",
+        engine_variant="engine.json", engine_factory="synthetic")
+    engine = generative_engine()
+    ep = EngineParams(algorithms=(("decoder", PARAMS),))
+    model = GenerativeModel(config=dict(SMALL), seed=PARAMS.seed)
+    qs = QueryServer(ctx, engine, ep, [model], inst,
+                     ServerConfig(batching=True, max_batch=8,
+                                  batch_window_ms=20.0))
+    assert qs.warm_done.wait(300), qs.warm_error
+    srv = create_engine_server(qs, host="127.0.0.1", port=0)
+    srv.start_background()
+    yield qs, srv
+    srv.shutdown()
+    qs.close()
+
+
+def _post(port, body):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    try:
+        conn.request("POST", "/queries.json", json.dumps(body),
+                     {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        payload = resp.read()
+        assert resp.status == 200, payload
+        return json.loads(payload)
+    finally:
+        conn.close()
+
+
+def test_http_answers_what_batch_predict_gives(served):
+    """Mixed history lengths (1 .. over the longest bucket) in one
+    batch: every caller gets ITS history's continuation, the one
+    ``batch_predict`` gives that history alone."""
+    qs, srv = served
+    assert isinstance(qs.batcher, StagedPipeline)
+    got = [None] * len(HISTORIES)
+
+    def fire(i):
+        got[i] = _post(srv.port, _query(HISTORIES[i], num=3 + i % 6))
+
+    threads = [threading.Thread(target=fire, args=(i,))
+               for i in range(len(HISTORIES))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    algo, model = qs.algorithms[0], qs.models[0]
+    for i, hist in enumerate(HISTORIES):
+        num = 3 + i % 6
+        want = algo.batch_predict(
+            model, [Query(items=_query(hist)["items"], num=num)]
+        )[0].to_json()["itemScores"]
+        scores = got[i]["itemScores"]
+        assert len(scores) == num
+        assert [s["item"] for s in scores] == [s["item"] for s in want]
+        for g, w in zip(scores, want):
+            assert g["score"] == pytest.approx(w["score"], abs=2e-4)
+    # the batcher coalesced: fewer batches than queries
+    occ = qs.metrics.export()["pio_batch_occupancy"]["children"][0]
+    assert occ["sum"] / occ["count"] > 1.0
+
+
+def test_no_compile_after_the_warm_ladder(served):
+    qs, srv = served
+    for hist in ([9], list(range(30)), list(range(100, 120))):
+        _post(srv.port, _query(hist))
+    status = _post_get(srv.port, "/status.json")
+    assert status["recompile"]["armed"] is True
+    assert status["recompile"]["compilesSinceWarm"] == 0
+
+
+def _post_get(port, path):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    try:
+        conn.request("GET", path)
+        return json.loads(conn.getresponse().read())
+    finally:
+        conn.close()
+
+
+def test_per_batch_series_are_on_the_servers_registry(served):
+    qs, srv = served
+    _post(srv.port, _query([1, 2, 3]))
+    export = qs.metrics.export()
+    kinds = {c["labels"]["kind"]: c["value"]
+             for c in export["pio_gen_tokens_total"]["children"]}
+    assert kinds["prompt"] > 0 and kinds["generated"] > 0
+    # one query in a 4 x 16 bucket: 3 prompt tokens, the rest padding
+    assert kinds["pad"] >= 4 * 16 - 3
+    touched = export["pio_moe_experts_touched"]["children"][0]
+    assert touched["count"] >= 1
+    assert 1 <= touched["sum"] / touched["count"] <= SMALL["num_experts"]
+    assert export["pio_moe_load_imbalance"]["children"][0]["count"] >= 4
+
+
+def test_unknown_items_and_empty_histories():
+    algo = GenerativeAlgorithm(PARAMS)
+    model = GenerativeModel(config=dict(SMALL), seed=1).materialise()
+    out = algo.batch_predict(model, [
+        Query(items=("i999", "nope"), num=4), Query(items=("i5",), num=2)])
+    assert out[0].item_scores == ()
+    assert len(out[1].item_scores) == 2
+    stored = algo.make_persistent_model(model, "x", 0)
+    assert stored.weights is None
+    again = algo.prepare_serving_model(stored, 8)
+    assert algo.batch_predict(again, [Query(items=("i5",), num=2)]
+                              )[0] == out[1]
