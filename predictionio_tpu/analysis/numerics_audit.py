@@ -391,7 +391,7 @@ def _gen_small():
         num_dense_layers=1, num_attention_heads=4, num_key_value_heads=2,
         num_experts=8, num_experts_per_tok=2, vocab_size=256)
     w = decoder.init_weights(jax.random.key(0), cfg)
-    tokens = np.zeros((4, 16), np.int32)
+    tokens = np.zeros((64,), np.int32)  # 4 rows of 9, packed; 28 spare
     lengths = np.full((4,), 9, np.int32)
     return decoder, cfg, w, tokens, lengths
 
@@ -401,7 +401,8 @@ def _entry_gen_prefill():
 
     decoder, cfg, w, tokens, lengths = _gen_small()
     return jax.make_jaxpr(
-        lambda w, t, n: decoder._gen_prefill(w, t, n, cfg=cfg, room=4)
+        lambda w, t, n: decoder._gen_prefill(w, t, n, cfg=cfg, history=16,
+                                             room=4)
     )(w, tokens, lengths)
 
 
@@ -410,7 +411,7 @@ def _entry_gen_decode():
 
     decoder, cfg, w, tokens, lengths = _gen_small()
     first, state = decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
-                                        room=4)
+                                        history=16, room=4)
     return jax.make_jaxpr(
         lambda w, s, f: decoder._gen_decode(w, s, f, cfg=cfg, steps=4)
     )(w, state, first)

@@ -16,14 +16,24 @@ Layer ``l``: ``h = x + op_l(n(x))``, ``y = h + ff_l(n(h))`` with RMSNorm
 (``ops/moe.py``) after. ``models/decoder_reference.py`` writes the
 equations out; the tests hold this module to it logit by logit.
 
-Layout. Histories are right-aligned into ``[B, L]`` as ``seqrec`` has
-them, so every row appends at the same slot when decoding. A pad slot
-is masked out of attention (``key_valid``), contributes a zero to a conv
-window, joins no expert's group, and rotary positions count from a row's
-first real token: a row's logits do not depend on how far it was padded
-or on what lies beside it. State of two kinds is carried from one
-program to the next: keys and values that grow (attention layers) and a
-fixed ``conv_L_cache``-wide window of ``B*u`` (conv layers).
+Layout. The prefill takes a batch PACKED: the real tokens of its rows
+one behind the other in one stream of ``T`` slots (``tokens [T]``,
+``lengths [B]``; the spare slots behind the last row hold anything), so
+its cost follows the tokens a batch has and not rows x its longest row.
+Whatever treats a token on its own (norms, projections, feed-forwards,
+the router, the expert products) runs over ``[T, H]`` and knows no rows.
+The two operators that mix positions stay inside a row: a conv tap that
+would reach before a row's first token adds zero, and attention runs
+over the rows gathered into the right-aligned ``[B, history]`` layout
+that the decode's cache has anyway (pad slots masked by ``key_valid``),
+with rotary positions counted from a row's first token. A spare slot
+joins no expert's group and no row reads it: a row's logits do not
+depend on where in the stream it lies or on what lies beside it. State
+of two kinds is carried from one program to the next: keys and values
+that grow (attention layers), right-aligned at ``history`` slots
+whatever the batch so that every row appends at the same slot and the
+decode's shapes depend on ``B`` alone, and a fixed ``conv_L_cache``-wide
+window of ``B*u`` (conv layers).
 
 Precision. Weights in ``cfg.dtype`` (bfloat16 as served). Every matrix
 product takes operands in that dtype and accumulates in float32
@@ -51,13 +61,14 @@ from ..ops import moe
 from ..ops.ring_attention import ring_attention
 
 CONV, ATTENTION = "conv", "full_attention"
-#: slots (rows x history) one pass of the prefill takes through the
-#: stack: larger batches go through in row groups of this many slots
-#: (``lax.map``), which bounds the activations (the widest, the sorted
-#: expert rows, is ``4 x slots x 2048`` float32) at one more streaming
-#: of the weights per group. 64 x 512 slots took 0.786 s in groups of
-#: 8,192, 0.743 s at 16,384 and 0.758 s whole (my chip run, PR 27)
-PREFILL_SLOTS = 16384
+#: rows whose attention is taken in one call: a row group's scores are
+#: ``[rows, heads, history, history]`` float32 (0.5 GB at 16 x 32 x 512
+#: x 512), and the group bounds what the program holds beside the
+#: stream: 1.55 GB of temporaries at 16,384 slots where the 64 rows
+#: whole take 2.89 (compiled for the v5e). The prefill of 64 rows in
+#: 16,384 slots took 466.8 ms in groups of 8, 466.4 at 16, 469.4 at 32
+#: and 470.3 whole (my chip run, PR 28)
+ATTENTION_ROWS = 16
 
 
 @dataclass(frozen=True)
@@ -274,59 +285,62 @@ def _feed_forward(lw, z, valid, cfg):
 
 # -- prefill ----------------------------------------------------------------
 
-def _conv_prefill(lw, z, valid, cfg):
+def _conv_prefill(lw, z, valid, pos, last, cfg):
+    """``z [T, H]`` packed. A tap ``s`` slots back is the slot ``s``
+    before in the stream where the token is at least ``s`` into its row,
+    and zero where it is not. ``last [B]``: each row's last slot, for
+    the window the decode goes on from."""
     K = cfg.conv_L_cache
     b, c, u = jnp.split(_dot(z, lw["w_in"]), 3, axis=-1)
-    v = jnp.where(valid[..., None], b * u, 0.0)
-    L = v.shape[1]
-    vp = jnp.pad(v, ((0, 0), (K - 1, 0), (0, 0)))
-    y = sum(lw["conv_w"][:, j] * vp[:, j:j + L] for j in range(K))
-    return _dot(c * y, lw["w_out"]), {"win": vp[:, L - 1:]}
+    v = jnp.where(valid[:, None], b * u, 0.0)
+    T = v.shape[0]
+    y = lw["conv_w"][:, K - 1] * v
+    for s in range(1, K):
+        back = jnp.pad(v, ((s, 0), (0, 0)))[:T]
+        y = y + lw["conv_w"][:, K - 1 - s] * jnp.where(
+            (pos >= s)[:, None], back, 0.0)
+    ago = jnp.arange(K - 1, -1, -1, dtype=jnp.int32)
+    # ptpu: allow[materialized-gather] — [B, K, H]: the K last slots of
+    # each row, the state itself
+    win = jnp.take(v, jnp.maximum(last[:, None] - ago, 0), axis=0)
+    win = jnp.where((pos[last][:, None] >= ago)[..., None], win, 0.0)
+    return _dot(c * y, lw["w_out"]), {"win": win}
 
 
-def _attention_prefill(lw, z, valid, pos, room, cfg):
-    q, k, v = _qkv(lw, z, pos, cfg)
+def _attention_prefill(lw, z, pos, rows, room, cfg):
+    """``z [T, H]`` packed. ``q``, ``k``, ``v`` are gathered into the
+    right-aligned ``[B, history]`` layout (``rows``: where each of its
+    slots lies in the stream, which of them are real, and where each
+    slot of the stream lies in it), attention is taken a row group at a
+    time, and the output is gathered back into the stream."""
+    src, real, dst = rows
+    B, L = src.shape
+
+    def to_rows(a):
+        # ptpu: allow[materialized-gather] — the layout the cache keeps:
+        # [B, history, heads, D] in the weights' dtype, zeros where a
+        # row has no token
+        flat = jnp.take(a.reshape(a.shape[0], -1), src.reshape(-1), axis=0)
+        return jnp.where(real.reshape(-1, 1), flat, 0).reshape(
+            (B, L) + a.shape[1:])
+
+    q, k, v = (to_rows(a) for a in _qkv(lw, z, pos, cfg))
     g = cfg.num_attention_heads // cfg.num_key_value_heads
-    o = ring_attention(q, jnp.repeat(k, g, axis=2),
-                       jnp.repeat(v, g, axis=2), mesh=None, causal=True,
-                       scale=cfg.head_dim ** -0.5, key_valid=valid)
+    n = max(r for r in range(1, min(B, ATTENTION_ROWS) + 1) if B % r == 0)
+
+    def group(a):
+        qg, kg, vg, ok = a
+        return ring_attention(qg, jnp.repeat(kg, g, axis=2),
+                              jnp.repeat(vg, g, axis=2), mesh=None,
+                              causal=True, scale=cfg.head_dim ** -0.5,
+                              key_valid=ok)
+
+    o = jax.lax.map(group, jax.tree_util.tree_map(
+        lambda a: a.reshape((B // n, n) + a.shape[1:]), (q, k, v, real)))
+    # ptpu: allow[materialized-gather] — back into the stream: [T, H]
+    o = jnp.take(o.reshape(B * L, -1), dst, axis=0)
     grow = ((0, 0), (0, room), (0, 0), (0, 0))
-    return (_dot(o.reshape(z.shape[:-1] + (-1,)), lw["wo"]),
-            {"k": jnp.pad(k, grow), "v": jnp.pad(v, grow)})
-
-
-def _layer_prefill(lw, kind, x, valid, pos, room, cfg):
-    z = _rms(x, lw["op_norm"], cfg.norm_eps)
-    if kind == CONV:
-        o, st = _conv_prefill(lw, z, valid, cfg)
-    else:
-        o, st = _attention_prefill(lw, z, valid, pos, room, cfg)
-    h = x + o
-    B, L, H = h.shape
-    f, load = _feed_forward(
-        lw, _rms(h, lw["ff_norm"], cfg.norm_eps).reshape(B * L, H),
-        valid.reshape(B * L), cfg)
-    return h + f.reshape(B, L, H), st, load
-
-
-def _prefill_rows(w, tokens, lengths, cfg, room):
-    """One row group through the stack: ``(last_logits, layer states,
-    load [expert layers, E])``."""
-    B, L = tokens.shape
-    slot = jnp.arange(L, dtype=jnp.int32)[None, :]
-    first = (L - lengths)[:, None]
-    valid, pos = slot >= first, slot - first
-    # ptpu: allow[materialized-gather] — the embedding lookup itself: the
-    # [rows, history, H] it makes is the residual stream, bounded by
-    # PREFILL_SLOTS
-    x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
-    states, loads = [], []
-    for lw, kind in zip(w["layers"], cfg.layer_types):
-        x, st, load = _layer_prefill(lw, kind, x, valid, pos, room, cfg)
-        states.append(st)
-        if load is not None:
-            loads.append(load)
-    return _head(w, x[:, -1], cfg), states, jnp.stack(loads)
+    return _dot(o, lw["wo"]), {"k": jnp.pad(k, grow), "v": jnp.pad(v, grow)}
 
 
 def _head(w, x, cfg):
@@ -335,33 +349,51 @@ def _head(w, x, cfg):
                    preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "room"))
+@functools.partial(jax.jit, static_argnames=("cfg", "history", "room"))
 def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
-                 cfg: DecoderConfig, room: int):
-    """``tokens [B, L]`` right-aligned (any id in the pad slots),
-    ``lengths [B]`` -> ``(last_logits [B, V] float32, state)`` with room
-    for ``room`` more tokens in the state."""
-    B, L = tokens.shape
-    rows = max(1, min(B, PREFILL_SLOTS // L))
-    while B % rows:
-        rows -= 1
-    n = B // rows
-    if n == 1:
-        logits, states, load = _prefill_rows(w, tokens, lengths, cfg, room)
-    else:
-        logits, states, load = jax.lax.map(
-            lambda a: _prefill_rows(w, a[0], a[1], cfg, room),
-            (tokens.reshape(n, rows, L), lengths.reshape(n, rows)))
-        logits = logits.reshape(B, -1)
-        states = jax.tree_util.tree_map(
-            lambda a: a.reshape((B,) + a.shape[2:]), states)
-        load = load.sum(axis=0)
-    slot = jnp.arange(L + room, dtype=jnp.int32)[None, :]
-    state = {"layers": states, "load": load,
-             "pos": lengths.astype(jnp.int32),
-             "valid": (slot >= (L - lengths)[:, None]) & (slot < L),
-             "filled": jnp.asarray(L, jnp.int32)}
-    return logits, state
+                 cfg: DecoderConfig, history: int, room: int):
+    """``tokens [T]``: the rows' tokens one behind the other, row 0
+    first (any id in the spare slots behind the last row); ``lengths
+    [B]``, each from 1 to ``history`` and ``T`` or under together ->
+    ``(last_logits [B, V] float32, state)``, the state laid out at
+    ``history`` slots and room for ``room`` more tokens."""
+    T, B = tokens.shape[0], lengths.shape[0]
+    lengths = lengths.astype(jnp.int32)
+    ends = jnp.cumsum(lengths)
+    first = ends - lengths
+    slot = jnp.arange(T, dtype=jnp.int32)
+    row = jnp.minimum(jnp.searchsorted(ends, slot, side="right",
+                                       method="compare_all"), B - 1)
+    valid, pos = slot < ends[-1], slot - first[row]
+    # the right-aligned [B, history] layout: its slot ``a`` of row ``r``
+    # is slot ``first[r] + a - (history - lengths[r])`` of the stream
+    at = jnp.arange(history, dtype=jnp.int32)[None, :]
+    lead = history - lengths
+    real = at >= lead[:, None]
+    rows = (jnp.where(real, (first - lead)[:, None] + at, 0), real,
+            jnp.where(valid, row * history + lead[row] + pos, 0))
+    # ptpu: allow[materialized-gather] — the embedding lookup itself: the
+    # [T, H] it makes is the residual stream
+    x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
+    states, loads = [], []
+    for lw, kind in zip(w["layers"], cfg.layer_types):
+        z = _rms(x, lw["op_norm"], cfg.norm_eps)
+        if kind == CONV:
+            o, st = _conv_prefill(lw, z, valid, pos, ends - 1, cfg)
+        else:
+            o, st = _attention_prefill(lw, z, pos, rows, room, cfg)
+        h = x + o
+        f, load = _feed_forward(lw, _rms(h, lw["ff_norm"], cfg.norm_eps),
+                                valid, cfg)
+        x = h + f
+        states.append(st)
+        if load is not None:
+            loads.append(load)
+    cache = jnp.arange(history + room, dtype=jnp.int32)[None, :]
+    state = {"layers": states, "load": jnp.stack(loads), "pos": lengths,
+             "valid": (cache >= lead[:, None]) & (cache < history),
+             "filled": jnp.asarray(history, jnp.int32)}
+    return _head(w, x[ends - 1], cfg), state
 
 
 # -- decode -----------------------------------------------------------------

@@ -11,13 +11,15 @@ its last ``max(history_buckets)`` items, and items outside the
 vocabulary are dropped.
 
 One query is a prefill and then ``max_new - 1`` decode steps, and a
-whole batch of them is ONE ``batch_predict_async``: the batch is padded
-to a bucket (rows and history), ``_gen_prefill`` and ``_gen_decode`` are
-enqueued back to back without a host sync, and the resolver blocks on
-the answer: the protocol of ``ALSAlgorithm.batch_predict_async``, so
-``StagedPipeline`` serves this engine as it serves ALS, with the next
-batch's prefill enqueued behind this one's decode. There is no
-per-step scheduler, no paged cache and no prefix reuse (ROADMAP.md).
+whole batch of them is ONE ``batch_predict_async``: the rows are padded
+to a row bucket and their real tokens packed one behind the other into
+a stream of ``rows x the history bucket of the batch's MEAN history``
+slots, ``_gen_prefill`` and ``_gen_decode`` are enqueued back to back
+without a host sync, and the resolver blocks on the answer: the
+protocol of ``ALSAlgorithm.batch_predict_async``, so ``StagedPipeline``
+serves this engine as it serves ALS, with the next batch's prefill
+enqueued behind this one's decode. There is no per-step scheduler, no
+paged cache and no prefix reuse (ROADMAP.md).
 
 ``train`` materialises the weights from ``params.seed``: importing a
 published checkpoint and training this architecture are not in the
@@ -155,7 +157,7 @@ class GenerativeAlgorithm(Algorithm):
         self._tokens = registry.counter(
             "pio_gen_tokens_total",
             "Token slots of generative batches by kind: prompt (real "
-            "history tokens), pad (the rest of rows x history bucket), "
+            "history tokens), pad (the rest of the slots the prefill ran), "
             "generated")
         self._touched = registry.histogram(
             "pio_moe_experts_touched",
@@ -178,29 +180,33 @@ class GenerativeAlgorithm(Algorithm):
         return out[-self.params.history_buckets[-1]:]
 
     def _dispatch(self, model: GenerativeModel, hists: List[List[int]]):
-        """Enqueue one padded batch: ``(device outputs, rows, slots)``."""
+        """Enqueue one packed batch: ``(device outputs, slots)``, the
+        slots the prefill ran."""
         import jax
 
         from ..models.decoder import _gen_decode, _gen_prefill
 
         p = self.params
         B = _bucket(p.row_buckets, len(hists))
-        L = _bucket(p.history_buckets, max(len(h) for h in hists))
-        tokens = np.zeros((B, L), np.int32)
         lengths = np.ones((B,), np.int32)  # a pad row is one token long
-        for r, h in enumerate(hists):
-            tokens[r, L - len(h):] = h
-            lengths[r] = len(h)
+        lengths[:len(hists)] = [len(h) for h in hists]
+        # the stream is sized by the batch's MEAN history: uniform rows
+        # at a history bucket (the warm ladder) give every size there is
+        T = B * _bucket(p.history_buckets, -(-int(lengths.sum()) // B))
+        flat = np.concatenate(hists)
+        tokens = np.zeros((T,), np.int32)
+        tokens[:len(flat)] = flat
         cfg = model.cfg
         # explicit: the server's transfer guard logs an implicit one
         tokens, lengths = jax.device_put((tokens, lengths))
-        with annotate("pio:gen_prefill", rows=B, history=L):
-            first, state = _gen_prefill(model.weights, tokens, lengths,
-                                        cfg=cfg, room=p.max_new)
+        with annotate("pio:gen_prefill", rows=B, slots=T):
+            first, state = _gen_prefill(
+                model.weights, tokens, lengths, cfg=cfg,
+                history=p.history_buckets[-1], room=p.max_new)
         with annotate("pio:gen_decode", rows=B, steps=p.max_new):
             toks, scores, load, _ = _gen_decode(
                 model.weights, state, first, cfg=cfg, steps=p.max_new)
-        return (toks, scores, load), B * L
+        return (toks, scores, load), T
 
     def _observe(self, hists, slots: int, load) -> None:
         """Once a batch, never per query."""
@@ -221,7 +227,9 @@ class GenerativeAlgorithm(Algorithm):
     def warm_serving(self, model: GenerativeModel,
                      max_batch: int = 1) -> None:
         """Compile the ladder: every row bucket up to ``max_batch``'s
-        at every history bucket (two programs each)."""
+        with uniform rows at every history bucket, which is every
+        stream size a batch of that many rows can have (a prefill each;
+        the decode's program depends on the rows alone)."""
         p = self.params
         top = _bucket(p.row_buckets, max(max_batch, 1))
         for b in p.row_buckets:

@@ -48,22 +48,49 @@ def _ref_weights(w, cfg):
     return {**w, "layers": [dict(lw) for lw in w["layers"]]}
 
 
-def _right_align(hists, L):
-    tokens = np.zeros((len(hists), L), np.int32)
-    for r, h in enumerate(hists):
-        tokens[r, L - len(h):] = h
-    return jnp.asarray(tokens), jnp.asarray([len(h) for h in hists],
-                                            jnp.int32)
+HISTORY = 32  # the slots the state is laid out at in these tests
 
 
-def _generate(w, cfg, hists, L, steps=STEPS):
-    tokens, lengths = _right_align(hists, L)
-    first, state = decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
-                                        room=steps)
+def _pack(hists, rows, slots, seed=0):
+    """The engine's layout: the rows' tokens one behind the other, pad
+    rows of one token behind them, ARBITRARY ids in the spare slots."""
+    lengths = np.ones((rows,), np.int32)
+    lengths[:len(hists)] = [len(h) for h in hists]
+    tokens = np.random.default_rng(seed).integers(
+        0, SMALL["vocab_size"], slots).astype(np.int32)
+    flat = np.concatenate([np.asarray(h, np.int32) for h in hists])
+    tokens[:len(flat)] = flat
+    tokens[len(flat):int(lengths.sum())] = 0
+    return jnp.asarray(tokens), jnp.asarray(lengths)
+
+
+def _prefill(w, cfg, hists, slots, rows=None, steps=STEPS):
+    tokens, lengths = _pack(hists, rows or len(hists), slots)
+    return decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
+                                history=HISTORY, room=steps)
+
+
+def _generate(w, cfg, hists, slots, rows=None, steps=STEPS):
+    first, state = _prefill(w, cfg, hists, slots, rows, steps)
     first = np.asarray(first)
     toks, scores, load, _ = decoder._gen_decode(
         w, state, jnp.asarray(first), cfg=cfg, steps=steps)
     return first, np.asarray(toks), np.asarray(scores), load
+
+
+def _check_against_reference(d, w, hists, first, toks, scores):
+    """Every served score is the reference's logit of the served token
+    at that position, from ONE uncached forward over history + served
+    tokens; and greedy took the reference's best."""
+    rw = _ref_weights(w, None)
+    for r, h in enumerate(hists):
+        seq = h + toks[r, :-1].tolist()
+        logits = np.asarray(ref.forward(rw, seq, d))[len(h) - 1:]
+        assert logits.shape[0] == toks.shape[1]
+        np.testing.assert_allclose(first[r], logits[0], atol=TOL32)
+        at = logits[np.arange(len(logits)), toks[r]]
+        np.testing.assert_allclose(scores[r], at, atol=TOL32)
+        assert np.all(logits.max(axis=1) - at <= TOL32)
 
 
 def _hists(rng, lengths):
@@ -79,10 +106,10 @@ def small():
 @pytest.fixture(scope="module")
 def served(small):
     d, cfg, w = small
-    # 6 x 32 slots: the prefill's experts go through the sorted groups,
+    # 192 slots: the prefill's experts go through the sorted groups,
     # the decode's 6 rows through the every-expert product
     hists = _hists(np.random.default_rng(1), [5, 16, 11, 1, 30, 32])
-    return hists, _generate(w, cfg, hists, 32)
+    return hists, _generate(w, cfg, hists, 192)
 
 
 def test_config_reads_the_published_keys():
@@ -100,65 +127,146 @@ def test_config_reads_the_published_keys():
 
 
 def test_prefill_and_cached_decode_match_the_full_forward(small, served):
-    """Every served score is the reference's logit of the served token
-    at that position, from ONE uncached forward over history + served
-    tokens; and greedy took the reference's best."""
     d, cfg, w = small
     hists, (first, toks, scores, _) = served
-    rw = _ref_weights(w, cfg)
-    for r, h in enumerate(hists):
-        seq = h + toks[r, :-1].tolist()
-        logits = np.asarray(ref.forward(rw, seq, d))[len(h) - 1:]
-        assert logits.shape[0] == STEPS
-        np.testing.assert_allclose(first[r], logits[0], atol=TOL32)
-        at = logits[np.arange(STEPS), toks[r]]
-        np.testing.assert_allclose(scores[r], at, atol=TOL32)
-        assert np.all(logits.max(axis=1) - at <= TOL32)
+    _check_against_reference(d, w, hists, first, toks, scores)
 
 
-@pytest.mark.parametrize("window,beside", [(16, []), (32, [30, 32]),
-                                           (64, [64, 40, 3]),
-                                           (128, [3, 128])])
-def test_padding_and_neighbours_do_not_move_a_row(small, served, window,
-                                                  beside):
-    """The same history alone, and left-padded to 2x and 4x its window
-    beside longer rows, gives the same logits and the same tokens."""
+#: (history lengths, rows, slots): the stream's sizes are the engine's
+#: ladder for 4 rows over history buckets (8, 16, 32)
+RAGGED = {
+    "rows_of_one_token": ([1, 1, 1, 1], 4, 32),
+    "rows_shorter_than_the_conv_window": ([2, 1, 2, 1], 4, 32),
+    "a_row_at_the_top_bucket": ([32, 3, 9, 20], 4, 64),
+    "every_row_at_the_top_bucket": ([32, 32, 32, 32], 4, 128),
+    "a_batch_under_its_row_bucket": ([7, 12], 4, 32),
+    "one_row_in_a_row_bucket": ([32], 4, 64),
+    "a_sum_on_the_lowest_rung": ([8, 8, 8, 8], 4, 32),
+    "a_sum_just_over_the_lowest_rung": ([9, 8, 8, 8], 4, 64),
+    "a_sum_on_the_middle_rung": ([16, 30, 2, 16], 4, 64),
+    "a_sum_on_the_top_rung": ([32, 31, 30, 29], 4, 128),
+    "the_sorted_product_with_a_spare_tail": ([32, 1, 2, 32, 17, 5, 3, 9],
+                                             8, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_a_packed_ragged_batch_matches_the_reference(small, case):
+    """Prefill's last-token logits and the decode that follows, row by
+    row, whatever the rows' lengths, the spare slots' ids and the
+    stream's size."""
+    d, cfg, w = small
+    lengths, rows, slots = RAGGED[case]
+    hists = _hists(np.random.default_rng(len(case)), lengths)
+    first, toks, scores, (pre, _) = _generate(w, cfg, hists, slots, rows,
+                                              steps=4)
+    _check_against_reference(d, w, hists, first[:len(hists)],
+                             toks[:len(hists)], scores[:len(hists)])
+    # pad rows are one token long; spare slots count for no expert
+    assert (np.asarray(pre).sum(axis=1) == cfg.num_experts_per_tok
+            * (sum(lengths) + rows - len(lengths))).all()
+
+
+@pytest.mark.parametrize("slots,beside", [(32, []), (96, [30, 32]),
+                                          (128, [32, 20, 3]),
+                                          (256, [3, 32])])
+def test_the_stream_and_neighbours_do_not_move_a_row(small, served, slots,
+                                                     beside):
+    """The same history alone, and behind other rows in streams of 2x,
+    4x and 8x the slots, gives the same logits and the same tokens."""
     d, cfg, w = small
     hists, (first, toks, scores, _) = served
-    rng = np.random.default_rng(2)
-    mix = [hists[1]] + _hists(rng, beside)
-    f2, t2, s2, _ = _generate(w, cfg, mix, window)
-    np.testing.assert_allclose(f2[0], first[1], atol=TOL32)
-    np.testing.assert_array_equal(t2[0], toks[1])
-    np.testing.assert_allclose(s2[0], scores[1], atol=TOL32)
+    mix = _hists(np.random.default_rng(2), beside) + [hists[1]]
+    f2, t2, s2, _ = _generate(w, cfg, mix, slots)
+    np.testing.assert_allclose(f2[-1], first[1], atol=TOL32)
+    np.testing.assert_array_equal(t2[-1], toks[1])
+    np.testing.assert_allclose(s2[-1], scores[1], atol=TOL32)
 
 
-def test_row_groups_give_what_one_pass_gives(small, served, monkeypatch):
-    """A batch that goes through the prefill in row groups
-    (``PREFILL_SLOTS``) reads as it does in one pass."""
+def _row_state(state, r):
+    """Row ``r``'s logits-independent state, layer by layer."""
+    return [np.asarray(a[r]) for st in state["layers"]
+            for _, a in sorted(st.items())] + [
+        np.asarray(state[k][r]) for k in ("pos", "valid")]
+
+
+@pytest.mark.parametrize("order", [(5, 4, 3, 2, 1, 0), (2, 0, 5, 1, 4, 3),
+                                   (1, 2, 3, 4, 5, 0)])
+def test_permuting_the_rows_permutes_logits_and_state(small, served, order):
     d, cfg, w = small
-    hists, (first, toks, scores, load) = served
-    monkeypatch.setattr(decoder, "PREFILL_SLOTS", 32)
-    jax.clear_caches()
-    f2, t2, s2, l2 = _generate(w, cfg, hists, 32)
-    jax.clear_caches()
-    np.testing.assert_allclose(f2, first, atol=TOL32)
-    np.testing.assert_array_equal(t2, toks)
-    np.testing.assert_array_equal(np.asarray(l2[0]), np.asarray(load[0]))
+    hists, _ = served
+    f0, st0 = _prefill(w, cfg, hists, 192)
+    f1, st1 = _prefill(w, cfg, [hists[i] for i in order], 192)
+    for new, old in enumerate(order):
+        np.testing.assert_allclose(f1[new], f0[old], atol=TOL32)
+        for a, b in zip(_row_state(st1, new), _row_state(st0, old)):
+            np.testing.assert_allclose(a, b, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(st1["load"]),
+                                  np.asarray(st0["load"]))
+
+
+@pytest.mark.parametrize("changed", [0, 3, 5])
+def test_a_neighbours_tokens_move_no_other_row(small, served, changed):
+    d, cfg, w = small
+    hists, _ = served
+    f0, st0 = _prefill(w, cfg, hists, 192)
+    other = [list(h) for h in hists]
+    other[changed] = [(t + 1) % SMALL["vocab_size"]
+                      for t in other[changed]]
+    f1, st1 = _prefill(w, cfg, other, 192)
+    for r in range(len(hists)):
+        if r == changed:
+            assert np.abs(np.asarray(f1[r]) - np.asarray(f0[r])).max() \
+                > 10 * TOL32
+            continue
+        np.testing.assert_allclose(f1[r], f0[r], atol=TOL32)
+        for a, b in zip(_row_state(st1, r), _row_state(st0, r)):
+            np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_the_state_does_not_depend_on_the_batch(small):
+    """Keys, values and ``valid`` are laid out at ``history`` slots
+    whatever the rows' lengths and the stream's size: the decode's
+    program depends on the rows alone."""
+    d, cfg, w = small
+    shapes = []
+    for lengths, slots in (([1, 2], 16), ([32, 32], 64), ([9, 30], 64)):
+        _, st = _prefill(w, cfg, _hists(np.random.default_rng(3), lengths),
+                         slots)
+        shapes.append(jax.tree_util.tree_map(lambda a: a.shape, st))
+        assert int(st["filled"]) == HISTORY
+        assert st["valid"].shape == (2, HISTORY + STEPS)
+        np.testing.assert_array_equal(
+            np.asarray(st["valid"]).sum(axis=1), lengths)
+    assert shapes[0] == shapes[1] == shapes[2]
 
 
 def test_conv_step_equals_its_prefill(small):
+    """Two rows of 7 in a stream of 16: a row's window after 6 tokens
+    and one step is its window after 7, and a tap never reaches into
+    the row before."""
     d, cfg, w = small
     lw = w["layers"][0]
-    z = jax.random.normal(jax.random.key(3), (2, 7, cfg.hidden_size))
-    valid = jnp.ones((2, 7), bool)
-    full, st = decoder._conv_prefill(lw, z, valid, cfg)
-    part, st6 = decoder._conv_prefill(lw, z[:, :6], valid[:, :6], cfg)
-    step, st7 = decoder._conv_step(lw, z[:, 6], st6, cfg)
-    np.testing.assert_allclose(step, full[:, 6], atol=1e-5)
+    z = jax.random.normal(jax.random.key(3), (16, cfg.hidden_size))
+    slot = jnp.arange(16)
+
+    def run(stream, n):
+        first = jnp.asarray([0, n])
+        return decoder._conv_prefill(
+            lw, stream, slot < 2 * n, slot - first[jnp.minimum(slot // n, 1)],
+            first + n - 1, cfg)
+
+    full, st = run(z, 7)
+    # the same rows less their last token, one behind the other
+    _, st6 = run(jnp.concatenate([z[:6], z[7:13], z[:4]]), 6)
+    step, st7 = decoder._conv_step(lw, jnp.stack([z[6], z[13]]), st6, cfg)
+    np.testing.assert_allclose(step, jnp.stack([full[6], full[13]]),
+                               atol=1e-5)
     np.testing.assert_allclose(st7["win"], st["win"], atol=1e-6)
-    np.testing.assert_allclose(
-        full[0], ref.conv_op(lw, z[0], d), atol=1e-5)
+    for r in (0, 1):
+        np.testing.assert_allclose(
+            full[7 * r:7 * r + 7], ref.conv_op(lw, z[7 * r:7 * r + 7], d),
+            atol=1e-5)
 
 
 def test_expert_load_counts_real_tokens_only(small, served):
@@ -279,7 +387,7 @@ def test_bfloat16_stays_inside_the_tolerance_and_int8_does_not(seed):
     assert _rel(ref.expert_ff(lossy, z, d), want) > 4e-3
 
     hists = _hists(np.random.default_rng(seed + 1), [12, 16, 7, 16])
-    _, toks, scores, _ = _generate(w, cfg, hists, 16)
+    _, toks, scores, _ = _generate(w, cfg, hists, 64)
     gaps = []
     for r, h in enumerate(hists):
         seq = h + toks[r, :-1].tolist()

@@ -22,6 +22,7 @@ from predictionio_tpu.server.engineserver import (
     StagedPipeline,
     create_engine_server,
 )
+from predictionio_tpu.server.stats import RecompileSentinel
 from predictionio_tpu.templates.generative import (
     GenerativeAlgorithm,
     GenerativeModel,
@@ -130,14 +131,70 @@ def _post_get(port, path):
         conn.close()
 
 
+#: history lengths of one batch -> the slots its prefill runs: the row
+#: bucket x the history bucket of the batch's MEAN history (a pad row
+#: is one token long), with row buckets (4, 8) and history buckets
+#: (16, 32)
+MIXES = {
+    "one_short_row": ([1], 64),
+    "every_row_at_the_top_bucket": ([32, 32, 32, 32], 128),
+    "long_rows_beside_short_ones": ([32, 1, 1, 30], 64),
+    "a_mean_just_over_a_bucket": ([32, 32, 1], 128),
+    "five_rows_in_the_bucket_of_eight": ([16] * 5, 128),
+    "eight_rows_at_the_top_bucket": ([32] * 8, 256),
+    "seven_ragged_rows": ([32, 31, 2, 9, 17, 1, 25], 128),
+    "eight_rows_on_the_lowest_rung": ([32, 32, 32, 9, 1, 1, 1, 1], 128),
+}
+
+
+def _token_kinds(qs):
+    return {c["labels"]["kind"]: c["value"] for c in
+            qs.metrics.export()["pio_gen_tokens_total"]["children"]}
+
+
+def _mix(name):
+    lengths, slots = MIXES[name]
+    return [[(7 * r + t) % SMALL["vocab_size"] for t in range(n)]
+            for r, n in enumerate(lengths)], slots
+
+
+@pytest.mark.parametrize("name", sorted(MIXES))
+def test_ragged_batches_compile_nothing_after_the_uniform_ladder(served,
+                                                                 name):
+    """The server warmed with UNIFORM histories at every history bucket
+    (``warm_serving``); a batch of any mix then finds its programs."""
+    qs, _ = served
+    hists, slots = _mix(name)
+    sentinel = RecompileSentinel()
+    sentinel.arm()
+    arrays, ran = qs.algorithms[0]._dispatch(qs.models[0], hists)
+    arrays[0].block_until_ready()
+    assert sentinel.since_armed == 0
+    assert ran == slots
+
+
+@pytest.mark.parametrize("name", sorted(MIXES))
+def test_pad_counts_the_slots_run_less_the_real_tokens(served, name):
+    qs, _ = served
+    hists, slots = _mix(name)
+    before = _token_kinds(qs)
+    qs.algorithms[0].batch_predict(qs.models[0], [
+        Query(items=_query(h)["items"]) for h in hists])
+    after = _token_kinds(qs)
+    prompt = sum(len(h) for h in hists)
+    assert after["prompt"] - before["prompt"] == prompt
+    assert after["pad"] - before["pad"] == slots - prompt
+    assert after["generated"] - before["generated"] == 8 * len(hists)
+
+
 def test_per_batch_series_are_on_the_servers_registry(served):
     qs, srv = served
     _post(srv.port, _query([1, 2, 3]))
     export = qs.metrics.export()
-    kinds = {c["labels"]["kind"]: c["value"]
-             for c in export["pio_gen_tokens_total"]["children"]}
+    kinds = _token_kinds(qs)
     assert kinds["prompt"] > 0 and kinds["generated"] > 0
-    # one query in a 4 x 16 bucket: 3 prompt tokens, the rest padding
+    # one query in a stream of 4 x 16 slots: 3 prompt tokens, the rest
+    # padding
     assert kinds["pad"] >= 4 * 16 - 3
     touched = export["pio_moe_experts_touched"]["children"][0]
     assert touched["count"] >= 1
