@@ -518,10 +518,6 @@ def main():
         "compiles_since_warm": _tele(tele_cfg, "compilesSinceWarm"),
         "transfer_guard_violations": _tele(tele_cfg,
                                            "transferGuardViolations"),
-        # staged-vs-serial serving pipeline ratios + overlap proof
-        # (ISSUE 9): qps_x / p99_x and the device-idle fraction from
-        # the staged server's own accounting
-        "serving_pipeline": (serving or {}).get("pipeline"),
         # flight-recorder overhead (ISSUE 12 acceptance ≤5%): host
         # fast-path p50 with tracing on vs off, same load
         "trace_overhead_pct": (serving or {}).get("trace_overhead_pct"),

@@ -41,7 +41,7 @@ Usage: python benchmarks/load_harness.py
            [--endpoints URL[,URL...]]
 
 ``--ci`` picks small, runner-friendly defaults (the CI capacity-gate
-step). Configs: host | staged | serial | cached | replicated |
+step). Configs: host | staged | cached | replicated |
 sharded | quantized | router (mesh configs skip themselves on one
 device). The ``router`` config (ISSUE 18) boots TWO engine-server
 replicas behind the entity-affinity :class:`QueryRouter` and drives
@@ -123,9 +123,6 @@ def _server_config(name: str, app_name: str, step_sec: float):
         "host": {},
         "staged": dict(batching=True, max_batch=64,
                        batch_window_ms=2.0),
-        "serial": dict(batching=True, max_batch=64,
-                       batch_window_ms=2.0,
-                       serving_pipeline="serial"),
         "cached": dict(serving_cache=True, cache_ttl_sec=5.0,
                        hot_entities=0),
         "replicated": dict(batching=True, max_batch=64,

@@ -3,7 +3,7 @@ CLI — app new → import → train → eval (VERDICT r3 task 6).
 
 The reference's end-to-end is ``pio build && pio train && pio eval`` on
 the scala-parallel-recommendation template over ml-20m
-(``BASELINE.json`` north_star; ``Evaluation.scala:32-89`` metric grid).
+(``Evaluation.scala:32-89`` metric grid).
 This script drives the same flow through ``predictionio_tpu.cli``
 subprocesses: the surrogate events land in a segmentfs store via
 ``ptpu import``, ``ptpu train`` runs the recommendation engine at the

@@ -28,7 +28,7 @@ Usage:
   python benchmarks/quality_anchor.py --scale 1.0 \
       [--npz /tmp/ml20m_full.npz] [--rank 64] [--sample 16384]
 
-Prints ONE JSON document (the PARITY_EVAL artifact). Exit 1 if the
+Prints ONE JSON document. Exit 1 if the
 holdout NDCG@10 relative delta exceeds --gate (default 2%).
 """
 

@@ -145,7 +145,7 @@ def serving_roofline() -> dict:
     qU, qV = jax.device_put(qU), jax.device_put(qV)
     q_block = probe_tables(qU, qV)
     out = {
-        "metric": "serving_topk_roofline",
+        "metric": "serve_topk_roofline",
         "device": device,
         "rank": rank, "n_items": n_items, "batch": B, "k": k,
         "quant": quant,

@@ -35,12 +35,6 @@ fan-out (a full model copy per device, per-device lanes), and the
 row-sharded mesh — per-mode qps plus the replicated/single
 ``scaling_x`` ratio.
 
-Since ISSUE 9 the micro-batch config runs twice — the staged
-continuous-batching pipeline vs the serial drainer at the same load —
-and a ``pipeline_overlap`` row embeds the qps/p99 ratios plus the
-server's own device-idle / overlap fractions (the proof the device
-stays busy while host stages run).
-
 With ``--arrival-rate QPS``, an OPEN-LOOP fixed-rate generator replaces
 the closed-loop battery (coordinated-omission-safe: latency is measured
 from each request's scheduled arrival, so a stalling server accrues
@@ -50,7 +44,7 @@ load-harness item.
 
 With ``--quant DTYPE`` (ISSUE 13), the device per-query and
 micro-batch configs run again with row-quantized serving tables
-(``serving_quant=DTYPE`` + the autotuned fused top-k kernel) and a
+(``serving_quant=DTYPE``) and a
 ``serving_quant`` summary row reports quantized-vs-f32 per-query p50
 and micro-batch qps/p99 ratios side by side — the row ``bench.py``
 embeds in the BENCH line.
@@ -288,41 +282,13 @@ def bench_config(model: ALSModel, cfg: ServerConfig, n_requests: int,
     return out
 
 
-def pipeline_block(staged: dict, serial: dict) -> dict:
-    """The ISSUE 9 acceptance view: staged vs serial drainer at the
-    SAME offered load — qps/p99 ratios plus the staged server's own
-    overlap accounting (device idle fraction proving the device stayed
-    busy while host stages ran)."""
-    out = {
-        "config": "pipeline_overlap",
-        "staged_qps": staged.get("qps"),
-        "serial_qps": serial.get("qps"),
-        "staged_p99_ms": staged.get("p99_ms"),
-        "serial_p99_ms": serial.get("p99_ms"),
-    }
-    if serial.get("qps") and staged.get("qps"):
-        out["qps_x"] = round(staged["qps"] / serial["qps"], 2)
-    if serial.get("p99_ms") and staged.get("p99_ms"):
-        out["p99_x"] = round(serial["p99_ms"] / staged["p99_ms"], 2)
-    pipe = ((staged.get("telemetry") or {}).get("pipeline")) or {}
-    ov = pipe.get("overlap") or {}
-    out["device_idle_fraction"] = ov.get("deviceIdleFraction")
-    out["overlap_fraction"] = ov.get("overlapFraction")
-    out["overlapped_dispatches"] = ov.get("overlappedDispatches")
-    out["deadline_exceeded"] = pipe.get("deadlineExceeded")
-    return out
-
-
 def standard_battery(n_items_dev: int, rank: int, n_req: int,
                      n_threads: int, hi_threads: int) -> dict:
     """The serving battery — ONE definition shared by this script's
     ``main()`` and ``bench.py``'s serving block (they drifted when each
     kept its own copy): host fast path, per-query at trickle load,
     per-query and micro-batcher at burst load (``hi_threads`` offered
-    concurrency — the apples-to-apples pair). Since ISSUE 9 the
-    micro-batcher runs TWICE at the same load — staged continuous-
-    batching pipeline vs the serial drainer — and a ``pipeline``
-    summary row carries the ratio + overlap proof."""
+    concurrency — the apples-to-apples pair)."""
     from predictionio_tpu.server.engineserver import ServerConfig
 
     host_model = synth_model(2000, 2000, rank, device=False)
@@ -348,14 +314,7 @@ def standard_battery(n_items_dev: int, rank: int, n_req: int,
             dev_model, ServerConfig(batching=True, max_batch=128,
                                     batch_window_ms=2.0),
             hi_req, hi_threads, "device_microbatch_staged"),
-        "microbatch_serial": bench_config(
-            dev_model, ServerConfig(batching=True, max_batch=128,
-                                    batch_window_ms=2.0,
-                                    serving_pipeline="serial"),
-            hi_req, hi_threads, "device_microbatch_serial"),
     }
-    out["pipeline"] = pipeline_block(out["microbatch"],
-                                     out["microbatch_serial"])
     traced = out["host_fast_path"].get("p50_ms")
     untraced = out["host_fast_path_untraced"].get("p50_ms")
     if traced and untraced:
@@ -542,11 +501,10 @@ def quant_battery(n_items_dev: int, rank: int, n_req: int,
                   f32_micro: dict | None = None) -> list:
     """The --quant view (ISSUE 13): the SAME workload against the
     device per-query path and the micro-batched lane with
-    ``serving_quant=DTYPE`` (+ the autotuned top-k kernel), side by
-    side with the f32 einsum lane — reusing the standard battery's f32
-    rows when the caller already measured them. Emits a
-    ``serving_quant`` summary row (embedded in the BENCH line): the
-    acceptance view is the quant/fused lane beating the f32 einsum
+    ``serving_quant=DTYPE``, side by side with the f32 lane — reusing
+    the standard battery's f32 rows when the caller already measured
+    them. Emits a ``serving_quant`` summary row (embedded in the BENCH
+    line): the acceptance view is the quantized lane beating the f32
     lane on the benched path at equal p99."""
     dev_model = synth_model(50_000, n_items_dev, rank, device=True)
     hi_req = max(n_req, 8 * hi_threads)
@@ -666,8 +624,8 @@ def main() -> None:
     hi = int(os.environ.get("SERVE_THREADS_HI", "256"))
     if arrival_rate is not None:
         # open-loop mode REPLACES the closed-loop battery: fixed-rate
-        # arrivals against the staged and serial micro-batch paths at
-        # the same offered qps — sweep the rate to trace the knee
+        # arrivals against the micro-batch path — sweep the rate to
+        # trace the knee
         from predictionio_tpu.server.engineserver import ServerConfig
 
         dev_model = synth_model(50_000, n_items_dev, rank, device=True)
@@ -677,11 +635,6 @@ def main() -> None:
                 dev_model, ServerConfig(batching=True, max_batch=128,
                                         batch_window_ms=2.0),
                 arrival_rate, n_open, hi, "open_loop_staged"),
-            bench_open_loop(
-                dev_model, ServerConfig(batching=True, max_batch=128,
-                                        batch_window_ms=2.0,
-                                        serving_pipeline="serial"),
-                arrival_rate, n_open, hi, "open_loop_serial"),
         ]
         print(json.dumps({
             "bench": "serving_queries_json_open_loop",

@@ -442,10 +442,8 @@ def deploy_and_query(engine_json: Path, f: dict, label: str,
         kern = status["servingKernel"]
         say(f"{label}: {len(hbm)} device(s) {hbm[0]['kind']}, HBM in use "
             f"{in_use / 2**20:.1f} MiB (tables {table_bytes / 2**20:.1f}"
-            f" MiB); top-k {kern['configuredTopk']} -> {kern['mode']}, "
-            f"quant {kern['quant']}, refused "
-            f"{kern.get('refused') or 'nothing'}; mesh "
-            f"{status['mesh'].get('mode')}")
+            f" MiB); quant {kern['configuredQuant']} -> {kern['quant']}; "
+            f"mesh {status['mesh'].get('mode')}")
 
         # queries: one at a time, then concurrent bursts until the
         # batcher has formed a batch above 8 (a burst usually does at
@@ -491,7 +489,7 @@ def deploy_and_query(engine_json: Path, f: dict, label: str,
             f"above 8, compilesSinceWarm {recompiles}")
         lanes = metric_values(metrics, "pio_lane_dispatches_total")
         report = {"devices": len(hbm), "kind": hbm[0]["kind"],
-                  "topk": kern["mode"], "lanes": lanes,
+                  "quant": kern["quant"], "lanes": lanes,
                   "worst_abs": worst["abs"], "worst_share": worst["share"]}
         http_json(port, "POST", "/stop")
         require(child.wait(timeout=60) == 0,
@@ -578,7 +576,7 @@ def run(device: dict, cache_dir: str, cache_before: int) -> int:
                    "ratings": N_RATINGS, "iterations": ITERATIONS},
         "resolved": {"gram": trained["kernels"]["gram"]["resolved"],
                      "solver": trained["kernels"]["solver"],
-                     "topk": served["topk"]},
+                     "serving_quant": served["quant"]},
         "replicated_lanes": None if replicated is None
         else len(replicated["lanes"]),
         "largest_score_deviation": served["worst_abs"],

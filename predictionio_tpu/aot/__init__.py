@@ -1,7 +1,9 @@
 """AOT compile artifacts — capture at build time, load at deploy time.
 
-The cold-start gap (NORTHSTAR_r05: ~29 s deploy warm) is almost entirely
-XLA compilation of the serving entry points.  JAX's AOT API makes those
+The cold-start gap is almost entirely XLA compilation of the serving
+entry points (``first_setup_s`` against ``setup_s`` in
+PERF_LEDGER.jsonl: the ALS cells' set-up is 9.2-9.5 s on the v5e once
+the compile cache holds their programs; ledger, PR 28).  JAX's AOT API makes those
 executables portable: ``fn.lower(...).compile()`` yields a loaded
 executable whose bytes ``jax.experimental.serialize_executable``
 round-trips, and the deserialized executable is called with the dynamic

@@ -316,7 +316,6 @@ def cmd_deploy(args, storage: Storage) -> int:
         max_batch=args.max_batch,
         batch_window_ms=args.batch_window_ms,
         batch_pipeline=args.batch_pipeline,
-        serving_pipeline=args.pipeline,
         queue_deadline_ms=args.queue_deadline_ms,
         assemble_workers=args.assemble_workers,
         readback_workers=args.readback_workers,
@@ -329,7 +328,6 @@ def cmd_deploy(args, storage: Storage) -> int:
         debug_locks=args.debug_locks,
         serving_mode=args.serving_mode,
         serving_quant=args.serving_quant,
-        serving_topk=args.serving_topk,
         streaming=args.stream,
         stream_app_name=args.stream_app or None,
         stream_interval_ms=args.stream_interval_ms,
@@ -1568,8 +1566,7 @@ def cmd_build(args, storage: Storage) -> int:
             batching=args.batching,
             max_batch=args.max_batch,
             serving_mode=args.serving_mode,
-            serving_quant=args.serving_quant,
-            serving_topk=args.serving_topk)
+            serving_quant=args.serving_quant)
         result = build_artifacts(
             ctx, engine, engine_params,
             artifact_root(args.artifact_dir),
@@ -2109,9 +2106,6 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["off", "bf16", "int8"],
                    help="serving-table quantization the deploy will "
                         "use")
-    s.add_argument("--serving-topk", default="auto",
-                   choices=["auto", "einsum", "fused"],
-                   help="top-k realization the deploy will use")
 
     s = sub.add_parser("train", help="train an engine")
     add_engine_flags(s)
@@ -2150,15 +2144,9 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--batch-window-ms", type=float, default=2.0,
                    help="wait for a lone query before serving it solo")
     s.add_argument("--batch-pipeline", type=int, default=4,
-                   help="concurrent batch dispatches in flight "
-                        "(serial pipeline only)")
-    s.add_argument("--pipeline", default="staged",
-                   choices=["staged", "serial"],
-                   help="serving batch-path architecture "
-                        "(docs/serving-pipeline.md): staged = "
-                        "continuous-batching pipeline overlapping host "
-                        "assembly, device dispatch and readback; "
-                        "serial = the pre-pipeline drainer threads")
+                   help="staged pipeline: dispatch threads of a "
+                        "single-binding deploy (a lane binding runs "
+                        "one per lane)")
     s.add_argument("--queue-deadline-ms", type=float, default=30000.0,
                    help="per-query deadline covering queue wait "
                         "through readback; exceeded queries shed with "
@@ -2211,13 +2199,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "accumulation; bf16 halves both; guarded by "
                         "a deploy-time NDCG@10 parity probe that "
                         "auto-falls-back to f32")
-    s.add_argument("--serving-topk", default="auto",
-                   choices=["auto", "einsum", "fused"],
-                   help="batched-lane top-k realization: fused = the "
-                        "Pallas gather->score->top-k kernel (the "
-                        "[B, I] score matrix never lands in HBM), "
-                        "einsum = the XLA baseline, auto = the "
-                        "support-gated autotune table")
     s.add_argument("--stream", action="store_true",
                    help="streaming incremental training "
                         "(docs/streaming.md): a trainer daemon tails "

@@ -1714,102 +1714,6 @@ def quantize_serving_model(model: "ALSModel", quant: str, *,
 
 # -- serving ----------------------------------------------------------------
 
-#: process-wide serving top-k override (None = the autotune table);
-#: set per deploy from ``ServerConfig.serving_topk`` — an explicit
-#: "fused" on a CPU host is a debugging/test run and exercises the
-#: interpret-mode kernel, mirroring ``gram_mode="fused"``
-_serving_topk_override: Optional[str] = None
-
-
-def set_serving_topk_mode(mode: Optional[str]) -> None:
-    """Pin the batched-lane top-k realization ("einsum" | "fused");
-    None/"auto" returns control to the support-gated autotune table
-    (``ops/gram_autotune.best_topk_mode``)."""
-    global _serving_topk_override
-    if mode in (None, "", "auto"):
-        _serving_topk_override = None
-        return
-    if mode not in ("einsum", "fused"):
-        raise ValueError(
-            f"serving topk mode must be 'auto', 'einsum' or 'fused', "
-            f"got {mode!r}")
-    _serving_topk_override = mode
-
-
-def _quant_wire(quant: Optional[str]) -> Tuple[str, str]:
-    """(autotune key, wire dtype) of a serving-quant value."""
-    if quant in (None, "off"):
-        return "f32", "float32"
-    return quant, {"bf16": "bfloat16", "int8": "int8"}[quant]
-
-
-def resolved_topk_mode(rank: int, quant: str = "off", *, batch: int,
-                       n_rows: int, k: int) -> str:
-    """The concrete serving top-k realization ("einsum" | "fused") for
-    a ``[batch]`` dispatch of top-``k`` over ``n_rows`` item rows on
-    the attached backend — the ``mode`` label of the
-    ``pio_serving_kernel`` info gauge (docs/observability.md).
-
-    An explicit override is returned as pinned: "fused" compiles the
-    kernel or raises the compiler's message where it is dispatched
-    (the deploy-time bind checks it first,
-    :func:`serving_kernel_report`). ``auto`` reads the autotune table;
-    an entry naming the fused kernel is compiled at these shapes first
-    and skipped — with the refusal on record — if the compiler
-    refuses."""
-    if _serving_topk_override is not None:
-        return _serving_topk_override
-    from ..ops.gram_autotune import best_topk_mode
-
-    key, wire = _quant_wire(quant)
-    pick = best_topk_mode(rank, key)
-    if pick == "fused":
-        from ..ops.fused_topk import fused_topk_refusal
-
-        if fused_topk_refusal(batch, rank, n_rows, k, wire) is not None:
-            return "einsum"
-    return pick
-
-
-def serving_kernel_report(model, batch: int) -> dict:
-    """What the batched serving lane of ``model`` resolves to for
-    ``[batch]`` dispatches on the attached backend: ``{"mode", "quant",
-    "refused"}`` — the ``servingKernel`` block of ``/status.json``.
-    Raises the compiler's message when ``serving_topk="fused"`` was
-    asked for explicitly and the attached TPU cannot compile it: the
-    deploy fails instead of serving from anything else."""
-    from ..ops import _probe, fused_topk
-
-    vd, _ = _table_leaves(model.item_factors)
-    quant = table_quant(model.item_factors)
-    rank, n_rows = int(vd.shape[-1]), int(vd.shape[0])
-    k = _compiled_k(16, model.n_items)
-    if _serving_topk_override == "fused" and _probe.tpu_attached():
-        why = fused_topk.fused_topk_refusal(
-            batch, rank, n_rows, k, _quant_wire(quant)[1])
-        if why is not None:
-            raise RuntimeError(
-                f"serving_topk='fused' does not compile on this backend "
-                f"(batch {batch}, rank {rank}, {n_rows} item rows, "
-                f"k {k}, quant {quant}): {why}")
-    return {"mode": resolved_topk_mode(rank, quant, batch=batch,
-                                       n_rows=n_rows, k=k),
-            "quant": quant,
-            "refused": fused_topk.refusals()}
-
-
-@functools.partial(jax.jit, static_argnames=("k", "n_items"))
-def _topk_scores(user_vecs: jax.Array, item_factors: jax.Array,
-                 k: int, n_items: int) -> Tuple[jax.Array, jax.Array]:
-    """Batched top-k over all items: [B, r] × [n_pad, r]ᵀ → scores+ids.
-    Padded item rows are masked to -inf before ``lax.top_k``."""
-    scores = user_vecs @ item_factors.T  # [B, n_pad] — MXU matmul
-    n_pad = item_factors.shape[0]
-    mask = jnp.arange(n_pad) < n_items
-    scores = jnp.where(mask[None, :], scores, -jnp.inf)
-    return jax.lax.top_k(scores, k)
-
-
 @functools.partial(jax.jit, static_argnames=("k", "n_items"))
 def _serve_topk(user_factors, item_factors, idx: jax.Array, *, k: int,
                 n_items: int) -> Tuple[jax.Array, jax.Array]:
@@ -1820,9 +1724,7 @@ def _serve_topk(user_factors, item_factors, idx: jax.Array, *, k: int,
 
     Tables may be :class:`QuantizedFactors`: rows upcast to f32 (and
     per-row scales apply) INSIDE the program, so the dot accumulates
-    f32 while HBM holds int8/bf16 — the einsum realization of the
-    serving-quant co-design. This is also the XLA reference the fused
-    kernel (``ops/fused_topk.py``) is held exact against."""
+    f32 while HBM holds int8/bf16 — the serving-quant co-design."""
     ud, us = _table_leaves(user_factors)
     vd, vs = _table_leaves(item_factors)
     # the three scopes are metadata only (each operation's op_name in
@@ -1853,57 +1755,17 @@ def _serve_topk(user_factors, item_factors, idx: jax.Array, *, k: int,
         return jax.lax.top_k(scores, k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n_items"))
-def _fused_topk_entry(user_table, item_table, idx, *, k: int,
-                      n_items: int) -> Tuple[jax.Array, jax.Array]:
-    """The fused-kernel serving dispatch as ONE named jit entry so the
-    AOT seam (``predictionio_tpu.aot``) can lower/serialize it whole —
-    the outer jit inlines the inner kernel jits, and quantized tables
-    split into leaves inside the traced program exactly as
-    :func:`_serve_topk` does."""
-    from ..ops.fused_topk import fused_topk_dispatch
-
-    ud, us = _table_leaves(user_table)
-    vd, vs = _table_leaves(item_table)
-    return fused_topk_dispatch(ud, idx, vd, us, vs, k=k,
-                               n_items=n_items)
-
-
 def _device_topk(user_table, item_table, idx: np.ndarray, k_dev: int,
                  n_items: int) -> Tuple[jax.Array, jax.Array]:
-    """The single-device batched top-k dispatch switch (ISSUE 13):
-    routes to the fused gather→score→top-k Pallas kernel
-    (``ops/fused_topk.py`` — the [B, I] score matrix never lands in
-    HBM) when the autotune table resolves "fused" and the compiled k
-    fits the on-chip merge, else the :func:`_serve_topk` einsum
-    program. Both realizations share tie semantics (descending score,
-    lowest id first), so the switch is invisible to callers.
-
-    Both realizations launch through :func:`aot.dispatch` — the seam
-    that answers from a deserialized build-time executable when a warm
-    artifact store is active (ISSUE 19), and is a plain tail call
-    otherwise."""
+    """The single-device batched top-k dispatch: :func:`_serve_topk`
+    launched through :func:`aot.dispatch` — the seam that answers from
+    a deserialized build-time executable when a warm artifact store is
+    active (ISSUE 19), and is a plain tail call otherwise."""
     from .. import aot
-    from ..ops.fused_topk import TOPK_MAX_K
 
-    vd, vs = _table_leaves(item_table)
-    mode = "einsum"
-    if 1 <= k_dev <= TOPK_MAX_K:  # the on-chip merge carries k ≤ this
-        mode = resolved_topk_mode(
-            int(vd.shape[-1]), table_quant(item_table), batch=len(idx),
-            n_rows=int(vd.shape[0]), k=k_dev)
-    if mode == "fused":
-        # the index stays uncommitted numpy (int32 — the kernel's SMEM
-        # staging dtype): the jitted kernel places it, no eager
-        # host→device hop for the transfer guard to flag
-        out = aot.dispatch(
-            "fused_topk", _fused_topk_entry,
-            (user_table, item_table, np.asarray(idx, dtype=np.int32)),
-            {"k": k_dev, "n_items": n_items})
-    else:
-        out = aot.dispatch(
-            "serve_topk", _serve_topk, (user_table, item_table, idx),
-            {"k": k_dev, "n_items": n_items})
+    out = aot.dispatch(
+        "serve_topk", _serve_topk, (user_table, item_table, idx),
+        {"k": k_dev, "n_items": n_items})
     if _numerics.active():
         # debug_numerics: host NaN probe on the served scores (forces
         # the dispatch sync — the documented debug-mode cost);
@@ -2031,23 +1893,13 @@ def _rank_sharded(mesh: Mesh, vecs, item_factors, k_dev: int,
     """Launch the sharded ranking program for replicated [B, r] query
     vectors against a (possibly quantized) row-sharded item table —
     the shared entry of :func:`recommend_batch_sharded`,
-    :func:`_dispatch_topk_chunk` and :func:`recommend_pinned`.
-    Resolves the per-shard top-k realization (einsum vs the fused
-    kernel) ONCE per (mesh, shape) via the compile-once cache.
-    Callers hold ``_mesh_dispatch_lock``."""
-    from ..ops.fused_topk import TOPK_MAX_K
-
+    :func:`_dispatch_topk_chunk` and :func:`recommend_pinned`, through
+    the compile-once cache. Callers hold ``_mesh_dispatch_lock``."""
     vd, vs = _table_leaves(item_factors)
     n_pad = vd.shape[0]
     k_local = min(k_dev, n_pad // mesh.devices.size)
     quant = table_quant(item_factors)
-    mode = "einsum"
-    if 1 <= k_local <= TOPK_MAX_K:  # the on-chip merge carries k ≤ this
-        mode = resolved_topk_mode(
-            int(vd.shape[-1]), quant, batch=int(vecs.shape[0]),
-            n_rows=n_pad // mesh.devices.size, k=k_local)
-    ranked = _sharded_rank_fn(mesh, k_dev, k_local, n_items, quant,
-                              mode)
+    ranked = _sharded_rank_fn(mesh, k_dev, k_local, n_items, quant)
     # ptpu: allow[callback-under-lock] — `ranked` is a compiled XLA
     # executable (jit of shard_map), not user code: it cannot re-enter
     # the dispatch lock, and serializing the launch is the lock's
@@ -2055,33 +1907,29 @@ def _rank_sharded(mesh: Mesh, vecs, item_factors, k_dev: int,
     dyn = (vecs, vd) if vs is None else (vecs, vd, vs)
     # key_extra mirrors the _sharded_rank_fn cache key: the argument
     # signature alone cannot distinguish two mesh programs that differ
-    # only in k/k_local/topk realization
+    # only in k/k_local
     from .. import aot
     return aot.dispatch(
         "sharded_rank", ranked, dyn,
         key_extra=(tuple(int(s) for s in mesh.devices.shape),
                    tuple(mesh.axis_names), k_dev, k_local, n_items,
-                   quant or "off", mode))
+                   quant or "off"))
 
 
 @functools.lru_cache(maxsize=64)
 def _sharded_rank_fn(mesh: Mesh, k: int, k_local: int, n_items: int,
-                     quant: str = "off", topk_mode: str = "einsum"):
+                     quant: str = "off"):
     """Compile-once cache for the sharded serving program (a fresh
     closure per call would defeat the jit cache and recompile the mesh
     program on every serving batch). Keyed on (mesh, k, k_local,
-    n_items, quant, topk_mode); shapes key the inner jit cache as
-    usual. Axis names come from the mesh, so the same program serves a
+    n_items, quant); shapes key the inner jit cache as usual. Axis
+    names come from the mesh, so the same program serves a
     ``(data, model)`` training mesh and the ``(batch, model)`` serving
     mesh.
 
-    Each shard ranks its LOCAL item rows — through the fused
-    gather→score→top-k kernel when ``topk_mode="fused"`` (the shard's
-    [B, n_local] score block never lands in HBM; the shard's global id
-    origin rides in as the kernel's ``base``), else the einsum + local
-    top_k baseline with int8/bf16 rows dequantized in-program — then
-    the per-shard candidates all-gather and reduce to the global
-    top-k, exactly as before."""
+    Each shard ranks its LOCAL item rows (matmul + local top_k, with
+    int8/bf16 rows dequantized in-program), then the per-shard
+    candidates all-gather and reduce to the global top-k."""
     axes = tuple(mesh.axis_names)
     has_scale = quant == "int8"
 
@@ -2089,26 +1937,16 @@ def _sharded_rank_fn(mesh: Mesh, k: int, k_local: int, n_items: int,
         n_local = itf_local.shape[0]
         shard = jax.lax.axis_index(axes)
         base = shard * n_local
-        if topk_mode == "fused":
-            from ..ops.fused_topk import fused_topk_dispatch
-
-            uscale = jnp.ones((vecs.shape[0], 1), jnp.float32) \
-                if has_scale else None  # vecs arrive dequantized
-            s, gid = fused_topk_dispatch(
-                vecs, jnp.arange(vecs.shape[0], dtype=jnp.int32),
-                itf_local, uscale, isc_local, base, k=k_local,
-                n_items=n_items)
-        else:
-            itf = itf_local.astype(jnp.float32) \
-                if itf_local.dtype != jnp.float32 else itf_local
-            scores = vecs @ itf.T            # [B, n_local]
-            if isc_local is not None:
-                scores = scores * isc_local.reshape(1, -1)
-            local_ids = base + jnp.arange(n_local)
-            scores = jnp.where((local_ids < n_items)[None, :], scores,
-                               -jnp.inf)
-            s, i = jax.lax.top_k(scores, k_local)
-            gid = jnp.take(local_ids, i)
+        itf = itf_local.astype(jnp.float32) \
+            if itf_local.dtype != jnp.float32 else itf_local
+        scores = vecs @ itf.T            # [B, n_local]
+        if isc_local is not None:
+            scores = scores * isc_local.reshape(1, -1)
+        local_ids = base + jnp.arange(n_local)
+        scores = jnp.where((local_ids < n_items)[None, :], scores,
+                           -jnp.inf)
+        s, i = jax.lax.top_k(scores, k_local)
+        gid = jnp.take(local_ids, i)
         # gather the candidate sets along the candidate axis
         s_all = jax.lax.all_gather(s, axes, axis=1,
                                    tiled=True)  # [B, k_local*n_dev]

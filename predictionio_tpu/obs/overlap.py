@@ -8,8 +8,9 @@ claim like that needs a number, not an architecture diagram:
 and into an overlap counter whenever the device track and at least one
 host track are simultaneously active. The engine server exports the
 fractions as ``pio_pipeline_device_idle_fraction`` and
-``pio_pipeline_overlap_fraction`` (docs/observability.md) — a serial
-drainer shows overlap ≈ 0; the staged pipeline under load must not.
+``pio_pipeline_overlap_fraction`` (docs/observability.md): stages
+run one after another show overlap ≈ 0; the pipeline under load must
+not.
 
 Beside the tracks runs the **starvation clock** (ISSUE 24): every
 batch in the pipeline is in one of four places (:data:`STATES` less

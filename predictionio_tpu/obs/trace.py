@@ -31,9 +31,8 @@ The staged pipeline's batch spans are the batch's own stamps
 (``server/engineserver.py::_AssembledBatch``): each stage's end is the
 next one's start, so the hand-off waits between stage threads are
 spans of their own (``dispatch_q``, ``readback_q``), not gaps. The
-serial paths (``query``, ``query_batch``) run their stages back to
-back on one thread and still lay durations out from one anchor with
-:func:`add_stage_spans`.
+unbatched ``query`` runs its stages back to back on one thread and
+lays durations out from one anchor with :func:`add_stage_spans`.
 
 The same sites open a :func:`stage_span`, a
 ``jax.profiler.TraceAnnotation`` named ``pio:<stage>``: whenever a
@@ -307,23 +306,19 @@ class _SpanCtx:
 def add_stage_spans(trace: Optional[Trace], anchor: float,
                     phases: Dict[str, float],
                     order: Iterable[str] = STAGE_ORDER,
-                    parent_id: Optional[str] = None,
-                    skip: Iterable[str] = (),
                     **attrs: Any) -> None:
     """Reconstruct a sequential stage timeline from a phases dict
-    (stage → duration seconds, the shape ``query_batch`` and
-    ``batch_predict`` already produce) laid out from ``anchor``
-    onward in canonical ``order``. No-op on a None trace so call
-    sites stay branch-free."""
+    (stage → duration seconds, the shape the unbatched ``query``
+    produces) laid out from ``anchor`` onward in canonical ``order``.
+    No-op on a None trace so call sites stay branch-free."""
     if trace is None:
         return
     t = anchor
-    skipset = set(skip)
     for name in order:
         dur = phases.get(name)
-        if dur is None or name in skipset:
+        if dur is None:
             continue
-        trace.add_span(name, t, t + dur, parent_id=parent_id, **attrs)
+        trace.add_span(name, t, t + dur, **attrs)
         t += dur
 
 

@@ -1,7 +1,6 @@
 """Compile probes for the Pallas kernels: does the attached TPU's
 compiler accept a kernel at the shapes about to run, and if not, what
-did it say. Shared by ``fused_gram`` and ``fused_topk`` so both keep the
-same contract — the compiler's message is the result, never swallowed."""
+did it say. The compiler's message is the result, never swallowed."""
 
 from __future__ import annotations
 

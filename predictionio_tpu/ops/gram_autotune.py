@@ -1,4 +1,4 @@
-"""Shape-keyed gram-mode and serving top-k selection for ``"auto"``.
+"""Shape-keyed gram-mode selection for ``"auto"``.
 
 ``ALSParams(gram_mode="auto")`` needs a concrete realization (baseline
 einsum, the pair-packed MXU tiling of ``ops/gram.py``, or the fused
@@ -127,66 +127,30 @@ def best_mode(rank: int, bf16: bool = False,
     return "einsum"
 
 
-# -- serving top-k mode table (ISSUE 13) -------------------------------------
-#
-# The serving batched lane has the same einsum-vs-fused choice training
-# got in PR 7: the [B, I] score-matrix einsum (ops/… `_serve_topk`) vs
-# the fused gather→score→top-k Pallas kernel (ops/fused_topk.py). Keys
-# add a quant dimension — the wire dtype of the row-quantized serving
-# tables moves the bandwidth math, and therefore the winner.
-
-#: the serving top-k realizations a table entry may name
-TOPK_MODES = ("einsum", "fused")
-
-#: serving-table wire dtypes the key's quant field may carry
-TOPK_QUANTS = ("f32", "bf16", "int8")
-
-
-def _topk_key(family: str, rank: int, quant: str) -> str:
-    return f"{family}|topk|r{_rank_bucket(rank)}|{quant}"
-
-
-def best_topk_mode(rank: int, quant: str = "f32",
-                   device_kind: str | None = None) -> str:
-    """The serving top-k mode ("einsum" | "fused") the table names for
-    the batched lane — a pure lookup like :func:`best_mode`. With no
-    entry the einsum lane serves: a kernel is chosen only by an entry
-    that names it."""
-    if quant not in TOPK_QUANTS:
-        quant = "f32"
-    fam = device_family(device_kind)
-    ent = _table().get(_topk_key(fam, rank, quant))
-    if isinstance(ent, dict) and ent.get("mode") in TOPK_MODES:
-        return ent["mode"]
-    return "einsum"
-
-
-def record_topk(rank: int, mode: str, quant: str = "f32",
-                device_kind: str | None = None,
-                measured: dict | None = None) -> bool:
-    """Persist a measured serving top-k winner (serving_bench --quant
-    races the lanes); same atomic merge-on-write + source-priority
-    discipline as :func:`record`."""
-    if mode not in TOPK_MODES or quant not in TOPK_QUANTS:
+def record(rank: int, mode: str, bf16: bool = False,
+           device_kind: str | None = None,
+           measured: dict | None = None) -> bool:
+    """Persist a measured winner (atomic write; merge-on-write so
+    concurrent processes tuning different shapes don't clobber).
+    Returns whether anything was persisted — callers reporting
+    "recorded" must not claim success for a refused write."""
+    if mode not in MODES:
         return False
     fam = device_family(device_kind)
     if fam in ("unknown", "cpu"):
-        return False
-    ent = {"mode": mode}
-    if measured:
-        ent.update(measured)
-    return _persist(_topk_key(fam, rank, quant), ent)
-
-
-def _persist(key: str, ent: dict) -> bool:
-    """Atomic merge-on-write of one table entry, honoring the
-    measurement-source priority (shared by :func:`record` and
-    :func:`record_topk`)."""
+        return False  # only persist real-accelerator measurements
     path = _cache_path()
     if not path:
         return False  # nothing outside the checkout is written
+    ent = {"mode": mode}
+    if measured:
+        ent.update(measured)
+    key = _key(fam, rank, bf16)
     global _cache_mem
-    prio = {"bench_race": 2, "serving_bench": 2, "gram_profile": 1}
+    # whole-training measurements (bench_race) beat single-op profile
+    # measurements for the same key: the end-to-end number includes the
+    # fusion context the op actually runs in
+    prio = {"bench_race": 2, "gram_profile": 1}
     with _LOCK:
         try:
             os.makedirs(os.path.dirname(os.path.abspath(path)),
@@ -206,27 +170,6 @@ def _persist(key: str, ent: dict) -> bool:
             return False  # cache is advisory; never fail the caller
         _cache_mem = None  # re-overlay on next lookup
         return True
-
-
-def record(rank: int, mode: str, bf16: bool = False,
-           device_kind: str | None = None,
-           measured: dict | None = None) -> bool:
-    """Persist a measured winner (atomic write; merge-on-write so
-    concurrent processes tuning different shapes don't clobber).
-    Returns whether anything was persisted — callers reporting
-    "recorded" must not claim success for a refused write."""
-    if mode not in MODES:
-        return False
-    fam = device_family(device_kind)
-    if fam in ("unknown", "cpu"):
-        return False  # only persist real-accelerator measurements
-    ent = {"mode": mode}
-    if measured:
-        ent.update(measured)
-    # whole-training measurements (bench_race) beat single-op profile
-    # measurements for the same key: the end-to-end number includes the
-    # fusion context the op actually runs in (_persist's priority map)
-    return _persist(_key(fam, rank, bf16), ent)
 
 
 def reset_for_tests() -> None:

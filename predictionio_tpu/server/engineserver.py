@@ -60,6 +60,11 @@ from ..obs.trace import (
 from ..rollout.registry import ReleaseRegistry
 from ..rollout.splitter import ARM_CANDIDATE, ARM_STABLE
 from ..utils.jsonutil import from_jsonable, to_jsonable
+from ..workflow.batch_predict import (
+    PendingBatch,
+    dispatch_batch,
+    supplement_batch,
+)
 from .http import (
     AppServer,
     HTTPApp,
@@ -121,23 +126,11 @@ class ServerConfig:
     #: default). Not re-decided on the current chip: that needs a
     #: benchmark cell.
     max_batch: int = 128
-    #: Concurrent batch dispatches in flight. Serial mode: the drainer
-    #: thread count; staged mode: the single-binding dispatch-thread
-    #: count (enqueue concurrency — in-flight batches are bounded by
-    #: ``pipeline_depth``, not this). Not re-decided on the current
-    #: chip: that needs a benchmark cell.
+    #: Dispatch threads of a single-binding ``StagedPipeline`` (enqueue
+    #: concurrency; a lane binding runs one thread per lane instead).
+    #: In-flight batches are bounded by ``pipeline_depth``, not this.
+    #: Not re-decided on the current chip: that needs a benchmark cell.
     batch_pipeline: int = 4
-    #: Serving batch-path architecture (ISSUE 9,
-    #: docs/serving-pipeline.md). "staged": the continuous-batching
-    #: pipeline — assemble (host pool parses/validates/supplements the
-    #: next batch while the device is busy), dispatch (one thread per
-    #: lane ENQUEUES executables via JAX async dispatch, never blocking
-    #: on results), readback (host pool blocks on device arrays, runs
-    #: serve/to_jsonable/feedback and wakes callers), with bounded
-    #: hand-off queues between stages. "serial": the pre-ISSUE-9
-    #: drainer threads, each doing everything for its own batch — kept
-    #: for A/B benches and as the conservative fallback.
-    serving_pipeline: str = "staged"
     #: Per-query deadline (ms) covering queue wait through readback: a
     #: submit unanswered by then returns 503 and its queue entry is
     #: shed (``pio_query_deadline_exceeded_total`` counts them), so a
@@ -161,10 +154,9 @@ class ServerConfig:
     #: batches per lane — the knob that trades every query's wait
     #: behind earlier dispatches against latency hiding. 0 = auto: 2
     #: on every backend, one batch on the device and one behind it.
-    #: On the CPU maximum occupancy wins (measured 1.6× the serial
-    #: drainer); on the v5e 2 against the former 4 took 10 ms off the
-    #: median at 0.8 × the knee and held the saturated rate, with
-    #: fuller, fewer dispatches (PR 26, PERF.md section 6). While the
+    #: On the v5e 2 against the former 4 took 10 ms off the median at
+    #: 0.8 × the knee and held the saturated rate, with fuller, fewer
+    #: dispatches (PR 26, PERF.md section 6). While the
     #: pipeline is full, arrivals pool in the submit queue (where the
     #: deadline sheds them) and the next pickup coalesces the backlog
     #: into one fat batch.
@@ -237,15 +229,6 @@ class ServerConfig:
     #: when the model's rank/scale cannot take the quantization, so
     #: the knob can never silently degrade ranking. "off" serves f32.
     serving_quant: str = "off"
-    #: Batched-lane top-k realization: "fused" = the Pallas
-    #: gather→score→top-k kernel (ops/fused_topk.py — the [B, I]
-    #: score matrix never lands in HBM), "einsum" = the XLA matmul +
-    #: top_k baseline, "auto" = the persistent autotune table
-    #: (gram_autotune.best_topk_mode), support-gated so "fused" never
-    #: resolves where the kernel cannot lower. An explicit "fused" on
-    #: a CPU host runs the interpret-mode kernel (a debugging/A-B
-    #: configuration, mirroring gram_mode="fused").
-    serving_topk: str = "auto"
     #: Mesh-wide serving (ISSUE 6, docs/sharded-serving.md):
     #: "single" — today's one-device path; "replicated" — a full model
     #: copy per device, the micro-batcher fans micro-batches out
@@ -487,7 +470,7 @@ class QueryServer:
             "pio_pipeline_overlap_fraction",
             "Fraction of wall time where the device was busy WHILE an "
             "assemble/readback host stage ran — the overlap the staged "
-            "pipeline exists to create (a serial drainer reads ~0)",
+            "pipeline exists to create",
             fn=self.overlap.overlap_fraction)
         # mesh-wide serving series (ISSUE 6): per-device lane depth /
         # latency / dispatch counts while replicated fan-out is active,
@@ -674,31 +657,17 @@ class QueryServer:
         # serve() path and direct embedders share one batcher.
         # Replicated mode implies it: the dispatch threads ARE the
         # per-device lanes (fan-out), so a replicated binding without
-        # --batching still gets its N lanes. serving_pipeline picks the
-        # architecture: the staged continuous-batching pipeline
-        # (ISSUE 9) or the pre-ISSUE-9 serial drainers.
-        if self.config.serving_pipeline not in ("staged", "serial"):
-            raise ValueError(
-                f"serving_pipeline must be 'staged' or 'serial', got "
-                f"{self.config.serving_pipeline!r}")
+        # --batching still gets its N lanes.
         lanes = len(self.lane_models) or 1
         if self.config.batching or lanes > 1:
-            if self.config.serving_pipeline == "staged":
-                self.batcher = StagedPipeline(
-                    self, self.config.batch_window_ms,
-                    self.config.max_batch, lanes=lanes,
-                    assemble_workers=self.config.assemble_workers,
-                    readback_workers=self.config.readback_workers,
-                    depth=self.config.pipeline_depth,
-                    deadline_ms=self.config.queue_deadline_ms,
-                    dispatch_workers=self.config.batch_pipeline)
-            else:
-                self.batcher = MicroBatcher(
-                    self, self.config.batch_window_ms,
-                    self.config.max_batch,
-                    pipeline=max(self.config.batch_pipeline, lanes),
-                    lanes=lanes,
-                    deadline_ms=self.config.queue_deadline_ms)
+            self.batcher = StagedPipeline(
+                self, self.config.batch_window_ms,
+                self.config.max_batch, lanes=lanes,
+                assemble_workers=self.config.assemble_workers,
+                readback_workers=self.config.readback_workers,
+                depth=self.config.pipeline_depth,
+                deadline_ms=self.config.queue_deadline_ms,
+                dispatch_workers=self.config.batch_pipeline)
         else:
             self.batcher = None
         self._warm_gen = 0  # stale warm threads must not set the event
@@ -828,7 +797,6 @@ class QueryServer:
             lanes=lanes,
             rank=tuple(ranks),
             quant=tuple(quants),
-            topk=str(self.config.serving_topk),
             max_batch=int(self.config.max_batch),
             batching=bool(self.config.batching or lanes),
         )
@@ -993,21 +961,17 @@ class QueryServer:
                 algo.bind_serving(self.ctx)
                 self._bind_feature_cache(algo)
                 self._bind_algorithm_metrics(algo)
-            # serving fast path knobs (ISSUE 13): pin the batched-lane
-            # top-k realization for this deploy (validates the value —
-            # a bad config fails the deploy, not the first query) and
+            # serving fast path (ISSUE 13): validate the knob — a bad
+            # config fails the deploy, not the first query — and
             # row-quantize the serving tables BEFORE device placement,
             # so the host→HBM transfer already moves the small tables.
             # The quantize hook runs its NDCG parity probe and returns
             # the f32 model unchanged where quantization loses ranking
             # (auto-off).
-            from ..models.als import set_serving_topk_mode
-
             if self.config.serving_quant not in ("off", "bf16", "int8"):
                 raise ValueError(
                     f"serving_quant must be 'off', 'bf16' or 'int8', "
                     f"got {self.config.serving_quant!r}")
-            set_serving_topk_mode(self.config.serving_topk)
             if self.config.serving_quant != "off":
                 quantized = []
                 for a, m in zip(self.algorithms, models):
@@ -1036,11 +1000,6 @@ class QueryServer:
             # lowering, and the result must be recorded inside the
             # same swap that installs the binding it describes
             self._record_gram_mode()
-            # ptpu: allow[blocking-under-lock] — same bind-time-only
-            # contract for the serving-kernel resolution probe; an
-            # explicit kernel request the TPU cannot compile raises
-            # here and fails the deploy
-            self._resolve_serving_kernel(bind_batch)
             self._record_serving_kernel()
             # mesh-wide placement (ISSUE 6): resolve the serving mode
             # against the live devices and the model's resident bytes,
@@ -1091,46 +1050,29 @@ class QueryServer:
             pass           # deploy/reload/promote
 
     # ptpu: guarded-by[_lock] — only ever called from _bind under the
-    # binding lock
-    def _resolve_serving_kernel(self, batch: int) -> None:
-        """Resolve the batched-lane top-k realization x serving-quant
-        dtype of the bound ALS models at the bind batch
-        (``models/als.serving_kernel_report``: autotune table, then a
-        compile of any kernel it names at these shapes). An explicit
-        ``serving_topk="fused"`` the attached TPU cannot compile RAISES
-        the compiler's message here, so the deploy fails at bind
-        instead of serving from anything else."""
-        from ..models.als import ALSModel, serving_kernel_report
-
-        self._serving_kernel = None
-        for model in self.models:
-            if isinstance(model, ALSModel):
-                self._serving_kernel = serving_kernel_report(model, batch)
-                return
-
-    # ptpu: guarded-by[_lock] — only ever called from _bind under the
     # binding lock (the gauge family itself is thread-safe)
     def _record_serving_kernel(self) -> None:
-        """Refresh the ``pio_serving_kernel`` info gauge (ISSUE 13)
-        from what :meth:`_resolve_serving_kernel` found: the resolved
-        mode x quant reads 1; stale labels from a prior bind drop to 0
-        — a deploy that auto-disabled quantization or skipped a kernel
-        the compiler refused is visible on /metrics, not just in bench
-        lines. Sits next to ``pio_gram_mode``."""
+        """Note the quantization the bound ALS tables ended up with
+        (the parity probe may have refused the configured one) and
+        refresh the ``pio_serving_kernel`` info gauge (ISSUE 13) from
+        it: that label reads 1, a label from a prior bind drops to 0.
+        Sits next to ``pio_gram_mode``."""
+        from ..models.als import ALSModel, serving_quant_of
+
+        self._serving_quant = next(
+            (serving_quant_of(m) for m in self.models
+             if isinstance(m, ALSModel)), None)
         if getattr(self, "metrics", None) is None:
             return  # constructor's initial _bind; __init__ re-records
-        kern = getattr(self, "_serving_kernel", None)
-        if not kern:
+        if self._serving_quant is None:
             return
         fam = self.metrics.gauge(
             "pio_serving_kernel",
-            "Resolved serving top-k realization x quant dtype of "
-            "the bound engine (info gauge: 1 at the active "
-            "labels)")
-        self._serving_kernel_gauge = fam
+            "Quant dtype of the bound engine's serving tables (info "
+            "gauge: 1 at the active label)")
         for _, child in fam.children():
             child.set(0.0)
-        fam.labels(mode=kern["mode"], quant=kern["quant"]).set(1.0)
+        fam.labels(quant=self._serving_quant).set(1.0)
 
     def _record_sharding_findings(self) -> None:
         """Record the ``pio_sharding_findings`` info gauge (ISSUE 14):
@@ -1166,16 +1108,14 @@ class QueryServer:
                 "byRule": dict(sorted(counts.items()))}
 
     def serving_kernel_status(self) -> dict:
-        """The resolved serving-kernel block for /status.json: top-k
-        realization, quant dtype, the configured knobs (resolved may
-        differ — auto-off parity fallback, a kernel ``auto`` skipped)
-        and, under ``refused``, the compiler's message for every
-        kernel that was skipped."""
-        out = {"configuredQuant": self.config.serving_quant,
-               "configuredTopk": self.config.serving_topk}
-        out.update(getattr(self, "_serving_kernel", None)
-                   or {"mode": None, "quant": None, "refused": {}})
-        return out
+        """The serving-kernel block for /status.json: the configured
+        quant dtype and the one the bound ALS tables ended up with
+        (they differ after the auto-off parity fallback; None with no
+        ALS model bound)."""
+        with self._lock:
+            quant = self._serving_quant
+        return {"configuredQuant": self.config.serving_quant,
+                "quant": quant}
 
     @staticmethod
     def _models_nbytes(models: List[Any]) -> Optional[int]:
@@ -1742,145 +1682,17 @@ class QueryServer:
         return result
 
     # -- batched hot path ---------------------------------------------------
-    def query_batch(self, query_jsons: List[Any],
-                    obs_list: Optional[List[dict]] = None,
-                    lane: Optional[int] = None) -> List[Any]:
-        """Serve many queries with ONE ``batch_predict`` device dispatch
-        per algorithm. Per-query errors come back as ``HTTPError``s in the
-        result slots so one bad query never fails its batch-mates.
-        ``obs_list`` (one dict per query, from the batcher) receives each
-        query's access-log payload: the shared batch phase timings plus
-        its own readback/feedback time.
-
-        ``lane`` (replicated fan-out, ISSUE 6) selects that lane's
-        per-device model copies — the dispatch compiles and runs on the
-        lane's own chip, no cross-device sync. With no lanes bound the
-        argument is ignored (a stale drainer after a mode-changing
-        reload falls back to the stable binding, never a torn one)."""
-        from ..workflow.batch_predict import predict_serve_batch
-
-        t0 = time.monotonic()
-        phases: dict = {}
-        with self._lock:
-            algorithms, serving = self.algorithms, self.serving
-            if lane is not None and self.lane_models:
-                lane = lane % len(self.lane_models)
-                models = self.lane_models[lane]
-            else:
-                lane = None
-                models = self.models
-            instance_id = self.instance.id
-        traces = [self._trace_of(o) for o in (obs_list or [])]
-        traces += [None] * (len(query_jsons) - len(traces))
-        query_cls = algorithms[0].query_class
-        parsed: List[Any] = []
-        out: List[Any] = [None] * len(query_jsons)
-        ok_rows: List[int] = []
-        for i, qj in enumerate(query_jsons):
-            try:
-                parsed.append(from_jsonable(query_cls, qj))
-                ok_rows.append(i)
-            except (TypeError, ValueError) as e:
-                out[i] = HTTPError(400, str(e))
-        phases["assemble"] = time.monotonic() - t0
-        per_query_ms: List[dict] = [{} for _ in query_jsons]
-        if ok_rows:
-            if lane is not None:
-                fire(F_LANE, lane=str(lane))
-            fire(F_DISPATCH)
-            with activate_traces(traces), self._transfer_guard():
-                served = predict_serve_batch(algorithms, models, serving,
-                                             parsed, timings=phases)
-            for j, i in enumerate(ok_rows):
-                prediction = served[j]
-                if isinstance(prediction, Exception):
-                    out[i] = HTTPError(500, str(prediction))
-                    continue
-                try:
-                    tr0 = time.monotonic()
-                    result = to_jsonable(prediction)
-                    tr1 = time.monotonic()
-                    # batch-phase readback is the MAX per-query
-                    # serialization, not the sum: the sum overstated
-                    # the phase ~B× at large batches in the status
-                    # page's percentile table (per_query_ms below
-                    # keeps each query's own split)
-                    phases["readback"] = max(phases.get("readback", 0.0),
-                                             tr1 - tr0)
-                    per_query_ms[i]["readbackMs"] = round(
-                        (tr1 - tr0) * 1000, 3)
-                    if self.config.feedback:
-                        result = self._feedback(parsed[j], query_jsons[i],
-                                                result, instance_id)
-                        tf = time.monotonic() - tr1
-                        phases["feedback"] = (phases.get("feedback", 0.0)
-                                              + tf)
-                        per_query_ms[i]["feedbackMs"] = round(tf * 1000, 3)
-                    out[i] = self.plugins.process_output(query_jsons[i],
-                                                         result)
-                except Exception as e:  # noqa: BLE001 — per-query slot
-                    out[i] = HTTPError(500, str(e))
-        dt = time.monotonic() - t0
-        self._record_phases(phases)
-        self._batch_occupancy.observe(len(query_jsons))
-        if lane is not None:
-            self._lane_latency.labels(lane=str(lane)).observe(dt)
-            self._lane_dispatches.labels(lane=str(lane)).inc()
-        batch_obs = {"batchSize": len(query_jsons)}
-        if lane is not None:
-            batch_obs["lane"] = lane
-        batch_obs.update({f"{k}Ms": round(v * 1000, 3)
-                          for k, v in phases.items()})
-        for i, result in enumerate(out):
-            # each coalesced query experienced the batch's wall time
-            self._latency_hist.observe(dt)
-            is_err = isinstance(result, HTTPError)
-            self._observe_release(
-                ARM_STABLE, dt, error=is_err and result.status >= 500)
-            if is_err:
-                self._query_errors.labels(
-                    status=str(result.status)).inc()
-            if traces[i] is not None:
-                # per-batch AND per-query spans (ISSUE 12): one
-                # "batch" parent carrying the shared attributes, the
-                # stage children laid sequentially from the batch
-                # start (this serial path really is sequential)
-                tr = traces[i]
-                tr.set_attr("engineInstanceId", instance_id)
-                tr.set_attr("arm", ARM_STABLE)
-                if lane is not None:
-                    tr.set_attr("lane", lane)
-                parent = tr.add_span(
-                    "batch", t0, t0 + dt,
-                    batchSize=len(query_jsons),
-                    **({"lane": lane} if lane is not None else {}))
-                add_stage_spans(tr, t0, phases,
-                                parent_id=parent.span_id,
-                                skip=("queue_wait",))
-                tr.exemplar(self._latency_hist, dt)
-            if obs_list is not None and i < len(obs_list) \
-                    and obs_list[i] is not None:
-                obs_list[i].update(batch_obs)
-                obs_list[i].update(per_query_ms[i])
-        with self._lock:
-            self.last_serving_sec = dt / max(len(query_jsons), 1)
-            n = self.request_count
-            self.avg_serving_sec = (
-                (self.avg_serving_sec * n + dt)
-                / (n + len(query_jsons)))
-            self.request_count += len(query_jsons)
-        return out
-
     def _finish_pipeline_batch(self, ab: "_AssembledBatch",
                                results: List[Any]) -> None:
         """Readback-stage tail of the staged pipeline (ISSUE 9): the
-        per-query host work the serial drainer did inline after
-        blocking on the device — serialization (``to_jsonable``),
-        feedback, output plugins, metric recording, caller wake. The
-        staged twin of :meth:`query_batch`'s post-dispatch section;
-        ``results`` is the resolved :class:`PendingBatch` output,
-        aligned with ``ab.entries``. Stamps ``ab.t_done`` and derives
-        everything it records from the batch's stamps."""
+        per-query host work after the device results resolve —
+        serialization (``to_jsonable``), feedback, output plugins,
+        metric recording, caller wake. Per-query errors come back as
+        ``HTTPError``s in the result slots so one bad query never fails
+        its batch-mates. ``results`` is the resolved
+        :class:`PendingBatch` output, aligned with ``ab.entries``.
+        Stamps ``ab.t_done`` and derives everything it records from the
+        batch's stamps."""
         cfg = self.config
         readback = feedback = None
         final: List[Any] = [None] * len(ab.entries)
@@ -1896,7 +1708,9 @@ class QueryServer:
                 jsonable = to_jsonable(result)
                 tr1 = tf = time.monotonic()
                 # max-not-sum: the batch phase reports the worst
-                # query's serialization (see query_batch)
+                # query's serialization; the sum overstated the phase
+                # ~B× at large batches in the status page's percentile
+                # table (entry.own keeps each query's own split)
                 readback = max(readback or 0.0, tr1 - tr0)
                 if cfg.feedback:
                     jsonable = self._feedback(
@@ -1931,8 +1745,7 @@ class QueryServer:
         total_dt = 0.0
         for i, (entry, result) in enumerate(zip(ab.entries, final)):
             # end-to-end per query INCLUDING its queue wait — the
-            # latency the caller actually experienced (the serial
-            # drainer recorded only the batch's own wall time)
+            # latency the caller actually experienced
             dt = now - entry.t_enq
             total_dt += dt
             self._latency_hist.observe(dt)
@@ -1969,8 +1782,8 @@ class QueryServer:
         ``t_wake``, observe ``wake`` (and ``admit``, which needs the
         request's ``t_enter``), copy the query's stamps onto the
         request's record for the HTTP layer's closing stamps, and hand
-        the stamps to the query's trace. A serial drainer's or a shed
-        query's entry has no ``t_done``: nothing to derive."""
+        the stamps to the query's trace. A shed query's entry has no
+        ``t_done``: nothing to derive."""
         if e.t_done is None:
             return
         t_wake = time.monotonic()
@@ -1994,15 +1807,13 @@ class QueryServer:
         overlap snapshot that proves (or disproves) the device stays
         busy while host stages run."""
         b = self.batcher
-        mode = ("staged" if isinstance(b, StagedPipeline)
-                else "serial" if b is not None else "off")
         out: dict = {
-            "mode": mode,
+            "mode": "staged" if b is not None else "off",
             "deadlineMs": self.config.queue_deadline_ms,
             "deadlineExceeded": int(self._deadline_exceeded
                                     .labels().value),
         }
-        if isinstance(b, StagedPipeline):
+        if b is not None:
             out["assembleWorkers"] = self.config.assemble_workers
             out["readbackWorkers"] = self.config.readback_workers
             out["depth"] = b.depth  # resolved (0 = auto in config)
@@ -3264,7 +3075,7 @@ class _Submit:
 
 #: close sentinel for the batcher worker queues: each worker consumes
 #: exactly one and exits; ``_form_batch`` re-queues any it pulls on a
-#: sibling's behalf (see ``MicroBatcher.close`` / ``StagedPipeline.close``)
+#: sibling's behalf (see ``StagedPipeline.close``)
 _CLOSE = object()
 
 
@@ -3294,12 +3105,12 @@ def _deadline_submit(batcher, server: QueryServer, query_json: Any,
 
 def _form_batch(q, first: _Submit, max_batch: int,
                 window: float) -> List[_Submit]:
-    """Greedy ADAPTIVE batch formation, shared by both batch-path
-    architectures: while a dispatch is in flight, arrivals pile up and
-    the next batch takes everything queued (up to ``max_batch``) with
-    no timed wait — batch size self-tunes to arrival rate × service
-    time. The ``window`` wait applies only when the queue held a single
-    query, giving truly concurrent arrivals one chance to coalesce.
+    """Greedy ADAPTIVE batch formation: while a dispatch is in flight,
+    arrivals pile up and the next batch takes everything queued (up to
+    ``max_batch``) with no timed wait — batch size self-tunes to
+    arrival rate × service time. The ``window`` wait applies only when
+    the queue held a single query, giving truly concurrent arrivals
+    one chance to coalesce.
     (The round-4 batcher waited the window from EVERY first arrival —
     under 8-thread load the backlog grew unboundedly and p99 hit 11.4s;
     greedy draining is the fix.) Entries whose submitter already gave
@@ -3338,126 +3149,12 @@ def _form_batch(q, first: _Submit, max_batch: int,
             except queue.Empty:
                 break
         if nxt is _CLOSE:
-            # a close sentinel meant for a sibling drainer — put it
-            # back for that thread and stop batching
+            # a close sentinel meant for a sibling assemble worker —
+            # put it back for that thread and stop batching
             q.put(nxt)
             break
         admit(nxt)
     return batch
-
-
-class MicroBatcher:
-    """Coalesces concurrent queries into one device dispatch — the
-    SERIAL drainer architecture (``ServerConfig.serving_pipeline=
-    "serial"``; the staged :class:`StagedPipeline` is the default).
-
-    Each HTTP worker thread enqueues its query and blocks; ``pipeline``
-    drainer threads run ``QueryServer.query_batch`` — parse, supplement,
-    dispatch, block on the device, serialize — and wake the callers.
-
-    With ``lanes`` > 1 (replicated fan-out, ISSUE 6), drainer ``i``
-    serves lane ``i % lanes``: consecutive micro-batches land
-    round-robin on different devices (each with its own full model
-    copy and its own compiled executables), so N chips serve ~N×
-    the single-lane micro-batch qps with zero cross-device traffic
-    on the serve path.
-    """
-
-    def __init__(self, server: QueryServer, window_ms: float = 2.0,
-                 max_batch: int = 128, pipeline: int = 4,
-                 lanes: int = 1, deadline_ms: float = 0.0):
-        import queue
-
-        self.server = server
-        self.window = max(window_ms, 0.0) / 1000.0
-        self.max_batch = max(max_batch, 1)
-        self.lanes = max(lanes, 1)
-        self.deadline_sec = max(deadline_ms, 0.0) / 1000.0
-        # ptpu: allow[unbounded-queue] — every entry has an HTTP worker
-        # thread blocked on its done-Event, so depth is bounded by the
-        # server's connection concurrency; past the queue deadline,
-        # _deadline_submit sheds with a counted 503
-        self._q: "queue.Queue" = queue.Queue()
-        self._threads = [
-            threading.Thread(target=self._drain, daemon=True,
-                             args=(i % self.lanes
-                                   if self.lanes > 1 else None,),
-                             name=f"query-microbatcher-{i}")
-            for i in range(max(pipeline, 1))]
-        for t in self._threads:
-            t.start()
-
-    def submit(self, query_json: Any, obs: Optional[dict] = None) -> Any:
-        return _deadline_submit(self, self.server, query_json, obs)
-
-    def close(self, timeout: float = 5.0) -> None:
-        """Stop the drainer threads: one close sentinel per live
-        drainer (each consumes exactly one and exits; ``_form_batch``
-        re-queues any it pulls on a sibling's behalf), then join.
-        Queued work ahead of the sentinels still serves — no caller
-        blocked on its done-Event is stranded. Idempotent."""
-        live = [t for t in self._threads if t.is_alive()]
-        for _ in live:
-            self._q.put(_CLOSE)
-        deadline = time.monotonic() + timeout
-        for t in live:
-            t.join(timeout=max(0.0, deadline - time.monotonic()))
-
-    def _drain(self, lane: Optional[int] = None) -> None:
-        while True:
-            first = self._q.get()
-            if first is _CLOSE:
-                return
-            # queue depth at pickup: how much backlog this batch found —
-            # the arrival-rate × service-time signal the round-4
-            # unbounded-backlog pathology would have shown immediately
-            depth = self._q.qsize() + 1
-            self.server._queue_depth.observe(depth)
-            if lane is not None:
-                self.server._lane_depth.labels(
-                    lane=str(lane)).observe(depth)
-            batch = _form_batch(self._q, first, self.max_batch,
-                                self.window)
-            if not batch:
-                continue
-            t_pick = time.monotonic()
-            phase = self.server._phase("queue_wait")
-            obs_list: List[Optional[dict]] = []
-            for e in batch:
-                wait = t_pick - e.t_enq
-                phase.observe(wait)
-                if e.obs is not None:
-                    e.obs["queueWaitMs"] = round(wait * 1000, 3)
-                    tr = self.server._trace_of(e.obs)
-                    if tr is not None:
-                        tr.add_span("queue_wait", e.t_enq, t_pick)
-                obs_list.append(e.obs)
-            # lane supervision (ISSUE 11): redistribute a dead lane's
-            # traffic at pickup and fail a dispatch over to surviving
-            # lanes before failing the batch (mirrors StagedPipeline)
-            attempts = ([None] if lane is None
-                        else self.server.lane_attempt_order(lane))
-            results = None
-            for n_try, eff in enumerate(attempts):
-                try:
-                    results = self.server.query_batch(
-                        [e.query_json for e in batch], obs_list=obs_list,
-                        lane=eff)
-                    if eff is not None:
-                        self.server._lane_ok(eff)
-                    break
-                except Exception as exc:  # noqa: BLE001 — isolate batch
-                    if eff is not None:
-                        self.server._lane_error(eff, exc)
-                    if n_try + 1 < len(attempts):
-                        continue
-                    self.server.remote_log(str(exc))  # once per batch
-                    err = HTTPError(500, str(exc))
-                    err._remote_logged = True
-                    results = [err] * len(batch)
-            for e, result in zip(batch, results):
-                e.slot[0] = result
-                e.done.set()
 
 
 #: process-wide batch sequence number: names a batch on the profiler's
@@ -3578,15 +3275,15 @@ class _AssembledBatch:
 
 class StagedPipeline:
     """Continuous-batching serving pipeline (ISSUE 9,
-    docs/serving-pipeline.md) — the staged replacement for the serial
-    drainer on the hottest path in the repo.
+    docs/serving-pipeline.md): the one batcher, on the hottest path in
+    the repo.
 
     Three stages with bounded hand-off queues:
 
     - **assemble** (host pool, ``assemble_workers`` threads): greedy
-      adaptive batch formation (same policy as the serial drainer),
-      JSON→query parse — per-query 400s complete IMMEDIATELY, a
-      malformed query never waits on a device round trip — and
+      adaptive batch formation (``_form_batch``), JSON→query parse —
+      per-query 400s complete IMMEDIATELY, a malformed query never
+      waits on a device round trip — and
       concurrent supplement. All of it runs while the device chews on
       earlier batches.
     - **dispatch** (one thread per lane): takes the next assembled
@@ -3598,7 +3295,7 @@ class StagedPipeline:
       device; in sharded mode the single dispatcher serializes the
       mesh launches exactly as ``_mesh_dispatch_lock`` requires.
     - **readback** (host pool, ``readback_workers`` threads): blocks on
-      the device arrays (``PendingBatch.resolve``), serves, serializes,
+      the device arrays (``PendingBatch.wait``), serves, serializes,
       records feedback and metrics, wakes the callers
       (``QueryServer._finish_pipeline_batch``).
 
@@ -3649,8 +3346,8 @@ class StagedPipeline:
         # pickup drains them greedily into one fat batch. Without
         # this, a fast assemble stage races ahead of the device and
         # shreds the workload into minimum-size batches (measured:
-        # mean occupancy 1.7 vs the serial drainer's 4.8 at the same
-        # load — and device efficiency scales with occupancy).
+        # mean occupancy 1.7 against 4.8 at the same load — and device
+        # efficiency scales with occupancy).
         self._inflight = threading.BoundedSemaphore(depth * self.lanes)
         # bound children: labels() validates and sorts its keywords
         # under a lock on every call
@@ -3678,10 +3375,8 @@ class StagedPipeline:
             # single binding: several dispatchers enqueue concurrently
             # (JAX async dispatch is thread-safe; sharded-mesh launches
             # serialize on _mesh_dispatch_lock inside the model). On a
-            # TPU the device still executes in order; on backends whose
-            # runtime can overlap independent executions (CPU CI) this
-            # matches the serial drainer's in-flight concurrency
-            # instead of regressing below it.
+            # TPU the device still executes in order; backends whose
+            # runtime can overlap independent executions (CPU CI) do.
             for i in range(max(dispatch_workers, 1)):
                 self._dispatch_threads.append(threading.Thread(
                     target=self._dispatch_loop, daemon=True,
@@ -3776,8 +3471,6 @@ class StagedPipeline:
         """Parse and supplement a formed batch against ONE snapshot of
         the binding. The loop passes the batch's number and the
         ``t_pick`` it stamped; alone (tests) the pickup is now."""
-        from ..workflow.batch_predict import supplement_batch
-
         server = self.server
         if t_pick is None:
             t_pick = time.monotonic()
@@ -3829,8 +3522,6 @@ class StagedPipeline:
 
     # -- stage 2: dispatch ---------------------------------------------------
     def _dispatch_loop(self, lane: Optional[int] = None) -> None:
-        from ..workflow.batch_predict import PendingBatch, dispatch_batch
-
         server = self.server
         tracker = server.overlap
         while True:
@@ -3914,6 +3605,16 @@ class StagedPipeline:
     def _readback_loop(self) -> None:
         server = self.server
         tracker = server.overlap
+        # ``pending``, ``fetched`` and ``results`` are this LOOP's
+        # locals on purpose: a finished batch's resolvers (they hold
+        # its device arrays) stay alive until this thread's next batch
+        # rebinds them, just before it blocks on the device, where
+        # nobody waits for this thread. Freeing a device array gives up
+        # the interpreter lock; with the body in a method of its own
+        # the arrays died with ``ab``, between a hand-off queue's
+        # ``get`` and the pick stamp of whichever stage thread let go
+        # of it last: +2.1 ms ``readback_q``, +0.8 ms ``dispatch_q`` a
+        # batch, 3 % of the saturated rate (PERF.md finding 29.4)
         while True:
             with stage_span("wait_readback_q") as waiting:
                 ab = self._readback_q.get()
@@ -4051,7 +3752,7 @@ def build_artifacts(ctx: Context, engine: Engine,
     loading instead of compiling.
 
     ``config`` must match the eventual deploy on the key-bearing
-    serving knobs (mode/quant/topk/batching/max_batch); observability
+    serving knobs (mode/quant/batching/max_batch); observability
     side-cars are forced off here — they never affect the artifacts.
     """
     from dataclasses import replace

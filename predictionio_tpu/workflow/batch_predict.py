@@ -12,8 +12,7 @@ query per dispatch.
 from __future__ import annotations
 
 import json
-import time
-from typing import Any, Dict, Iterable, Iterator, List, Optional
+from typing import Any, Iterable, Iterator, List, Optional
 
 from ..controller.context import Context
 from ..controller.engine import Engine
@@ -39,9 +38,8 @@ def _algo_pool():
     return _dispatch_pool
 
 
-def supplement_batch(serving: Any, queries: List[Any], out: List[Any],
-                     timings: Optional[Dict[str, float]] = None
-                     ) -> tuple:
+def supplement_batch(serving: Any, queries: List[Any],
+                     out: List[Any]) -> tuple:
     """Supplement each query (the assemble-stage host work). Returns
     ``(supplemented, live)``; per-query supplement failures land as the
     raised exception in that query's ``out`` slot. With more than one
@@ -53,7 +51,6 @@ def supplement_batch(serving: Any, queries: List[Any], out: List[Any],
     error slots are exactly the serial loop's."""
     supplemented: List[Any] = []
     live: List[int] = []
-    t0 = time.monotonic()
     if len(queries) > 1:
         pool = _algo_pool()
         futures = [pool.submit(serving.supplement, q) for q in queries]
@@ -70,16 +67,11 @@ def supplement_batch(serving: Any, queries: List[Any], out: List[Any],
                 live.append(i)
             except Exception as e:  # noqa: BLE001 — isolate per query
                 out[i] = e
-    if timings is not None:
-        timings["supplement"] = (timings.get("supplement", 0.0)
-                                 + (time.monotonic() - t0))
     return supplemented, live
 
 
 def dispatch_batch(algorithms: List[Any], models: List[Any],
-                   supplemented: List[Any],
-                   timings: Optional[Dict[str, float]] = None
-                   ) -> List[Any]:
+                   supplemented: List[Any]) -> List[Any]:
     """Per-algorithm device DISPATCH without readback (ISSUE 9):
     returns one no-arg resolver per algorithm; calling it blocks until
     that algorithm's predictions are host-real. Algorithms exposing
@@ -94,29 +86,24 @@ def dispatch_batch(algorithms: List[Any], models: List[Any],
     A dispatch-time failure raises out of this call (the caller fills
     every live slot — one dispatch, whole batch); resolver-time
     failures raise out of the resolver the same way."""
-    t0 = time.monotonic()
-    try:
-        resolvers: List[Any] = []
-        for a, m in zip(algorithms, models):
-            async_fn = getattr(a, "batch_predict_async", None)
-            if async_fn is not None:
-                resolvers.append(async_fn(m, supplemented))
-            else:
-                resolvers.append(_algo_pool().submit(
-                    a.batch_predict, m, supplemented).result)
-        return resolvers
-    finally:
-        if timings is not None:
-            timings["dispatch"] = (timings.get("dispatch", 0.0)
-                                   + (time.monotonic() - t0))
+    resolvers: List[Any] = []
+    for a, m in zip(algorithms, models):
+        async_fn = getattr(a, "batch_predict_async", None)
+        if async_fn is not None:
+            resolvers.append(async_fn(m, supplemented))
+        else:
+            resolvers.append(_algo_pool().submit(
+                a.batch_predict, m, supplemented).result)
+    return resolvers
 
 
 class PendingBatch:
     """An in-flight coalesced batch: device dispatches enqueued, host
-    results not yet read back. Built by :func:`dispatch_serve_batch`
-    (or assembled from parts by the engine server's staged pipeline);
-    :meth:`resolve` blocks on the device arrays and finishes the
-    per-query serving — the readback stage's work."""
+    results not yet read back. Assembled from parts by the engine
+    server's dispatch stage (and by :func:`predict_serve_batch`);
+    :meth:`wait` blocks on the device arrays and :meth:`serve` finishes
+    the per-query serving — the readback stage's work, which stamps
+    between the two (``server/engineserver.py::_AssembledBatch``)."""
 
     __slots__ = ("queries", "serving", "out", "live", "resolvers")
 
@@ -157,71 +144,29 @@ class PendingBatch:
                 out[i] = e
         return out
 
-    def resolve(self, timings: Optional[Dict[str, float]] = None
-                ) -> List[Any]:
-        """:meth:`wait` (``device_wait``) then :meth:`serve`
-        (``serve``), timed into ``timings`` for the serial paths. The
-        staged pipeline calls the two halves itself and stamps between
-        them (``server/engineserver.py::_AssembledBatch``)."""
-        if not self.live:
-            return self.out
-        t1 = time.monotonic()
-        per_algo = self.wait()
-        t2 = time.monotonic()
-        if timings is not None:
-            timings["device_wait"] = (timings.get("device_wait", 0.0)
-                                      + (t2 - t1))
-        if per_algo is None:
-            return self.out
-        out = self.serve(per_algo)
-        if timings is not None:
-            timings["serve"] = (timings.get("serve", 0.0)
-                                + (time.monotonic() - t2))
-        return out
 
-
-def dispatch_serve_batch(algorithms: List[Any], models: List[Any],
-                         serving: Any, queries: List[Any],
-                         timings: Optional[Dict[str, float]] = None
-                         ) -> PendingBatch:
-    """Supplement + per-algorithm device dispatch, WITHOUT blocking on
-    results: returns a :class:`PendingBatch` whose ``resolve()`` does
-    the readback and per-query serving. The serving pipeline's dispatch
-    stage uses this to keep the device enqueued batch after batch while
-    earlier batches' results are still in flight (ISSUE 9)."""
+def predict_serve_batch(algorithms: List[Any], models: List[Any],
+                        serving: Any, queries: List[Any]) -> List[Any]:
+    """The batch-predict job's batch: supplement each query, ONE
+    ``batch_predict`` device dispatch per algorithm, then serve per
+    query. Per-query failures (supplement/serve) come back as the raised
+    exception in that query's slot; a ``batch_predict`` failure fills
+    every live slot (it is one dispatch). Built from the functions the
+    engine server's stages call (:func:`supplement_batch`,
+    :func:`dispatch_batch`, :class:`PendingBatch`), resolved at once,
+    so the job and the server can never diverge."""
     out: List[Any] = [None] * len(queries)
-    supplemented, live = supplement_batch(serving, queries, out,
-                                          timings=timings)
+    supplemented, live = supplement_batch(serving, queries, out)
     resolvers: List[Any] = []
     if live:
         try:
-            resolvers = dispatch_batch(algorithms, models, supplemented,
-                                       timings=timings)
+            resolvers = dispatch_batch(algorithms, models, supplemented)
         except Exception as e:  # noqa: BLE001 — one dispatch, whole batch
             for i in live:
                 out[i] = e
             live = []
-    return PendingBatch(queries, serving, out, live, resolvers)
-
-
-def predict_serve_batch(algorithms: List[Any], models: List[Any],
-                        serving: Any, queries: List[Any],
-                        timings: Optional[Dict[str, float]] = None
-                        ) -> List[Any]:
-    """The batched serving pipeline shared by the engine server's
-    micro-batcher and the batch-predict job: supplement each query, ONE
-    ``batch_predict`` device dispatch per algorithm, then serve per
-    query. Per-query failures (supplement/serve) come back as the raised
-    exception in that query's slot; a ``batch_predict`` failure fills
-    every live slot (it is one dispatch). When ``timings`` is given, the
-    wall time of each internal phase is accumulated into it under
-    ``supplement``/``dispatch``/``device_wait``/``serve`` (the engine
-    server's per-phase telemetry reads these; ``dispatch`` is the pure
-    device ENQUEUE since ISSUE 9, ``device_wait`` the block on its
-    results). Realized as dispatch + immediate resolve so the serial
-    and staged paths can never diverge."""
-    return dispatch_serve_batch(algorithms, models, serving, queries,
-                                timings=timings).resolve(timings=timings)
+    pending = PendingBatch(queries, serving, out, live, resolvers)
+    return pending.serve(pending.wait())
 
 
 def batch_predict_lines(engine: Engine,

@@ -58,6 +58,32 @@ def start_sqlite_backed_storage_server(tmp_path, secret=None):
     return srv, backing
 
 
+def serve_staged_batch(qs, query_jsons, obs_list=None, timeout=60.0):
+    """Serve ``query_jsons`` as ONE batch of ``qs``'s StagedPipeline:
+    assembled here (``_assemble``, on the calling thread), then handed
+    to the pipeline's own dispatch and readback threads, as the
+    assemble loop hands its batches over. Returns ``(slots, lane)``:
+    the result slots in query order and the lane that dispatched the
+    batch (None on a single binding). For tests that must name a
+    batch's members; the rest ``submit``."""
+    from predictionio_tpu.server.engineserver import _Submit
+
+    pipe = qs.batcher
+    entries = [_Submit(q, obs_list[i] if obs_list else None, 0.0)
+               for i, q in enumerate(query_jsons)]
+    # the in-flight slot the assemble loop takes before a pickup; the
+    # readback stage frees it, as it does for the loop's batches
+    pipe._inflight.acquire()
+    ab = pipe._assemble(entries)
+    if not ab.entries:  # every query answered at parse
+        pipe._inflight.release()
+    else:
+        pipe._dispatch_q.put(ab)
+    for e in entries:
+        assert e.done.wait(timeout), "the pipeline never answered"
+    return [e.slot[0] for e in entries], ab.lane
+
+
 @pytest.fixture(autouse=True)
 def _fail_on_lock_inversions():
     """Instrumented-lock CI mode: when the suite runs with

@@ -216,25 +216,25 @@ class TestRecommend:
         must collapse onto power-of-two compiled shapes (each novel
         [B, r] shape is a fresh XLA compile, which under traffic lands
         in the micro-batch p90)."""
-        from predictionio_tpu.models.als import _topk_scores
+        import jax.numpy as jnp
 
-        model, _ = self._model()
-        # force the device path regardless of model size heuristics
-        import predictionio_tpu.models.als as als
+        from predictionio_tpu.models.als import _serve_topk
 
-        orig = als._serve_on_host
-        als._serve_on_host = lambda *a, **k: False
-        try:
-            before = _topk_scores._cache_size()
-            for batch in ([0], [0, 1], [0, 1, 2], [0, 1, 2, 3],
-                          [0] * 5, [0] * 7):
-                ids, _ = recommend_batch(model, np.array(batch), 3)
-                assert ids.shape[0] == len(batch)
-            added = _topk_scores._cache_size() - before
-            # sizes {1,2,3,4,5,7} collapse to padded {1,2,4,8}
-            assert added <= 4, f"cache grew by {added} (> 4 shapes)"
-        finally:
-            als._serve_on_host = orig
+        # device-resident tables (so the device path serves) of a shape
+        # no other test compiles: every program counted here is new
+        rng = np.random.default_rng(11)
+        model = ALSModel(
+            user_factors=jnp.asarray(rng.normal(size=(37, 5)), jnp.float32),
+            item_factors=jnp.asarray(rng.normal(size=(53, 5)), jnp.float32),
+            n_users=37, n_items=53, params=ALSParams(rank=5))
+        before = _serve_topk._cache_size()
+        for batch in ([0], [0, 1], [0, 1, 2], [0, 1, 2, 3],
+                      [0] * 5, [0] * 7):
+            ids, _ = recommend_batch(model, np.array(batch), 3)
+            assert ids.shape == (len(batch), 3)
+        added = _serve_topk._cache_size() - before
+        # sizes {1,2,3,4,5,7} collapse to padded {1,2,4,8}
+        assert added == 4, f"cache grew by {added}, not 4 shapes"
 
     def test_padded_items_never_recommended(self, mesh8):
         ratings, _, _ = make_synthetic(n_users=16, n_items=10, seed=5)

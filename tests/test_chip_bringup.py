@@ -21,9 +21,8 @@ from predictionio_tpu.models.als import (
     ALSModel,
     ALSParams,
     resolved_gram_mode,
-    set_serving_topk_mode,
 )
-from predictionio_tpu.ops import _probe, fused_gram, fused_topk
+from predictionio_tpu.ops import _probe, fused_gram
 from predictionio_tpu.ops import gram_autotune as ga
 from predictionio_tpu.utils import platform
 
@@ -122,11 +121,8 @@ def fake_tpu_attach(monkeypatch):
     refuses — a stand-in for a kernel the chip's compiler refuses."""
     monkeypatch.setattr(_probe, "tpu_attached", lambda: True)
     fused_gram.reset_support_cache_for_tests()
-    fused_topk.reset_support_cache_for_tests()
     yield
-    set_serving_topk_mode(None)
     fused_gram.reset_support_cache_for_tests()
-    fused_topk.reset_support_cache_for_tests()
 
 
 def _server(cfg):
@@ -164,54 +160,6 @@ def _server(cfg):
 
 
 class TestNoStandInForAKernel:
-    def test_explicit_topk_request_fails_the_deploy(self, fake_tpu_attach):
-        from predictionio_tpu.server.engineserver import ServerConfig
-
-        with pytest.raises(RuntimeError) as err:
-            _server(ServerConfig(serving_topk="fused", warm_start=False))
-        msg = str(err.value)
-        assert "serving_topk='fused' does not compile" in msg
-        # the compiler's own words ride along
-        assert fused_topk.refusals()
-        assert next(iter(fused_topk.refusals().values())) in msg
-
-    def test_auto_skips_a_named_kernel_and_says_why(
-            self, fake_tpu_attach, monkeypatch, tmp_path):
-        from predictionio_tpu.server.engineserver import ServerConfig
-
-        table = tmp_path / "tune.json"
-        table.write_text(json.dumps(
-            {"cpu|topk|r32|f32": {"mode": "fused", "source": "test"}}))
-        monkeypatch.setenv("PIO_GRAM_AUTOTUNE_CACHE", str(table))
-        ga.reset_for_tests()
-        try:
-            server = _server(ServerConfig(warm_start=False))
-            kern = server.serving_kernel_status()
-            assert kern["mode"] == "einsum"
-            assert kern["configuredTopk"] == "auto"
-            assert kern["refused"]  # the compiler's message, by shape
-            rendered = server.metrics.render()
-            assert 'pio_serving_kernel{mode="einsum",quant="off"} 1' \
-                in rendered
-            assert 'mode="fused"' not in rendered
-        finally:
-            ga.reset_for_tests()
-
-    def test_dispatch_on_a_tpu_never_runs_the_reference(
-            self, fake_tpu_attach, monkeypatch):
-        import jax.numpy as jnp
-
-        def boom(*a, **k):
-            raise AssertionError("a reference stood in for the kernel")
-
-        monkeypatch.setattr(fused_topk, "fused_topk_reference", boom)
-        monkeypatch.setattr(fused_gram, "fused_gram_reference", boom)
-        tab = jnp.zeros((16, 8), jnp.float32)
-        with pytest.raises(Exception) as err:
-            fused_topk.fused_topk_dispatch(
-                tab, jnp.zeros((8,), jnp.int32), tab, k=4, n_items=16)
-        assert "reference stood in" not in str(err.value)
-
     def test_explicit_gram_request_raises_and_gauge_stays_off_fused(
             self, fake_tpu_attach):
         from predictionio_tpu.server.engineserver import ServerConfig
@@ -303,12 +251,8 @@ def test_autotune_touches_nothing_under_home(monkeypatch, tmp_path):
     ga.reset_for_tests()
     try:
         assert ga.best_mode(64, device_kind="TPU v5 lite0") == "einsum"
-        assert ga.best_topk_mode(64, device_kind="TPU v5 lite0") \
-            == "einsum"
         assert not ga.record(64, "pair", device_kind="TPU v5 lite0",
                              measured={"source": "bench_race"})
-        assert not ga.record_topk(64, "einsum", "f32",
-                                  device_kind="TPU v5 lite0")
         assert list(tmp_path.iterdir()) == []
     finally:
         ga.reset_for_tests()

@@ -439,7 +439,10 @@ def test_deploy_batching_defaults_match_config():
     field defaults (the CLI uses literals so storage-only commands
     never import the server stack / jax — this test is the sync)."""
     from predictionio_tpu.cli import build_parser
-    from predictionio_tpu.server.engineserver import MicroBatcher, ServerConfig
+    from predictionio_tpu.server.engineserver import (
+        ServerConfig,
+        StagedPipeline,
+    )
 
     args = build_parser().parse_args(
         ["deploy", "--engine-json", "engine.json"])
@@ -449,14 +452,12 @@ def test_deploy_batching_defaults_match_config():
     assert args.batch_pipeline == cfg.batch_pipeline
     assert args.serving_mode == cfg.serving_mode
     # staged-pipeline knobs (ISSUE 9) stay in sync the same way
-    assert args.pipeline == cfg.serving_pipeline
     assert args.queue_deadline_ms == cfg.queue_deadline_ms
     assert args.assemble_workers == cfg.assemble_workers
     assert args.readback_workers == cfg.readback_workers
     assert args.pipeline_depth == cfg.pipeline_depth
-    # serving fast-path knobs (ISSUE 13) stay in sync the same way
+    # serving fast-path knob (ISSUE 13) stays in sync the same way
     assert args.serving_quant == cfg.serving_quant
-    assert args.serving_topk == cfg.serving_topk
     # tracing knobs (ISSUE 12) stay in sync the same way
     assert (not args.no_trace) == cfg.tracing
     assert args.trace_ring == cfg.trace_ring
@@ -466,5 +467,5 @@ def test_deploy_batching_defaults_match_config():
     assert args.hot_keys_k == cfg.hot_keys_k
     import inspect
 
-    sig = inspect.signature(MicroBatcher.__init__)
+    sig = inspect.signature(StagedPipeline.__init__)
     assert sig.parameters["max_batch"].default == cfg.max_batch

@@ -287,8 +287,10 @@ class TestGramModeGauge:
             in server.metrics.render()
     def test_serve_error_isolated_in_mixed_batch(self, trained_ctx):
         """A serve-time exception for one query must not poison its
-        batch-mates (exercises query_batch directly with a genuinely
-        mixed batch)."""
+        batch-mates (one genuinely mixed batch through the staged
+        stages)."""
+        from conftest import serve_staged_batch
+
         from predictionio_tpu.server.engineserver import (
             HTTPError,
             QueryServer,
@@ -301,7 +303,9 @@ class TestGramModeGauge:
         ctx, engine, ep = trained_ctx
         inst = get_latest_completed(ctx, engine_id="srv")
         models = load_models_for_deploy(ctx, engine, inst, ep)
-        server = QueryServer(ctx, engine, ep, models, inst)
+        server = QueryServer(ctx, engine, ep, models, inst,
+                             ServerConfig(batching=True, max_batch=8,
+                                          warm_start=False))
 
         class PoisonServing:
             def __init__(self, inner):
@@ -316,7 +320,7 @@ class TestGramModeGauge:
                 return self.inner.serve(q, ps)
 
         server.serving = PoisonServing(server.serving)
-        out = server.query_batch([
+        out, _ = serve_staged_batch(server, [
             {"user": "u1", "num": 2},
             {"user": "u3", "num": 2},   # serve raises
             {"bogus": 1},               # parse error

@@ -1,5 +1,5 @@
 """Mesh-wide serving & training (ISSUE 6) on the 8-device virtual CPU
-mesh: replicated fan-out (per-device lanes through the MicroBatcher)
+mesh: replicated fan-out (per-device lanes through the StagedPipeline)
 must answer identically on every lane, row-sharded factor tables
 (``shard_model`` over the ``(batch, model)`` serving mesh) must answer
 identically to the single-device baseline, and ALS must train to the
@@ -13,6 +13,7 @@ from datetime import datetime, timezone
 
 import numpy as np
 import pytest
+from conftest import serve_staged_batch
 
 import jax
 import jax.numpy as jnp
@@ -246,7 +247,7 @@ def _mk_server(cfg: ServerConfig, model: ALSModel) -> QueryServer:
 
 class TestQueryServerMeshModes:
     """The engine-server integration: mode resolution at bind,
-    per-device lane fan-out through the MicroBatcher, the sharded
+    per-device lane fan-out through the StagedPipeline, the sharded
     binding serving /queries.json-shaped queries, and the status
     surfaces."""
 
@@ -260,8 +261,15 @@ class TestQueryServerMeshModes:
         assert qs.serving_mode_resolved == "replicated"
         assert len(qs.lane_models) == 8
         assert qs.batcher is not None and qs.batcher.lanes == 8
-        outs = [qs.query_batch([{"user": "u7", "num": 5}], lane=lane)[0]
-                for lane in range(8)]
+        # batch after batch until every lane's dispatcher has had one
+        by_lane = {}
+        for _ in range(64):
+            out, lane = serve_staged_batch(qs, [{"user": "u7", "num": 5}])
+            by_lane.setdefault(lane, out[0])
+            if len(by_lane) == 8:
+                break
+        assert sorted(by_lane) == list(range(8))
+        outs = list(by_lane.values())
         assert all(o == outs[0] for o in outs)
         assert [s["item"] for s in outs[0]["itemScores"]] \
             == [s["item"] for s in want["itemScores"]]
@@ -275,8 +283,9 @@ class TestQueryServerMeshModes:
             ServerConfig(warm_start=False, serving_mode="replicated",
                          batching=True, max_batch=8),
             _model(nu=300, ni=150))
-        for lane in range(3):
-            qs.query_batch([{"user": "u1", "num": 3}], lane=lane)
+        for _ in range(64):  # until lane 0's dispatcher has had a batch
+            if serve_staged_batch(qs, [{"user": "u1", "num": 3}])[1] == 0:
+                break
         mesh = qs.mesh_status()
         assert mesh["mode"] == "replicated"
         assert mesh["devices"] == 8

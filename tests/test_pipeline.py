@@ -8,6 +8,7 @@ from datetime import datetime, timezone
 
 import numpy as np
 import pytest
+from conftest import serve_staged_batch
 
 from predictionio_tpu.controller import Context
 from predictionio_tpu.data.bimap import BiMap
@@ -20,7 +21,6 @@ from predictionio_tpu.models.als import ALSModel, ALSParams
 from predictionio_tpu.obs import OverlapTracker
 from predictionio_tpu.server.engineserver import (
     HTTPError,
-    MicroBatcher,
     QueryServer,
     ServerConfig,
     StagedPipeline,
@@ -157,10 +157,8 @@ class TestDeadline:
 
         qs.serving = Wedged(qs.serving)
 
-    @pytest.mark.parametrize("pipeline", ["staged", "serial"])
-    def test_wedged_dispatch_sheds_503(self, pipeline):
+    def test_wedged_dispatch_sheds_503(self):
         qs = _mk_server(ServerConfig(batching=True, max_batch=4,
-                                     serving_pipeline=pipeline,
                                      queue_deadline_ms=150.0,
                                      warm_start=False))
         self._wedge(qs, 2.0)
@@ -208,12 +206,6 @@ class TestDeadline:
         r = qs.batcher.submit({"user": "u1", "num": 2})
         assert len(r["itemScores"]) == 2
         assert qs._deadline_exceeded.labels().value == 0
-
-    def test_microbatcher_deadline_signature_default(self):
-        import inspect
-
-        sig = inspect.signature(MicroBatcher.__init__)
-        assert sig.parameters["deadline_ms"].default == 0.0
 
 
 class TestMidFlightRebind:
@@ -310,10 +302,12 @@ class TestPipelineTelemetry:
     def test_readback_phase_is_max_not_sum(self):
         """Satellite: the batch readback phase reports the worst
         query's serialization, not the sum over the batch."""
-        qs = _mk_server(ServerConfig(warm_start=False))
+        qs = _mk_server(ServerConfig(batching=True, max_batch=8,
+                                     warm_start=False))
         obs_list = [{} for _ in range(6)]
-        qs.query_batch([{"user": f"u{i}", "num": 3} for i in range(6)],
-                       obs_list=obs_list)
+        serve_staged_batch(
+            qs, [{"user": f"u{i}", "num": 3} for i in range(6)],
+            obs_list=obs_list)
         per_query = [o["readbackMs"] for o in obs_list]
         batch_ms = obs_list[0]["readbackMs"]
         # identical batch value broadcast to every query's obs
@@ -323,21 +317,6 @@ class TestPipelineTelemetry:
         readback_ms = phases["phase=readback"]["max"] * 1000
         assert readback_ms <= sum(per_query) + 1e-6
         assert readback_ms >= max(per_query) * 0.5 - 1e-6
-
-    def test_serial_mode_still_works_and_reports(self):
-        qs = _mk_server(ServerConfig(batching=True,
-                                     serving_pipeline="serial",
-                                     warm_start=False))
-        assert isinstance(qs.batcher, MicroBatcher)
-        r = qs.batcher.submit({"user": "u1", "num": 2})
-        assert len(r["itemScores"]) == 2
-        assert qs.pipeline_status()["mode"] == "serial"
-
-    def test_unknown_pipeline_mode_rejected(self):
-        with pytest.raises(ValueError, match="serving_pipeline"):
-            _mk_server(ServerConfig(batching=True,
-                                    serving_pipeline="bogus",
-                                    warm_start=False))
 
 
 class TestInflightDepth:

@@ -14,6 +14,7 @@ from datetime import datetime, timedelta, timezone
 
 import numpy as np
 import pytest
+from conftest import serve_staged_batch
 
 from predictionio_tpu.cli import main as cli_main
 from predictionio_tpu.controller import Context
@@ -611,7 +612,7 @@ class TestParallelAlgoDispatch:
         assert wall < 0.45, f"predictions look serial: {wall:.2f}s"
 
     def test_batched_dispatch_also_concurrent(self):
-        """The micro-batcher / batch-predict lane shares the fix: one
+        """The staged pipeline / batch-predict lane shares the fix: one
         concurrent batch_predict dispatch per algorithm."""
         ctx = _fake_ctx()
         inst = _fake_instance(ctx.storage, "p2")
@@ -619,10 +620,12 @@ class TestParallelAlgoDispatch:
         qs = QueryServer(ctx, engine, object(),
                          [FakeModel("a"), FakeModel("b"),
                           FakeModel("c")],
-                         inst, ServerConfig(warm_start=False))
+                         inst, ServerConfig(batching=True,
+                                            warm_start=False))
         t0 = time.monotonic()
-        out = qs.query_batch([{"user": "u1"}, {"user": "u2"}])
+        out, _ = serve_staged_batch(qs, [{"user": "u1"}, {"user": "u2"}])
         wall = time.monotonic() - t0
+        qs.close()
         assert [o["tags"] for o in out] == [["a", "b", "c"]] * 2
         assert wall < 0.45, f"batch dispatch looks serial: {wall:.2f}s"
 

@@ -247,26 +247,20 @@ def _boot_server(cfg, model=None, rank=16):
 
 class TestServerWiring:
     def test_bind_quantizes_and_records_gauge(self):
-        from predictionio_tpu.models.als import set_serving_topk_mode
         from predictionio_tpu.server.engineserver import ServerConfig
 
-        try:
-            qs = _boot_server(ServerConfig(warm_start=False,
-                                           serving_quant="int8"))
-            assert isinstance(qs.models[0].user_factors,
-                              QuantizedFactors)
-            st = qs.serving_kernel_status()
-            assert st["quant"] == "int8"
-            assert st["configuredQuant"] == "int8"
-            fam = qs.metrics.gauge("pio_serving_kernel")
-            active = {tuple(sorted(dict(items).items())): c.value
-                      for items, c in fam.children()}
-            assert any(v == 1.0 for v in active.values())
-            # queries still answer on the quantized binding
-            out = qs.query({"user": "u3", "num": 5})
-            assert len(out["itemScores"]) == 5
-        finally:
-            set_serving_topk_mode(None)
+        qs = _boot_server(ServerConfig(warm_start=False,
+                                       serving_quant="int8"))
+        assert isinstance(qs.models[0].user_factors, QuantizedFactors)
+        st = qs.serving_kernel_status()
+        assert st == {"quant": "int8", "configuredQuant": "int8"}
+        fam = qs.metrics.gauge("pio_serving_kernel")
+        active = {tuple(sorted(dict(items).items())): c.value
+                  for items, c in fam.children()}
+        assert active == {(("quant", "int8"),): 1.0}
+        # queries still answer on the quantized binding
+        out = qs.query({"user": "u3", "num": 5})
+        assert len(out["itemScores"]) == 5
 
     def test_bad_config_fails_deploy(self):
         from predictionio_tpu.server.engineserver import ServerConfig
@@ -274,14 +268,6 @@ class TestServerWiring:
         with pytest.raises(ValueError, match="serving_quant"):
             _boot_server(ServerConfig(warm_start=False,
                                       serving_quant="fp8"))
-        from predictionio_tpu.models.als import set_serving_topk_mode
-
-        try:
-            with pytest.raises(ValueError, match="serving topk"):
-                _boot_server(ServerConfig(warm_start=False,
-                                          serving_topk="fastest"))
-        finally:
-            set_serving_topk_mode(None)
 
     def test_off_default_serves_f32(self):
         from predictionio_tpu.server.engineserver import ServerConfig
