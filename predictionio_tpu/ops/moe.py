@@ -14,7 +14,12 @@ expert) assignments are sorted by expert and each weight takes one
 grouped matrix product (``jax.lax.ragged_dot``: a Mosaic grouped-matmul
 kernel on a TPU, ``ragged-dot`` in its trace, and a native op on the
 CPU), so the work is that of the assignments made and not of experts x
-tokens. FEW tokens (a decode step, ``DENSE_MAX_ROWS`` or under): every
+tokens. The third product's rows ``y [T*k, H]`` float32 stay where the
+sort put them: the combine walks the TOKENS, gathers each token's ``k``
+rows through the inverse permutation and sums them under the routing
+weights and the mask as routed (``[T, k]``), so ``y`` is read once and
+no other array of its size is written (:func:`_combine`). FEW tokens
+(a decode step, ``DENSE_MAX_ROWS`` or under): every
 held expert is computed for every token in one batched product and the
 routing weights, zero where an expert was not selected, do the
 selecting; a step's rows touch every expert anyway, each expert's
@@ -123,25 +128,40 @@ def _every_expert(x, local, wts, w1, w3, w2):
 
 def _sorted_groups(x, local, wts, w1, w3, w2):
     """Many tokens: assignments sorted by expert, one grouped product
-    per weight; index ``E_held`` sorts behind the last group."""
+    per weight; index ``E_held`` sorts behind the last group. Every
+    index below is in range by construction and the gathers say so: a
+    fill-mode gather pays a select over its whole output."""
     T, k = local.shape
     n_held = w1.shape[0]
     flat = local.reshape(-1)
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(
         jnp.int32)
-    xs = jnp.take(x, order // k, axis=0)
+    xs = x.at[order // k].get(mode="promise_in_bounds")
     f32 = jnp.float32
     h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes,
                                        preferred_element_type=f32)) \
         * jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=f32)
     y = jax.lax.ragged_dot(h.astype(x.dtype), w2, sizes,
                            preferred_element_type=f32)
-    # rows behind the last group are never written: zero them before
-    # the weights (0 x garbage is not 0)
-    live = (jnp.take(flat, order) < n_held)[:, None]
-    y = jnp.where(live, y * jnp.take(wts.reshape(-1), order)[:, None],
-                  0.0)
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(T * k, dtype=order.dtype))
-    return jnp.take(y, back, axis=0).reshape(T, k, -1).sum(axis=1)
+    # the inverse permutation: assignment (t, j) is row back[t, j] of y
+    back = jnp.argsort(order).reshape(T, k)
+    return _combine(y, back, local < n_held, wts)
+
+
+def _combine(y, back, live, wts):
+    """``out[t] = sum_j where(live[t, j], wts[t, j] * y[back[t, j]], 0)``
+    float32, in TOKEN order: ``y [T*k, H]`` is read once, row by row,
+    by ``k`` gathers of ``[T, H]`` that the weighted sum consumes; the
+    mask and the weights are the ``[T, k]`` arrays as routed. Rows of
+    ``y`` behind the last group are never written, and 0 x garbage is
+    not 0: the mask is applied to the gathered row, not to its weight."""
+    def term(j):
+        rows = y.at[back[:, j]].get(mode="promise_in_bounds",
+                                    unique_indices=True)
+        return jnp.where(live[:, j:j + 1], wts[:, j:j + 1] * rows, 0.0)
+
+    out = term(0)
+    for j in range(1, back.shape[1]):
+        out = out + term(j)
+    return out

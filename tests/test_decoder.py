@@ -301,37 +301,131 @@ def test_router_bias_moves_the_selection_not_the_weights():
     np.testing.assert_allclose(raw, 2.0 * picked, rtol=1e-5)
 
 
-@pytest.mark.parametrize("tokens", [24, 200])
-def test_both_forms_of_the_product_give_the_reference(small, tokens):
+HELD7 = (0, 1, 2, 4, 5, 6, 7)
+
+
+@pytest.mark.parametrize("tokens,top_k,real,held", [
+    (24, 2, None, HELD7), (200, 2, None, HELD7),
+    (24, 4, None, HELD7), (200, 4, None, HELD7),
+    (512, 4, 300, None), (512, 4, 300, HELD7), (512, 4, 300, (3, 6)),
+    (192, 4, 192, None), (130, 2, 1, (5,)), (640, 4, 0, None),
+], ids=lambda v: "all" if v is None else str(v).replace(" ", ""))
+def test_both_forms_of_the_product_give_the_reference(small, tokens, top_k,
+                                                      real, held):
     """Few tokens (every expert for every token) and many (sorted
-    groups), pad slots and an absent expert among them."""
+    groups) against each other at ANY size, and what ``expert_product``
+    picks against the reference. ``tokens`` slots of which the first
+    ``real`` are a packed stream's tokens (``None``: every fifth slot is
+    padding), routed ``top_k`` ways over 8 experts of which ``held`` are
+    here (``None``: all)."""
     d, cfg, w = small
     lw = w["layers"][4]
-    z = jax.random.normal(jax.random.key(tokens), (tokens, cfg.hidden_size))
-    valid = jnp.arange(tokens) % 5 != 0
-    sel, wts = moe.route(z, lw["gate"], lw["gate_bias"], top_k=2)
-    held = (0, 1, 2, 4, 5, 6, 7)
-    idx = jnp.asarray(held)
-    args = (z, sel, wts, lw["w1"][idx], lw["w3"][idx], lw["w2"][idx])
-    got = moe.expert_product(*args, n_experts=8, held=held, valid=valid)
-    local, _ = moe.local_index(sel, 8, held)
-    local = jnp.where(valid[:, None], local, len(held))
-    np.testing.assert_allclose(
-        moe._every_expert(z, local, wts, *args[3:]),
-        moe._sorted_groups(z, local, wts, *args[3:]), atol=1e-5)
-    share = {**lw, "w1": args[3], "w3": args[4], "w2": args[5]}
-    want = np.where(np.asarray(valid)[:, None], np.asarray(
-        ref.expert_ff(share, z, {**d, "experts_held": held})), 0.0)
+    z = jax.random.normal(jax.random.key(tokens + top_k),
+                          (tokens, cfg.hidden_size))
+    valid = jnp.arange(tokens) % 5 != 0 if real is None \
+        else jnp.arange(tokens) < real
+    sel, wts = moe.route(z, lw["gate"], lw["gate_bias"], top_k=top_k)
+    idx = jnp.arange(8) if held is None else jnp.asarray(held)
+    ws = (lw["w1"][idx], lw["w3"][idx], lw["w2"][idx])
+    got = moe.expert_product(z, sel, wts, *ws, n_experts=8, held=held,
+                             valid=valid)
+    assert got.dtype == jnp.float32 and got.shape == z.shape
+    local, n_held = moe.local_index(sel, 8, held)
+    local = jnp.where(valid[:, None], local, n_held)
+    few = moe._every_expert(z, local, wts, *ws)
+    many = moe._sorted_groups(z, local, wts, *ws)
+    np.testing.assert_allclose(few, many, atol=1e-5)
+    np.testing.assert_array_equal(
+        got, many if tokens > moe.DENSE_MAX_ROWS else few)
+    share = {**lw, "w1": ws[0], "w3": ws[1], "w2": ws[2]}
+    cfg_ref = {**d, "num_experts_per_tok": top_k}
+    if held is not None:
+        cfg_ref["experts_held"] = held
+    want = np.where(np.asarray(valid)[:, None],
+                    np.asarray(ref.expert_ff(share, z, cfg_ref)), 0.0)
     np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(many, want, atol=1e-5)
+    # a pad slot's output is exactly 0, not nearly
+    assert not np.asarray(many)[~np.asarray(valid)].any()
 
 
-def test_four_shares_add_up_to_the_uncut_layer(small):
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+def test_the_many_token_form_moves_each_assignment_row_once():
+    """Structure of the many-token form at ``T`` 512, ``k`` 4: every
+    gather promises its indices in bounds (a fill-mode gather pays a
+    select over its whole output), the only ``[T*k, H]`` arrays are the
+    gathered tokens and the third product's output, and none of them is
+    reshaped (``[T*k, H] -> [T, k, H]`` moves ``k`` into the tiled
+    dimension on the chip)."""
+    T, k, E, H, F = 512, 4, 8, 64, 32
+    f32 = jnp.float32
+    shapes = (jax.ShapeDtypeStruct((T, H), f32),
+              jax.ShapeDtypeStruct((T, k), jnp.int32),
+              jax.ShapeDtypeStruct((T, k), f32),
+              jax.ShapeDtypeStruct((E, H, F), f32),
+              jax.ShapeDtypeStruct((E, H, F), f32),
+              jax.ShapeDtypeStruct((E, F, H), f32))
+    eqns = list(_all_eqns(
+        jax.make_jaxpr(moe._sorted_groups)(*shapes).jaxpr))
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    assert len(gathers) >= 1 + k
+    for e in gathers:
+        assert e.params["mode"] in (
+            jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+            jax.lax.GatherScatterMode.CLIP), e
+    wide = [e.primitive.name for e in eqns for v in e.outvars
+            if getattr(v.aval, "shape", None) == (T * k, H)]
+    assert wide == ["gather", "ragged_dot_general"], wide
+    for e in eqns:
+        if e.primitive.name == "reshape":
+            assert e.invars[0].aval.shape != (T * k, H), e
+    assert sum(e.primitive.name == "ragged_dot_general" for e in eqns) == 3
+
+
+@pytest.mark.parametrize("garbage", [np.nan, np.inf, -np.inf])
+def test_rows_behind_the_last_group_cannot_leak(garbage):
+    """``ragged_dot`` never writes the rows behind the last group: the
+    combine must not let them through, whatever they hold (0 x NaN is
+    NaN). Token 0 is a pad slot, token 1 routed to absent experts only,
+    token 2 to one held and one absent expert."""
+    T, k, H, E = 6, 2, 8, 3
+    local = np.array([[E, E], [E, E], [1, E], [0, 2], [2, 1], [0, 1]],
+                     np.int32)
+    wts = np.full((T, k), 0.5, np.float32)
+    flat = local.reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    n_live = int((flat < E).sum())
+    y = np.arange(T * k * H, dtype=np.float32).reshape(T * k, H) + 1.0
+    y[n_live:] = garbage
+    back = np.empty_like(order)
+    back[order] = np.arange(T * k)
+    back = back.reshape(T, k)
+    got = np.asarray(moe._combine(jnp.asarray(y), jnp.asarray(back),
+                                  jnp.asarray(local < E), jnp.asarray(wts)))
+    assert np.isfinite(got).all()
+    assert not got[:2].any()
+    want = np.where((local < E)[..., None], wts[..., None] * y[back],
+                    0.0).sum(axis=1)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert (got[2] == 0.5 * y[back[2, 0]]).all()
+
+
+@pytest.mark.parametrize("tokens", [24, 384])
+def test_four_shares_add_up_to_the_uncut_layer(small, tokens):
     """The guide's share test: four chips of 2 experts each route over
     all 8 and compute their own experts' part; the parts add up to the
-    reference's whole layer (program AND reference given the shares)."""
+    reference's whole layer (program AND reference given the shares),
+    in the few-token form and in the many-token one."""
     d, cfg, w = small
     lw = w["layers"][3]
-    z = jax.random.normal(jax.random.key(6), (24, cfg.hidden_size))
+    z = jax.random.normal(jax.random.key(6), (tokens, cfg.hidden_size))
     whole = np.asarray(ref.expert_ff(lw, z, d))
     full, _ = decoder._feed_forward(lw, z, None, cfg)
     np.testing.assert_allclose(full, whole, atol=1e-5)
@@ -343,7 +437,7 @@ def test_four_shares_add_up_to_the_uncut_layer(small):
                  "w2": lw["w2"][idx]}
         part, load = decoder._feed_forward(
             share, z, None, dataclasses.replace(cfg, experts_held=held))
-        assert int(load.sum()) == 24 * cfg.num_experts_per_tok
+        assert int(load.sum()) == tokens * cfg.num_experts_per_tok
         got += np.asarray(part)
         want += np.asarray(ref.expert_ff(share, z,
                                          {**d, "experts_held": held}))
