@@ -1,67 +1,26 @@
-"""The plain reference of ``models/decoder.py``: the full forward pass of
-the block stack in straightforward ``jax.numpy``, float32, at
-``highest`` matmul precision, one sequence at a time. No cache, no
-batching, no padding, no kernels; the experts one after the other. Two
-families' equations, told apart by the keys a configuration has.
+"""The benchmark's copy of the plain reference of the decoder block stack
+(``predictionio_tpu/models/decoder_reference.py``; one test holds the
+two to identical outputs), as the ``laguna`` family's cell uses it.
+Nothing here imports the program.
 
-``cfg`` is the published ``config.json`` as a dict (plus ``head_dim``
-where the source gives none, and ``experts_held`` for a chip's share).
-``weights`` is ``{"embed": [V, H], "norm_out": [H], ["head": [V, H],]
-"layers": [layer dict, ...]}``, the tree ``decoder.init_weights`` makes;
-they are widened to float32 where they are used, so bfloat16 weights
-give the float32 result OF THOSE WEIGHTS.
+The full forward pass in straightforward ``jax.numpy``, float32, at
+``highest`` matmul precision, one sequence at a time: no cache, no
+batching, no padding, no kernels, the experts one after the other.
+``cfg`` is the configuration file's dict; ``weights`` is ``{"embed",
+"norm_out", "head", "layers": [layer dict, ...]}`` and is widened to
+float32 where it is used, so the served bfloat16 weights give the
+float32 result OF THOSE WEIGHTS. The equations (full and sliding
+attention with their own head counts and rotary, the head gate, the
+routed and the shared experts, the untied head) are written out in the
+program's copy and in PERF.md; the three conventions the published
+config leaves open are the named arguments ``head_gate``, ``qk_norm``
+and ``scores``. ``attention_op(..., query_block=512)`` takes the queries
+a block at a time, so a 4,127-token sequence's scores are ``[8, 512,
+4127]`` a key-value head and never ``[heads, T, T]``;
+``window=None`` is the ``no_window`` control.
 
-The equations (``H`` hidden size, ``n`` RMSNorm with ``norm_eps`` or
-``rms_norm_eps``):
-
-- layer ``l``: ``h = x + op_l(n_op(x))``, ``y = h + ff_l(n_ff(h))``.
-- ``conv``: ``[B, C, u] = split3(z W_in)``; ``v = B * u``; ``c_t =
-  sum_j w[:, j] v_{t-K+1+j}`` per channel (depthwise, causal, ``K =
-  conv_L_cache``, zeros before the sequence); ``out = (C * c) W_out``.
-- ``full_attention`` and ``sliding_attention``: ``q``, ``k``, ``v`` by
-  head, ``heads_l = num_attention_heads_per_layer[l]`` query heads
-  (``num_attention_heads`` where the family has one count) over
-  ``num_key_value_heads``: each key-value head serves ``heads_l /
-  kv_heads`` consecutive query heads; RMSNorm over each head of ``q``
-  and of ``k`` (own gains; ``qk_norm``); rotary (rotate-half) by the
-  layer's KIND: ``rope_parameters[kind]`` where the family gives them
-  (else ``rope_theta`` over the whole head) with ``rope_theta``,
-  ``partial_rotary_factor`` (the first ``r D`` dimensions of a head are
-  rotated, the rest pass through) and ``rope_type``: ``default``
-  ``1 / theta^(2i/d)``; ``yarn`` the blend ``w_i / (factor theta^(2i/d))
-  + (1 - w_i) / theta^(2i/d)`` with ``w`` a ramp from 0 at dimension
-  ``low`` to 1 at ``high`` (the dimensions that turn ``beta_fast`` and
-  ``beta_slow`` times over ``original_max_position_embeddings``), cos
-  and sin times ``attention_factor``. Query ``i`` sees keys ``j <= i``,
-  in a sliding layer only ``j > i - sliding_window``; softmax at scale
-  ``head_dim ** -0.5``. ``gating``: each head's output times
-  ``sigmoid(z W_g)``, one scalar a head (``head_gate``). ``out = attn
-  W_o``.
-- dense feed-forward (``mlp_layer_types[l] == "dense"``, or ``l <
-  num_dense_layers``): ``(silu(z W_1) * z W_3) W_2``.
-- expert block: ``s = sigmoid(z W_g)`` (``scores``); the top ``k`` of
-  ``s + b`` are selected; their weights are ``s`` WITHOUT ``b``;
-  ``norm_topk_prob``: ``w / (sum w + 1e-6)``; times
-  ``routed_scaling_factor`` (``moe_routed_scaling_factor``); ``out =
-  sum_e w_e (silu(z W_1e) * z W_3e) W_2e``. No capacity, no drop. A
-  shared expert (``shared_expert_intermediate_size``) is one more dense
-  feed-forward that every token takes at weight 1.
-- ``logits = n_out(x) E^T`` with the embedding ``E`` (tied), or with
-  the head's own matrix where ``tie_word_embeddings`` is false.
-
-Departures from the published implementations, all listed in the
-benchmark configurations' ``assumed``. ``lfm2_moe``: ``head_dim = hidden
-/ heads`` (the source gives null), the tied head, a conv kernel exactly
-``conv_L_cache`` wide. ``laguna``: three conventions its config does not
-spell out, each ONE named argument below so that the other reading is a
-one-line change: ``head_gate="scalar"`` (one sigmoid scalar a head;
-``"wide"``: one a channel, ``W_g [H, heads D]``), ``qk_norm=True``
-(RMSNorm over each head of ``q`` and ``k`` before rotary) and
-``scores="sigmoid"`` (with the selection bias and the normalised top
-``k``; ``"softmax"``: softmax over the experts). Both: seeded weights in
-place of trained ones. ``cellbench/reference_lfm2.py`` and
-``cellbench/reference_laguna.py`` are the benchmark's copies;
-``tests/test_decoder.py`` holds them to identical outputs.
+``served_gaps`` is what ``correct`` reads: a served answer against the
+reference's logits over its history plus the tokens served.
 """
 
 from __future__ import annotations
@@ -299,3 +258,27 @@ def forward(weights, tokens, cfg):
     for l, lw in enumerate(weights["layers"]):
         x = layer(lw, l, x, cfg)
     return head(weights, x, cfg)
+
+
+def int8_round_trip(a):
+    """Symmetric int8 with one scale per output column and back: the
+    control one precision below the configuration's."""
+    a = _f(a)
+    scale = jnp.max(jnp.abs(a), axis=-2, keepdims=True) / 127.0
+    return jnp.round(a / scale) * scale
+
+
+def served_gaps(logits, tokens, scores):
+    """One answer against the reference. ``logits [n, V]`` are the
+    reference's at the ``n`` generated positions (teacher-forced on the
+    served tokens), ``tokens`` / ``scores [n]`` what was served. Per
+    position, in units of the spread (standard deviation over the
+    vocabulary) of that position's reference logits: ``score`` = |served
+    score - reference logit of the served token| and ``rank`` =
+    reference's largest logit - reference logit of the served token
+    (greedy has to pick within rounding of the best)."""
+    at = jnp.take_along_axis(logits, jnp.asarray(tokens)[:, None],
+                             axis=1)[:, 0]
+    unit = jnp.std(logits, axis=1)
+    return (jnp.abs(jnp.asarray(scores, F32) - at) / unit,
+            (jnp.max(logits, axis=1) - at) / unit)

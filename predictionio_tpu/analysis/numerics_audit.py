@@ -417,6 +417,61 @@ def _entry_gen_decode():
     )(w, state, first)
 
 
+def _gen_laguna_small():
+    """The ``laguna`` family's programs at a small size in the served
+    dtype: a full and a sliding layer (window 8, 4 and 6 query heads
+    over 2 key-value heads, yarn over half a head), the head gate, a
+    shared expert, an untied head."""
+    import jax
+    import numpy as np
+
+    from ..models import decoder
+
+    cfg = decoder.DecoderConfig.from_dict({
+        "hidden_size": 64, "intermediate_size": 128,
+        "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+        "num_hidden_layers": 2,
+        "layer_types": ["full_attention", "sliding_attention"],
+        "mlp_layer_types": ["dense", "sparse"],
+        "num_attention_heads": 4, "num_attention_heads_per_layer": [4, 6],
+        "num_key_value_heads": 2, "head_dim": 16, "num_experts": 8,
+        "num_experts_per_tok": 2, "vocab_size": 256, "sliding_window": 8,
+        "gating": True, "tie_word_embeddings": False,
+        "rms_norm_eps": 1e-6, "moe_routed_scaling_factor": 2.5,
+        "rope_parameters": {
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+                "original_max_position_embeddings": 16, "beta_slow": 1,
+                "beta_fast": 4, "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000}}})
+    w = decoder.init_weights(jax.random.key(0), cfg)
+    tokens = np.zeros((64,), np.int32)  # 4 rows of 9, packed; 28 spare
+    lengths = np.full((4,), 9, np.int32)
+    return decoder, cfg, w, tokens, lengths
+
+
+def _entry_gen_prefill_laguna():
+    import jax
+
+    decoder, cfg, w, tokens, lengths = _gen_laguna_small()
+    return jax.make_jaxpr(
+        lambda w, t, n: decoder._gen_prefill(w, t, n, cfg=cfg, history=16,
+                                             room=4)
+    )(w, tokens, lengths)
+
+
+def _entry_gen_decode_laguna():
+    import jax
+
+    decoder, cfg, w, tokens, lengths = _gen_laguna_small()
+    first, state = decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
+                                        history=16, room=4)
+    return jax.make_jaxpr(
+        lambda w, s, f: decoder._gen_decode(w, s, f, cfg=cfg, steps=4)
+    )(w, state, first)
+
+
 #: name → (builder, one-line description); ordered — the manifest and
 #: the CI artifact list entries in this order
 ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
@@ -465,6 +520,14 @@ ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
     "gen_decode": (
         _entry_gen_decode,
         "generative greedy decode (models/decoder._gen_decode)"),
+    "gen_prefill_laguna": (
+        _entry_gen_prefill_laguna,
+        "generative prefill of the laguna family (window attention "
+        "kernel, head gate, shared expert, untied head), bf16 weights"),
+    "gen_decode_laguna": (
+        _entry_gen_decode_laguna,
+        "generative greedy decode of the laguna family (rings beside "
+        "caches)"),
 }
 
 

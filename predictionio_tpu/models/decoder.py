@@ -1,7 +1,10 @@
-"""A config-driven decoder block stack with generation: short-convolution
-and grouped-query attention layers side by side, dense and sparse-expert
-feed-forwards, driven by the keys of a published ``config.json`` (the
-``lfm2_moe`` family's names).
+"""A config-driven decoder block stack with generation, driven by the
+keys of a published ``config.json``. Two families' names are read, into
+ONE stack: ``lfm2_moe`` (short-convolution and grouped-query attention
+layers side by side, dense and sparse-expert feed-forwards, a tied head)
+and ``laguna`` (full and sliding-window attention layers side by side
+with their own head counts and rotary, a sigmoid gate a head, a shared
+expert beside the routed ones, an untied head).
 
 Two jitted entry points, whose names the benchmark's metrics match in
 the device trace: :func:`_gen_prefill` runs the histories of a batch and
@@ -11,10 +14,12 @@ passes in one program (the first token is the prefill's). The host
 sees one dispatch of two programs and syncs once, on the answer.
 
 Layer ``l``: ``h = x + op_l(n(x))``, ``y = h + ff_l(n(h))`` with RMSNorm
-``n``; ``op_l`` by ``layer_types[l]`` (``conv`` or ``full_attention``),
-``ff_l`` dense for ``l < num_dense_layers`` and the expert block
-(``ops/moe.py``) after. ``models/decoder_reference.py`` writes the
-equations out; the tests hold this module to it logit by logit.
+``n``; ``op_l`` by ``layer_types[l]`` (``conv``, ``full_attention`` or
+``sliding_attention``), ``ff_l`` dense where ``mlp_layer_types[l]`` is
+``dense`` (``lfm2_moe``: for ``l < num_dense_layers``) and the expert
+block (``ops/moe.py``, plus the shared expert where the family has one)
+elsewhere. ``models/decoder_reference.py`` writes the equations out; the
+tests hold this module to it logit by logit.
 
 Layout. The prefill takes a batch PACKED: the real tokens of its rows
 one behind the other in one stream of ``T`` slots (``tokens [T]``,
@@ -24,21 +29,28 @@ Whatever treats a token on its own (norms, projections, feed-forwards,
 the router, the expert products) runs over ``[T, H]`` and knows no rows.
 The two operators that mix positions stay inside a row: a conv tap that
 would reach before a row's first token adds zero, and attention runs
-over the rows gathered into the right-aligned ``[B, history]`` layout
-that the decode's cache has anyway (pad slots masked by ``key_valid``),
-with rotary positions counted from a row's first token. A spare slot
-joins no expert's group and no row reads it: a row's logits do not
-depend on where in the stream it lies or on what lies beside it. State
-of two kinds is carried from one program to the next: keys and values
-that grow (attention layers), right-aligned at ``history`` slots
+over the rows gathered into the right-aligned ``[B, heads, history]``
+layout that the decode's cache has anyway, blockwise
+(``ops/window_attention.py``: causal, a window for sliding layers, each
+row from its first real slot, tiles nobody sees skipped), with rotary
+positions counted from a row's first token. A spare slot joins no
+expert's group and no row reads it: a row's logits do not depend on
+where in the stream it lies or on what lies beside it. State of three
+kinds is carried from one program to the next: keys and values that
+grow (full-attention layers), right-aligned at ``history`` slots
 whatever the batch so that every row appends at the same slot and the
-decode's shapes depend on ``B`` alone, and a fixed ``conv_L_cache``-wide
-window of ``B*u`` (conv layers).
+decode's shapes depend on ``B`` alone; a RING of ``sliding_window`` keys
+and values (sliding layers: the token at position ``p`` lives in slot
+``p mod window``, the prefill leaves a row's last ``window`` tokens
+there and a decode step overwrites the oldest, so a step reads
+``window`` slots whatever the history); and a fixed
+``conv_L_cache``-wide window of ``B*u`` (conv layers).
 
 Precision. Weights in ``cfg.dtype`` (bfloat16 as served). Every matrix
 product takes operands in that dtype and accumulates in float32
 (``preferred_element_type``); the residual stream, norms, rotary,
-softmax, the gate's sigmoid and sums, and the conv window are float32.
+softmax (running maximum, sum and accumulator), the gates' sigmoids and
+sums, and the conv window are float32.
 
 Every layer holds its own arrays and the stack is unrolled. (Stacking
 the periods of the layer pattern and scanning them compiles the period
@@ -51,45 +63,71 @@ decode step moves.)
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass, fields
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import moe
-from ..ops.ring_attention import ring_attention
+from ..ops.window_attention import BLOCK as ATTENTION_BLOCK, window_attention
 
-CONV, ATTENTION = "conv", "full_attention"
-#: rows whose attention is taken in one call: a row group's scores are
-#: ``[rows, heads, history, history]`` float32 (0.5 GB at 16 x 32 x 512
-#: x 512), and the group bounds what the program holds beside the
-#: stream: 1.55 GB of temporaries at 16,384 slots where the 64 rows
-#: whole take 2.89 (compiled for the v5e). The prefill of 64 rows in
-#: 16,384 slots took 466.8 ms in groups of 8, 466.4 at 16, 469.4 at 32
-#: and 470.3 whole (my chip run, PR 28)
-ATTENTION_ROWS = 16
+CONV, ATTENTION, SLIDING = "conv", "full_attention", "sliding_attention"
+#: published names of one family that mean a field named by the other
+ALIASES = {"rms_norm_eps": "norm_eps",
+           "moe_routed_scaling_factor": "routed_scaling_factor"}
+#: keys that switch on mathematics nobody has written here: they are
+#: accepted at the value that switches it off, and raise otherwise
+UNWRITTEN = {"conv_bias": False, "attention_bias": False,
+             "moe_apply_router_weight_on_input": False,
+             "moe_router_logit_softcapping": 0}
+
+
+def _freeze(v):
+    """Dicts and lists of a ``config.json`` as tuples: a configuration
+    is a static argument of the jitted programs, so it is hashed."""
+    if isinstance(v, dict):
+        return tuple(sorted((k, _freeze(x)) for k, x in v.items()))
+    if isinstance(v, (list, tuple)):
+        return tuple(_freeze(x) for x in v)
+    return v
 
 
 @dataclass(frozen=True)
 class DecoderConfig:
     """The published keys, by their published names, plus ``dtype`` and
     ``experts_held`` (which experts' weights this chip holds; ``None``:
-    all)."""
+    all). ``lfm2_moe`` gives ``num_dense_layers``, one head count and one
+    ``rope_theta``; ``laguna`` gives ``mlp_layer_types``,
+    ``num_attention_heads_per_layer``, ``sliding_window``,
+    ``rope_parameters`` by layer kind, ``gating``,
+    ``shared_expert_intermediate_size`` and ``tie_word_embeddings``."""
 
     hidden_size: int
     intermediate_size: int
     moe_intermediate_size: int
     num_hidden_layers: int
     layer_types: Tuple[str, ...]
-    num_dense_layers: int
     num_attention_heads: int
     num_key_value_heads: int
     num_experts: int
     num_experts_per_tok: int
     vocab_size: int
+    num_dense_layers: Optional[int] = None
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
+    num_attention_heads_per_layer: Optional[Tuple[int, ...]] = None
+    sliding_window: Optional[int] = None
+    rope_parameters: Optional[tuple] = None
+    gating: bool = False
+    shared_expert_intermediate_size: int = 0
+    tie_word_embeddings: bool = True
     conv_L_cache: int = 3
     conv_bias: bool = False
+    attention_bias: bool = False
+    moe_apply_router_weight_on_input: bool = False
+    moe_router_logit_softcapping: float = 0
     norm_eps: float = 1e-5
     norm_topk_prob: bool = True
     use_expert_bias: bool = True
@@ -100,24 +138,48 @@ class DecoderConfig:
     experts_held: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        put = functools.partial(object.__setattr__, self)
+        n = self.num_hidden_layers
+        put("layer_types", tuple(self.layer_types))
         if self.experts_held is not None:
-            object.__setattr__(self, "experts_held",
-                               tuple(int(e) for e in self.experts_held))
+            put("experts_held", tuple(int(e) for e in self.experts_held))
         if self.head_dim is None:
-            object.__setattr__(
-                self, "head_dim",
-                self.hidden_size // self.num_attention_heads)
-        if len(self.layer_types) != self.num_hidden_layers:
-            raise ValueError("layer_types must name num_hidden_layers "
-                             "layers")
-        if set(self.layer_types) - {CONV, ATTENTION}:
+            put("head_dim", self.hidden_size // self.num_attention_heads)
+        if self.mlp_layer_types is None:
+            if self.num_dense_layers is None:
+                raise ValueError("one of num_dense_layers and "
+                                 "mlp_layer_types says which layers are "
+                                 "dense")
+            put("mlp_layer_types",
+                tuple("dense" if l < self.num_dense_layers else "sparse"
+                      for l in range(n)))
+        put("mlp_layer_types", tuple(self.mlp_layer_types))
+        put("num_attention_heads_per_layer", tuple(
+            self.num_attention_heads_per_layer
+            or (self.num_attention_heads,) * n))
+        put("rope_parameters", _freeze(self.rope_parameters))
+        for name in ("layer_types", "mlp_layer_types",
+                     "num_attention_heads_per_layer"):
+            if len(getattr(self, name)) != n:
+                raise ValueError(f"{name} must name num_hidden_layers "
+                                 f"layers")
+        if set(self.layer_types) - {CONV, ATTENTION, SLIDING}:
             raise ValueError(f"layer types {set(self.layer_types)}: only "
-                             f"{CONV!r} and {ATTENTION!r} are written")
-        if self.conv_bias:
-            raise ValueError("conv_bias: the family publishes none")
-        if self.num_attention_heads % self.num_key_value_heads:
+                             f"{CONV!r}, {ATTENTION!r} and {SLIDING!r} are "
+                             f"written")
+        if set(self.mlp_layer_types) - {"dense", "sparse"}:
+            raise ValueError(f"mlp layer types {set(self.mlp_layer_types)}")
+        for key, off in UNWRITTEN.items():
+            if getattr(self, key) != off:
+                raise ValueError(f"{key}={getattr(self, key)!r}: not "
+                                 f"written here (only {off!r} is)")
+        if SLIDING in self.layer_types and not self.sliding_window:
+            raise ValueError("sliding_attention layers need sliding_window")
+        if any(h % self.num_key_value_heads
+               for h in self.num_attention_heads_per_layer):
             raise ValueError("query heads must divide by key-value heads")
+        for kind in set(self.layer_types) - {CONV}:
+            self.rope(kind)  # raises on a rope_type nobody has written
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "DecoderConfig":
@@ -125,12 +187,73 @@ class DecoderConfig:
         the block (``model_type``, ``max_position_embeddings``, the
         benchmark's own notes) are left where they are."""
         names = {f.name for f in fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
+        out = {}
+        for k, v in d.items():
+            k = ALIASES.get(k, k)
+            if k in names:
+                if k in out and out[k] != v:
+                    raise ValueError(f"{k} is given twice, differently")
+                out[k] = v
+        return cls(**out)
 
     @property
     def n_held(self) -> int:
         return self.num_experts if self.experts_held is None \
             else len(self.experts_held)
+
+    def rope(self, kind: str) -> Tuple[Tuple[float, ...], float]:
+        """``(inverse frequencies, factor on cos and sin)`` of a layer
+        kind: ``rope_parameters[kind]`` where the family gives them by
+        kind, else ``rope_theta`` over the whole head."""
+        by_kind = dict(self.rope_parameters or ())
+        if kind not in by_kind:
+            return _inverse_frequencies(self.head_dim, float(self.rope_theta),
+                                        "default", ())
+        p = dict(by_kind[kind])
+        rotated = int(self.head_dim * float(p.pop("partial_rotary_factor",
+                                                  1.0)))
+        return _inverse_frequencies(
+            rotated, float(p.pop("rope_theta")),
+            p.pop("rope_type", "default"), tuple(sorted(p.items())))
+
+
+@functools.lru_cache(maxsize=None)
+def _inverse_frequencies(rotated: int, theta: float, rope_type: str,
+                         rest: tuple):
+    """Rotary inverse frequencies over the first ``rotated`` of a head's
+    dimensions. ``yarn`` (the HF rope utilities' blend): interpolated
+    frequencies ``1 / (factor theta^(2i/d))`` below the correction range,
+    the plain ones above it, a linear ramp between; the range is where a
+    dimension turns ``beta_fast`` .. ``beta_slow`` times over the
+    original context."""
+    # ptpu: allow[unguarded-domain] — rotated is a static size, never 0
+    plain = theta ** (-np.arange(0, rotated, 2, dtype=np.float64) / rotated)
+    p = dict(rest)
+    if rope_type == "default":
+        return tuple(plain), 1.0
+    if rope_type != "yarn":
+        raise ValueError(f"rope_type {rope_type!r}: only 'default' and "
+                         f"'yarn' are written")
+    factor = float(p["factor"])
+    ctx = float(p["original_max_position_embeddings"])
+
+    # ptpu: allow[unguarded-domain] — a config's positive constants, here
+    # and below (theta 5e5, factor 64, a context of thousands)
+    per_turn = rotated / (2 * math.log(theta))
+
+    def turns(n):  # the dimension that turns n times over ctx positions
+        # ptpu: allow[unguarded-domain] — see above
+        return per_turn * math.log(ctx / (n * 2 * math.pi))
+
+    low = max(math.floor(turns(float(p.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(turns(float(p.get("beta_slow", 1)))), rotated - 1)
+    high = high + 0.001 if low == high else high
+    ramp = np.clip((np.arange(rotated // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    # ptpu: allow[unguarded-domain] — see above
+    attention = p.get("attention_factor", 0.1 * math.log(factor) + 1.0)
+    # ptpu: allow[unguarded-domain] — see above
+    return tuple(plain / factor * ramp + plain * (1.0 - ramp)), attention
 
 
 # -- weights ----------------------------------------------------------------
@@ -154,17 +277,17 @@ class DecoderConfig:
 #: layers of the benchmark's cut), the gated operators amplify little,
 #: and the expert blocks carry most of what is added.
 INIT = {"embed": 0.02, "op_out": 0.08, "dense_out": 0.67,
-        "expert_out": 2.0, "gate_bias": 0.01}
+        "expert_out": 2.0, "shared_out": 1.0, "gate_bias": 0.01}
 
 
-def _layer_shapes(cfg: DecoderConfig, kind: str, dense: bool) -> dict:
-    """``{name: (shape, fan_in, factor)}`` of one layer; fan-in 0 marks
+def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
+    """``{name: (shape, fan_in, factor)}`` of layer ``l``; fan-in 0 marks
     a gain (ones); ``factor`` names the entry of :data:`INIT` a matrix
     is scaled by beside 1/sqrt(fan-in) (``None``: none)."""
     H, D = cfg.hidden_size, cfg.head_dim
-    nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    nq, nkv = cfg.num_attention_heads_per_layer[l], cfg.num_key_value_heads
     out = {"op_norm": ((H,), 0, None), "ff_norm": ((H,), 0, None)}
-    if kind == CONV:
+    if cfg.layer_types[l] == CONV:
         out.update(w_in=((H, 3 * H), H, None),
                    w_out=((H, H), H, "op_out"),
                    conv_w=((H, cfg.conv_L_cache), cfg.conv_L_cache, None))
@@ -173,7 +296,9 @@ def _layer_shapes(cfg: DecoderConfig, kind: str, dense: bool) -> dict:
                    wv=((H, nkv * D), H, None),
                    wo=((nq * D, H), nq * D, "op_out"),
                    q_norm=((D,), 0, None), k_norm=((D,), 0, None))
-    if dense:
+        if cfg.gating:
+            out["wg"] = ((H, nq), H, None)
+    if cfg.mlp_layer_types[l] == "dense":
         I = cfg.intermediate_size
         out.update(w1=((H, I), H, None), w3=((H, I), H, None),
                    w2=((I, H), I, "dense_out"))
@@ -184,13 +309,17 @@ def _layer_shapes(cfg: DecoderConfig, kind: str, dense: bool) -> dict:
                    w2=((E, F, H), F, "expert_out"))
         if cfg.use_expert_bias:
             out["gate_bias"] = ((cfg.num_experts,), 1, "gate_bias")
+        S = cfg.shared_expert_intermediate_size
+        if S:
+            out.update(s1=((H, S), H, None), s3=((H, S), H, None),
+                       s2=((S, H), S, "shared_out"))
     return out
 
 
 @functools.partial(jax.jit, static_argnames=("shapes", "dtype"))
 def _draw(key, init: dict, *, shapes: tuple, dtype: str) -> dict:
     """One layer's arrays; jitted per signature of shapes, so a stack
-    compiles three small programs and not one of every layer."""
+    compiles a few small programs and not one of every layer."""
     out = {}
     for i, (name, (shape, fan, factor)) in enumerate(shapes):
         k = jax.random.fold_in(key, i)
@@ -212,22 +341,25 @@ def init_weights(key: jax.Array, cfg: DecoderConfig,
     """Seeded weights on the device: matrices normal / sqrt(fan-in)
     times their factor of :data:`INIT` (``init`` overrides entries of
     it; they are traced, so another scale is not another program),
-    embedding normal x ``embed`` (tied to the head), gains 1, the expert
-    bias normal x ``gate_bias``. ``{"embed", "norm_out", "layers":
-    [layer, ...]}``: the tree ``decoder_reference`` reads too."""
+    embedding normal x ``embed`` (tied to the head, or the head drawn
+    like it where ``tie_word_embeddings`` is false), gains 1, the expert
+    bias normal x ``gate_bias``. ``{"embed", "norm_out", ["head",]
+    "layers": [layer, ...]}``: the tree ``decoder_reference`` reads too."""
     init = {**INIT, **(init or {})}
-    unit = {**init, "op_out": 1.0, "dense_out": 1.0, "expert_out": 1.0}
+    unit = {**init, **{k: 1.0 for k in ("op_out", "dense_out",
+                                        "expert_out", "shared_out")}}
     ke, kl = jax.random.split(key)
-    top = (("embed", ((cfg.vocab_size, cfg.hidden_size), 1, "embed")),
-           ("norm_out", ((cfg.hidden_size,), 0, None)))
+    table = ((cfg.vocab_size, cfg.hidden_size), 1, "embed")
+    top = (("embed", table), ("norm_out", ((cfg.hidden_size,), 0, None)))
+    if not cfg.tie_word_embeddings:
+        top += (("head", table),)
     return {
         **_draw(ke, init, shapes=top, dtype=cfg.dtype),
         "layers": [
             _draw(jax.random.fold_in(kl, l), init if l else unit,
-                  shapes=tuple(sorted(_layer_shapes(
-                      cfg, kind, l < cfg.num_dense_layers).items())),
+                  shapes=tuple(sorted(_layer_shapes(cfg, l).items())),
                   dtype=cfg.dtype)
-            for l, kind in enumerate(cfg.layer_types)]}
+            for l in range(cfg.num_hidden_layers)]}
 
 
 # -- pieces -----------------------------------------------------------------
@@ -243,35 +375,107 @@ def _dot(a, w):
                    preferred_element_type=jnp.float32)
 
 
-def _rotary(x, pos, theta):
-    """Rotate-half rotary over the whole head: ``x [..., heads, D]``,
-    ``pos`` shaped like ``x`` without its last two axes."""
-    D = x.shape[-1]
-    # ptpu: allow[unguarded-domain] — D is the static head size, never 0
-    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    ang = pos.astype(jnp.float32)[..., None, None] * inv
+#: float32 elements of one temporary that a stage of the prefill may
+#: hold. A wider stage goes in equal parts, one after the other: a dense
+#: feed-forward by blocks of tokens (three ``[T, intermediate]`` arrays),
+#: the queries' projection, norm and rotary by groups of heads (four
+#: ``[heads, T, D]`` arrays: 8 GB for 64 heads at 65,536 slots whole, by
+#: the v5e compiler's count). Everything ``lfm2-8b-a1b-l14``'s cell runs
+#: is under both, in one part.
+BLOCK_ELEMENTS = 1 << 27
+HEAD_GROUP_ELEMENTS = 1 << 26
+
+
+def _token_blocks(fn, width: int, z):
+    """``fn(z)`` over ``z [T, ...]`` in as few equal blocks of tokens as
+    keep ``tokens x width`` at ``BLOCK_ELEMENTS`` or under; ``fn``
+    treats every token on its own."""
+    T = z.shape[0]
+    n = moe.equal_parts(T, width, BLOCK_ELEMENTS)
+    if n == 1:
+        return fn(z)
+    out = jax.lax.map(fn, z.reshape((n, T // n) + z.shape[1:]))
+    return out.reshape((T,) + out.shape[2:])
+
+
+def _rotary(x, pos, rope):
+    """Rotate-half rotary over the first ``2 len(inv)`` dimensions of a
+    head (the rest pass through): ``x [heads, ..., D]``, ``pos`` shaped
+    like ``x`` without its first and last axes, ``rope`` from
+    :meth:`DecoderConfig.rope`."""
+    inv, factor = rope
+    R = 2 * len(inv)
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv, jnp.float32)
     ang = jnp.concatenate([ang, ang], axis=-1)
-    x1, x2 = x[..., :D // 2], x[..., D // 2:]
-    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+    xr = x[..., :R]
+    x1, x2 = xr[..., :R // 2], xr[..., R // 2:]
+    out = xr * (jnp.cos(ang) * factor) \
+        + jnp.concatenate([-x2, x1], -1) * (jnp.sin(ang) * factor)
+    return out if R == x.shape[-1] \
+        else jnp.concatenate([out, x[..., R:]], axis=-1)
 
 
-def _qkv(lw, z, pos, cfg):
-    D, dt = cfg.head_dim, jnp.dtype(cfg.dtype)
-    lead = z.shape[:-1]
-    q = _dot(z, lw["wq"]).reshape(lead + (cfg.num_attention_heads, D))
-    k = _dot(z, lw["wk"]).reshape(lead + (cfg.num_key_value_heads, D))
-    v = _dot(z, lw["wv"]).reshape(lead + (cfg.num_key_value_heads, D))
-    q = _rotary(_rms(q, lw["q_norm"], cfg.norm_eps), pos, cfg.rope_theta)
-    k = _rotary(_rms(k, lw["k_norm"], cfg.norm_eps), pos, cfg.rope_theta)
-    return q.astype(dt), k.astype(dt), v.astype(dt)
+def _by_head(z, w, heads):
+    """``z [..., H] x w [H, heads x D] -> [heads, ..., D]``: the
+    projection written by head, so that its output IS heads-first."""
+    w = w.reshape(w.shape[0], heads, -1)
+    return jnp.einsum("...h,hnd->n...d", z.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def _qkv(lw, z, pos, l, cfg):
+    """``q [heads_l, ..., D]``, ``k``, ``v [kv heads, ..., D]`` of
+    ``z [..., H]`` at positions ``pos [...]``, in the weights' dtype.
+    The queries go by groups of heads where all of them at once would
+    hold more than ``HEAD_GROUP_ELEMENTS``: the stacked groups ARE the
+    heads-first array."""
+    dt = jnp.dtype(cfg.dtype)
+    rope = cfg.rope(cfg.layer_types[l])
+    nq, D = cfg.num_attention_heads_per_layer[l], cfg.head_dim
+
+    def project(z, w, norm=None):
+        a = _by_head(z, w, w.shape[1] // D)
+        if norm is not None:
+            a = _rotary(_rms(a, norm, cfg.norm_eps), pos, rope)
+        return a.astype(dt)
+
+    n = moe.equal_parts(nq, z[..., 0].size * D, HEAD_GROUP_ELEMENTS)
+    if n == 1:
+        q = project(z, lw["wq"], lw["q_norm"])
+    else:
+        zb = z.astype(lw["wq"].dtype)  # read once a group: half the bytes
+        q = jax.lax.map(
+            lambda w: project(zb, w, lw["q_norm"]),
+            lw["wq"].reshape(-1, n, nq // n * D).swapaxes(0, 1))
+        q = q.reshape((nq,) + q.shape[2:])
+    return q, project(z, lw["wk"], lw["k_norm"]), project(z, lw["wv"])
+
+
+def _attention_out(lw, o, z):
+    """``o [heads, ..., D]`` through the head gate (``gating``: each
+    head times ``sigmoid(z W_g)``, one scalar a head) and ``W_o``."""
+    wo = lw["wo"].reshape(o.shape[0], o.shape[-1], -1)
+    if "wg" in lw:  # a float32 product, rounded once for the next one
+        gate = jax.nn.sigmoid(_dot(z, lw["wg"]))
+        o = o.astype(jnp.float32) * jnp.moveaxis(gate, -1, 0)[..., None]
+    return jnp.einsum("n...d,ndh->...h", o.astype(wo.dtype), wo,
+                      preferred_element_type=jnp.float32)
+
+
+def _swiglu(z, w1, w3, w2):
+    return _dot(jax.nn.silu(_dot(z, w1)) * _dot(z, w3), w2)
 
 
 def _feed_forward(lw, z, valid, cfg):
     """Dense or expert feed-forward of ``z [T, H]``; ``(out, load)``
-    with ``load [E]`` (``None`` for a dense layer)."""
+    with ``load [E]`` (``None`` for a dense layer). The shared expert,
+    where the family has one, is a dense feed-forward that every token
+    takes at weight 1: added once, here, whatever share of the routed
+    experts this chip holds."""
     if "gate" not in lw:
-        h = jax.nn.silu(_dot(z, lw["w1"])) * _dot(z, lw["w3"])
-        return _dot(h, lw["w2"]), None
+        return _token_blocks(
+            lambda z: _swiglu(z, lw["w1"], lw["w3"], lw["w2"]),
+            cfg.intermediate_size, z), None
     sel, wts = moe.route(z, lw["gate"], lw.get("gate_bias"),
                          top_k=cfg.num_experts_per_tok,
                          norm_topk=cfg.norm_topk_prob,
@@ -280,6 +484,8 @@ def _feed_forward(lw, z, valid, cfg):
         z.astype(jnp.dtype(cfg.dtype)), sel, wts, lw["w1"], lw["w3"],
         lw["w2"], n_experts=cfg.num_experts, held=cfg.experts_held,
         valid=valid)
+    if "s1" in lw:
+        out = out + _swiglu(z, lw["s1"], lw["s3"], lw["s2"])
     return out, moe.expert_load(sel, cfg.num_experts, valid)
 
 
@@ -307,45 +513,57 @@ def _conv_prefill(lw, z, valid, pos, last, cfg):
     return _dot(c * y, lw["w_out"]), {"win": win}
 
 
-def _attention_prefill(lw, z, pos, rows, room, cfg):
+def _attention_prefill(lw, z, pos, rows, room, l, cfg):
     """``z [T, H]`` packed. ``q``, ``k``, ``v`` are gathered into the
-    right-aligned ``[B, history]`` layout (``rows``: where each of its
-    slots lies in the stream, which of them are real, and where each
-    slot of the stream lies in it), attention is taken a row group at a
-    time, and the output is gathered back into the stream."""
-    src, real, dst = rows
+    right-aligned ``[B, heads, history]`` layout (``rows``: where each
+    of its slots lies in the stream, which of them are real, where each
+    slot of the stream lies in it, and each row's first real slot),
+    attention is taken blockwise from each row's first real slot, and
+    the output is gathered back into the stream. The state: a full
+    layer's keys and values in that layout with ``room`` behind them; a
+    sliding layer's ring, slot ``s`` holding the row's last token whose
+    position is ``s`` modulo the window (zeros where it has none)."""
+    src, real, dst, lead, last = rows
     B, L = src.shape
+    sliding = cfg.layer_types[l] == SLIDING
 
-    def to_rows(a):
-        # ptpu: allow[materialized-gather] — the layout the cache keeps:
-        # [B, history, heads, D] in the weights' dtype, zeros where a
-        # row has no token
-        flat = jnp.take(a.reshape(a.shape[0], -1), src.reshape(-1), axis=0)
-        return jnp.where(real.reshape(-1, 1), flat, 0).reshape(
-            (B, L) + a.shape[1:])
+    def to_rows(a, at, ok=None):
+        # ptpu: allow[materialized-gather] — [heads, B, slots, D] in the
+        # weights' dtype from the stream's [heads, T, D]. Zeros where a
+        # row has no token (``ok``); the queries' pad slots hold token
+        # 0's, which no real slot reads
+        out = jnp.take(a, at.reshape(-1), axis=1)
+        if ok is not None:
+            out = jnp.where(ok.reshape(1, -1, 1), out, 0)
+        return out.reshape(a.shape[:1] + at.shape + a.shape[2:])
 
-    q, k, v = (to_rows(a) for a in _qkv(lw, z, pos, cfg))
-    g = cfg.num_attention_heads // cfg.num_key_value_heads
-    n = max(r for r in range(1, min(B, ATTENTION_ROWS) + 1) if B % r == 0)
+    def state(k, v):  # batch first, as the decode reads it
+        return {"k": k.swapaxes(0, 1), "v": v.swapaxes(0, 1)}
 
-    def group(a):
-        qg, kg, vg, ok = a
-        return ring_attention(qg, jnp.repeat(kg, g, axis=2),
-                              jnp.repeat(vg, g, axis=2), mesh=None,
-                              causal=True, scale=cfg.head_dim ** -0.5,
-                              key_valid=ok)
-
-    o = jax.lax.map(group, jax.tree_util.tree_map(
-        lambda a: a.reshape((B // n, n) + a.shape[1:]), (q, k, v, real)))
-    # ptpu: allow[materialized-gather] — back into the stream: [T, H]
-    o = jnp.take(o.reshape(B * L, -1), dst, axis=0)
-    grow = ((0, 0), (0, room), (0, 0), (0, 0))
-    return _dot(o, lw["wo"]), {"k": jnp.pad(k, grow), "v": jnp.pad(v, grow)}
+    q, k, v = _qkv(lw, z, pos, l, cfg)
+    kr, vr = to_rows(k, src, real), to_rows(v, src, real)
+    o = window_attention(
+        to_rows(q, src), kr, vr, lead, scale=cfg.head_dim ** -0.5,
+        window=cfg.sliding_window if sliding else None,
+        block=ATTENTION_BLOCK)
+    # ptpu: allow[materialized-gather] — back into the stream: [heads, T, D]
+    o = jnp.take(o.reshape(o.shape[0], B * L, -1), dst, axis=1)
+    out = _attention_out(lw, o, z)
+    if not sliding:
+        grow = ((0, 0), (0, 0), (0, room), (0, 0))
+        return out, state(jnp.pad(kr, grow), jnp.pad(vr, grow))
+    W = cfg.sliding_window
+    at = jnp.arange(W, dtype=jnp.int32)[None, :]
+    back = (pos[last][:, None] - at) % W  # tokens back from a row's last
+    ring, held = last[:, None] - back, back <= pos[last][:, None]
+    return out, state(to_rows(k, jnp.where(held, ring, 0), held),
+                      to_rows(v, jnp.where(held, ring, 0), held))
 
 
 def _head(w, x, cfg):
     z = _rms(x, w["norm_out"], cfg.norm_eps)
-    return jnp.dot(z.astype(w["embed"].dtype), w["embed"].T,
+    table = w["embed"] if cfg.tie_word_embeddings else w["head"]
+    return jnp.dot(z.astype(table.dtype), table.T,
                    preferred_element_type=jnp.float32)
 
 
@@ -371,17 +589,18 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
     lead = history - lengths
     real = at >= lead[:, None]
     rows = (jnp.where(real, (first - lead)[:, None] + at, 0), real,
-            jnp.where(valid, row * history + lead[row] + pos, 0))
+            jnp.where(valid, row * history + lead[row] + pos, 0), lead,
+            ends - 1)
     # ptpu: allow[materialized-gather] — the embedding lookup itself: the
     # [T, H] it makes is the residual stream
     x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
     states, loads = [], []
-    for lw, kind in zip(w["layers"], cfg.layer_types):
+    for l, (lw, kind) in enumerate(zip(w["layers"], cfg.layer_types)):
         z = _rms(x, lw["op_norm"], cfg.norm_eps)
         if kind == CONV:
             o, st = _conv_prefill(lw, z, valid, pos, ends - 1, cfg)
         else:
-            o, st = _attention_prefill(lw, z, pos, rows, room, cfg)
+            o, st = _attention_prefill(lw, z, pos, rows, room, l, cfg)
         h = x + o
         f, load = _feed_forward(lw, _rms(h, lw["ff_norm"], cfg.norm_eps),
                                 valid, cfg)
@@ -407,28 +626,43 @@ def _conv_step(lw, z, st, cfg):
     return _dot(c * y, lw["w_out"]), {"win": win}
 
 
-def _attention_step(lw, z, st, valid, pos, at, cfg):
-    """One query a row against its cache; the new key and value land in
-    slot ``at``, which ``valid`` already counts."""
-    q, k, v = _qkv(lw, z, pos, cfg)
-    ks = jax.lax.dynamic_update_slice_in_dim(st["k"], k[:, None], at, 1)
-    vs = jax.lax.dynamic_update_slice_in_dim(st["v"], v[:, None], at, 1)
+def _attention_step(lw, z, st, valid, pos, at, l, cfg):
+    """One query a row against its state. A full layer's new key and
+    value land in slot ``at`` of every row, which ``valid`` already
+    counts; a sliding layer's in slot ``pos mod window`` of its ring,
+    over the oldest, and the ring's slots up to ``pos`` are the real
+    ones until it has wrapped."""
+    q, k, v = (a.swapaxes(0, 1) for a in _qkv(lw, z, pos, l, cfg))
+    if cfg.layer_types[l] == SLIDING:
+        W = st["k"].shape[2]
+
+        def put(ring, new):
+            return jax.vmap(
+                lambda r, n, s: jax.lax.dynamic_update_slice_in_dim(
+                    r, n[:, None], s, 1))(ring, new, pos % W)
+
+        ks, vs = put(st["k"], k), put(st["v"], v)
+        valid = jnp.arange(W, dtype=jnp.int32)[None, :] <= pos[:, None]
+    else:
+        ks = jax.lax.dynamic_update_slice_in_dim(st["k"], k[:, :, None], at, 2)
+        vs = jax.lax.dynamic_update_slice_in_dim(st["v"], v[:, :, None], at, 2)
     B, nkv, D = k.shape
-    s = jnp.einsum("bgrd,bsgd->bgrs", q.reshape(B, nkv, -1, D), ks,
+    s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, nkv, -1, D), ks,
                    preferred_element_type=jnp.float32) * D ** -0.5
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
-    o = jnp.einsum("bgrs,bsgd->bgrd",
+    o = jnp.einsum("bgrs,bgsd->bgrd",
                    jax.nn.softmax(s, axis=-1).astype(vs.dtype), vs,
                    preferred_element_type=jnp.float32)
-    return _dot(o.reshape(B, -1), lw["wo"]), {"k": ks, "v": vs}
+    return _attention_out(lw, o.reshape(B, -1, D).swapaxes(0, 1), z), \
+        {"k": ks, "v": vs}
 
 
-def _layer_step(lw, kind, x, st, valid, pos, at, cfg):
+def _layer_step(lw, l, x, st, valid, pos, at, cfg):
     z = _rms(x, lw["op_norm"], cfg.norm_eps)
-    if kind == CONV:
+    if cfg.layer_types[l] == CONV:
         o, st = _conv_step(lw, z, st, cfg)
     else:
-        o, st = _attention_step(lw, z, st, valid, pos, at, cfg)
+        o, st = _attention_step(lw, z, st, valid, pos, at, l, cfg)
     h = x + o
     f, load = _feed_forward(lw, _rms(h, lw["ff_norm"], cfg.norm_eps),
                             None, cfg)
@@ -443,8 +677,8 @@ def _decode_step(w, state, tok, cfg):
         state["valid"], jnp.ones((tok.shape[0], 1), bool), at, 1)
     x = jnp.take(w["embed"], tok, axis=0).astype(jnp.float32)
     states, loads = [], []
-    for lw, kind, st in zip(w["layers"], cfg.layer_types, state["layers"]):
-        x, st, load = _layer_step(lw, kind, x, st, valid, pos, at, cfg)
+    for l, (lw, st) in enumerate(zip(w["layers"], state["layers"])):
+        x, st, load = _layer_step(lw, l, x, st, valid, pos, at, cfg)
         states.append(st)
         if load is not None:
             loads.append(load)

@@ -18,7 +18,10 @@ tokens. The third product's rows ``y [T*k, H]`` float32 stay where the
 sort put them: the combine walks the TOKENS, gathers each token's ``k``
 rows through the inverse permutation and sums them under the routing
 weights and the mask as routed (``[T, k]``), so ``y`` is read once and
-no other array of its size is written (:func:`_combine`). FEW tokens
+no other array of its size is written (:func:`_combine`). A stream of
+more than ``BLOCK_ASSIGNMENTS`` assignments goes through all of that in
+equal blocks of tokens, one after the other (``lax.map``), so the
+temporaries are a block's whatever the stream. FEW tokens
 (a decode step, ``DENSE_MAX_ROWS`` or under): every
 held expert is computed for every token in one batched product and the
 routing weights, zero where an expert was not selected, do the
@@ -48,6 +51,20 @@ NORM_EPS = 1e-6  # the family's constant in the top-k normalisation
 #: tokens the weights' streaming hides the products nobody selected.
 #: Half of that, for an MXU that is not at its peak at these heights.
 DENSE_MAX_ROWS = 128
+#: assignments (tokens x experts a token) sorted and multiplied at once.
+#: Per assignment the many-token form holds a gathered row and an output
+#: row of ``H`` (bfloat16 and float32) and two of ``F``: 1.6 GB at
+#: ``H`` 2048, ``F`` 512 and this many, where a 65,536-slot stream at 8
+#: experts a token would hold 6.4 GB whole. 16,384 tokens at 8, 32,768
+#: at 4: every stream the ``lfm2_moe`` cell runs is one block.
+BLOCK_ASSIGNMENTS = 131072
+
+
+def equal_parts(total: int, size: int, limit: int) -> int:
+    """The fewest equal parts of ``total`` with ``part x size`` at
+    ``limit`` or under (1 where the whole is)."""
+    return next(n for n in range(-(-total * size // limit), total + 1)
+                if total % n == 0)
 
 
 def route(z: jax.Array, w_gate: jax.Array, bias: Optional[jax.Array], *,
@@ -88,8 +105,10 @@ def expert_load(sel: jax.Array, n_experts: int,
     count for none): what the counters read."""
     flat = sel if valid is None else jnp.where(valid[:, None], sel,
                                                n_experts)
-    return jnp.bincount(flat.reshape(-1), length=n_experts + 1
-                        )[:n_experts].astype(jnp.int32)
+    # compared and summed, not scattered: a scatter-add of T x k ones
+    # took 1.1 ms a layer at 262,144 assignments (my chip run, PR 32)
+    return jnp.sum(flat.reshape(-1, 1) == jnp.arange(
+        n_experts, dtype=flat.dtype), axis=0, dtype=jnp.int32)
 
 
 def expert_product(x: jax.Array, sel: jax.Array, wts: jax.Array,
@@ -127,16 +146,34 @@ def _every_expert(x, local, wts, w1, w3, w2):
 
 
 def _sorted_groups(x, local, wts, w1, w3, w2):
-    """Many tokens: assignments sorted by expert, one grouped product
-    per weight; index ``E_held`` sorts behind the last group. Every
-    index below is in range by construction and the gathers say so: a
-    fill-mode gather pays a select over its whole output."""
+    """Many tokens, in as few equal blocks of tokens as keep a block's
+    assignments at ``BLOCK_ASSIGNMENTS`` or under; a token's experts are
+    all in its block, so the blocks' outputs are the stream's, one
+    behind the other."""
+    T, k = local.shape
+    n = equal_parts(T, k, BLOCK_ASSIGNMENTS)
+    if n == 1:
+        return _sorted_block(x, local, wts, w1, w3, w2)
+    out = jax.lax.map(
+        lambda a: _sorted_block(*a, w1, w3, w2),
+        (x.reshape(n, T // n, -1), local.reshape(n, T // n, k),
+         wts.reshape(n, T // n, k)))
+    return out.reshape(T, -1)
+
+
+def _sorted_block(x, local, wts, w1, w3, w2):
+    """Assignments sorted by expert, one grouped product per weight;
+    index ``E_held`` sorts behind the last group. Every index below is
+    in range by construction and the gathers say so: a fill-mode gather
+    pays a select over its whole output."""
     T, k = local.shape
     n_held = w1.shape[0]
-    flat = local.reshape(-1)
-    order = jnp.argsort(flat, stable=True)
-    sizes = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(
-        jnp.int32)
+    keys, order = jax.lax.sort_key_val(
+        local.reshape(-1), jnp.arange(T * k, dtype=jnp.int32))
+    # group sizes off the sorted keys' boundaries (no scatter-add)
+    ends = jnp.sum(keys[None, :] <= jnp.arange(
+        n_held, dtype=keys.dtype)[:, None], axis=1, dtype=jnp.int32)
+    sizes = jnp.diff(ends, prepend=0)
     xs = x.at[order // k].get(mode="promise_in_bounds")
     f32 = jnp.float32
     h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes,

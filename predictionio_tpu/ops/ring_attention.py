@@ -119,8 +119,12 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     positions — padding slots in right-aligned sequence-model windows —
     on BOTH paths (the mask rotates around the ring with its KV block).
     Returns attention output with the same sharding. With ``mesh=None``
-    this is plain (single-device) blockwise attention — the same
-    contract, ring of length 1.
+    this is plain single-device DENSE softmax attention (the whole
+    ``[batch, heads, seq, seq]`` float32 score matrix; the same
+    contract, what the sequence model runs at its window of a few
+    hundred). The blockwise single-device kernel, with a causal and a
+    window mask, is ``ops/window_attention.py`` (the generative
+    prefill's).
     """
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5

@@ -45,7 +45,6 @@ from ..controller import (
 from ..utils.tracing import annotate
 from .sequential import ItemScore, PredictedResult
 
-EXPERTS_TOUCHED_BOUNDS = (1, 2, 4, 8, 12, 16, 20, 24, 28, 30, 31, 32)
 IMBALANCE_BOUNDS = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0)
 
 
@@ -123,11 +122,34 @@ class GenerativeDataSource(DataSource):
         return TrainingData()
 
 
+def experts_touched_bounds(num_experts: int) -> Tuple[int, ...]:
+    """Bucket bounds of ``pio_moe_experts_touched`` that follow the
+    model: powers of two up to an eighth of the experts, eighths from
+    there, and the last few one by one (32 experts: 1, 2, 4, 8, 12, ..,
+    28, 30, 31, 32)."""
+    E = max(int(num_experts), 1)
+    low = [b for b in (1, 2, 4, 8, 16, 32, 64) if b < E / 8]
+    return tuple(sorted({*low, *(E * i // 8 for i in range(1, 9)),
+                         E - E // 16, E - 1, E} - {0}))
+
+
 def _bucket(buckets: Sequence[int], n: int) -> int:
     for b in buckets:
         if n <= b:
             return b
     return buckets[-1]
+
+
+def _state_bytes(cfg, state) -> Dict[str, int]:
+    """Bytes of a batch's per-sequence state by kind, from the shapes
+    (no sync): ``full``, ``window``, ``conv``."""
+    from ..models.decoder import ATTENTION, CONV
+
+    out: Dict[str, int] = {}
+    for kind, st in zip(cfg.layer_types, state["layers"]):
+        name = {ATTENTION: "full", CONV: "conv"}.get(kind, "window")
+        out[name] = out.get(name, 0) + sum(a.nbytes for a in st.values())
+    return out
 
 
 class GenerativeAlgorithm(Algorithm):
@@ -138,6 +160,7 @@ class GenerativeAlgorithm(Algorithm):
     def __init__(self, params: GenerativeParams = GenerativeParams()):
         self.params = params
         self._tokens = self._touched = self._imbalance = None
+        self._state_bytes = None
 
     def train(self, ctx: Context, td: TrainingData) -> GenerativeModel:
         return GenerativeModel(config=dict(self.params.model),
@@ -163,12 +186,19 @@ class GenerativeAlgorithm(Algorithm):
             "pio_moe_experts_touched",
             "Distinct experts a decode step read, mean over the expert "
             "layers and the steps of a batch",
-            bounds=EXPERTS_TOUCHED_BOUNDS)
+            bounds=experts_touched_bounds(
+                self.params.model.get("num_experts", 32)))
         self._imbalance = registry.histogram(
             "pio_moe_load_imbalance",
             "Largest over mean tokens per expert of an expert layer in "
             "a batch's prefill",
             bounds=IMBALANCE_BOUNDS)
+        self._state_bytes = registry.gauge(
+            "pio_gen_state_bytes",
+            "Bytes of per-sequence state the last batch carried from its "
+            "prefill to its decode, by kind: full (keys and values that "
+            "grow), window (rings of sliding_window), conv (windows of "
+            "conv_L_cache)")
 
     def _history(self, model: GenerativeModel, query: Query) -> List[int]:
         vocab = int(model.config["vocab_size"])
@@ -203,6 +233,9 @@ class GenerativeAlgorithm(Algorithm):
             first, state = _gen_prefill(
                 model.weights, tokens, lengths, cfg=cfg,
                 history=p.history_buckets[-1], room=p.max_new)
+        if self._state_bytes is not None:  # once a batch, from shapes
+            for kind, nbytes in _state_bytes(cfg, state).items():
+                self._state_bytes.labels(kind=kind).set(nbytes)
         with annotate("pio:gen_decode", rows=B, steps=p.max_new):
             toks, scores, load, _ = _gen_decode(
                 model.weights, state, first, cfg=cfg, steps=p.max_new)

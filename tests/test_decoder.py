@@ -31,14 +31,50 @@ SMALL = {
     "rope_theta": 1000000, "head_dim": 16, "dtype": "float32",
     "model_type": "lfm2_moe", "max_position_embeddings": 128000,
 }
+#: the ``laguna`` family at a small size: window 8, two query-head
+#: counts over 2 key-value heads, 8 experts of which 2 a token and a
+#: shared one, per-kind rotary (yarn over half a head in the full
+#: layers, with a correction range that falls inside the 4 rotated
+#: frequencies), the head gate, an untied head
+LAGUNA = {
+    "model_type": "laguna", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 5,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "max_position_embeddings": 4096, "attention_bias": False,
+    "rms_norm_eps": 1e-6, "num_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+    "tie_word_embeddings": False, "gating": True, "sliding_window": 8,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "original_max_position_embeddings": 16, "beta_slow": 1,
+            "beta_fast": 4, "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1},
+        "original_max_position_embeddings": 16},
+    "layer_types": ["full_attention", "sliding_attention",
+                    "sliding_attention", "sliding_attention",
+                    "full_attention"],
+    "moe_apply_router_weight_on_input": False,
+    "partial_rotary_factor": 0.5,
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"],
+    "moe_routed_scaling_factor": 2.5,
+    "num_attention_heads_per_layer": [4, 6, 6, 6, 4],
+    "norm_topk_prob": True, "use_expert_bias": True, "dtype": "float32",
+}
+#: attention outputs and expert blocks both a visible share of the
+#: stream, as the benchmark's configuration sets them
+LAGUNA_INIT = {"op_out": 4.0, "expert_out": 1.0}
+LAGUNA_HISTORY = 40  # five tiles of 8: see the ``lag`` fixture
 TOL32 = 2e-4
 STEPS = 9  # the prefill's token and 8 decode steps
 
 
-def _setup(dtype="float32", seed=0, **over):
-    d = {**SMALL, "dtype": dtype, **over}
+def _setup(dtype="float32", seed=0, base=SMALL, init=None, **over):
+    d = {**base, "dtype": dtype, **over}
     cfg = decoder.DecoderConfig.from_dict(d)
-    w = decoder.init_weights(jax.random.key(seed), cfg)
+    w = decoder.init_weights(jax.random.key(seed), cfg, init)
     return d, cfg, w
 
 
@@ -64,10 +100,14 @@ def _pack(hists, rows, slots, seed=0):
     return jnp.asarray(tokens), jnp.asarray(lengths)
 
 
+def _history(cfg):
+    return LAGUNA_HISTORY if cfg.sliding_window else HISTORY
+
+
 def _prefill(w, cfg, hists, slots, rows=None, steps=STEPS):
     tokens, lengths = _pack(hists, rows or len(hists), slots)
     return decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
-                                history=HISTORY, room=steps)
+                                history=_history(cfg), room=steps)
 
 
 def _generate(w, cfg, hists, slots, rows=None, steps=STEPS):
@@ -104,6 +144,25 @@ def small():
 
 
 @pytest.fixture(scope="module")
+def lag():
+    """The ``laguna`` family at a small size, its prefill's attention
+    in tiles of 8 (the window): histories to 40 cross five of them."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(decoder, "ATTENTION_BLOCK", 8)
+        yield _setup(base=LAGUNA, init=LAGUNA_INIT)
+
+
+LONG = 21  # the prefill's token and 20 steps: a ring of 8 wraps twice
+
+
+@pytest.fixture(scope="module")
+def lag_served(lag):
+    d, cfg, w = lag
+    hists = _hists(np.random.default_rng(1), [5, 40, 23, 1, 17, 33])
+    return hists, _generate(w, cfg, hists, 240, steps=LONG)
+
+
+@pytest.fixture(scope="module")
 def served(small):
     d, cfg, w = small
     # 192 slots: the prefill's experts go through the sorted groups,
@@ -132,6 +191,17 @@ def test_prefill_and_cached_decode_match_the_full_forward(small, served):
     _check_against_reference(d, w, hists, first, toks, scores)
 
 
+def test_the_ring_wraps_twice_and_still_matches_the_full_forward(
+        lag, lag_served):
+    """Histories to five windows, then 20 cached steps (a ring of 8
+    overwritten two and a half times) against ONE uncached forward of
+    the reference over history + served tokens, logit by logit."""
+    d, cfg, w = lag
+    hists, (first, toks, scores, _) = lag_served
+    assert toks.shape[1] == LONG
+    _check_against_reference(d, w, hists, first, toks, scores)
+
+
 #: (history lengths, rows, slots): the stream's sizes are the engine's
 #: ladder for 4 rows over history buckets (8, 16, 32)
 RAGGED = {
@@ -147,15 +217,20 @@ RAGGED = {
     "a_sum_on_the_top_rung": ([32, 31, 30, 29], 4, 128),
     "the_sorted_product_with_a_spare_tail": ([32, 1, 2, 32, 17, 5, 3, 9],
                                              8, 256),
+    # the ``laguna`` family (history 40, window 8, tiles of 8)
+    "laguna_rows_inside_one_window": ([3, 8, 1, 7], 4, 32),
+    "laguna_rows_of_several_windows": ([40, 9, 25, 16], 4, 160),
+    "laguna_a_batch_under_its_row_bucket": ([33, 12], 4, 64),
+    "laguna_every_row_at_the_top_bucket": ([40, 40, 40, 40], 4, 160),
 }
 
 
 @pytest.mark.parametrize("case", sorted(RAGGED))
-def test_a_packed_ragged_batch_matches_the_reference(small, case):
+def test_a_packed_ragged_batch_matches_the_reference(small, lag, case):
     """Prefill's last-token logits and the decode that follows, row by
     row, whatever the rows' lengths, the spare slots' ids and the
     stream's size."""
-    d, cfg, w = small
+    d, cfg, w = lag if case.startswith("laguna") else small
     lengths, rows, slots = RAGGED[case]
     hists = _hists(np.random.default_rng(len(case)), lengths)
     first, toks, scores, (pre, _) = _generate(w, cfg, hists, slots, rows,
@@ -167,17 +242,18 @@ def test_a_packed_ragged_batch_matches_the_reference(small, case):
             * (sum(lengths) + rows - len(lengths))).all()
 
 
-@pytest.mark.parametrize("slots,beside", [(32, []), (96, [30, 32]),
-                                          (128, [32, 20, 3]),
-                                          (256, [3, 32])])
-def test_the_stream_and_neighbours_do_not_move_a_row(small, served, slots,
-                                                     beside):
+@pytest.mark.parametrize("slots,beside,family", [
+    (32, [], "lfm2"), (96, [30, 32], "lfm2"), (128, [32, 20, 3], "lfm2"),
+    (256, [3, 32], "lfm2"), (40, [], "laguna"), (160, [40, 9], "laguna")])
+def test_the_stream_and_neighbours_do_not_move_a_row(
+        small, served, lag, lag_served, slots, beside, family):
     """The same history alone, and behind other rows in streams of 2x,
     4x and 8x the slots, gives the same logits and the same tokens."""
-    d, cfg, w = small
-    hists, (first, toks, scores, _) = served
+    d, cfg, w = lag if family == "laguna" else small
+    hists, (first, toks, scores, _) = \
+        lag_served if family == "laguna" else served
     mix = _hists(np.random.default_rng(2), beside) + [hists[1]]
-    f2, t2, s2, _ = _generate(w, cfg, mix, slots)
+    f2, t2, s2, _ = _generate(w, cfg, mix, slots, steps=toks.shape[1])
     np.testing.assert_allclose(f2[-1], first[1], atol=TOL32)
     np.testing.assert_array_equal(t2[-1], toks[1])
     np.testing.assert_allclose(s2[-1], scores[1], atol=TOL32)
@@ -205,15 +281,18 @@ def test_permuting_the_rows_permutes_logits_and_state(small, served, order):
                                   np.asarray(st0["load"]))
 
 
-@pytest.mark.parametrize("changed", [0, 3, 5])
-def test_a_neighbours_tokens_move_no_other_row(small, served, changed):
-    d, cfg, w = small
-    hists, _ = served
+@pytest.mark.parametrize("changed,family", [
+    (0, "lfm2"), (3, "lfm2"), (5, "lfm2"), (1, "laguna")])
+def test_a_neighbours_tokens_move_no_other_row(small, served, lag,
+                                               lag_served, changed, family):
+    d, cfg, w = lag if family == "laguna" else small
+    hists, _ = lag_served if family == "laguna" else served
     f0, st0 = _prefill(w, cfg, hists, 192)
     other = [list(h) for h in hists]
     other[changed] = [(t + 1) % SMALL["vocab_size"]
                       for t in other[changed]]
     f1, st1 = _prefill(w, cfg, other, 192)
+    assert sum(len(h) for h in hists) <= 192
     for r in range(len(hists)):
         if r == changed:
             assert np.abs(np.asarray(f1[r]) - np.asarray(f0[r])).max() \
@@ -417,18 +496,29 @@ def test_rows_behind_the_last_group_cannot_leak(garbage):
     assert (got[2] == 0.5 * y[back[2, 0]]).all()
 
 
-@pytest.mark.parametrize("tokens", [24, 384])
-def test_four_shares_add_up_to_the_uncut_layer(small, tokens):
+@pytest.mark.parametrize("tokens,family", [
+    (24, "lfm2"), (384, "lfm2"), (24, "laguna"), (384, "laguna")])
+def test_four_shares_add_up_to_the_uncut_layer(small, lag, tokens, family):
     """The guide's share test: four chips of 2 experts each route over
     all 8 and compute their own experts' part; the parts add up to the
     reference's whole layer (program AND reference given the shares),
-    in the few-token form and in the many-token one."""
-    d, cfg, w = small
+    in the few-token form and in the many-token one. What every chip
+    computes alike, the ``laguna`` family's shared expert, is counted
+    ONCE: each share's output holds it whole."""
+    d, cfg, w = lag if family == "laguna" else small
     lw = w["layers"][3]
     z = jax.random.normal(jax.random.key(6), (tokens, cfg.hidden_size))
     whole = np.asarray(ref.expert_ff(lw, z, d))
+    alike = np.asarray(ref.dense_ff(lw, z, ("s1", "s3", "s2"))) \
+        if family == "laguna" else 0.0
+    if family == "laguna":
+        assert np.abs(alike).mean() > 0.1 * np.abs(whole).mean()
     full, _ = decoder._feed_forward(lw, z, None, cfg)
-    np.testing.assert_allclose(full, whole, atol=1e-5)
+    # float32 both sides, outputs up to 7: the largest difference read is
+    # 1.9e-6 (lfm2) and 2.9e-6 (laguna: routed scale 2.5, shared expert
+    # added), at 384 tokens; the same 1e-5 holds both families
+    np.testing.assert_allclose(full, whole + alike, atol=1e-5)
+    # the reference's layer, residual and norm left out: z IS n_ff(h)
     got = np.zeros_like(whole)
     want = np.zeros_like(whole)
     for held in ((0, 1), (2, 3), (4, 5), (6, 7)):
@@ -438,10 +528,10 @@ def test_four_shares_add_up_to_the_uncut_layer(small, tokens):
         part, load = decoder._feed_forward(
             share, z, None, dataclasses.replace(cfg, experts_held=held))
         assert int(load.sum()) == tokens * cfg.num_experts_per_tok
-        got += np.asarray(part)
+        got += np.asarray(part) - alike
         want += np.asarray(ref.expert_ff(share, z,
                                          {**d, "experts_held": held}))
-    np.testing.assert_allclose(got, whole, atol=1e-5)
+    np.testing.assert_allclose(got + alike, whole + alike, atol=1e-5)
     np.testing.assert_allclose(want, whole, atol=1e-5)
 
 
@@ -491,17 +581,22 @@ def test_bfloat16_stays_inside_the_tolerance_and_int8_does_not(seed):
     assert np.median(np.concatenate(gaps)) <= 0.05
 
 
-def test_the_benchmarks_copy_of_the_reference_is_the_same(small):
-    """``cellbench/reference_lfm2.py`` imports nothing of the program;
-    it is held to this package's reference output for output."""
+def _benchmarks_copy(name):
     import importlib.util
     import os
 
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "cellbench", "reference_lfm2.py")
-    spec = importlib.util.spec_from_file_location("reference_lfm2", path)
+        os.path.abspath(__file__))), "cellbench", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
     copy = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(copy)
+    return copy
+
+
+def test_the_benchmarks_copy_of_the_reference_is_the_same(small):
+    """``cellbench/reference_lfm2.py`` imports nothing of the program;
+    it is held to this package's reference output for output."""
+    copy = _benchmarks_copy("reference_lfm2")
     d, cfg, w = small
     seq = _hists(np.random.default_rng(9), [13])[0]
     np.testing.assert_array_equal(np.asarray(copy.forward(w, seq, d)),

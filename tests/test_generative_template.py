@@ -214,3 +214,79 @@ def test_unknown_items_and_empty_histories():
     again = algo.prepare_serving_model(stored, 8)
     assert algo.batch_predict(again, [Query(items=("i5",), num=2)]
                               )[0] == out[1]
+
+
+# -- the ``laguna`` family through the same engine --------------------------
+
+def _laguna_toy():
+    """An untied head, window rings, per-kind heads and a 4096 bucket at
+    toy widths: one layer of each kind, window 128."""
+    from test_decoder import LAGUNA
+
+    return {**LAGUNA, "hidden_size": 32, "intermediate_size": 64,
+            "moe_intermediate_size": 16,
+            "shared_expert_intermediate_size": 16, "head_dim": 8,
+            "num_hidden_layers": 2, "sliding_window": 128,
+            "layer_types": ["full_attention", "sliding_attention"],
+            "mlp_layer_types": ["dense", "sparse"],
+            "num_attention_heads_per_layer": [4, 6]}
+
+
+LAGUNA_PARAMS = GenerativeParams(
+    model=_laguna_toy(), seed=5, max_new=4, row_buckets=(2,),
+    history_buckets=(1024, 2048, 4096))
+
+
+@pytest.mark.parametrize("lengths,slots", [
+    ([300, 1500], 2 * 1024), ([4096, 700], 2 * 4096),
+    ([5000, 2], 2 * 4096)])
+def test_long_histories_through_an_untied_head(lengths, slots):
+    """Histories to the 4096 bucket (a longer one keeps its last 4096)
+    in streams sized by the batch's mean; the answer's first item is
+    the reference's greedy choice through the head's OWN matrix, and the
+    state's bytes by kind are on the registry."""
+    import numpy as np
+
+    from predictionio_tpu.models import decoder_reference as ref
+    from predictionio_tpu.obs.registry import MetricsRegistry
+
+    algo = GenerativeAlgorithm(LAGUNA_PARAMS)
+    registry = MetricsRegistry()
+    algo.register_metrics(registry)
+    model = GenerativeModel(config=_laguna_toy(), seed=5).materialise()
+    assert "head" in model.weights
+    assert not np.array_equal(np.asarray(model.weights["head"]),
+                              np.asarray(model.weights["embed"]))
+    hists = [[(11 * r + 3 * t) % 256 for t in range(n)]
+             for r, n in enumerate(lengths)]
+    arrays, ran = algo._dispatch(model, [h[-4096:] for h in hists])
+    assert ran == slots
+    out = algo.batch_predict(model, [Query(items=_query(h)["items"], num=4)
+                                     for h in hists])
+    kept = hists[1][-4096:]
+    logits = np.asarray(ref.forward(model.weights, kept,
+                                    _laguna_toy()))[-1]
+    first = out[1].item_scores[0]
+    assert first.item == f"i{int(logits.argmax())}"
+    assert first.score == pytest.approx(float(logits.max()), abs=2e-4)
+    kinds = {c["labels"]["kind"]: c["value"] for c in
+             registry.export()["pio_gen_state_bytes"]["children"]}
+    # float32 here: 2 rows x 2 key-value heads x 8 x 4 bytes x (keys and
+    # values) x slots
+    assert kinds == {"full": 2 * 2 * 8 * 4 * 2 * (4096 + 4),
+                     "window": 2 * 2 * 8 * 4 * 2 * 128}
+    touched = registry.export()["pio_moe_experts_touched"]["children"][0]
+    assert [b[0] for b in touched["buckets"]] == [
+        1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0, "+Inf"]
+
+
+def test_experts_touched_bounds_follow_the_model():
+    from predictionio_tpu.templates.generative import (
+        experts_touched_bounds)
+
+    assert experts_touched_bounds(32) == (1, 2, 4, 8, 12, 16, 20, 24, 28,
+                                          30, 31, 32)
+    big = experts_touched_bounds(256)
+    assert big[-3:] == (240, 255, 256) and big[:4] == (1, 2, 4, 8)
+    assert all(a < b for a, b in zip(big, big[1:]))
+    assert experts_touched_bounds(8) == (1, 2, 3, 4, 5, 6, 7, 8)
