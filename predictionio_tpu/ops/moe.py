@@ -8,8 +8,10 @@ bias ``b`` that balances load, and WEIGHTED by ``s`` without it;
 ``norm_topk_prob`` divides the weights by their sum (plus ``1e-6``).
 No token is dropped and there is no capacity limit.
 
-The product (:func:`expert_product`) has two forms and picks by the
-number of tokens, which it can see. MANY tokens (a prefill): the (token,
+The product (:func:`expert_product`) has three forms and picks among
+them from shapes it can see (:func:`product_form`): the tokens ``T``,
+the experts a token ``k`` and the experts held ``E_held``. MANY tokens
+(a prefill, over ``DENSE_MAX_ROWS``): the (token,
 expert) assignments are sorted by expert and each weight takes one
 grouped matrix product (``jax.lax.ragged_dot``: a Mosaic grouped-matmul
 kernel on a TPU, ``ragged-dot`` in its trace, and a native op on the
@@ -21,20 +23,33 @@ weights and the mask as routed (``[T, k]``), so ``y`` is read once and
 no other array of its size is written (:func:`_combine`). A stream of
 more than ``BLOCK_ASSIGNMENTS`` assignments goes through all of that in
 equal blocks of tokens, one after the other (``lax.map``), so the
-temporaries are a block's whatever the stream. FEW tokens
-(a decode step, ``DENSE_MAX_ROWS`` or under): every
-held expert is computed for every token in one batched product and the
-routing weights, zero where an expert was not selected, do the
-selecting; a step's rows touch every expert anyway, each expert's
+temporaries are a block's whatever the stream. FEW tokens (a decode
+step, ``DENSE_MAX_ROWS`` or under) stream expert weights past the rows,
+and the question is WHICH experts' weights. Where the step's ``T x k``
+assignments outnumber the held experts (``TOUCHED_REACH x T x k >
+E_held``: 64 rows x 4 over 32 experts touch 31.5 of them), every held
+expert is computed for every token in one
+batched product and the routing weights, zero where an expert was not
+selected, do the selecting (:func:`_every_expert`): each expert's
 weights are read once either way, and the batched product reads them at
 14.2 ms a step of 64 rows where the sorted one took 24.5 (my chip run,
-PR 27). An assignment to an expert that is not held
+PR 27). Where they do not (``TOUCHED_REACH x T x k <= E_held``: 16 rows
+x 8 over 256 experts touch 94), only the experts that at least one row
+selected are fetched and multiplied (:func:`_touched_experts`: one Pallas
+kernel, ``touched_experts`` in a device trace, whose grid walks the
+ascending list of distinct selected experts through a prefetched
+index; an expert nobody selected is never read, where the batched
+product read it and multiplied its output by zero: 0.79 ms a layer
+where the batched product took 2.24, my chip run, PR 33).
+An assignment to an expert that is not held
 here (``held``: the chip's share of an expert-parallel deployment,
 model-configs guide section 4) or from a slot that is padding
 (``valid``) joins no group: it is sorted behind the last group and its
 rows are never computed. What the absent experts would have added is
 left out; four shares of a layer add up to the whole layer
 (``tests/test_decoder.py``).
+
+Off the TPU the kernel runs in Pallas' interpreter (tests, rehearsals).
 """
 
 from __future__ import annotations
@@ -43,14 +58,38 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .window_attention import _interpreted
 
 NORM_EPS = 1e-6  # the family's constant in the top-k normalisation
-#: tokens up to which every expert is computed for every token. Reading
-#: one expert's weights takes 27 us at the v5e's 819 GB/s and computing
-#: it for one more token 0.11 us at its 197 TFLOP/s: under about 240
-#: tokens the weights' streaming hides the products nobody selected.
-#: Half of that, for an MXU that is not at its peak at these heights.
+#: tokens up to which expert weights are streamed past all the tokens
+#: (the two few-token forms). Reading one expert's weights (2048 x 1792
+#: x 3) takes 27 us at the v5e's 819 GB/s and computing it for one more
+#: token 0.11 us at its 197 TFLOP/s: under about 240 tokens the weights'
+#: streaming hides the products nobody selected. Half of that, for an
+#: MXU that is not at its peak at these heights.
 DENSE_MAX_ROWS = 128
+#: a few-token step reads only the experts its rows selected where
+#: this many times its ``T x k`` assignments do not outnumber the held
+#: experts. One layer of 256 experts of 2048 x 512 x 3 bfloat16, device
+#: ms touched / every (my chip run, PR 33): 16 rows x 8 with 94 distinct
+#: 0.79 / 2.24 (60: 0.52, all 128: 1.07); 32 x 8 with 162 (what
+#: uniform routing touches at ``T x k = E_held``) 1.37 / 2.14; 64 x 8
+#: with 221 1.85 / 2.14. Both forms stream an expert in 8.4-8.7 us, so
+#: the kernel wins by the share of experts nobody selected: 37 % or more
+#: at 1. An expert in 7 tiles of ``F`` (32 of 2048 x 1792 x 3): 8 rows
+#: x 4 with 20 distinct 0.61 / 0.94, with all 32 0.94 / 0.94. Past 1 the
+#: most it can win is the third or less of the experts that uniform
+#: routing leaves untouched, and where the rows touch them all (64 x 4
+#: over 32: 0.95 / 0.95) nothing.
+TOUCHED_REACH = 1
+#: bytes of ONE grid step's three weight blocks in the touched-experts
+#: kernel (the pipeline holds two steps' worth): a whole expert where
+#: it fits (2048 x 512 x 3 bfloat16 are 6.3 MB, three contiguous reads),
+#: else equal tiles of ``F`` in multiples of 128 lanes
+EXPERT_BLOCK_BYTES = 8 << 20
 #: assignments (tokens x experts a token) sorted and multiplied at once.
 #: Per assignment the many-token form holds a gathered row and an output
 #: row of ``H`` (bfloat16 and float32) and two of ``F``: 1.6 GB at
@@ -111,6 +150,20 @@ def expert_load(sel: jax.Array, n_experts: int,
         n_experts, dtype=flat.dtype), axis=0, dtype=jnp.int32)
 
 
+SORTED, TOUCHED, EVERY = "sorted_groups", "touched_experts", "every_expert"
+
+
+def product_form(tokens: int, top_k: int, n_held: int) -> str:
+    """Which of the three forms :func:`expert_product` takes for
+    ``tokens`` rows routed ``top_k`` ways over ``n_held`` held experts:
+    shapes alone decide (the module's docstring says why)."""
+    if tokens > DENSE_MAX_ROWS:
+        return SORTED
+    if TOUCHED_REACH * tokens * top_k <= n_held:
+        return TOUCHED
+    return EVERY
+
+
 def expert_product(x: jax.Array, sel: jax.Array, wts: jax.Array,
                    w1: jax.Array, w3: jax.Array, w2: jax.Array, *,
                    n_experts: int, held: Optional[Sequence[int]] = None,
@@ -123,9 +176,9 @@ def expert_product(x: jax.Array, sel: jax.Array, wts: jax.Array,
     local, n_held = local_index(sel, n_experts, held)
     if valid is not None:
         local = jnp.where(valid[:, None], local, n_held)
-    if T <= DENSE_MAX_ROWS:
-        return _every_expert(x, local, wts, w1, w3, w2)
-    return _sorted_groups(x, local, wts, w1, w3, w2)
+    form = {SORTED: _sorted_groups, TOUCHED: _touched_experts,
+            EVERY: _every_expert}[product_form(T, k, n_held)]
+    return form(x, local, wts, w1, w3, w2)
 
 
 def _every_expert(x, local, wts, w1, w3, w2):
@@ -143,6 +196,117 @@ def _every_expert(x, local, wts, w1, w3, w2):
     # float32 x float32: at the default precision the MXU would round
     # the routing weights and the experts' outputs to bfloat16
     return jnp.einsum("te,eth->th", dense, y, precision="highest")
+
+
+def touched_list(local: jax.Array, n_held: int, length: int
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """``(ids [length] int32, n)``: the ``n`` DISTINCT held experts of
+    ``local`` (``n_held`` there is no expert) in ascending order, the
+    list padded to its static ``length`` with its last entry (0 where
+    ``n`` is 0), so that a padded step asks for the block already held.
+    Compared and summed, as :func:`expert_load` (whose counts say which
+    experts were hit): entry ``g`` is the number of experts with ``g``
+    or fewer touched ones up to them."""
+    upto = jnp.cumsum(expert_load(local, n_held) > 0, dtype=jnp.int32)
+    n = upto[-1]
+    at = jnp.minimum(jnp.arange(length, dtype=jnp.int32),
+                     jnp.maximum(n - 1, 0))
+    ids = jnp.sum(upto[None, :] <= at[:, None], axis=1, dtype=jnp.int32)
+    return jnp.where(n > 0, ids, 0), n
+
+
+def _f_tiles(H: int, F: int, itemsize: int) -> int:
+    """The fewest equal tiles of ``F`` (the whole, or multiples of 128
+    lanes) that keep a step's three weight blocks at
+    ``EXPERT_BLOCK_BYTES`` or under; the finest there is where none
+    does."""
+    ways = [n for n in range(1, F + 1)
+            if F % n == 0 and (n == 1 or F // n % 128 == 0)]
+    return next((n for n in ways
+                 if 3 * H * (F // n) * itemsize <= EXPERT_BLOCK_BYTES),
+                ways[-1])
+
+
+def _touched_kernel(ids_ref, n_ref, x_ref, dense_ref, w1_ref, w3_ref,
+                    w2_ref, o_ref):
+    g, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((g == 0) & (j == 0))
+    def _():  # whether or not any expert follows
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    @pl.when(g < n_ref[0])
+    def _():
+        f32 = jnp.float32
+        x = x_ref[...]
+        h = jax.nn.silu(jnp.dot(x, w1_ref[...], preferred_element_type=f32)) \
+            * jnp.dot(x, w3_ref[...], preferred_element_type=f32)
+        y = jnp.dot(h.astype(x.dtype), w2_ref[...],
+                    preferred_element_type=f32)
+        # the expert's column of the routing matrix, float32 on the VPU
+        dense = dense_ref[...]
+        col = jax.lax.broadcasted_iota(jnp.int32, dense.shape, 1)
+        o_ref[...] += y * jnp.sum(jnp.where(col == ids_ref[g], dense, 0.0),
+                                  axis=1, keepdims=True)
+
+
+def _touched_experts(x, local, wts, w1, w3, w2):
+    """Few tokens that cannot reach most experts: one Pallas kernel
+    (``touched_experts`` in a device trace) over the grid ``(G, F /
+    tile)``, ``G = min(E_held, T x k)``. Step ``g`` under the count of
+    distinct selected experts fetches expert ``ids[g]``'s weights (the
+    block index comes from the prefetched list), multiplies the resident
+    rows through it as :func:`_every_expert` does (operands in the
+    weights' dtype, float32 accumulation, ``silu(a) * b`` rounded once)
+    and adds the output, times the expert's float32 column of the dense
+    routing matrix, to the float32 ``[T, H]`` output block, which stays
+    in VMEM from the first step (zeroed there) to the last (written
+    once). A later step maps to the block already held: no DMA, no
+    product. Only the float32 order of the sum over experts differs
+    from :func:`_every_expert`."""
+    T, k = local.shape
+    n_held, H, F = w1.shape
+    f32 = jnp.float32
+    dense = jnp.sum(jax.nn.one_hot(local, n_held, dtype=f32)
+                    * wts[..., None], axis=1)
+    G = min(n_held, T * k)
+    ids, n = touched_list(local, n_held, G)
+    rows = -(-T // 16) * 16  # whole sublane tiles of either dtype
+    x = jnp.pad(x, ((0, rows - T), (0, 0)))
+    dense = jnp.pad(dense, ((0, rows - T), (0, 0)))
+    nf = _f_tiles(H, F, w1.dtype.itemsize)
+    tile = F // nf
+
+    def whole(g, j, ids, n):
+        return 0, 0
+
+    def f_tile(g, j, n):
+        # behind the list, the LAST block fetched: (ids[n - 1], nf - 1)
+        return jnp.where(g < n[0], j, nf - 1)
+
+    def in_map(g, j, ids, n):
+        return ids[g], 0, f_tile(g, j, n)
+
+    def out_map(g, j, ids, n):
+        return ids[g], f_tile(g, j, n), 0
+
+    out = pl.pallas_call(
+        _touched_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(G, nf),
+            in_specs=[pl.BlockSpec((rows, H), whole),
+                      pl.BlockSpec((rows, n_held), whole),
+                      pl.BlockSpec((None, H, tile), in_map),
+                      pl.BlockSpec((None, H, tile), in_map),
+                      pl.BlockSpec((None, tile, H), out_map)],
+            out_specs=pl.BlockSpec((rows, H), whole)),
+        out_shape=jax.ShapeDtypeStruct((rows, H), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * EXPERT_BLOCK_BYTES + (16 << 20)),
+        interpret=_interpreted(), name="touched_experts",
+    )(ids, n.reshape(1), x, dense, w1, w3, w2)
+    return out[:T]
 
 
 def _sorted_groups(x, local, wts, w1, w3, w2):

@@ -55,11 +55,13 @@ log = logging.getLogger(__name__)
 
 @functools.lru_cache(maxsize=None)
 def _interpreted() -> bool:
-    """No TPU backend: Pallas' interpreter takes the kernel (said once)."""
+    """No TPU backend: Pallas' interpreter takes this package's kernels
+    (said once; ``ops/moe.py`` asks here too)."""
     off_chip = jax.default_backend() != "tpu"
     if off_chip:
-        log.warning("window_attention: backend %s, not tpu: the kernel "
-                    "runs in Pallas' interpreter", jax.default_backend())
+        log.warning("backend %s, not tpu: the Pallas kernels "
+                    "(window_attention, touched_experts) run in Pallas' "
+                    "interpreter", jax.default_backend())
     return off_chip
 
 

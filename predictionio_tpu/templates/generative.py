@@ -159,7 +159,7 @@ class GenerativeAlgorithm(Algorithm):
 
     def __init__(self, params: GenerativeParams = GenerativeParams()):
         self.params = params
-        self._tokens = self._touched = self._imbalance = None
+        self._tokens = self._touched = self._read = self._imbalance = None
         self._state_bytes = None
 
     def train(self, ctx: Context, td: TrainingData) -> GenerativeModel:
@@ -182,12 +182,18 @@ class GenerativeAlgorithm(Algorithm):
             "Token slots of generative batches by kind: prompt (real "
             "history tokens), pad (the rest of the slots the prefill ran), "
             "generated")
+        bounds = experts_touched_bounds(
+            self.params.model.get("num_experts", 32))
         self._touched = registry.histogram(
             "pio_moe_experts_touched",
-            "Distinct experts a decode step read, mean over the expert "
-            "layers and the steps of a batch",
-            bounds=experts_touched_bounds(
-                self.params.model.get("num_experts", 32)))
+            "Distinct experts a decode step's rows selected, mean over "
+            "the expert layers and the steps of a batch", bounds=bounds)
+        self._read = registry.histogram(
+            "pio_moe_experts_read",
+            "Experts whose weights a decode step fetched, mean over the "
+            "expert layers and the steps of a batch: every held expert "
+            "where the step took the every-expert product, the held ones "
+            "its rows selected where it took another form", bounds=bounds)
         self._imbalance = registry.histogram(
             "pio_moe_load_imbalance",
             "Largest over mean tokens per expert of an expert layer in "
@@ -241,10 +247,13 @@ class GenerativeAlgorithm(Algorithm):
                 model.weights, state, first, cfg=cfg, steps=p.max_new)
         return (toks, scores, load), T
 
-    def _observe(self, hists, slots: int, load) -> None:
-        """Once a batch, never per query."""
+    def _observe(self, cfg, hists, rows: int, slots: int, load) -> None:
+        """Once a batch, never per query: its queries' histories, the
+        ``rows`` its decode ran and the ``slots`` its prefill ran."""
         if self._tokens is None:
             return
+        from ..ops import moe
+
         prompt = sum(len(h) for h in hists)
         self._tokens.labels(kind="prompt").inc(prompt)
         self._tokens.labels(kind="pad").inc(slots - prompt)
@@ -253,6 +262,16 @@ class GenerativeAlgorithm(Algorithm):
         prefill, decode = (np.asarray(a) for a in load)
         if decode.size:
             self._touched.observe(float((decode > 0).sum(axis=-1).mean()))
+            # from the loads the program returns anyway and the form
+            # ops/moe.py takes for a step of that many rows: no sync
+            held = slice(None) if cfg.experts_held is None \
+                else list(cfg.experts_held)
+            mine = decode[..., held] > 0
+            n_held = mine.shape[-1]
+            every = moe.product_form(rows, cfg.num_experts_per_tok,
+                                     n_held) == moe.EVERY
+            self._read.observe(float(n_held) if every
+                               else float(mine.sum(axis=-1).mean()))
         for layer in prefill:
             if layer.sum() > 0:
                 self._imbalance.observe(float(layer.max() / layer.mean()))
@@ -290,7 +309,8 @@ class GenerativeAlgorithm(Algorithm):
         def resolve() -> List[PredictedResult]:
             for chunk, arrays, slots in pending:
                 toks, scores, load = jax.device_get(arrays)
-                self._observe([hists[i] for i in chunk], slots, load)
+                self._observe(model.cfg, [hists[i] for i in chunk],
+                              len(toks), slots, load)
                 for row, i in enumerate(chunk):
                     n = min(max(queries[i].num, 0), self.params.max_new)
                     out[i] = PredictedResult(tuple(

@@ -388,15 +388,18 @@ HELD7 = (0, 1, 2, 4, 5, 6, 7)
     (24, 4, None, HELD7), (200, 4, None, HELD7),
     (512, 4, 300, None), (512, 4, 300, HELD7), (512, 4, 300, (3, 6)),
     (192, 4, 192, None), (130, 2, 1, (5,)), (640, 4, 0, None),
+    # tokens x top_k at the held experts or under: the touched form
+    (4, 2, None, None), (2, 2, 2, None), (2, 4, 1, None), (2, 2, 0, None),
+    (3, 2, 3, HELD7), (1, 2, 1, (3, 6)),
 ], ids=lambda v: "all" if v is None else str(v).replace(" ", ""))
 def test_both_forms_of_the_product_give_the_reference(small, tokens, top_k,
                                                       real, held):
-    """Few tokens (every expert for every token) and many (sorted
-    groups) against each other at ANY size, and what ``expert_product``
-    picks against the reference. ``tokens`` slots of which the first
-    ``real`` are a packed stream's tokens (``None``: every fifth slot is
-    padding), routed ``top_k`` ways over 8 experts of which ``held`` are
-    here (``None``: all)."""
+    """Few tokens (every expert for every token, or the touched experts
+    alone) and many (sorted groups) against each other at ANY size, and
+    what ``expert_product`` picks against the reference. ``tokens`` slots
+    of which the first ``real`` are a packed stream's tokens (``None``:
+    every fifth slot is padding), routed ``top_k`` ways over 8 experts of
+    which ``held`` are here (``None``: all)."""
     d, cfg, w = small
     lw = w["layers"][4]
     z = jax.random.normal(jax.random.key(tokens + top_k),
@@ -413,9 +416,15 @@ def test_both_forms_of_the_product_give_the_reference(small, tokens, top_k,
     local = jnp.where(valid[:, None], local, n_held)
     few = moe._every_expert(z, local, wts, *ws)
     many = moe._sorted_groups(z, local, wts, *ws)
+    touched = moe._touched_experts(z, local, wts, *ws)
     np.testing.assert_allclose(few, many, atol=1e-5)
+    np.testing.assert_allclose(touched, few, atol=1e-5)
+    form = moe.product_form(tokens, top_k, n_held)
+    assert form == (moe.SORTED if tokens > moe.DENSE_MAX_ROWS
+                    else moe.TOUCHED if tokens * top_k <= n_held
+                    else moe.EVERY)
     np.testing.assert_array_equal(
-        got, many if tokens > moe.DENSE_MAX_ROWS else few)
+        got, {moe.SORTED: many, moe.TOUCHED: touched, moe.EVERY: few}[form])
     share = {**lw, "w1": ws[0], "w3": ws[1], "w2": ws[2]}
     cfg_ref = {**d, "num_experts_per_tok": top_k}
     if held is not None:
@@ -426,6 +435,123 @@ def test_both_forms_of_the_product_give_the_reference(small, tokens, top_k,
     np.testing.assert_allclose(many, want, atol=1e-5)
     # a pad slot's output is exactly 0, not nearly
     assert not np.asarray(many)[~np.asarray(valid)].any()
+    assert not np.asarray(touched)[~np.asarray(valid)].any()
+
+
+@pytest.mark.parametrize("tokens,top_k,n_held,form", [
+    (16, 8, 256, moe.TOUCHED),    # the laguna cell's step: 128 over 256
+    (16, 8, 128, moe.TOUCHED),    # T k == E_held
+    (16, 8, 127, moe.EVERY),      # T k == E_held + 1
+    (32, 8, 256, moe.TOUCHED), (32, 8, 255, moe.EVERY),
+    (64, 4, 32, moe.EVERY),       # the lfm2_moe cell's step: 256 over 32
+    (4, 2, 16, moe.TOUCHED), (4, 2, 8, moe.TOUCHED), (5, 2, 9, moe.EVERY),
+    (1, 1, 1, moe.TOUCHED), (1, 2, 1, moe.EVERY),
+    (128, 1, 128, moe.TOUCHED), (128, 1, 127, moe.EVERY),
+    (129, 1, 100000, moe.SORTED), (32768, 8, 256, moe.SORTED),
+])
+def test_the_form_follows_from_the_shapes_alone(tokens, top_k, n_held,
+                                                form):
+    """Over ``DENSE_MAX_ROWS`` tokens the sorted groups; else the touched
+    experts where the step's assignments do not outnumber the held
+    experts, else every expert: at the boundary and one assignment past
+    it."""
+    assert moe.TOUCHED_REACH == 1
+    assert moe.product_form(tokens, top_k, n_held) == form
+
+
+def _touched_case(name):
+    """``(sel [T, k], valid [T] or None, n_experts, held, dtype,
+    distinct held experts selected)`` at shapes the rule sends to the
+    touched form (``T k <= E_held``)."""
+    rng = np.random.default_rng(len(name))
+    T, k, E = 4, 2, 16
+    spread = rng.permutation(E)[:T * k].reshape(T, k)
+    if name == "same_k":        # n = k: every later step is skipped
+        return np.tile([[11, 2]], (T, 1)), None, E, None, "float32", k
+    if name == "all_distinct":  # n = G: nothing is skipped
+        return spread, None, E, None, "float32", T * k
+    if name == "pads_among_real":
+        valid = np.array([True, False, True, False])
+        return spread, valid, E, None, "float32", 2 * k
+    if name == "every_row_a_pad":  # n = 0
+        return spread, np.zeros(T, bool), E, None, "float32", 0
+    if name == "bfloat16":
+        return spread, None, E, None, "bfloat16", T * k
+    # shares of 32 experts, 16 held: none, some, all of the selected
+    held = tuple(range(0, 32, 2))
+    sel = {"held_none": 2 * spread + 1,
+           "held_some": np.where(np.arange(k) == 0, 2 * spread,
+                                 2 * spread + 1),
+           "held_all": 2 * spread}[name]
+    return (sel, None, 32, held, "float32",
+            len(set(sel.reshape(-1).tolist()) & set(held)))
+
+
+@pytest.mark.parametrize("name", [
+    "same_k", "all_distinct", "pads_among_real", "every_row_a_pad",
+    "held_none", "held_some", "held_all", "bfloat16"])
+def test_the_touched_form_is_the_every_expert_product(name):
+    """The kernel (through Pallas' interpreter here) against
+    ``_every_expert`` at shapes ``expert_product`` sends to it: only the
+    float32 order of the sum over experts may differ. The list it walks
+    holds the distinct held experts in ascending order, padded with its
+    last entry; pad rows, and a step that selected nothing held here,
+    come out as exact zeros."""
+    sel, valid, n_experts, held, dtype, distinct = _touched_case(name)
+    T, k = sel.shape
+    H, F = 64, 32
+    n_held = n_experts if held is None else len(held)
+    assert moe.product_form(T, k, n_held) == moe.TOUCHED
+    keys = jax.random.split(jax.random.key(T + n_experts), 5)
+    x = jax.random.normal(keys[0], (T, H)).astype(dtype)
+    w1, w3 = (jax.random.normal(key, (n_held, H, F)).astype(dtype) / 8
+              for key in keys[1:3])
+    w2 = (jax.random.normal(keys[3], (n_held, F, H)) / 6).astype(dtype)
+    wts = jax.random.uniform(keys[4], (T, k), minval=0.2)
+    sel = jnp.asarray(sel, jnp.int32)
+    valid = None if valid is None else jnp.asarray(valid)
+    got = moe.expert_product(x, sel, wts, w1, w3, w2, n_experts=n_experts,
+                             held=held, valid=valid)
+    local, _ = moe.local_index(sel, n_experts, held)
+    if valid is not None:
+        local = jnp.where(valid[:, None], local, n_held)
+    ids, n = moe.touched_list(local, n_held, min(n_held, T * k))
+    want_ids = sorted(set(np.asarray(local).reshape(-1).tolist())
+                      - {n_held})
+    assert int(n) == distinct == len(want_ids)
+    assert np.asarray(ids).tolist() == (
+        want_ids + want_ids[-1:] * (len(ids) - distinct) if want_ids
+        else [0] * len(ids))
+    want = moe._every_expert(x, local, wts, w1, w3, w2)
+    assert got.dtype == jnp.float32 and got.shape == (T, H)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    if distinct:
+        assert np.abs(np.asarray(want)).max() > 0.1
+    else:
+        assert not np.asarray(got).any()
+    if valid is not None:
+        assert not np.asarray(got)[~np.asarray(valid)].any()
+
+
+def test_a_wide_expert_goes_through_the_kernel_in_tiles(monkeypatch):
+    """Where an expert's three weights are over ``EXPERT_BLOCK_BYTES``
+    the grid takes ``F`` in equal tiles of whole lanes; the sum over the
+    tiles is the expert's output."""
+    assert moe._f_tiles(2048, 512, 2) == 1      # the laguna cell: whole
+    assert moe._f_tiles(2048, 1792, 2) == 7     # lfm2_moe's: 256 a tile
+    assert moe._f_tiles(64, 96, 4) == 1         # no tile of whole lanes
+    T, k, E, H, F = 2, 2, 8, 16, 256
+    monkeypatch.setattr(moe, "EXPERT_BLOCK_BYTES", 3 * H * 128 * 4)
+    assert moe._f_tiles(H, F, 4) == 2
+    keys = jax.random.split(jax.random.key(2), 5)
+    x = jax.random.normal(keys[0], (T, H))
+    w1, w3 = (jax.random.normal(key, (E, H, F)) / 4 for key in keys[1:3])
+    w2 = jax.random.normal(keys[3], (E, F, H)) / 16
+    wts = jax.random.uniform(keys[4], (T, k))
+    local = jnp.asarray([[6, 1], [1, 3]], jnp.int32)
+    np.testing.assert_allclose(
+        moe._touched_experts(x, local, wts, w1, w3, w2),
+        moe._every_expert(x, local, wts, w1, w3, w2), atol=1e-5)
 
 
 def _all_eqns(jaxpr):

@@ -4,8 +4,9 @@ reference at a small size on the CPU: what has no case in
 batches, the neighbours, the shares and the ring wrapped twice): its
 published keys and the ones nobody has written, the rotary by kind
 against the formula in numpy, the rings' layout, the stages taken in
-parts, bfloat16 against int8, and the benchmark's copy of the
-reference. Tolerances as ``test_decoder.py`` states them."""
+parts, bfloat16 against int8, the benchmark's copy of the reference,
+and a decode whose steps take the touched-experts kernel. Tolerances as
+``test_decoder.py`` states them."""
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,9 @@ import pytest
 from predictionio_tpu.models import decoder, decoder_reference as ref
 from predictionio_tpu.ops import moe
 from test_decoder import (  # noqa: F401 — ``lag`` is a fixture
-    LAGUNA, LAGUNA_HISTORY, LAGUNA_INIT, LONG, _benchmarks_copy, _generate,
-    _hists, _int8_round_trip, _pack, _prefill, _rel, _setup, lag)
+    LAGUNA, LAGUNA_HISTORY, LAGUNA_INIT, LONG, _all_eqns, _benchmarks_copy,
+    _check_against_reference, _generate, _hists, _int8_round_trip, _pack,
+    _prefill, _rel, _setup, lag)
 
 
 def test_config_reads_the_laguna_keys():
@@ -244,3 +246,32 @@ def test_the_benchmarks_laguna_copy_of_the_reference_is_the_same(lag):
     np.testing.assert_array_equal(
         np.asarray(copy.feed_forward(lw, 1, z, d)),
         np.asarray(ref.feed_forward(lw, 1, z, d)))
+
+
+def test_a_decode_step_through_the_touched_form_matches_the_forward():
+    """``_gen_decode`` at a toy ``laguna`` shape whose steps take the
+    touched-experts form (16 experts, 2 a token, 4 rows: 8 assignments)
+    while its prefill takes the sorted groups: every served score is the
+    float32 reference forward's logit of the served token."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(decoder, "ATTENTION_BLOCK", 8)
+        d, cfg, w = _setup(base=LAGUNA, init=LAGUNA_INIT, num_experts=16)
+        hists = _hists(np.random.default_rng(3), [40, 33, 40, 33])
+        assert moe.product_form(len(hists), cfg.num_experts_per_tok,
+                                cfg.num_experts) == moe.TOUCHED
+        assert moe.product_form(160, cfg.num_experts_per_tok,
+                                cfg.num_experts) == moe.SORTED
+        first, state = _prefill(w, cfg, hists, 160, steps=5)
+        kernels = [e.params["name"] for e in _all_eqns(jax.make_jaxpr(
+            lambda w, state, first: decoder._gen_decode(
+                w, state, first, cfg=cfg, steps=5))(
+                    w, state, first).jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        assert kernels == ["touched_experts"] * d["mlp_layer_types"].count(
+            "sparse")
+        toks, scores, (_, dec), _ = decoder._gen_decode(
+            w, state, first, cfg=cfg, steps=5)
+        _check_against_reference(d, w, hists, np.asarray(first),
+                                 np.asarray(toks), np.asarray(scores))
+        assert (np.asarray(dec).sum(axis=2)
+                == cfg.num_experts_per_tok * len(hists)).all()

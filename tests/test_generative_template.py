@@ -199,7 +199,47 @@ def test_per_batch_series_are_on_the_servers_registry(served):
     touched = export["pio_moe_experts_touched"]["children"][0]
     assert touched["count"] >= 1
     assert 1 <= touched["sum"] / touched["count"] <= SMALL["num_experts"]
+    # beside it, once a batch too: what a step fetched is what it
+    # touched (batches of 4 rows: 8 assignments over 8 experts) or all 8
+    read = export["pio_moe_experts_read"]["children"][0]
+    assert read["count"] == touched["count"]
+    assert touched["sum"] <= read["sum"] <= 8 * read["count"]
     assert export["pio_moe_load_imbalance"]["children"][0]["count"] >= 4
+
+
+@pytest.mark.parametrize("rows,held,want", [
+    (4, None, 2.5),         # 8 assignments over 8 experts: the touched
+    (8, None, 8.0),         # 16 over 8: every held expert
+    (2, (0, 1, 2, 5), 1.5),  # 4 over the 4 held here: those touched HERE
+    (4, (0, 1, 2, 5), 4.0),  # 8 over 4 held: every one of the 4
+])
+def test_experts_read_follows_the_form_the_step_took(rows, held, want):
+    """``pio_moe_experts_read`` off the loads the decode returns and the
+    form ``ops/moe.py`` takes for that many rows: the touched count
+    (among the experts held) or all that are held."""
+    import dataclasses
+
+    import numpy as np
+
+    from predictionio_tpu.models.decoder import DecoderConfig
+    from predictionio_tpu.obs.registry import MetricsRegistry
+
+    algo = GenerativeAlgorithm(PARAMS)
+    registry = MetricsRegistry()
+    algo.register_metrics(registry)
+    cfg = dataclasses.replace(DecoderConfig.from_dict(SMALL),
+                              experts_held=held)
+    # two steps of one expert layer: experts 0, 3, 5 then 5, 6
+    decode = np.zeros((2, 1, 8), np.int32)
+    decode[0, 0, [0, 3, 5]] = 2
+    decode[1, 0, [5, 6]] = 3
+    algo._observe(cfg, [[1, 2]] * rows, rows, 64,
+                  (np.zeros((1, 8)), decode))
+    export = registry.export()
+    read = export["pio_moe_experts_read"]["children"][0]
+    assert (read["count"], read["sum"]) == (1, want)
+    touched = export["pio_moe_experts_touched"]["children"][0]
+    assert (touched["count"], touched["sum"]) == (1, 2.5)
 
 
 def test_unknown_items_and_empty_histories():
@@ -278,6 +318,12 @@ def test_long_histories_through_an_untied_head(lengths, slots):
     touched = registry.export()["pio_moe_experts_touched"]["children"][0]
     assert [b[0] for b in touched["buckets"]] == [
         1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0, "+Inf"]
+    # 2 rows x 2 over 8 experts: the decode went through the touched
+    # experts' kernel, and read what it touched
+    read = registry.export()["pio_moe_experts_read"]["children"][0]
+    assert read["buckets"] == touched["buckets"]
+    assert (read["count"], read["sum"]) == (touched["count"],
+                                            pytest.approx(touched["sum"]))
 
 
 def test_experts_touched_bounds_follow_the_model():

@@ -1,0 +1,56 @@
+"""The decode's Pallas kernel compiled at the benchmark's widths for a
+DESCRIBED TPU v5e (the chip's compiler is installed here; nothing
+runs): what Pallas' interpreter cannot refuse, the chip's compiler
+does here and not on the chip: a block that does not fit the tiling,
+more VMEM than a kernel may use. The topology is described inside a
+fixture of this one file (on-chip-measurement guide, section 2)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from predictionio_tpu.ops import moe
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """The kernel asks the backend whether to run interpreted; here the
+    backend is the CPU and the target is not."""
+    monkeypatch.setattr(moe, "_interpreted", lambda: False)
+
+
+def _compiled(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("rows,top_k,experts,width,inner,tiles", [
+    (16, 8, 256, 2048, 512, 1),    # the laguna cell's decode step
+    (1, 8, 256, 2048, 512, 1),     # one row, padded to a sublane tile
+    (8, 4, 32, 2048, 1792, 7),     # lfm2_moe's experts: 7 tiles of 256
+], ids=["laguna-16x8", "laguna-1x8", "lfm2-8x4"])
+def test_the_touched_experts_kernel_compiles_at_the_cells_widths(
+        one_chip, for_the_chip, rows, top_k, experts, width, inner, tiles):
+    bf16 = jnp.bfloat16
+    assert moe.product_form(rows, top_k, experts) == moe.TOUCHED
+    assert moe._f_tiles(width, inner, 2) == tiles
+    compiled = _compiled(
+        moe._touched_experts, one_chip,
+        ((rows, width), bf16), ((rows, top_k), jnp.int32),
+        ((rows, top_k), jnp.float32), ((experts, width, inner), bf16),
+        ((experts, width, inner), bf16), ((experts, inner, width), bf16))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "touched_experts" in text
