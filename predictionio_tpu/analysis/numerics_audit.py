@@ -472,6 +472,65 @@ def _entry_gen_decode_laguna():
     )(w, state, first)
 
 
+def _gen_xing_small():
+    """The ``xing4_0`` family's programs at a small size in the served
+    dtype: two latent-attention layers (ranks 24 / 16, a head 16 + 8
+    wide against values 16 wide, yarn on the softmax) inside four
+    residual streams under hyper-connections, a dense and a sparse
+    feed-forward with a shared expert, an untied head. Its float32
+    islands beside bfloat16 products: the streams, the coefficients'
+    projection, sigmoids, exponential and Sinkhorn passes, the latents'
+    norms."""
+    import jax
+    import numpy as np
+
+    from ..models import decoder
+
+    cfg = decoder.DecoderConfig.from_dict({
+        "hidden_size": 64, "intermediate_size": 128,
+        "moe_intermediate_size": 32, "num_hidden_layers": 2,
+        "num_attention_heads": 4, "num_key_value_heads": 4,
+        "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "first_k_dense_replace": 1, "n_routed_experts": 8,
+        "num_experts_per_tok": 2, "n_shared_experts": 1,
+        "routed_scaling_factor": 2, "vocab_size": 256,
+        "tie_word_embeddings": False, "rms_norm_eps": 1e-6,
+        "rope_theta": 10000,
+        "rope_scaling": {"type": "yarn", "factor": 64,
+                         "original_max_position_embeddings": 16,
+                         "beta_fast": 4, "beta_slow": 1, "mscale": 1,
+                         "mscale_all_dim": 1},
+        "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+        "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30})
+    w = decoder.init_weights(jax.random.key(0), cfg)
+    tokens = np.zeros((64,), np.int32)  # 4 rows of 9, packed; 28 spare
+    lengths = np.full((4,), 9, np.int32)
+    return decoder, cfg, w, tokens, lengths
+
+
+def _entry_gen_prefill_xing():
+    import jax
+
+    decoder, cfg, w, tokens, lengths = _gen_xing_small()
+    return jax.make_jaxpr(
+        lambda w, t, n: decoder._gen_prefill(w, t, n, cfg=cfg, history=16,
+                                             room=4)
+    )(w, tokens, lengths)
+
+
+def _entry_gen_decode_xing():
+    import jax
+
+    decoder, cfg, w, tokens, lengths = _gen_xing_small()
+    first, state = decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
+                                        history=16, room=4)
+    state.pop("sinkhorn_gap")  # beside the state, not of it
+    return jax.make_jaxpr(
+        lambda w, s, f: decoder._gen_decode(w, s, f, cfg=cfg, steps=4)
+    )(w, state, first)
+
+
 #: name → (builder, one-line description); ordered — the manifest and
 #: the CI artifact list entries in this order
 ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
@@ -528,6 +587,15 @@ ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
         _entry_gen_decode_laguna,
         "generative greedy decode of the laguna family (rings beside "
         "caches)"),
+    "gen_prefill_xing": (
+        _entry_gen_prefill_xing,
+        "generative prefill of the xing4_0 family (latent attention "
+        "expanded, four float32 residual streams, Sinkhorn-normalised "
+        "hyper-connections), bf16 weights"),
+    "gen_decode_xing": (
+        _entry_gen_decode_xing,
+        "generative greedy decode of the xing4_0 family (absorbed "
+        "attention over the latent cache)"),
 }
 
 

@@ -1,10 +1,14 @@
 """A config-driven decoder block stack with generation, driven by the
-keys of a published ``config.json``. Two families' names are read, into
-ONE stack: ``lfm2_moe`` (short-convolution and grouped-query attention
-layers side by side, dense and sparse-expert feed-forwards, a tied head)
-and ``laguna`` (full and sliding-window attention layers side by side
-with their own head counts and rotary, a sigmoid gate a head, a shared
-expert beside the routed ones, an untied head).
+keys of a published ``config.json``. Three families' names are read,
+into ONE stack: ``lfm2_moe`` (short-convolution and grouped-query
+attention layers side by side, dense and sparse-expert feed-forwards, a
+tied head), ``laguna`` (full and sliding-window attention layers side by
+side with their own head counts and rotary, a sigmoid gate a head, a
+shared expert beside the routed ones, an untied head) and ``xing4_0``
+(latent attention in every layer: low-rank queries, a cache that holds a
+token's normalised latent and ONE rotated key for all heads; ``hc_mult``
+residual streams read, written and mixed by Sinkhorn-normalised
+hyper-connections; a shared expert, an untied head).
 
 Two jitted entry points, whose names the benchmark's metrics match in
 the device trace: :func:`_gen_prefill` runs the histories of a batch and
@@ -14,12 +18,24 @@ passes in one program (the first token is the prefill's). The host
 sees one dispatch of two programs and syncs once, on the answer.
 
 Layer ``l``: ``h = x + op_l(n(x))``, ``y = h + ff_l(n(h))`` with RMSNorm
-``n``; ``op_l`` by ``layer_types[l]`` (``conv``, ``full_attention`` or
-``sliding_attention``), ``ff_l`` dense where ``mlp_layer_types[l]`` is
-``dense`` (``lfm2_moe``: for ``l < num_dense_layers``) and the expert
-block (``ops/moe.py``, plus the shared expert where the family has one)
-elsewhere. ``models/decoder_reference.py`` writes the equations out; the
-tests hold this module to it logit by logit.
+``n``; ``op_l`` by ``layer_types[l]``, one of four kinds (``conv``,
+``full_attention``, ``sliding_attention``, ``latent_attention``: every
+layer of a family that gives ``kv_lora_rank`` and no ``layer_types``),
+``ff_l`` dense where ``mlp_layer_types[l]`` is ``dense`` (``lfm2_moe``:
+for ``l < num_dense_layers``; ``xing4_0``: ``first_k_dense_replace``)
+and the expert block (``ops/moe.py``, plus the shared expert where the
+family has one) elsewhere. ``models/decoder_reference.py`` writes the
+equations out; the tests hold this module to it logit by logit.
+
+The residual path has two forms, told apart when a program is traced
+(:func:`_sub_block`). ``hc_mult`` absent or 1: the plain sums above over
+one stream ``[T, H]``. ``hc_mult = n`` over 1: the stream is ``[n, T,
+H]`` (the embedding ``n`` times at the entry, the ``n`` summed before
+the head) and each of a layer's two sub-blocks ``F`` computes, from a
+token's own streams, what it reads ``H_pre [n]``, writes ``H_post [n]``
+and mixes ``H_res [n, n]`` (``hc_sinkhorn_iters`` row-then-column
+normalisations of an exponential): ``u = H_pre X``, ``X' = H_res X +
+H_post^T F(n(u))`` (:func:`_hc_coefficients`).
 
 Layout. The prefill takes a batch PACKED: the real tokens of its rows
 one behind the other in one stream of ``T`` slots (``tokens [T]``,
@@ -35,7 +51,7 @@ layout that the decode's cache has anyway, blockwise
 row from its first real slot, tiles nobody sees skipped), with rotary
 positions counted from a row's first token. A spare slot joins no
 expert's group and no row reads it: a row's logits do not depend on
-where in the stream it lies or on what lies beside it. State of three
+where in the stream it lies or on what lies beside it. State of four
 kinds is carried from one program to the next: keys and values that
 grow (full-attention layers), right-aligned at ``history`` slots
 whatever the batch so that every row appends at the same slot and the
@@ -43,14 +59,23 @@ decode's shapes depend on ``B`` alone; a RING of ``sliding_window`` keys
 and values (sliding layers: the token at position ``p`` lives in slot
 ``p mod window``, the prefill leaves a row's last ``window`` tokens
 there and a decode step overwrites the oldest, so a step reads
-``window`` slots whatever the history); and a fixed
-``conv_L_cache``-wide window of ``B*u`` (conv layers).
+``window`` slots whatever the history); a fixed ``conv_L_cache``-wide
+window of ``B*u`` (conv layers); and LATENTS (latent-attention layers:
+a token's normalised ``kv_lora_rank`` latent beside its rotated shared
+key, ``[B, history + room, kv_lora_rank + rope]``, right-aligned like a
+full cache; the prefill lays keys and values of every head out from the
+tokens it has in hand, a decode step attends over the latents
+themselves with the up-projections absorbed into the query and the
+output, :func:`_latent_step`).
 
 Precision. Weights in ``cfg.dtype`` (bfloat16 as served). Every matrix
 product takes operands in that dtype and accumulates in float32
-(``preferred_element_type``); the residual stream, norms, rotary,
-softmax (running maximum, sum and accumulator), the gates' sigmoids and
-sums, and the conv window are float32.
+(``preferred_element_type``); the residual stream (all ``hc_mult`` of
+them), the hyper-connections' coefficients (their projection at
+``highest``, the sigmoids, the exponential and the Sinkhorn passes),
+norms (the latents' too), rotary, softmax (running maximum, sum and
+accumulator), the gates' sigmoids and sums, and the conv window are
+float32; the latent cache is kept in the weights' dtype.
 
 Every layer holds its own arrays and the stack is unrolled. (Stacking
 the periods of the layer pattern and scanning them compiles the period
@@ -75,14 +100,20 @@ from ..ops import moe
 from ..ops.window_attention import BLOCK as ATTENTION_BLOCK, window_attention
 
 CONV, ATTENTION, SLIDING = "conv", "full_attention", "sliding_attention"
+LATENT = "latent_attention"
 #: published names of one family that mean a field named by the other
 ALIASES = {"rms_norm_eps": "norm_eps",
-           "moe_routed_scaling_factor": "routed_scaling_factor"}
+           "moe_routed_scaling_factor": "routed_scaling_factor",
+           "n_routed_experts": "num_experts",
+           "first_k_dense_replace": "num_dense_layers"}
 #: keys that switch on mathematics nobody has written here: they are
 #: accepted at the value that switches it off, and raise otherwise
 UNWRITTEN = {"conv_bias": False, "attention_bias": False,
              "moe_apply_router_weight_on_input": False,
-             "moe_router_logit_softcapping": 0}
+             "moe_router_logit_softcapping": 0,
+             "n_group": 1, "topk_group": 1, "ep_size": 1,
+             "moe_layer_freq": 1, "topk_method": "noaux_tc",
+             "scoring_func": "sigmoid"}
 
 
 def _freeze(v):
@@ -103,18 +134,27 @@ class DecoderConfig:
     ``rope_theta``; ``laguna`` gives ``mlp_layer_types``,
     ``num_attention_heads_per_layer``, ``sliding_window``,
     ``rope_parameters`` by layer kind, ``gating``,
-    ``shared_expert_intermediate_size`` and ``tie_word_embeddings``."""
+    ``shared_expert_intermediate_size`` and ``tie_word_embeddings``;
+    ``xing4_0`` gives no ``layer_types`` (``kv_lora_rank`` makes every
+    layer ``latent_attention``), ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rope_scaling`` (yarn, by the key ``type``), ``n_routed_experts``,
+    ``first_k_dense_replace``, ``n_shared_experts`` (times
+    ``moe_intermediate_size``: the shared width) and the residual
+    path's ``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min`` / ``_max``. ``UNWRITTEN`` lists the keys
+    that raise at any value but the one that switches them off."""
 
     hidden_size: int
     intermediate_size: int
     moe_intermediate_size: int
     num_hidden_layers: int
-    layer_types: Tuple[str, ...]
     num_attention_heads: int
     num_key_value_heads: int
     num_experts: int
     num_experts_per_tok: int
     vocab_size: int
+    layer_types: Optional[Tuple[str, ...]] = None
     num_dense_layers: Optional[int] = None
     mlp_layer_types: Optional[Tuple[str, ...]] = None
     num_attention_heads_per_layer: Optional[Tuple[int, ...]] = None
@@ -134,13 +174,53 @@ class DecoderConfig:
     routed_scaling_factor: float = 1.0
     rope_theta: float = 1e6
     head_dim: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_scaling: Optional[tuple] = None
+    n_shared_experts: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    ep_size: int = 1
+    moe_layer_freq: int = 1
+    topk_method: str = "noaux_tc"
+    scoring_func: str = "sigmoid"
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
     dtype: str = "bfloat16"
     experts_held: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         put = functools.partial(object.__setattr__, self)
         n = self.num_hidden_layers
+        latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if self.layer_types is None:
+            if self.kv_lora_rank is None:
+                raise ValueError("layer_types says which operator each "
+                                 "layer has (only a family with "
+                                 "kv_lora_rank has one kind throughout)")
+            put("layer_types", (LATENT,) * n)
         put("layer_types", tuple(self.layer_types))
+        if LATENT in self.layer_types:
+            if None in latent:
+                raise ValueError(
+                    "latent_attention layers need q_lora_rank, "
+                    "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                    "v_head_dim")
+            if self.head_dim is None:
+                put("head_dim", self.qk_nope_head_dim + self.qk_rope_head_dim)
+        put("rope_scaling", _freeze(self.rope_scaling))
+        if not self.shared_expert_intermediate_size and self.n_shared_experts:
+            put("shared_expert_intermediate_size",
+                self.n_shared_experts * self.moe_intermediate_size)
+        if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
+            raise ValueError("hc_mult and hc_sinkhorn_iters are 1 or more")
         if self.experts_held is not None:
             put("experts_held", tuple(int(e) for e in self.experts_held))
         if self.head_dim is None:
@@ -163,10 +243,10 @@ class DecoderConfig:
             if len(getattr(self, name)) != n:
                 raise ValueError(f"{name} must name num_hidden_layers "
                                  f"layers")
-        if set(self.layer_types) - {CONV, ATTENTION, SLIDING}:
+        if set(self.layer_types) - {CONV, ATTENTION, SLIDING, LATENT}:
             raise ValueError(f"layer types {set(self.layer_types)}: only "
-                             f"{CONV!r}, {ATTENTION!r} and {SLIDING!r} are "
-                             f"written")
+                             f"{CONV!r}, {ATTENTION!r}, {SLIDING!r} and "
+                             f"{LATENT!r} are written")
         if set(self.mlp_layer_types) - {"dense", "sparse"}:
             raise ValueError(f"mlp layer types {set(self.mlp_layer_types)}")
         for key, off in UNWRITTEN.items():
@@ -204,7 +284,20 @@ class DecoderConfig:
     def rope(self, kind: str) -> Tuple[Tuple[float, ...], float]:
         """``(inverse frequencies, factor on cos and sin)`` of a layer
         kind: ``rope_parameters[kind]`` where the family gives them by
-        kind, else ``rope_theta`` over the whole head."""
+        kind, else ``rope_theta`` over the whole head. A latent layer
+        rotates its ``qk_rope_head_dim`` dimensions by ``rope_scaling``
+        (whose kind is the key ``type``); its yarn puts
+        ``yarn(mscale) / yarn(mscale_all_dim)`` on cos and sin and the
+        rest on the softmax (:meth:`latent_scale`)."""
+        if kind == LATENT:
+            p = dict(self.rope_scaling or ())
+            # ptpu: allow[unguarded-domain] — _yarn_mscale is 1 or more
+            on_cos = _yarn_mscale(p, "mscale") \
+                / _yarn_mscale(p, "mscale_all_dim")
+            rope_type = p.pop("type", None) or p.pop("rope_type", "default")
+            return _inverse_frequencies(
+                self.qk_rope_head_dim, float(self.rope_theta), rope_type,
+                tuple(sorted(p.items())))[0], on_cos
         by_kind = dict(self.rope_parameters or ())
         if kind not in by_kind:
             return _inverse_frequencies(self.head_dim, float(self.rope_theta),
@@ -215,6 +308,25 @@ class DecoderConfig:
         return _inverse_frequencies(
             rotated, float(p.pop("rope_theta")),
             p.pop("rope_type", "default"), tuple(sorted(p.items())))
+
+
+    @property
+    def latent_scale(self) -> float:
+        """A latent layer's softmax scale: ``(nope + rope) ** -0.5`` times
+        ``yarn(mscale_all_dim) ** 2`` (0.14468 at 192 wide, factor 64)."""
+        m = _yarn_mscale(dict(self.rope_scaling or ()), "mscale_all_dim")
+        # ptpu: allow[unguarded-domain] — a head is 1 wide or more
+        return self.head_dim ** -0.5 * m * m
+
+
+def _yarn_mscale(p: dict, key: str) -> float:
+    """``0.1 p[key] ln(factor) + 1`` under yarn with a factor over 1,
+    else 1: the family's ``yarn_get_mscale``."""
+    factor = float(p.get("factor", 1.0))
+    if p.get("type", p.get("rope_type")) != "yarn" or factor <= 1:
+        return 1.0
+    # ptpu: allow[unguarded-domain] — factor is over 1 here
+    return 0.1 * float(p.get(key, 1.0)) * math.log(factor) + 1.0
 
 
 @functools.lru_cache(maxsize=None)
@@ -276,8 +388,15 @@ def _inverse_frequencies(rotated: int, theta: float, rope_type: str,
 #: of layer 0's RMS: the stream grows slowly (1.1 to 2.4 over the 14
 #: layers of the benchmark's cut), the gated operators amplify little,
 #: and the expert blocks carry most of what is added.
+#: The hyper-connections' (``hc_mult`` over 1): ``hc_phi`` the standard
+#: deviation of a token's three dynamic terms ``x~ phi`` (``x~`` has
+#: unit RMS, ``phi`` is normal x ``hc_phi`` / sqrt(fan-in)), ``hc_bias``
+#: that of the static biases, ``hc_diag`` what ``b_res`` has on its
+#: diagonal beside them, and every ``a`` is 1: ``H_res`` then leans on
+#: the diagonal without being the identity and differs token to token.
 INIT = {"embed": 0.02, "op_out": 0.08, "dense_out": 0.67,
-        "expert_out": 2.0, "shared_out": 1.0, "gate_bias": 0.01}
+        "expert_out": 2.0, "shared_out": 1.0, "gate_bias": 0.01,
+        "hc_phi": 0.5, "hc_bias": 0.5, "hc_diag": 1.5}
 
 
 def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
@@ -287,10 +406,31 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
     H, D = cfg.hidden_size, cfg.head_dim
     nq, nkv = cfg.num_attention_heads_per_layer[l], cfg.num_key_value_heads
     out = {"op_norm": ((H,), 0, None), "ff_norm": ((H,), 0, None)}
+    n = cfg.hc_mult
+    if n > 1:  # float32 all: the residual path's own coefficients
+        for sub in ("op", "ff"):
+            out.update({
+                f"hc_{sub}_phi_pre": ((n * H, n), n * H, "hc_phi"),
+                f"hc_{sub}_phi_post": ((n * H, n), n * H, "hc_phi"),
+                f"hc_{sub}_phi_res": ((n * H, n * n), n * H, "hc_phi"),
+                f"hc_{sub}_b_pre": ((n,), 1, "hc_bias"),
+                f"hc_{sub}_b_post": ((n,), 1, "hc_bias"),
+                f"hc_{sub}_b_res": ((n, n), 1, "hc_bias"),
+                f"hc_{sub}_a": ((3,), 0, None)})  # a_pre, a_post, a_res
     if cfg.layer_types[l] == CONV:
         out.update(w_in=((H, 3 * H), H, None),
                    w_out=((H, H), H, "op_out"),
                    conv_w=((H, cfg.conv_L_cache), cfg.conv_L_cache, None))
+    elif cfg.layer_types[l] == LATENT:
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        out.update(w_qa=((H, rq), H, None), q_a_norm=((rq,), 0, None),
+                   w_qb=((rq, nq * (dn + dr)), rq, None),
+                   w_kva=((H, rkv + dr), H, None),
+                   kv_a_norm=((rkv,), 0, None),
+                   w_kvb=((rkv, nq * (dn + dv)), rkv, None),
+                   wo=((nq * dv, H), nq * dv, "op_out"))
     else:
         out.update(wq=((H, nq * D), H, None), wk=((H, nkv * D), H, None),
                    wv=((H, nkv * D), H, None),
@@ -331,8 +471,11 @@ def _draw(key, init: dict, *, shapes: tuple, dtype: str) -> dict:
             scale = scale * init[factor]
         # drawn in the target dtype: a float32 draw of one layer's
         # experts would be 1.4 GB of scratch beside 9 GB of weights
-        kind = jnp.float32 if name in ("conv_w", "gate_bias") else dtype
+        kind = jnp.float32 if name in ("conv_w", "gate_bias") \
+            or name.startswith("hc_") else dtype
         out[name] = jax.random.normal(k, shape, kind) * scale.astype(kind)
+        if name.endswith("_b_res"):
+            out[name] += init["hc_diag"] * jnp.eye(shape[0], dtype=kind)
     return out
 
 
@@ -489,6 +632,119 @@ def _feed_forward(lw, z, valid, cfg):
     return out, moe.expert_load(sel, cfg.num_experts, valid)
 
 
+# -- the residual path --------------------------------------------------------
+
+def _hc_coefficients(lw, sub, x, cfg):
+    """What sub-block ``sub`` (``op`` or ``ff``) of a layer reads, writes
+    and mixes, from the ``n = hc_mult`` streams ``x [n, T, H]`` float32
+    themselves: ``(pre [n, T], post [n, T], res [n, n, T])`` with ``res[i,
+    j]`` what stream ``i`` takes of stream ``j``. ``x~ = vec(x) / sqrt(
+    mean(vec(x)^2) + hc_eps)`` over all ``n H`` of a token; ``pre =
+    sigmoid(a_pre x~ phi_pre + b_pre)``, ``post = 2 sigmoid(..)``, ``res``
+    the Sinkhorn normalisation of ``exp(clip(a_res x~ phi_res + b_res))``:
+    ``hc_sinkhorn_iters`` times its rows, then its columns, over their
+    sums + ``hc_eps``. Float32 throughout (the projection at ``highest``:
+    24 columns decide how four streams of ``H`` are mixed); the tokens
+    lie on the minor axis, so that twenty passes over ``[n, n]`` a token
+    are passes over a few whole vectors."""
+    n, _, H = x.shape
+    f32, eps = jnp.float32, cfg.hc_eps
+    phi = jnp.concatenate([lw[f"hc_{sub}_phi_{k}"]
+                           for k in ("pre", "post", "res")], axis=1)
+    # a stream at a time: vec(x) is never laid out
+    p = sum(jnp.einsum("th,hc->ct", x[j], phi[j * H:(j + 1) * H],
+                       precision="highest", preferred_element_type=f32)
+            for j in range(n))
+    p = p * jax.lax.rsqrt(jnp.mean(x * x, axis=(0, 2)) + eps)
+    a = lw[f"hc_{sub}_a"]
+    pre = jax.nn.sigmoid(a[0] * p[:n] + lw[f"hc_{sub}_b_pre"][:, None])
+    post = 2.0 * jax.nn.sigmoid(a[1] * p[n:2 * n]
+                                + lw[f"hc_{sub}_b_post"][:, None])
+    res = jnp.exp(jnp.clip(
+        a[2] * p[2 * n:].reshape(n, n, -1)
+        + lw[f"hc_{sub}_b_res"][:, :, None],
+        cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max))
+    for _ in range(cfg.hc_sinkhorn_iters):
+        res = res / (jnp.sum(res, axis=1, keepdims=True) + eps)
+        res = res / (jnp.sum(res, axis=0, keepdims=True) + eps)
+    return pre, post, res
+
+
+def _sub_block(lw, sub, x, fn, cfg, valid=None):
+    """One sub-block around the residual path: ``(x', what fn returns
+    beside its output, gap)``. ``fn`` takes the normalised input ``[T,
+    H]`` and returns ``(out [T, H], aux)``. One stream (``hc_mult`` 1; a
+    branch taken when the program is traced): ``x' = x + fn(n(x))`` over
+    ``x [T, H]`` and no gap. ``n`` streams ``x [n, T, H]``: ``u = pre .
+    x``, ``x'_i = sum_j res[i, j] x_j + post_i fn(n(u))``, written as sums
+    of ``n`` scaled streams (elementwise: nothing here is the MXU's), and
+    ``gap`` the largest ``|sum_j res[i, j] - 1|`` over the ``valid``
+    tokens (all where ``None``): what the Sinkhorn iterations left."""
+    norm = lw[sub + "_norm"]
+    if cfg.hc_mult == 1:
+        out, aux = fn(_rms(x, norm, cfg.norm_eps))
+        return x + out, aux, None
+    n = cfg.hc_mult
+    pre, post, res = _hc_coefficients(lw, sub, x, cfg)
+    u = sum(pre[j][:, None] * x[j] for j in range(n))
+    out, aux = fn(_rms(u, norm, cfg.norm_eps))
+    new = jnp.stack([
+        sum(res[i, j][:, None] * x[j] for j in range(n))
+        + post[i][:, None] * out for i in range(n)])
+    off = jnp.abs(jnp.sum(res, axis=1) - 1.0)
+    if valid is not None:
+        off = jnp.where(valid[None, :], off, 0.0)
+    return new, aux, jnp.max(off)
+
+
+def _streams_in(x, cfg):
+    """The embedding ``[T, H]`` as the residual path takes it: itself, or
+    ``hc_mult`` copies of it."""
+    return x if cfg.hc_mult == 1 \
+        else jnp.broadcast_to(x, (cfg.hc_mult,) + x.shape)
+
+
+def _streams_out(x, cfg):
+    """What the head reads: the stream, or the sum of the ``n``."""
+    return x if cfg.hc_mult == 1 else jnp.sum(x, axis=0)
+
+
+# -- latent attention ---------------------------------------------------------
+
+def _latent_project(lw, z, pos, cfg):
+    """``(q [heads, ..., nope + rope], kv [..., kv_lora_rank + rope])``
+    of ``z [..., H]`` at positions ``pos [...]``, in the weights' dtype.
+    ``q``: the low-rank query path, normalised at ``q_lora_rank``, each
+    head's last ``rope`` dimensions rotated. ``kv``: what a token leaves
+    in the cache, its normalised latent beside its rotated key, ONE for
+    all heads."""
+    dt = jnp.dtype(cfg.dtype)
+    rope = cfg.rope(LATENT)
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    cq = _rms(_dot(z, lw["w_qa"]), lw["q_a_norm"], cfg.norm_eps)
+    q = _by_head(cq, lw["w_qb"], cfg.num_attention_heads)
+    q = jnp.concatenate([q[..., :dn], _rotary(q[..., dn:], pos, rope)],
+                        axis=-1)
+    kv = _dot(z, lw["w_kva"])
+    c = _rms(kv[..., :rkv], lw["kv_a_norm"], cfg.norm_eps)
+    r = _rotary(kv[None, ..., rkv:], pos, rope)[0]
+    return q.astype(dt), jnp.concatenate([c, r], axis=-1).astype(dt)
+
+
+def _latent_up(lw, cfg):
+    """``W_kvb [kv_lora_rank, heads x (nope + v)]`` as ``(W_uk, W_uv)``,
+    each ``[kv_lora_rank, heads, nope or v]``."""
+    w = lw["w_kvb"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _latent_out(lw, o):
+    """``o [heads, ..., v]`` through ``W_o``."""
+    wo = lw["wo"].reshape(o.shape[0], o.shape[-1], -1)
+    return jnp.einsum("n...d,ndh->...h", o.astype(wo.dtype), wo,
+                      preferred_element_type=jnp.float32)
+
+
 # -- prefill ----------------------------------------------------------------
 
 def _conv_prefill(lw, z, valid, pos, last, cfg):
@@ -513,6 +769,17 @@ def _conv_prefill(lw, z, valid, pos, last, cfg):
     return _dot(c * y, lw["w_out"]), {"win": win}
 
 
+def _to_rows(a, at, ok=None):
+    # ptpu: allow[materialized-gather] — [heads, B, slots, D] in the
+    # weights' dtype from the stream's [heads, T, D]. Zeros where a
+    # row has no token (``ok``); the queries' pad slots hold token
+    # 0's, which no real slot reads
+    out = jnp.take(a, at.reshape(-1), axis=1)
+    if ok is not None:
+        out = jnp.where(ok.reshape(1, -1, 1), out, 0)
+    return out.reshape(a.shape[:1] + at.shape + a.shape[2:])
+
+
 def _attention_prefill(lw, z, pos, rows, room, l, cfg):
     """``z [T, H]`` packed. ``q``, ``k``, ``v`` are gathered into the
     right-aligned ``[B, heads, history]`` layout (``rows``: where each
@@ -527,23 +794,13 @@ def _attention_prefill(lw, z, pos, rows, room, l, cfg):
     B, L = src.shape
     sliding = cfg.layer_types[l] == SLIDING
 
-    def to_rows(a, at, ok=None):
-        # ptpu: allow[materialized-gather] — [heads, B, slots, D] in the
-        # weights' dtype from the stream's [heads, T, D]. Zeros where a
-        # row has no token (``ok``); the queries' pad slots hold token
-        # 0's, which no real slot reads
-        out = jnp.take(a, at.reshape(-1), axis=1)
-        if ok is not None:
-            out = jnp.where(ok.reshape(1, -1, 1), out, 0)
-        return out.reshape(a.shape[:1] + at.shape + a.shape[2:])
-
     def state(k, v):  # batch first, as the decode reads it
         return {"k": k.swapaxes(0, 1), "v": v.swapaxes(0, 1)}
 
     q, k, v = _qkv(lw, z, pos, l, cfg)
-    kr, vr = to_rows(k, src, real), to_rows(v, src, real)
+    kr, vr = _to_rows(k, src, real), _to_rows(v, src, real)
     o = window_attention(
-        to_rows(q, src), kr, vr, lead, scale=cfg.head_dim ** -0.5,
+        _to_rows(q, src), kr, vr, lead, scale=cfg.head_dim ** -0.5,
         window=cfg.sliding_window if sliding else None,
         block=ATTENTION_BLOCK)
     # ptpu: allow[materialized-gather] — back into the stream: [heads, T, D]
@@ -556,8 +813,38 @@ def _attention_prefill(lw, z, pos, rows, room, l, cfg):
     at = jnp.arange(W, dtype=jnp.int32)[None, :]
     back = (pos[last][:, None] - at) % W  # tokens back from a row's last
     ring, held = last[:, None] - back, back <= pos[last][:, None]
-    return out, state(to_rows(k, jnp.where(held, ring, 0), held),
-                      to_rows(v, jnp.where(held, ring, 0), held))
+    return out, state(_to_rows(k, jnp.where(held, ring, 0), held),
+                      _to_rows(v, jnp.where(held, ring, 0), held))
+
+
+def _latent_prefill(lw, z, pos, rows, room, cfg):
+    """``z [T, H]`` packed, in the EXPANDED form (the tokens are in
+    hand): every head's keys ``[k_nope | k_rope]`` (the rotated key
+    repeated for each) and values from the tokens' latents through
+    ``W_kvb``, gathered into the right-aligned rows like any attention
+    layer's, through the blockwise kernel at the family's scale. The
+    state is what the decode attends over instead: the latents
+    themselves, ``[B, history + room, kv_lora_rank + rope]``."""
+    src, real, dst, lead, _ = rows
+    B, L = src.shape
+    dt = jnp.dtype(cfg.dtype)
+    q, kv = _latent_project(lw, z, pos, cfg)
+    w_uk, w_uv = _latent_up(lw, cfg)
+    c, r = kv[:, :cfg.kv_lora_rank], kv[:, cfg.kv_lora_rank:]
+    k = jnp.einsum("tc,cnd->ntd", c, w_uk,
+                   preferred_element_type=jnp.float32).astype(dt)
+    k = jnp.concatenate(
+        [k, jnp.broadcast_to(r, k.shape[:2] + r.shape[-1:])], axis=-1)
+    v = jnp.einsum("tc,cnd->ntd", c, w_uv,
+                   preferred_element_type=jnp.float32).astype(dt)
+    o = window_attention(
+        _to_rows(q, src), _to_rows(k, src, real), _to_rows(v, src, real),
+        lead, scale=cfg.latent_scale, block=ATTENTION_BLOCK)
+    # ptpu: allow[materialized-gather] — back into the stream: [heads, T, v]
+    o = jnp.take(o.reshape(o.shape[0], B * L, -1), dst, axis=1)
+    cache = jnp.pad(_to_rows(kv[None], src, real)[0],
+                    ((0, 0), (0, room), (0, 0)))
+    return _latent_out(lw, o), {"kv": cache}
 
 
 def _head(w, x, cfg):
@@ -594,25 +881,32 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
     # ptpu: allow[materialized-gather] — the embedding lookup itself: the
     # [T, H] it makes is the residual stream
     x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
-    states, loads = [], []
+    x = _streams_in(x, cfg)
+    states, loads, gaps = [], [], []
     for l, (lw, kind) in enumerate(zip(w["layers"], cfg.layer_types)):
-        z = _rms(x, lw["op_norm"], cfg.norm_eps)
-        if kind == CONV:
-            o, st = _conv_prefill(lw, z, valid, pos, ends - 1, cfg)
-        else:
-            o, st = _attention_prefill(lw, z, pos, rows, room, l, cfg)
-        h = x + o
-        f, load = _feed_forward(lw, _rms(h, lw["ff_norm"], cfg.norm_eps),
-                                valid, cfg)
-        x = h + f
+        def op(z, lw=lw, kind=kind, l=l):
+            if kind == CONV:
+                return _conv_prefill(lw, z, valid, pos, ends - 1, cfg)
+            if kind == LATENT:
+                return _latent_prefill(lw, z, pos, rows, room, cfg)
+            return _attention_prefill(lw, z, pos, rows, room, l, cfg)
+
+        h, st, gap_op = _sub_block(lw, "op", x, op, cfg, valid)
+        x, load, gap_ff = _sub_block(
+            lw, "ff", h, lambda z, lw=lw: _feed_forward(lw, z, valid, cfg),
+            cfg, valid)
         states.append(st)
         if load is not None:
             loads.append(load)
+        gaps += [g for g in (gap_op, gap_ff) if g is not None]
     cache = jnp.arange(history + room, dtype=jnp.int32)[None, :]
     state = {"layers": states, "load": jnp.stack(loads), "pos": lengths,
              "valid": (cache >= lead[:, None]) & (cache < history),
              "filled": jnp.asarray(history, jnp.int32)}
-    return _head(w, x[ends - 1], cfg), state
+    if gaps:  # hc_mult over 1: what twenty Sinkhorn passes left
+        state["sinkhorn_gap"] = jnp.max(jnp.stack(gaps))
+    last = x[..., ends - 1, :]
+    return _head(w, _streams_out(last, cfg), cfg), state
 
 
 # -- decode -----------------------------------------------------------------
@@ -657,16 +951,45 @@ def _attention_step(lw, z, st, valid, pos, at, l, cfg):
         {"k": ks, "v": vs}
 
 
+def _latent_step(lw, z, st, valid, pos, at, cfg):
+    """One query a row against the latents, the up-projections ABSORBED:
+    ``q_lat = q_nope W_uk^T`` a head, scores ``[q_lat | q_rope] . [c_kv |
+    k_rope]`` over the cache as it lies (one product 576 wide),
+    ``o_lat = p c_kv``, ``o = o_lat W_uv``. No key or value of any head
+    is ever laid out over the cache."""
+    dt = jnp.dtype(cfg.dtype)
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q, kv = _latent_project(lw, z, pos, cfg)   # [heads, B, 192], [B, 576]
+    cache = jax.lax.dynamic_update_slice_in_dim(st["kv"], kv[:, None], at, 1)
+    w_uk, w_uv = _latent_up(lw, cfg)
+    q_lat = jnp.einsum("nbd,cnd->bnc", q[..., :dn], w_uk,
+                       preferred_element_type=jnp.float32).astype(dt)
+    qs = jnp.concatenate([q_lat, q[..., dn:].swapaxes(0, 1)], axis=-1)
+    s = jnp.einsum("bnc,bsc->bns", qs, cache,
+                   preferred_element_type=jnp.float32) * cfg.latent_scale
+    s = jnp.where(valid[:, None, :], s, -jnp.inf)
+    # over the cache's whole width, the rotated key's columns dropped
+    # after: a slice of the cache itself would be a copy of it a step
+    o_lat = jnp.einsum("bns,bsc->bnc",
+                       jax.nn.softmax(s, axis=-1).astype(dt), cache,
+                       preferred_element_type=jnp.float32)[..., :rkv]
+    o = jnp.einsum("bnc,cnd->nbd", o_lat.astype(dt), w_uv,
+                   preferred_element_type=jnp.float32)
+    return _latent_out(lw, o), {"kv": cache}
+
+
 def _layer_step(lw, l, x, st, valid, pos, at, cfg):
-    z = _rms(x, lw["op_norm"], cfg.norm_eps)
-    if cfg.layer_types[l] == CONV:
-        o, st = _conv_step(lw, z, st, cfg)
-    else:
-        o, st = _attention_step(lw, z, st, valid, pos, at, l, cfg)
-    h = x + o
-    f, load = _feed_forward(lw, _rms(h, lw["ff_norm"], cfg.norm_eps),
-                            None, cfg)
-    return h + f, st, load
+    def op(z):
+        if cfg.layer_types[l] == CONV:
+            return _conv_step(lw, z, st, cfg)
+        if cfg.layer_types[l] == LATENT:
+            return _latent_step(lw, z, st, valid, pos, at, cfg)
+        return _attention_step(lw, z, st, valid, pos, at, l, cfg)
+
+    h, st, _ = _sub_block(lw, "op", x, op, cfg)
+    x, load, _ = _sub_block(
+        lw, "ff", h, lambda z: _feed_forward(lw, z, None, cfg), cfg)
+    return x, st, load
 
 
 def _decode_step(w, state, tok, cfg):
@@ -675,7 +998,8 @@ def _decode_step(w, state, tok, cfg):
     at, pos = state["filled"], state["pos"]
     valid = jax.lax.dynamic_update_slice_in_dim(
         state["valid"], jnp.ones((tok.shape[0], 1), bool), at, 1)
-    x = jnp.take(w["embed"], tok, axis=0).astype(jnp.float32)
+    x = _streams_in(
+        jnp.take(w["embed"], tok, axis=0).astype(jnp.float32), cfg)
     states, loads = [], []
     for l, (lw, st) in enumerate(zip(w["layers"], state["layers"])):
         x, st, load = _layer_step(lw, l, x, st, valid, pos, at, cfg)
@@ -684,7 +1008,7 @@ def _decode_step(w, state, tok, cfg):
             loads.append(load)
     new = {**state, "layers": states, "valid": valid, "filled": at + 1,
            "pos": pos + 1}
-    return _head(w, x, cfg), new, jnp.stack(loads)
+    return _head(w, _streams_out(x, cfg), cfg), new, jnp.stack(loads)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "steps"),

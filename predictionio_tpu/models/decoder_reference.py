@@ -1,7 +1,7 @@
 """The plain reference of ``models/decoder.py``: the full forward pass of
 the block stack in straightforward ``jax.numpy``, float32, at
 ``highest`` matmul precision, one sequence at a time. No cache, no
-batching, no padding, no kernels; the experts one after the other. Two
+batching, no padding, no kernels; the experts one after the other. Three
 families' equations, told apart by the keys a configuration has.
 
 ``cfg`` is the published ``config.json`` as a dict (plus ``head_dim``
@@ -48,17 +48,48 @@ The equations (``H`` hidden size, ``n`` RMSNorm with ``norm_eps`` or
   feed-forward that every token takes at weight 1.
 - ``logits = n_out(x) E^T`` with the embedding ``E`` (tied), or with
   the head's own matrix where ``tie_word_embeddings`` is false.
+- ``latent_attention`` (a configuration with ``kv_lora_rank``; every
+  layer), in the EXPANDED form: ``c_q = n_768(z W_qa)``; ``[q_nope |
+  q_rope] = c_q W_qb`` a head; ``[c | r] = z W_kva``; ``c_kv =
+  n_512(c)``; ``k_rope = rope(r)``, ONE for all heads; ``q_rope =
+  rope(q_rope)``; ``[k_nope | v] = c_kv W_kvb`` a head; scores ``[q_nope
+  | q_rope] . [k_nope | k_rope]`` at scale ``(nope + rope)^-0.5 m^2``
+  with ``m = 0.1 mscale_all_dim ln(factor) + 1`` (``rope_scaling``,
+  yarn; cos and sin take ``yarn(mscale) / yarn(mscale_all_dim)``);
+  causal softmax; ``out = concat(p v) W_o``. Rotary is rotate-half over
+  the ``rope`` dimensions with yarn's blended frequencies.
+- hyper-connections (``hc_mult = n`` over 1), around BOTH sub-blocks of
+  a layer: the stream is ``X [n, H]`` a token (the embedding ``n``
+  times at the entry, the ``n`` streams summed before ``n_out``). A
+  sub-block ``F`` has ``phi_pre``, ``phi_post [nH, n]``, ``phi_res [nH,
+  n n]``, biases ``b_pre``, ``b_post [n]``, ``b_res [n, n]`` and scalars
+  ``a``: ``x~ = vec(X) / sqrt(mean(vec(X)^2) + hc_eps)``; ``H_pre =
+  sigmoid(a_pre x~ phi_pre + b_pre)``; ``H_post = 2 sigmoid(a_post x~
+  phi_post + b_post)``; ``M = exp(clip(a_res mat(x~ phi_res) + b_res,
+  mhc_h_res_clamp_min, mhc_h_res_clamp_max))``, then
+  ``hc_sinkhorn_iters`` times its rows over (their sums + ``hc_eps``),
+  then its columns likewise: ``H_res``. ``u = H_pre X``; ``y = F(n(u))``;
+  ``X' = H_res X + H_post^T y``.
 
 Departures from the published implementations, all listed in the
 benchmark configurations' ``assumed``. ``lfm2_moe``: ``head_dim = hidden
 / heads`` (the source gives null), the tied head, a conv kernel exactly
 ``conv_L_cache`` wide. ``laguna``: three conventions its config does not
 spell out, each ONE named argument below so that the other reading is a
-one-line change: ``head_gate="scalar"`` (one sigmoid scalar a head;
+one-line change (a third family follows below):
+``head_gate="scalar"`` (one sigmoid scalar a head;
 ``"wide"``: one a channel, ``W_g [H, heads D]``), ``qk_norm=True``
 (RMSNorm over each head of ``q`` and ``k`` before rotary) and
 ``scores="sigmoid"`` (with the selection bias and the normalised top
-``k``; ``"softmax"``: softmax over the experts). Both: seeded weights in
+``k``; ``"softmax"``: softmax over the experts). ``xing4_0`` (latent
+attention and hyper-connections): the ``n`` copies at the entry and the
+sum at the exit; no gain in the stream's own norm (``phi`` holds it); a
+Sinkhorn pass is the row step, then the column step; ``hc_eps`` both
+under the norm's root and in the divisions; rotate-half inside the 64
+rotated dimensions (the published interleaving is a permutation of
+seeded columns); the routing's ``1e-6`` where the family writes
+``1e-20``; the next-next-token module (``num_nextn_predict_layers``) is
+not part of the forward pass. All: seeded weights in
 place of trained ones. ``cellbench/reference_lfm2.py`` and
 ``cellbench/reference_laguna.py`` are the benchmark's copies;
 ``tests/test_decoder.py`` holds them to identical outputs.
@@ -91,7 +122,24 @@ def _heads(cfg, l):
 def _is_dense(cfg, l):
     kinds = cfg.get("mlp_layer_types")
     return kinds[l] == "dense" if kinds \
-        else l < int(cfg["num_dense_layers"])
+        else l < int(cfg["num_dense_layers"] if "num_dense_layers" in cfg
+                     else cfg["first_k_dense_replace"])
+
+
+def _kind(cfg, l):
+    kinds = cfg.get("layer_types")
+    return kinds[l] if kinds else "latent_attention"
+
+
+def _n_experts(cfg):
+    return int(cfg["num_experts"] if "num_experts" in cfg
+               else cfg["n_routed_experts"])
+
+
+def _shared_width(cfg):
+    return int(cfg.get("shared_expert_intermediate_size")
+               or int(cfg.get("n_shared_experts") or 0)
+               * int(cfg["moe_intermediate_size"]))
 
 
 def rms(x, gain, eps):
@@ -213,6 +261,106 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
     return o.reshape(T, nq * D) @ _f(lw["wo"])
 
 
+def yarn_mscale(scaling, key):
+    """``0.1 scaling[key] ln(factor) + 1`` (yarn, a factor over 1)."""
+    factor = float(scaling.get("factor", 1.0))
+    if scaling.get("type") != "yarn" or factor <= 1:
+        return 1.0
+    # ptpu: allow[unguarded-domain] — factor is over 1 here
+    return 0.1 * float(scaling.get(key, 1.0)) * math.log(factor) + 1.0
+
+
+def latent_attention_op(lw, z, cfg, *, yarn_scale=True, query_block=None):
+    """Latent attention over one sequence ``z [T, H]``, EXPANDED: every
+    head's keys and values are laid out from the latents. ``yarn_scale
+    =False`` leaves ``m^2`` off the softmax scale (what a test shows to
+    differ). ``query_block``: queries that many at a time (the same
+    numbers; scores ``[heads, block, T]`` and never ``[heads, T, T]``)."""
+    T = z.shape[0]
+    nq = int(cfg["num_attention_heads"])
+    dn, dr, dv = (int(cfg[k]) for k in ("qk_nope_head_dim",
+                                        "qk_rope_head_dim", "v_head_dim"))
+    rkv = int(cfg["kv_lora_rank"])
+    scaling = dict(cfg.get("rope_scaling") or {})
+    rope = {**scaling, "rope_theta": cfg["rope_theta"],
+            "rope_type": scaling.get("type", "default"),
+            # ptpu: allow[unguarded-domain] — yarn_mscale is 1 or more
+            "attention_factor": yarn_mscale(scaling, "mscale")
+            / yarn_mscale(scaling, "mscale_all_dim")}
+    cq = rms(z @ _f(lw["w_qa"]), lw["q_a_norm"], _eps(cfg))
+    q = (cq @ _f(lw["w_qb"])).reshape(T, nq, dn + dr)
+    kv = z @ _f(lw["w_kva"])
+    c_kv = rms(kv[:, :rkv], lw["kv_a_norm"], _eps(cfg))
+    k_rope = rotary(kv[:, None, rkv:], rope)          # [T, 1, rope]
+    q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:], rope)], axis=-1)
+    up = (c_kv @ _f(lw["w_kvb"])).reshape(T, nq, dn + dv)
+    k = jnp.concatenate([up[..., :dn],
+                         jnp.broadcast_to(k_rope, (T, nq, dr))], axis=-1)
+    v = up[..., dn:]
+    m = yarn_mscale(scaling, "mscale_all_dim") if yarn_scale else 1.0
+    scale = (dn + dr) ** -0.5 * m * m
+    at = jnp.arange(T)
+
+    def attend(qs, i):  # queries ``qs [n, heads, D]`` at positions ``i``
+        s = jnp.einsum("qhd,khd->hqk", qs, k) * scale
+        p = jax.nn.softmax(
+            jnp.where((at[None, :] <= i[:, None])[None], s, -jnp.inf),
+            axis=-1)
+        return jnp.einsum("hqk,khd->qhd", p, v)
+
+    if query_block is None:
+        o = attend(q, at)
+    else:
+        bq = int(query_block)
+        pad = -T % bq
+        o = jax.lax.map(
+            lambda a: attend(*a),
+            (jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(
+                -1, bq, nq, dn + dr),
+             jnp.arange(T + pad).reshape(-1, bq))
+        ).reshape(T + pad, nq, dv)[:T]
+    return o.reshape(T, nq * dv) @ _f(lw["wo"])
+
+
+def hyper_coefficients(lw, sub, X, cfg, *, iters=None):
+    """``(H_pre [T, n], H_post [T, n], H_res [T, n, n])`` of sub-block
+    ``sub`` (``op`` or ``ff``) from the streams ``X [T, n, H]``;
+    ``iters``: Sinkhorn passes (``hc_sinkhorn_iters``)."""
+    T, n, H = X.shape
+    eps = float(cfg["hc_eps"])
+    flat = X.reshape(T, n * H)
+    xt = flat / jnp.sqrt(jnp.mean(flat * flat, axis=-1, keepdims=True) + eps)
+    a = _f(lw[f"hc_{sub}_a"])
+    pre = jax.nn.sigmoid(a[0] * (xt @ _f(lw[f"hc_{sub}_phi_pre"]))
+                         + _f(lw[f"hc_{sub}_b_pre"]))
+    post = 2.0 * jax.nn.sigmoid(a[1] * (xt @ _f(lw[f"hc_{sub}_phi_post"]))
+                                + _f(lw[f"hc_{sub}_b_post"]))
+    m = jnp.exp(jnp.clip(
+        a[2] * (xt @ _f(lw[f"hc_{sub}_phi_res"])).reshape(T, n, n)
+        + _f(lw[f"hc_{sub}_b_res"]),
+        float(cfg["mhc_h_res_clamp_min"]), float(cfg["mhc_h_res_clamp_max"])))
+    for _ in range(int(cfg["hc_sinkhorn_iters"] if iters is None else iters)):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)   # rows
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)   # columns
+    return pre, post, m
+
+
+def hyper_connection(lw, sub, X, fn, cfg, *, iters=None):
+    """``X' = H_res X + H_post^T fn(H_pre X)`` over ``X [T, n, H]``."""
+    pre, post, res = hyper_coefficients(lw, sub, X, cfg, iters=iters)
+    y = fn(jnp.einsum("tn,tnh->th", pre, X))
+    return jnp.einsum("tij,tjh->tih", res, X) \
+        + post[:, :, None] * y[:, None, :]
+
+
+def _around(lw, sub, x, fn, cfg, iters=None):
+    """A sub-block on the residual path: a plain sum, or the ``n``
+    streams' read, write and mix."""
+    if int(cfg.get("hc_mult") or 1) == 1:
+        return x + fn(x)
+    return hyper_connection(lw, sub, x, fn, cfg, iters=iters)
+
+
 def dense_ff(lw, z, names=("w1", "w3", "w2")):
     w1, w3, w2 = (_f(lw[n]) for n in names)
     return (jax.nn.silu(z @ w1) * (z @ w3)) @ w2
@@ -221,11 +369,12 @@ def dense_ff(lw, z, names=("w1", "w3", "w2")):
 def route(lw, z, cfg, *, scores="sigmoid"):
     """The dense ``[T, E]`` matrix of routing weights (zero where an
     expert is not selected)."""
-    E, k = int(cfg["num_experts"]), int(cfg["num_experts_per_tok"])
+    E, k = _n_experts(cfg), int(cfg["num_experts_per_tok"])
     logits = z @ _f(lw["gate"])
     s = jax.nn.sigmoid(logits) if scores == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    pick = s + _f(lw["gate_bias"]) if cfg.get("use_expert_bias") else s
+    biased = cfg.get("use_expert_bias", cfg.get("topk_method") == "noaux_tc")
+    pick = s + _f(lw["gate_bias"]) if biased else s
     _, sel = jax.lax.top_k(pick, k)
     w = jnp.take_along_axis(s, sel, axis=-1)
     if cfg.get("norm_topk_prob"):
@@ -241,7 +390,7 @@ def expert_ff(lw, z, cfg, *, scores="sigmoid"):
     sees once: ``lax.scan`` over the expert axis): ``lw['w1'][i]`` is
     expert ``held[i]``'s. The ROUTED experts only: the shared one is
     added by :func:`feed_forward`."""
-    held = cfg.get("experts_held") or range(int(cfg["num_experts"]))
+    held = cfg.get("experts_held") or range(_n_experts(cfg))
     weights = route(lw, z, cfg, scores=scores)[:, jnp.asarray(list(held))]
 
     def one(out, expert):
@@ -254,27 +403,36 @@ def expert_ff(lw, z, cfg, *, scores="sigmoid"):
     return out
 
 
-def operator(lw, l, x, cfg, **how):
-    """``h = x + op_l(n_op(x))`` over one sequence ``x [T, H]``; ``how``
-    goes to :func:`attention_op`."""
+def operator(lw, l, x, cfg, *, sinkhorn_iters=None, **how):
+    """``h = x + op_l(n_op(x))`` over one sequence ``x [T, H]`` (``[T, n,
+    H]`` and the hyper-connection under ``hc_mult``); ``how`` goes to
+    the attention (:func:`attention_op`, :func:`latent_attention_op`)."""
+    def op(u):
+        z = rms(u, lw["op_norm"], _eps(cfg))
+        if _kind(cfg, l) == "conv":
+            return conv_op(lw, z, cfg)
+        if _kind(cfg, l) == "latent_attention":
+            return latent_attention_op(lw, z, cfg, **how)
+        return attention_op(lw, z, cfg, l, **how)
+
     with jax.default_matmul_precision("highest"):
-        z = rms(x, lw["op_norm"], _eps(cfg))
-        if cfg["layer_types"][l] == "conv":
-            return x + conv_op(lw, z, cfg)
-        return x + attention_op(lw, z, cfg, l, **how)
+        return _around(lw, "op", x, op, cfg, sinkhorn_iters)
 
 
-def feed_forward(lw, l, h, cfg, *, scores="sigmoid"):
-    """``y = h + ff_l(n_ff(h))`` over tokens ``h [T, H]``; every token
-    on its own."""
-    with jax.default_matmul_precision("highest"):
-        z = rms(h, lw["ff_norm"], _eps(cfg))
+def feed_forward(lw, l, h, cfg, *, scores="sigmoid", sinkhorn_iters=None):
+    """``y = h + ff_l(n_ff(h))`` over tokens ``h [T, H]`` (``[T, n, H]``
+    under ``hc_mult``); every token on its own."""
+    def ff(u):
+        z = rms(u, lw["ff_norm"], _eps(cfg))
         if _is_dense(cfg, l):
-            return h + dense_ff(lw, z)
-        ff = expert_ff(lw, z, cfg, scores=scores)
-        if cfg.get("shared_expert_intermediate_size"):
-            ff = ff + dense_ff(lw, z, ("s1", "s3", "s2"))
-        return h + ff
+            return dense_ff(lw, z)
+        out = expert_ff(lw, z, cfg, scores=scores)
+        if _shared_width(cfg):
+            out = out + dense_ff(lw, z, ("s1", "s3", "s2"))
+        return out
+
+    with jax.default_matmul_precision("highest"):
+        return _around(lw, "ff", h, ff, cfg, sinkhorn_iters)
 
 
 def layer(lw, l, x, cfg):
@@ -286,6 +444,17 @@ def embed(weights, tokens):
     return _f(weights["embed"])[jnp.asarray(tokens)]
 
 
+def streams_in(x, cfg):
+    """``x [T, H]`` as the residual path takes it: ``hc_mult`` copies."""
+    n = int(cfg.get("hc_mult") or 1)
+    return x if n == 1 else jnp.repeat(x[:, None, :], n, axis=1)
+
+
+def streams_out(x, cfg):
+    """What the head reads: the ``hc_mult`` streams summed."""
+    return x if int(cfg.get("hc_mult") or 1) == 1 else jnp.sum(x, axis=-2)
+
+
 def head(weights, x, cfg):
     table = weights["embed"] if cfg.get("tie_word_embeddings", True) \
         else weights["head"]
@@ -295,7 +464,7 @@ def head(weights, x, cfg):
 
 def forward(weights, tokens, cfg):
     """Logits ``[T, V]`` of one sequence of token ids."""
-    x = embed(weights, tokens)
+    x = streams_in(embed(weights, tokens), cfg)
     for l, lw in enumerate(weights["layers"]):
         x = layer(lw, l, x, cfg)
-    return head(weights, x, cfg)
+    return head(weights, streams_out(x, cfg), cfg)

@@ -1,15 +1,17 @@
 """Blockwise causal attention over right-aligned rows, with an optional
 sliding window: the generative prefill's attention (``models/decoder.py``).
 
-``q [Hq, B, L, D]``, ``k`` / ``v [Hkv, B, L, D]`` (heads first, as a
-projection by head makes them) hold ``B`` rows of up to ``L`` tokens,
+``q [Hq, B, L, D]``, ``k [Hkv, B, L, D]``, ``v [Hkv, B, L, Dv]`` (heads
+first, as a projection by head makes them; ``Dv`` is ``D`` in the
+grouped-query families and 128 beside a ``D`` of 192 under latent
+attention) hold ``B`` rows of up to ``L`` tokens,
 RIGHT-aligned: row ``b``'s tokens lie in slots
 ``lead[b] .. L-1`` (the layout the decode's cache keeps), so a slot's
 index is its position plus ``lead[b]`` and causal order is slot order.
 Query slot ``i`` sees key slots ``max(lead, i - window + 1) .. i``
 (``window`` ``None``: all from ``lead``). Query head ``h`` reads
 key-value head ``h // (Hq / Hkv)`` through the block index, so ``k`` and
-``v`` are never repeated in memory. Returns ``o [Hq, B, L, D]`` in
+``v`` are never repeated in memory. Returns ``o [Hq, B, L, Dv]`` in
 ``q``'s dtype; slots before ``lead[b]`` hold nothing defined (blocks of
 pad slots are not computed and not written). What ``k`` and ``v`` hold
 before ``lead[b]`` weighs exactly 0 as long as it is finite.
@@ -147,7 +149,7 @@ def window_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """See the module's docstring. ``L`` must divide by ``block`` (which
     is held to ``L``); ``lead [B]`` int32, each under ``L``."""
     Hq, B, L, D = q.shape
-    Hkv = k.shape[0]
+    Hkv, Dv = k.shape[0], v.shape[-1]
     if Hq % Hkv:
         raise ValueError("query heads must divide by key-value heads")
     block = min(block, L)
@@ -169,19 +171,19 @@ def window_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kb = _first_key_block(ii, lead[b], block, window) + j
         return h // g, b, jnp.minimum(kb, ii), 0
 
-    tile = (None, None, block, D)
+    tile, v_tile = (None, None, block, D), (None, None, block, Dv)
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, block=block,
                           steps=steps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hq, n, steps),
             in_specs=[pl.BlockSpec(tile, q_map), pl.BlockSpec(tile, kv_map),
-                      pl.BlockSpec(tile, kv_map)],
-            out_specs=pl.BlockSpec(tile, q_map),
+                      pl.BlockSpec(v_tile, kv_map)],
+            out_specs=pl.BlockSpec(v_tile, q_map),
             scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, 1), jnp.float32),
-                            pltpu.VMEM((block, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+                            pltpu.VMEM((block, Dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape[:-1] + (Dv,), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),

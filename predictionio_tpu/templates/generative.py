@@ -46,6 +46,9 @@ from ..utils.tracing import annotate
 from .sequential import ItemScore, PredictedResult
 
 IMBALANCE_BOUNDS = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0)
+#: |row sum - 1| of a Sinkhorn-normalised mix: float32's rounding at the
+#: low end, what a single pass leaves at the high one
+SINKHORN_GAP_BOUNDS = (1e-7, 1e-6, 1e-5, 1e-4, 1e-3, 1e-2, 0.1, 1.0)
 
 
 @dataclass(frozen=True)
@@ -142,12 +145,13 @@ def _bucket(buckets: Sequence[int], n: int) -> int:
 
 def _state_bytes(cfg, state) -> Dict[str, int]:
     """Bytes of a batch's per-sequence state by kind, from the shapes
-    (no sync): ``full``, ``window``, ``conv``."""
-    from ..models.decoder import ATTENTION, CONV
+    (no sync): ``full``, ``window``, ``conv``, ``latent``."""
+    from ..models.decoder import ATTENTION, CONV, LATENT
 
     out: Dict[str, int] = {}
     for kind, st in zip(cfg.layer_types, state["layers"]):
-        name = {ATTENTION: "full", CONV: "conv"}.get(kind, "window")
+        name = {ATTENTION: "full", CONV: "conv",
+                LATENT: "latent"}.get(kind, "window")
         out[name] = out.get(name, 0) + sum(a.nbytes for a in st.values())
     return out
 
@@ -160,7 +164,7 @@ class GenerativeAlgorithm(Algorithm):
     def __init__(self, params: GenerativeParams = GenerativeParams()):
         self.params = params
         self._tokens = self._touched = self._read = self._imbalance = None
-        self._state_bytes = None
+        self._state_bytes = self._sinkhorn_gap = None
 
     def train(self, ctx: Context, td: TrainingData) -> GenerativeModel:
         return GenerativeModel(config=dict(self.params.model),
@@ -182,8 +186,8 @@ class GenerativeAlgorithm(Algorithm):
             "Token slots of generative batches by kind: prompt (real "
             "history tokens), pad (the rest of the slots the prefill ran), "
             "generated")
-        bounds = experts_touched_bounds(
-            self.params.model.get("num_experts", 32))
+        bounds = experts_touched_bounds(self.params.model.get(
+            "num_experts", self.params.model.get("n_routed_experts", 32)))
         self._touched = registry.histogram(
             "pio_moe_experts_touched",
             "Distinct experts a decode step's rows selected, mean over "
@@ -204,7 +208,14 @@ class GenerativeAlgorithm(Algorithm):
             "Bytes of per-sequence state the last batch carried from its "
             "prefill to its decode, by kind: full (keys and values that "
             "grow), window (rings of sliding_window), conv (windows of "
-            "conv_L_cache)")
+            "conv_L_cache), latent (normalised latents beside their rotated "
+            "shared key)")
+        self._sinkhorn_gap = registry.histogram(
+            "pio_mhc_sinkhorn_gap",
+            "Largest |row sum - 1| of any hyper-connection mixing matrix "
+            "over the real tokens of a batch's prefill: what the Sinkhorn "
+            "passes left (models with hc_mult over 1 only)",
+            bounds=SINKHORN_GAP_BOUNDS)
 
     def _history(self, model: GenerativeModel, query: Query) -> List[int]:
         vocab = int(model.config["vocab_size"])
@@ -242,17 +253,26 @@ class GenerativeAlgorithm(Algorithm):
         if self._state_bytes is not None:  # once a batch, from shapes
             for kind, nbytes in _state_bytes(cfg, state).items():
                 self._state_bytes.labels(kind=kind).set(nbytes)
+        # a scalar beside the state, not of it: the decode is not given
+        # it, and it rides behind the answer's arrays where there is one
+        gap = state.pop("sinkhorn_gap", None)
         with annotate("pio:gen_decode", rows=B, steps=p.max_new):
             toks, scores, load, _ = _gen_decode(
                 model.weights, state, first, cfg=cfg, steps=p.max_new)
-        return (toks, scores, load), T
+        return (toks, scores, load) + (() if gap is None else (gap,)), T
 
-    def _observe(self, cfg, hists, rows: int, slots: int, load) -> None:
+    def _observe(self, cfg, hists, rows: int, slots: int, load,
+                 gap=None) -> None:
         """Once a batch, never per query: its queries' histories, the
-        ``rows`` its decode ran and the ``slots`` its prefill ran."""
+        ``rows`` its decode ran, the ``slots`` its prefill ran and the
+        ``gap`` its Sinkhorn passes left (``None``: one residual
+        stream)."""
         if self._tokens is None:
             return
         from ..ops import moe
+
+        if gap is not None:
+            self._sinkhorn_gap.observe(float(gap))
 
         prompt = sum(len(h) for h in hists)
         self._tokens.labels(kind="prompt").inc(prompt)
@@ -308,9 +328,9 @@ class GenerativeAlgorithm(Algorithm):
 
         def resolve() -> List[PredictedResult]:
             for chunk, arrays, slots in pending:
-                toks, scores, load = jax.device_get(arrays)
+                toks, scores, load, *gap = jax.device_get(arrays)
                 self._observe(model.cfg, [hists[i] for i in chunk],
-                              len(toks), slots, load)
+                              len(toks), slots, load, *gap)
                 for row, i in enumerate(chunk):
                     n = min(max(queries[i].num, 0), self.params.max_new)
                     out[i] = PredictedResult(tuple(

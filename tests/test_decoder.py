@@ -63,6 +63,33 @@ LAGUNA = {
     "num_attention_heads_per_layer": [4, 6, 6, 6, 4],
     "norm_topk_prob": True, "use_expert_bias": True, "dtype": "float32",
 }
+#: the ``xing4_0`` family at a small size, by its published key names:
+#: latent attention (ranks 24 / 16, a head 16 + 8 wide against values
+#: 16 wide, yarn whose correction range falls inside the 4 rotated
+#: frequencies), four residual streams under hyper-connections, two
+#: dense layers and two of 8 experts (2 a token) with a shared one
+XING = {
+    "model_type": "xing4_0", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_hidden_layers": 4, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "first_k_dense_replace": 2, "moe_layer_freq": 1,
+    "n_routed_experts": 8, "num_experts_per_tok": 2,
+    "n_shared_experts": 1, "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 2,
+    "rms_norm_eps": 1e-6, "rope_theta": 10000,
+    "rope_scaling": {"type": "yarn", "factor": 64,
+                     "original_max_position_embeddings": 16,
+                     "beta_fast": 4, "beta_slow": 1, "mscale": 1,
+                     "mscale_all_dim": 1},
+    "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+    "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+    "tie_word_embeddings": False, "attention_bias": False, "ep_size": 1,
+    "hidden_act": "silu", "num_nextn_predict_layers": 1,
+    "max_position_embeddings": 262144, "dtype": "float32",
+}
 #: attention outputs and expert blocks both a visible share of the
 #: stream, as the benchmark's configuration sets them
 LAGUNA_INIT = {"op_out": 4.0, "expert_out": 1.0}
@@ -150,6 +177,15 @@ def lag():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(decoder, "ATTENTION_BLOCK", 8)
         yield _setup(base=LAGUNA, init=LAGUNA_INIT)
+
+
+@pytest.fixture(scope="module")
+def xing():
+    """The ``xing4_0`` family at a small size, its prefill's attention
+    in tiles of 8: histories to 32 cross four of them."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(decoder, "ATTENTION_BLOCK", 8)
+        yield _setup(base=XING, init=LAGUNA_INIT)
 
 
 LONG = 21  # the prefill's token and 20 steps: a ring of 8 wraps twice
@@ -623,21 +659,25 @@ def test_rows_behind_the_last_group_cannot_leak(garbage):
 
 
 @pytest.mark.parametrize("tokens,family", [
-    (24, "lfm2"), (384, "lfm2"), (24, "laguna"), (384, "laguna")])
-def test_four_shares_add_up_to_the_uncut_layer(small, lag, tokens, family):
+    (24, "lfm2"), (384, "lfm2"), (24, "laguna"), (384, "laguna"),
+    (24, "xing"), (384, "xing")])
+def test_four_shares_add_up_to_the_uncut_layer(small, lag, xing, tokens,
+                                               family):
     """The guide's share test: four chips of 2 experts each route over
     all 8 and compute their own experts' part; the parts add up to the
     reference's whole layer (program AND reference given the shares),
     in the few-token form and in the many-token one. What every chip
     computes alike, the ``laguna`` family's shared expert, is counted
-    ONCE: each share's output holds it whole."""
-    d, cfg, w = lag if family == "laguna" else small
+    ONCE: each share's output holds it whole. The ``xing4_0`` family
+    likewise (its 8 experts by their published key ``n_routed_experts``,
+    scaled by 2, and its shared expert)."""
+    d, cfg, w = {"laguna": lag, "xing": xing}.get(family, small)
     lw = w["layers"][3]
     z = jax.random.normal(jax.random.key(6), (tokens, cfg.hidden_size))
     whole = np.asarray(ref.expert_ff(lw, z, d))
     alike = np.asarray(ref.dense_ff(lw, z, ("s1", "s3", "s2"))) \
-        if family == "laguna" else 0.0
-    if family == "laguna":
+        if family != "lfm2" else 0.0
+    if family != "lfm2":
         assert np.abs(alike).mean() > 0.1 * np.abs(whole).mean()
     full, _ = decoder._feed_forward(lw, z, None, cfg)
     # float32 both sides, outputs up to 7: the largest difference read is

@@ -326,6 +326,47 @@ def test_long_histories_through_an_untied_head(lengths, slots):
                                             pytest.approx(touched["sum"]))
 
 
+# -- the ``xing4_0`` family through the same engine -------------------------
+
+def test_four_streams_and_a_latent_cache_through_the_same_engine():
+    """Latent attention inside four residual streams, by the family's
+    published keys: the answer's first item is the reference's greedy
+    choice, the state's bytes read the latents, the Sinkhorn passes'
+    gap is observed once a batch and rides BEHIND the three arrays of
+    an answer (a one-stream model's dispatch returns three, as ever)."""
+    import numpy as np
+
+    from predictionio_tpu.models import decoder_reference as ref
+    from predictionio_tpu.obs.registry import MetricsRegistry
+    from test_decoder import XING
+
+    toy = {**XING, "num_hidden_layers": 2, "first_k_dense_replace": 1}
+    params = GenerativeParams(model=toy, seed=5, max_new=4,
+                              row_buckets=(2,), history_buckets=(16, 32))
+    algo = GenerativeAlgorithm(params)
+    registry = MetricsRegistry()
+    algo.register_metrics(registry)
+    model = GenerativeModel(config=toy, seed=5).materialise()
+    hists = [[(11 * r + 3 * t) % 256 for t in range(n)]
+             for r, n in enumerate([9, 30])]
+    arrays, ran = algo._dispatch(model, hists)
+    assert ran == 2 * 32 and len(arrays) == 4 and arrays[3].shape == ()
+    out = algo.batch_predict(model, [Query(items=_query(h)["items"], num=4)
+                                     for h in hists])
+    logits = np.asarray(ref.forward(model.weights, hists[1], toy))[-1]
+    first = out[1].item_scores[0]
+    assert first.item == f"i{int(logits.argmax())}"
+    assert first.score == pytest.approx(float(logits.max()), abs=2e-4)
+    kinds = {c["labels"]["kind"]: c["value"] for c in
+             registry.export()["pio_gen_state_bytes"]["children"]}
+    # float32 here: 2 layers x 2 rows x (32 + 4) slots x (16 + 8) x 4 bytes
+    assert kinds == {"latent": 2 * 2 * (32 + 4) * (16 + 8) * 4}
+    gap = registry.export()["pio_mhc_sinkhorn_gap"]["children"][0]
+    assert gap["count"] == 1 and 0 < gap["sum"] < 5e-3
+    touched = registry.export()["pio_moe_experts_touched"]["children"][0]
+    assert [b[0] for b in touched["buckets"]][-2:] == [8.0, "+Inf"]
+
+
 def test_experts_touched_bounds_follow_the_model():
     from predictionio_tpu.templates.generative import (
         experts_touched_bounds)

@@ -1,4 +1,4 @@
-"""The decode's Pallas kernel compiled at the benchmark's widths for a
+"""The Pallas kernels compiled at the benchmark's widths for a
 DESCRIBED TPU v5e (the chip's compiler is installed here; nothing
 runs): what Pallas' interpreter cannot refuse, the chip's compiler
 does here and not on the chip: a block that does not fit the tiling,
@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from predictionio_tpu.ops import moe
+from predictionio_tpu.ops import moe, window_attention as wa
 
 
 @pytest.fixture(scope="module")
@@ -29,6 +29,7 @@ def for_the_chip(monkeypatch):
     """The kernel asks the backend whether to run interpreted; here the
     backend is the CPU and the target is not."""
     monkeypatch.setattr(moe, "_interpreted", lambda: False)
+    monkeypatch.setattr(wa, "_interpreted", lambda: False)
 
 
 def _compiled(fn, one_chip, *shapes):
@@ -41,7 +42,8 @@ def _compiled(fn, one_chip, *shapes):
     (16, 8, 256, 2048, 512, 1),    # the laguna cell's decode step
     (1, 8, 256, 2048, 512, 1),     # one row, padded to a sublane tile
     (8, 4, 32, 2048, 1792, 7),     # lfm2_moe's experts: 7 tiles of 256
-], ids=["laguna-16x8", "laguna-1x8", "lfm2-8x4"])
+    (4, 4, 64, 3584, 1024, 4),     # the xing4 cell's step: 4 tiles of 256
+], ids=["laguna-16x8", "laguna-1x8", "lfm2-8x4", "xing4-4x4"])
 def test_the_touched_experts_kernel_compiles_at_the_cells_widths(
         one_chip, for_the_chip, rows, top_k, experts, width, inner, tiles):
     bf16 = jnp.bfloat16
@@ -54,3 +56,20 @@ def test_the_touched_experts_kernel_compiles_at_the_cells_widths(
         ((experts, width, inner), bf16), ((experts, inner, width), bf16))
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "touched_experts" in text
+
+
+def test_the_attention_kernel_compiles_with_values_narrower_than_keys(
+        one_chip, for_the_chip):
+    """Latent attention expanded, at the xing4 cell's widths: 32 heads
+    on both sides, queries and keys 192 wide (not a multiple of 128
+    lanes) against values 128 wide, 4 rows of 4,096 slots."""
+    import functools
+
+    bf16 = jnp.bfloat16
+    compiled = _compiled(
+        functools.partial(wa.window_attention.__wrapped__, scale=0.14468,
+                          window=None, block=wa.BLOCK),
+        one_chip, ((32, 4, 4096, 192), bf16), ((32, 4, 4096, 192), bf16),
+        ((32, 4, 4096, 128), bf16), ((4,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "window_attention" in text

@@ -14,7 +14,9 @@ import pytest
 from predictionio_tpu.ops.window_attention import (
     dense_attention, key_steps, window_attention)
 
-#: (rows, query heads, key-value heads, slots, head size, tile, window)
+#: (rows, query heads, key-value heads, slots, head size, tile, window[,
+#: value size, scale]): values as wide as the head and ``D ** -0.5``
+#: where the last two are left out
 CASES = {
     "full_four_tiles": (3, 4, 2, 32, 16, 8, None),
     "full_one_tile": (2, 2, 1, 16, 8, 16, None),
@@ -24,23 +26,30 @@ CASES = {
     "window_under_a_tile": (2, 8, 2, 32, 16, 16, 5),
     "window_of_one": (2, 2, 2, 16, 8, 8, 1),
     "window_over_the_row": (2, 4, 2, 16, 8, 8, 64),
+    # latent attention expanded: as many key-value heads as query heads,
+    # a head 16 + 8 wide against values 16 wide, a scale handed in
+    "values_narrower_than_keys": (3, 4, 4, 32, 24, 8, None, 16, 0.29),
+    "values_wider_than_keys": (2, 4, 2, 16, 8, 8, None, 24, 0.5),
+    "values_narrower_under_a_window": (2, 4, 4, 32, 24, 8, 12, 16, 0.29),
 }
 
 
 @pytest.mark.parametrize("leads", ["none", "ragged", "one_token"])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_the_kernel_is_dense_softmax_over_the_mask(case, leads):
-    B, Hq, Hkv, L, D, block, window = CASES[case]
+    B, Hq, Hkv, L, D, block, window = CASES[case][:7]
+    Dv, scale = (CASES[case][7:] or (D, D ** -0.5))
     rng = np.random.default_rng(len(case))
-    q, k, v = (jnp.asarray(rng.normal(size=(h, B, L, D)), jnp.float32)
-               for h in (Hq, Hkv, Hkv))
+    q, k, v = (jnp.asarray(rng.normal(size=(h, B, L, d)), jnp.float32)
+               for h, d in ((Hq, D), (Hkv, D), (Hkv, Dv)))
     lead = {"none": np.zeros(B), "one_token": np.full(B, L - 1),
             "ragged": rng.integers(0, L, B)}[leads].astype(np.int32)
     got = np.asarray(window_attention(q, k, v, jnp.asarray(lead),
-                                      scale=D ** -0.5, window=window,
+                                      scale=scale, window=window,
                                       block=block))
+    assert got.shape == (Hq, B, L, Dv)
     want = np.asarray(dense_attention(q, k, v, jnp.asarray(lead),
-                                      scale=D ** -0.5, window=window))
+                                      scale=scale, window=window))
     for b in range(B):  # slots before a row's first hold nothing defined
         np.testing.assert_allclose(got[:, b, lead[b]:], want[:, b, lead[b]:],
                                    atol=2e-6)
