@@ -35,7 +35,10 @@ the head) and each of a layer's two sub-blocks ``F`` computes, from a
 token's own streams, what it reads ``H_pre [n]``, writes ``H_post [n]``
 and mixes ``H_res [n, n]`` (``hc_sinkhorn_iters`` row-then-column
 normalisations of an exponential): ``u = H_pre X``, ``X' = H_res X +
-H_post^T F(n(u))`` (:func:`_hc_coefficients`).
+H_post^T F(n(u))`` (:func:`_hc_coefficients`). A prefill's streams go
+through two kernels a sub-block, one either side of ``F``
+(``ops/hyper_mix.py``: the streams read twice and written once); a
+decode step's few tokens through the same arithmetic as plain sums.
 
 Layout. The prefill takes a batch PACKED: the real tokens of its rows
 one behind the other in one stream of ``T`` slots (``tokens [T]``,
@@ -96,7 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import moe
+from ..ops import hyper_mix, moe
 from ..ops.window_attention import BLOCK as ATTENTION_BLOCK, window_attention
 
 CONV, ATTENTION, SLIDING = "conv", "full_attention", "sliding_attention"
@@ -670,27 +673,53 @@ def _hc_coefficients(lw, sub, x, cfg):
     return pre, post, res
 
 
+def _hc_columns(lw, sub, n):
+    """The weights of :func:`_hc_coefficients` a column of the projection
+    at a time, as ``ops/hyper_mix.py`` takes them: ``(phi [n H, c], a
+    [c], b [c])``, the columns ``pre``, ``post``, then ``res`` row by
+    row."""
+    a = lw[f"hc_{sub}_a"]
+    return (jnp.concatenate([lw[f"hc_{sub}_phi_{k}"]
+                             for k in ("pre", "post", "res")], axis=1),
+            a[np.repeat(np.arange(3), (n, n, n * n))],
+            jnp.concatenate([lw[f"hc_{sub}_b_{k}"].reshape(-1)
+                             for k in ("pre", "post", "res")]))
+
+
 def _sub_block(lw, sub, x, fn, cfg, valid=None):
     """One sub-block around the residual path: ``(x', what fn returns
     beside its output, gap)``. ``fn`` takes the normalised input ``[T,
     H]`` and returns ``(out [T, H], aux)``. One stream (``hc_mult`` 1; a
     branch taken when the program is traced): ``x' = x + fn(n(x))`` over
     ``x [T, H]`` and no gap. ``n`` streams ``x [n, T, H]``: ``u = pre .
-    x``, ``x'_i = sum_j res[i, j] x_j + post_i fn(n(u))``, written as sums
-    of ``n`` scaled streams (elementwise: nothing here is the MXU's), and
-    ``gap`` the largest ``|sum_j res[i, j] - 1|`` over the ``valid``
-    tokens (all where ``None``): what the Sinkhorn iterations left."""
+    x``, ``x'_i = sum_j res[i, j] x_j + post_i fn(n(u))``, and ``gap``
+    the largest ``|sum_j res[i, j] - 1|`` over the ``valid`` tokens (all
+    where ``None``): what the Sinkhorn iterations left. A stream of a
+    tile's tokens or more (a prefill; told when the program is traced)
+    goes through the two kernels of ``ops/hyper_mix.py``, which read the
+    streams twice and write them once; a decode step's few tokens are
+    written as sums of ``n`` scaled streams (elementwise: sixty-odd
+    operations of under a microsecond, 0.025 ms a sub-block)."""
     norm = lw[sub + "_norm"]
     if cfg.hc_mult == 1:
         out, aux = fn(_rms(x, norm, cfg.norm_eps))
         return x + out, aux, None
     n = cfg.hc_mult
-    pre, post, res = _hc_coefficients(lw, sub, x, cfg)
-    u = sum(pre[j][:, None] * x[j] for j in range(n))
-    out, aux = fn(_rms(u, norm, cfg.norm_eps))
-    new = jnp.stack([
-        sum(res[i, j][:, None] * x[j] for j in range(n))
-        + post[i][:, None] * out for i in range(n)])
+    if x.shape[1] >= hyper_mix.TILE:
+        z, coef = hyper_mix.hyper_mix_read(
+            x, *_hc_columns(lw, sub, n), norm, eps=cfg.hc_eps,
+            norm_eps=cfg.norm_eps, iters=cfg.hc_sinkhorn_iters,
+            clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max))
+        out, aux = fn(z)
+        new = hyper_mix.hyper_mix_write(x, out, coef)
+        res = hyper_mix.coefficients(coef, n)[2]
+    else:
+        pre, post, res = _hc_coefficients(lw, sub, x, cfg)
+        u = sum(pre[j][:, None] * x[j] for j in range(n))
+        out, aux = fn(_rms(u, norm, cfg.norm_eps))
+        new = jnp.stack([
+            sum(res[i, j][:, None] * x[j] for j in range(n))
+            + post[i][:, None] * out for i in range(n)])
     off = jnp.abs(jnp.sum(res, axis=1) - 1.0)
     if valid is not None:
         off = jnp.where(valid[None, :], off, 0.0)
@@ -707,6 +736,17 @@ def _streams_in(x, cfg):
 def _streams_out(x, cfg):
     """What the head reads: the stream, or the sum of the ``n``."""
     return x if cfg.hc_mult == 1 else jnp.sum(x, axis=0)
+
+
+def _stream_rows(x, at, cfg):
+    """Slots ``at [B]`` of the stream or of each of the ``n``. The ``n``
+    as one ``[n T, H]`` array of rows: a gather along the middle axis of
+    ``[n, T, H]`` wants the streams in another layout than the kernel
+    that wrote them leaves, a copy of all of them for ``B`` rows."""
+    if cfg.hc_mult == 1:
+        return x[..., at, :]
+    n, T, H = x.shape
+    return x.reshape(n * T, H)[jnp.arange(n)[:, None] * T + at]
 
 
 # -- latent attention ---------------------------------------------------------
@@ -905,7 +945,7 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
              "filled": jnp.asarray(history, jnp.int32)}
     if gaps:  # hc_mult over 1: what twenty Sinkhorn passes left
         state["sinkhorn_gap"] = jnp.max(jnp.stack(gaps))
-    last = x[..., ends - 1, :]
+    last = _stream_rows(x, ends - 1, cfg)
     return _head(w, _streams_out(last, cfg), cfg), state
 
 

@@ -58,12 +58,12 @@ log = logging.getLogger(__name__)
 @functools.lru_cache(maxsize=None)
 def _interpreted() -> bool:
     """No TPU backend: Pallas' interpreter takes this package's kernels
-    (said once; ``ops/moe.py`` asks here too)."""
+    (said once; ``ops/moe.py`` and ``ops/hyper_mix.py`` ask here too)."""
     off_chip = jax.default_backend() != "tpu"
     if off_chip:
         log.warning("backend %s, not tpu: the Pallas kernels "
-                    "(window_attention, touched_experts) run in Pallas' "
-                    "interpreter", jax.default_backend())
+                    "(window_attention, touched_experts, hyper_mix) run "
+                    "in Pallas' interpreter", jax.default_backend())
     return off_chip
 
 

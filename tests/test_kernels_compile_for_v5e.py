@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from predictionio_tpu.ops import moe, window_attention as wa
+from predictionio_tpu.ops import hyper_mix, moe, window_attention as wa
 
 
 @pytest.fixture(scope="module")
@@ -30,6 +30,7 @@ def for_the_chip(monkeypatch):
     backend is the CPU and the target is not."""
     monkeypatch.setattr(moe, "_interpreted", lambda: False)
     monkeypatch.setattr(wa, "_interpreted", lambda: False)
+    monkeypatch.setattr(hyper_mix, "_interpreted", lambda: False)
 
 
 def _compiled(fn, one_chip, *shapes):
@@ -73,3 +74,40 @@ def test_the_attention_kernel_compiles_with_values_narrower_than_keys(
         ((32, 4, 4096, 128), bf16), ((4,), jnp.int32))
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "window_attention" in text
+
+
+@pytest.mark.parametrize("slots", [4096, 16384])
+def test_the_read_kernel_compiles_at_the_xing4_cells_rungs(
+        one_chip, for_the_chip, slots):
+    """Four float32 streams 3584 wide in tiles of 128 tokens, ``phi``'s
+    three bfloat16 parts resident, within the VMEM the kernel asks; and
+    the parts are still rounded apart in what the compiler made (a cast
+    there and back it would have dropped, with two of the three)."""
+    import functools
+
+    f32, n, H = jnp.float32, 4, 3584
+    c = n * (n + 2)
+    compiled = _compiled(
+        functools.partial(hyper_mix.hyper_mix_read.__wrapped__, eps=1e-6,
+                          norm_eps=1e-6, clamp=(-30.0, 30.0), iters=20),
+        one_chip, ((n, slots, H), f32), ((n * H, c), f32), ((c,), f32),
+        ((c,), f32), ((H,), f32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "hyper_mix_read" in text
+    assert "reduce-precision" in text
+
+
+@pytest.mark.parametrize("slots", [4096, 16384])
+def test_the_write_kernel_compiles_onto_its_input(one_chip, for_the_chip,
+                                                  slots):
+    """The stacked ``[4, slots, 3584]`` comes out in the buffer the
+    streams came in: no second 0.94 GB at 16,384 slots."""
+    f32, n, H = jnp.float32, 4, 3584
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one_chip) for s in (
+        (n, slots, H), (slots, H), (slots, hyper_mix.LANES))]
+    compiled = jax.jit(hyper_mix.hyper_mix_write.__wrapped__,
+                       donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "hyper_mix_write" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == n * slots * H * 4
