@@ -290,6 +290,7 @@ class MetricsRegistry:
     def __init__(self) -> None:
         self._families: Dict[str, _Family] = {}
         self._collectors: List[Callable[[], Iterable[str]]] = []
+        self._before: List[Callable[[], None]] = []
         self._lock = threading.Lock()
         self.start_time = time.time()
 
@@ -341,13 +342,32 @@ class MetricsRegistry:
         with self._lock:
             self._collectors.append(fn)
 
+    def before_collect(self, fn: Callable[[], None]) -> None:
+        """Call ``fn`` once at the start of every :meth:`render`,
+        :meth:`export` and :meth:`snapshot`: where several fn-backed
+        children share one costly read (a pass over ``/proc``), the
+        hook takes it once and the children read what it left."""
+        with self._lock:
+            self._before.append(fn)
+
+    def _collect(self) -> List[_Family]:
+        with self._lock:
+            before = list(self._before)
+            families = list(self._families.values())
+        for fn in before:
+            try:
+                fn()
+            except Exception:  # noqa: BLE001 — as a broken gauge: its
+                continue       # children read stale, the scrape lives
+        return families
+
     def render(self, openmetrics: bool = False) -> str:
         """Text exposition: Prometheus 0.0.4 by default; OpenMetrics
         1.0 (exemplars on histogram buckets, ``# EOF`` terminator,
         suffix-aware counter metadata) when ``openmetrics`` — the
         format ``Accept: application/openmetrics-text`` negotiates."""
+        families = self._collect()
         with self._lock:
-            families = list(self._families.values())
             collectors = list(self._collectors)
         lines: List[str] = []
         for fam in families:
@@ -362,9 +382,7 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
     def snapshot(self) -> Dict[str, Any]:
-        with self._lock:
-            families = list(self._families.values())
-        return {fam.name: fam.snapshot() for fam in families}
+        return {fam.name: fam.snapshot() for fam in self._collect()}
 
     def export(self) -> Dict[str, Any]:
         """Full-fidelity JSON exposition (``GET /metrics.json``): every
@@ -375,6 +393,4 @@ class MetricsRegistry:
         which the percentile-summary :meth:`snapshot` cannot support.
         Render-time collectors (build info, HBM) are exposition-only
         and deliberately absent here."""
-        with self._lock:
-            families = list(self._families.values())
-        return {fam.name: fam.export() for fam in families}
+        return {fam.name: fam.export() for fam in self._collect()}

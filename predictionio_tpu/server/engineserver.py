@@ -51,6 +51,7 @@ from ..obs import (
 )
 from ..obs import numerics as numerics_sentinel
 from ..obs.overlap import STATES
+from ..obs.runtime import RoleThread, name_os_thread
 from ..obs.trace import (
     activate_traces,
     add_stage_spans,
@@ -1280,7 +1281,8 @@ class QueryServer:
             if self._algo_pool is None:
                 from concurrent.futures import ThreadPoolExecutor
                 self._algo_pool = ThreadPoolExecutor(
-                    max_workers=8, thread_name_prefix="algo-dispatch")
+                    max_workers=8, thread_name_prefix="algo-dispatch",
+                    initializer=name_os_thread)
             return self._algo_pool
 
     def _predict_all(self, algorithms: List[Any], models: List[Any],
@@ -3361,14 +3363,14 @@ class StagedPipeline:
         self._dispatch_threads: List[threading.Thread] = []
         self._readback_threads: List[threading.Thread] = []
         for i in range(max(assemble_workers, 1)):
-            self._assemble_threads.append(threading.Thread(
+            self._assemble_threads.append(RoleThread(
                 target=self._assemble_loop, daemon=True,
                 name=f"pipeline-assemble-{i}"))
         if self.lanes > 1:
             # replicated fan-out: ONE dispatcher per lane — a lane's
             # launches stay ordered on its own device
             for lane in range(self.lanes):
-                self._dispatch_threads.append(threading.Thread(
+                self._dispatch_threads.append(RoleThread(
                     target=self._dispatch_loop, daemon=True,
                     args=(lane,), name=f"pipeline-dispatch-{lane}"))
         else:
@@ -3378,11 +3380,11 @@ class StagedPipeline:
             # TPU the device still executes in order; backends whose
             # runtime can overlap independent executions (CPU CI) do.
             for i in range(max(dispatch_workers, 1)):
-                self._dispatch_threads.append(threading.Thread(
+                self._dispatch_threads.append(RoleThread(
                     target=self._dispatch_loop, daemon=True,
                     args=(None,), name=f"pipeline-dispatch-{i}"))
         for i in range(max(readback_workers, 1)):
-            self._readback_threads.append(threading.Thread(
+            self._readback_threads.append(RoleThread(
                 target=self._readback_loop, daemon=True,
                 name=f"pipeline-readback-{i}"))
         self._threads: List[threading.Thread] = (

@@ -25,6 +25,7 @@ from urllib.parse import parse_qs, urlparse
 from ..concurrency import new_lock
 from ..data.storage.base import StorageError
 from ..faults import FaultError
+from ..obs.runtime import RoleThread, name_os_thread
 from ..obs.trace import stage_span
 
 __all__ = ["Request", "RequestStamps", "Response", "HTTPApp", "AppServer",
@@ -593,6 +594,12 @@ class _Handler(BaseHTTPRequestHandler):
     def log_message(self, fmt, *args):  # quiet by default
         pass
 
+    def setup(self) -> None:
+        # once a connection, on the thread ThreadingHTTPServer started
+        # for it (``Thread-N (process_request_thread)`` until here)
+        name_os_thread("http-handler")
+        super().setup()
+
     def parse_request(self) -> bool:
         # the request line has arrived: the request's first stamp
         self._stamps = st = RequestStamps(time.monotonic())
@@ -658,9 +665,9 @@ class AppServer:
         return self.httpd.server_address[1]
 
     def start_background(self) -> "AppServer":
-        self._thread = threading.Thread(
-            target=self.httpd.serve_forever, name=f"{self.app.name}-http",
-            daemon=True)
+        self._thread = RoleThread(
+            target=self.httpd.serve_forever,
+            name=f"http-acceptor-{self.app.name}", daemon=True)
         self._thread.start()
         return self
 

@@ -18,6 +18,7 @@ from ..controller.context import Context
 from ..controller.engine import Engine
 from ..controller.params import EngineParams
 from ..data.storage.base import EngineInstance
+from ..obs.runtime import name_os_thread
 from ..utils.jsonutil import from_jsonable, to_jsonable
 
 _dispatch_pool = None
@@ -34,7 +35,8 @@ def _algo_pool():
         from concurrent.futures import ThreadPoolExecutor
 
         _dispatch_pool = ThreadPoolExecutor(
-            max_workers=8, thread_name_prefix="algo-batch-dispatch")
+            max_workers=8, thread_name_prefix="algo-batch-dispatch",
+            initializer=name_os_thread)
     return _dispatch_pool
 
 

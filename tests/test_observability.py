@@ -3,6 +3,7 @@ exposition validity, per-phase spans through a real in-process engine
 server (batched and unbatched), transfer-guard counter wiring, and
 memory-boundedness of the span registry under 100k records."""
 
+import contextlib
 import json
 import logging
 import re
@@ -490,6 +491,293 @@ class TestProcessMetrics:
                      "pio_process_open_fds", "pio_process_threads"):
             assert re.search(rf"^{name} [0-9.e+]+$", text,
                              re.MULTILINE), name
+
+
+def _burn(seconds):
+    import time as _time
+
+    t0 = _time.thread_time()
+    while _time.thread_time() - t0 < seconds:
+        pass
+
+
+def _run_to_its_tasks_end(name, seconds):
+    """Burn ``seconds`` of CPU on a thread called ``name``, join it, and
+    wait until its OS task is gone too: a pass that finds it still
+    there sees a task Python no longer knows."""
+    import os
+    import time as _time
+
+    tid = []
+
+    def run():
+        tid.append(threading.get_native_id())
+        _burn(seconds)
+
+    thread = threading.Thread(target=run, name=name)
+    thread.start()
+    thread.join(30)
+    deadline = _time.monotonic() + 10
+    while os.path.exists(f"/proc/self/task/{tid[0]}") \
+            and _time.monotonic() < deadline:
+        _time.sleep(0.001)
+
+
+@contextlib.contextmanager
+def _live_thread_that_burned(name, seconds):
+    """A thread called ``name`` that has burned ``seconds`` of CPU and
+    is still alive (asleep) inside the block."""
+    stop, burned = threading.Event(), threading.Event()
+
+    def work():
+        _burn(seconds)
+        burned.set()
+        stop.wait(30)
+
+    t = threading.Thread(target=work, name=name)
+    t.start()
+    try:
+        assert burned.wait(30)
+        yield
+    finally:
+        stop.set()
+        t.join(30)
+
+
+def _thread_seconds(export, state="cpu"):
+    return {c["labels"]["role"]: c["value"]
+            for c in export["pio_thread_seconds_total"]["children"]
+            if c["labels"]["state"] == state}
+
+
+@pytest.fixture
+def host_registry():
+    from predictionio_tpu.obs import register_process_metrics
+
+    reg = MetricsRegistry()
+    register_process_metrics(reg)
+    if reg.get("pio_thread_seconds_total") is None:
+        pytest.skip("/proc/self/task not readable on this platform")
+    return reg
+
+
+class TestHostClocks:
+    """The host's seconds by thread role (ISSUE 37): one pass over
+    /proc/self/task per export, every server thread under a role."""
+
+    def test_every_engine_server_thread_has_a_role(self):
+        import http.client
+
+        from predictionio_tpu.obs.runtime import thread_role
+
+        before = set(threading.enumerate())
+        qs, srv = _deploy_synthetic(batching=True)
+        conns = [http.client.HTTPConnection("127.0.0.1", srv.port,
+                                            timeout=60) for _ in range(6)]
+        try:
+            def fire(conn, i):
+                conn.request("POST", "/queries.json", json.dumps(
+                    {"user": f"u{i}", "num": 3}))
+                assert conn.getresponse().read()
+
+            # concurrently, so that a batch holds several queries and
+            # the supplement pool starts; keep-alive, so that the
+            # handler threads are still there to be looked at
+            clients = [threading.Thread(target=fire, args=(c, i))
+                       for i, c in enumerate(conns)]
+            for t in clients:
+                t.start()
+            for t in clients:
+                t.join(60)
+            roles = {}
+            for t in set(threading.enumerate()) - before:
+                roles.setdefault(thread_role(t.name), set()).add(t.name)
+            # the SLO evaluator ticks beside the query path; every
+            # thread ON it has a role of its own
+            assert roles.pop("other", set()) <= {"slo-engine"}
+            assert set(roles) == {"handler", "acceptor", "assemble",
+                                  "dispatch", "readback", "supplement"}
+            assert len(roles["handler"]) == 1  # all called http-handler
+            counts = {c["labels"]["role"]: c["value"] for c in
+                      qs.metrics.export()["pio_thread_count"]["children"]}
+            assert counts["handler"] == len(conns)
+            assert counts["acceptor"] == 1 and counts["assemble"] >= 1
+            assert counts["other"] >= 1  # the main thread
+        finally:
+            for c in conns:
+                c.close()
+            srv.shutdown()
+            qs.close()
+
+    def test_os_thread_name_is_the_roles(self):
+        from predictionio_tpu.obs.runtime import RoleThread, name_os_thread
+
+        comm = {}
+
+        def read_comm(key):
+            with open(f"/proc/self/task/{threading.get_native_id()}"
+                      f"/comm") as f:
+                comm[key] = f.read().strip()
+
+        def handler():
+            name_os_thread("http-handler")  # what _Handler.setup does
+            read_comm(threading.current_thread().name)
+
+        threads = [
+            RoleThread(target=read_comm, args=("dispatch",),
+                       name="pipeline-dispatch-3"),
+            RoleThread(target=read_comm, args=("other",),
+                       name="slo-engine"),
+            threading.Thread(target=handler)]
+        try:
+            for t in threads:
+                t.start()
+        finally:
+            for t in threads:
+                t.join(10)
+        if not comm:
+            pytest.skip("/proc/self/task not readable on this platform")
+        with open("/proc/self/comm") as f:
+            process = f.read().strip()
+        assert comm == {"dispatch": "pio-dispatch", "other": process,
+                        "http-handler": "pio-handler"}
+
+    def test_cpu_children_sum_to_the_process(self, host_registry):
+        import time as _time
+
+        def total(export):
+            return sum(_thread_seconds(export).values())
+
+        _burn(0.2)
+        a = host_registry.export()
+        assert total(a) == pytest.approx(_time.process_time(), rel=0.02)
+        assert a["pio_process_cpu_seconds_total"]["children"][0][
+            "value"] == pytest.approx(total(a), rel=0.02, abs=0.02)
+        # a thread no pass ever saw: the exited child takes it
+        _run_to_its_tasks_end("pipeline-readback-9", 0.2)
+        b = host_registry.export()
+        assert total(b) == pytest.approx(_time.process_time(), rel=0.02)
+        grew = {r: v - _thread_seconds(a)[r]
+                for r, v in _thread_seconds(b).items()}
+        assert grew["exited"] == pytest.approx(0.2, abs=0.05)
+        assert grew["readback"] == 0.0
+
+    def test_a_role_keeps_what_a_pass_saw_when_its_thread_exits(
+            self, host_registry):
+        # a role's seconds only grow: what a handler burned before its
+        # connection closed stays the handlers'
+        a = host_registry.export()
+        with _live_thread_that_burned("http-handler", 0.2):
+            b = host_registry.export()
+        c = host_registry.export()
+        handler = [_thread_seconds(e)["handler"] for e in (a, b, c)]
+        assert handler[1] - handler[0] == pytest.approx(0.2, abs=0.05)
+        assert handler[2] >= handler[1]
+
+    def test_a_busy_dispatch_thread_moves_its_role_alone(
+            self, host_registry):
+        a = host_registry.export()
+        with _live_thread_that_burned("pipeline-dispatch-0", 0.3):
+            b = host_registry.export()
+        grew = {r: v - _thread_seconds(a)[r]
+                for r, v in _thread_seconds(b).items()}
+        assert grew.pop("dispatch") == pytest.approx(0.3, abs=0.05)
+        assert grew.pop("other") < 0.2   # this test's own thread
+        assert grew.pop("native") < 0.2
+        # the process's clock is read after the tasks': what ran
+        # meanwhile is the pass's own few hundred microseconds
+        assert grew.pop("exited") < 0.01
+        assert all(v == 0.0 for v in grew.values()), grew
+        assert set(_thread_seconds(b, "runqueue")) \
+            == set(grew) | {"dispatch", "other", "native"}
+
+    def test_without_schedstat_the_threads_cpu_clocks_are_read(
+            self, monkeypatch):
+        # a kernel without CONFIG_SCHED_INFO, or a sandboxed one (the
+        # chip machine's): no file a task, no run-queue children, the
+        # same roles and the same sum
+        import time as _time
+
+        from predictionio_tpu.obs import register_process_metrics, runtime
+
+        def no_schedstat(path):
+            if path.endswith("/schedstat"):
+                raise FileNotFoundError(path)
+            return runtime._read(path)
+
+        monkeypatch.setattr(runtime, "_lock_held_reader",
+                            lambda: no_schedstat)
+        reg = MetricsRegistry()
+        register_process_metrics(reg)
+        a = reg.export()
+        if "pio_thread_seconds_total" not in a:
+            pytest.skip("/proc/self/task not readable on this platform")
+        assert _thread_seconds(a, "runqueue") == {}
+        _run_to_its_tasks_end("pipeline-readback-9", 0.1)
+        with _live_thread_that_burned("pipeline-dispatch-0", 0.2):
+            b = reg.export()
+        grew = {r: v - _thread_seconds(a)[r]
+                for r, v in _thread_seconds(b).items()}
+        assert grew["dispatch"] == pytest.approx(0.2, abs=0.05)
+        assert grew["exited"] == pytest.approx(0.1, abs=0.05)
+        assert sum(_thread_seconds(b).values()) == pytest.approx(
+            _time.process_time(), rel=0.02)
+
+    def test_one_export_makes_one_pass(self, host_registry, monkeypatch):
+        import os
+
+        from predictionio_tpu.obs import runtime
+
+        listed = []
+        listdir = os.listdir
+
+        def counting(path="."):
+            listed.append(path)
+            return listdir(path)
+
+        monkeypatch.setattr(runtime.os, "listdir", counting)
+        host_registry.export()
+        assert listed.count(runtime._TASKS) == 1
+        host_registry.render()
+        host_registry.snapshot()
+        assert listed.count(runtime._TASKS) == 3
+        # and the four pio_process_* gauges share the pass's one read
+        assert listed.count("/proc/self/fd") == 3
+
+    def test_without_proc_self_task_the_families_are_absent(
+            self, monkeypatch):
+        from predictionio_tpu.obs import (
+            process_stats,
+            register_process_metrics,
+            runtime,
+        )
+
+        if not process_stats():
+            pytest.skip("/proc not readable on this platform")
+        monkeypatch.setattr(runtime, "_TASKS", "/proc/self/no-such-dir")
+        reg = MetricsRegistry()
+        register_process_metrics(reg)
+        export = reg.export()
+        assert "pio_process_cpu_seconds_total" in export
+        for name in ("pio_thread_seconds_total", "pio_thread_count",
+                     "pio_host_cpus"):
+            assert name not in export
+        validate_exposition(reg.render())
+
+    def test_a_failing_hook_does_not_fail_the_export(self):
+        reg = MetricsRegistry()
+        reg.gauge("t_plain").set(1.0)
+        calls = []
+
+        def hook():
+            calls.append(1)
+            raise OSError("no /proc today")
+
+        reg.before_collect(hook)
+        assert reg.export()["t_plain"]["children"][0]["value"] == 1.0
+        assert "t_plain 1" in reg.render()
+        assert reg.snapshot()["t_plain"] == 1.0
+        assert len(calls) == 3
 
 
 class TestScrapeSelfCost:
