@@ -41,19 +41,27 @@ through two kernels a sub-block, one either side of ``F``
 decode step's few tokens through the same arithmetic as plain sums.
 
 Layout. The prefill takes a batch PACKED: the real tokens of its rows
-one behind the other in one stream of ``T`` slots (``tokens [T]``,
-``lengths [B]``; the spare slots behind the last row hold anything), so
-its cost follows the tokens a batch has and not rows x its longest row.
-Whatever treats a token on its own (norms, projections, feed-forwards,
-the router, the expert products) runs over ``[T, H]`` and knows no rows.
-The two operators that mix positions stay inside a row: a conv tap that
-would reach before a row's first token adds zero, and attention runs
-over the rows gathered into the right-aligned ``[B, heads, history]``
-layout that the decode's cache has anyway, blockwise
-(``ops/window_attention.py``: causal, a window for sliding layers, each
-row from its first real slot, tiles nobody sees skipped), with rotary
-positions counted from a row's first token. A spare slot joins no
-expert's group and no row reads it: a row's logits do not depend on
+one row behind the other in one stream of ``T`` slots (``tokens [T]``,
+``lengths [B]``), every row ENDING on a memory tile's edge
+(:func:`row_ends`: at most a tile's slots less one lie spare before a
+row's first token; the spare slots there and behind the last row hold
+anything), so its cost follows the tokens a batch has and not rows x
+its longest row. Whatever treats a token on its own (norms,
+projections, feed-forwards, the router, the expert products) runs over
+``[T, H]`` and knows no rows. The two operators that mix positions stay
+inside a row: a conv tap that would reach before a row's first token
+adds zero, and attention runs over the rows laid out right-aligned,
+``[G, B, history, lanes]``, as the decode's cache has them anyway,
+blockwise (``ops/window_attention.py``: causal, a window for sliding
+layers, each row from its first real slot, tiles nobody sees skipped),
+with rotary positions counted from a row's first token. Between the
+projections and ``W_o`` nothing changes a layout: a head that fills
+whole lane tiles stays in the lanes the matrix product wrote it to
+(:func:`_groups`; its norm, rotary and gate by ``ops/head_lanes.py``),
+and a row's tokens move between the stream and the rows whole tiles at
+a time (:func:`_to_rows`: a row ends on a tile's edge in both). A spare
+slot joins no expert's group and no row reads it: a row's logits do
+not depend on
 where in the stream it lies or on what lies beside it. State of four
 kinds is carried from one program to the next: keys and values that
 grow (full-attention layers), right-aligned at ``history`` slots
@@ -93,13 +101,13 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass, fields
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import hyper_mix, moe
+from ..ops import head_lanes, hyper_mix, moe
 from ..ops.window_attention import BLOCK as ATTENTION_BLOCK, window_attention
 
 CONV, ATTENTION, SLIDING = "conv", "full_attention", "sliding_attention"
@@ -524,10 +532,11 @@ def _dot(a, w):
 #: float32 elements of one temporary that a stage of the prefill may
 #: hold. A wider stage goes in equal parts, one after the other: a dense
 #: feed-forward by blocks of tokens (three ``[T, intermediate]`` arrays),
-#: the queries' projection, norm and rotary by groups of heads (four
-#: ``[heads, T, D]`` arrays: 8 GB for 64 heads at 65,536 slots whole, by
-#: the v5e compiler's count). Everything ``lfm2-8b-a1b-l14``'s cell runs
-#: is under both, in one part.
+#: the queries' projection, norm and rotary by groups of heads (the
+#: float32 product of every head at once, and until PR 39 three more
+#: arrays its size for norm and rotary: 8 GB for 64 heads at 65,536
+#: slots whole, by the v5e compiler's count). Everything
+#: ``lfm2-8b-a1b-l14``'s cell runs is under both, in one part.
 BLOCK_ELEMENTS = 1 << 27
 HEAD_GROUP_ELEMENTS = 1 << 26
 
@@ -544,14 +553,37 @@ def _token_blocks(fn, width: int, z):
     return out.reshape((T,) + out.shape[2:])
 
 
+#: lanes of a vector register. A head whose width is a multiple of it is
+#: a whole block of an array's minor axis, so ``window_attention`` can
+#: pick it out of ``heads x D`` lanes and a projection stays as the
+#: product writes it; a narrower head gets an axis of its own
+LANES = 128
+#: tokens from which a projection's input is a STREAM (a prefill's
+#: packed batch; the cells' shortest has 4,096) and under which it is a
+#: decode step's rows (4 to 64)
+STREAM_TOKENS = 256
+
+
+def _groups(heads: int, tokens: int, *widths: int) -> int:
+    """How many groups the heads of a projection of ``tokens`` tokens
+    come out in: ONE (``[1, T, heads x D]``, token-major: what a matrix
+    product writes) for a STREAM (``STREAM_TOKENS`` or more) whose
+    every width fills whole lane tiles, else one a head
+    (``[heads, T, D]``): a decode step's few rows keep the programs they
+    had, a narrow head an axis of its own."""
+    return 1 if tokens >= STREAM_TOKENS \
+        and all(w % LANES == 0 for w in widths) else heads
+
+
 def _rotary(x, pos, rope):
     """Rotate-half rotary over the first ``2 len(inv)`` dimensions of a
-    head (the rest pass through): ``x [heads, ..., D]``, ``pos`` shaped
-    like ``x`` without its first and last axes, ``rope`` from
-    :meth:`DecoderConfig.rope`."""
+    head (the rest pass through): ``x [..., heads, D]``, ``pos`` shaped
+    like ``x`` without its last two axes (or broadcast against them),
+    ``rope`` from :meth:`DecoderConfig.rope`."""
     inv, factor = rope
     R = 2 * len(inv)
-    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv, jnp.float32)
+    ang = pos.astype(jnp.float32)[..., None, None] \
+        * jnp.asarray(inv, jnp.float32)
     ang = jnp.concatenate([ang, ang], axis=-1)
     xr = x[..., :R]
     x1, x2 = xr[..., :R // 2], xr[..., R // 2:]
@@ -561,49 +593,93 @@ def _rotary(x, pos, rope):
         else jnp.concatenate([out, x[..., R:]], axis=-1)
 
 
-def _by_head(z, w, heads):
-    """``z [..., H] x w [H, heads x D] -> [heads, ..., D]``: the
-    projection written by head, so that its output IS heads-first."""
-    w = w.reshape(w.shape[0], heads, -1)
+def _project(z, w, groups):
+    """``z [..., H] x w [H, W] -> [groups, ..., W / groups]`` float32,
+    group ``g`` the ``g``-th block of the product's columns. One group
+    is the plain product, laid out as a matrix product writes it; as
+    many groups as heads is the projection written by head, whose
+    output IS heads-first."""
+    if groups == 1:
+        return _dot(z, w)[None]
+    w = w.reshape(w.shape[0], groups, -1)
     return jnp.einsum("...h,hnd->n...d", z.astype(w.dtype), w,
                       preferred_element_type=jnp.float32)
 
 
+def _norm_rotary(a, gain, pos, rope, D, cfg):
+    """``a [G, ..., heads / G x D]`` float32 -> every head through its
+    RMSNorm (``gain [D]``; ``None``: no norm) and rotary at ``pos
+    [...]``, in the weights' dtype. A stream's heads side by side in the
+    lanes take one pass of ``ops/head_lanes.py``; a head with an axis of
+    its own (a decode step's, a narrow head's) the plain lines."""
+    if a.shape[0] == 1 and a.shape[-1] > D:
+        return head_lanes.head_norm_rotary(
+            a[0], gain, pos, rope=rope, head_dim=D, eps=cfg.norm_eps,
+            dtype=cfg.dtype)[None]
+    by_head = a.reshape(a.shape[:-1] + (-1, D))
+    if gain is not None:
+        by_head = _rms(by_head, gain, cfg.norm_eps)
+    return _rotary(by_head, pos, rope).reshape(a.shape).astype(
+        jnp.dtype(cfg.dtype))
+
+
 def _qkv(lw, z, pos, l, cfg):
-    """``q [heads_l, ..., D]``, ``k``, ``v [kv heads, ..., D]`` of
-    ``z [..., H]`` at positions ``pos [...]``, in the weights' dtype.
-    The queries go by groups of heads where all of them at once would
-    hold more than ``HEAD_GROUP_ELEMENTS``: the stacked groups ARE the
-    heads-first array."""
+    """``q [G, ..., heads_l / G x D]``, ``k``, ``v [Gkv, ..., kv heads /
+    Gkv x D]`` of ``z [..., H]`` at positions ``pos [...]``, in the
+    weights' dtype: each group's heads side by side in the lanes, the
+    layout ``window_attention`` reads (:func:`_groups`; a stream's
+    per-head norm and rotary by ``ops/head_lanes.py``, a head with an
+    axis of its own by the plain lines). The queries go by groups
+    of heads where all of them at once would hold more than
+    ``HEAD_GROUP_ELEMENTS``: those groups are ``G``, nothing stacks or
+    transposes them."""
     dt = jnp.dtype(cfg.dtype)
     rope = cfg.rope(cfg.layer_types[l])
     nq, D = cfg.num_attention_heads_per_layer[l], cfg.head_dim
 
     def project(z, w, norm=None):
-        a = _by_head(z, w, w.shape[1] // D)
-        if norm is not None:
-            a = _rotary(_rms(a, norm, cfg.norm_eps), pos, rope)
-        return a.astype(dt)
+        a = _project(z, w, _groups(w.shape[1] // D, z[..., 0].size, D))
+        if norm is None:
+            return a.astype(dt)
+        return _norm_rotary(a, norm, pos, rope, D, cfg)
 
     n = moe.equal_parts(nq, z[..., 0].size * D, HEAD_GROUP_ELEMENTS)
     if n == 1:
         q = project(z, lw["wq"], lw["q_norm"])
     else:
         zb = z.astype(lw["wq"].dtype)  # read once a group: half the bytes
+
+        def part(w):  # [T, lanes] where the part's heads are one group
+            a = project(zb, w, lw["q_norm"])
+            return a[0] if a.shape[0] == 1 else a
+
         q = jax.lax.map(
-            lambda w: project(zb, w, lw["q_norm"]),
-            lw["wq"].reshape(-1, n, nq // n * D).swapaxes(0, 1))
-        q = q.reshape((nq,) + q.shape[2:])
+            part, lw["wq"].reshape(-1, n, nq // n * D).swapaxes(0, 1))
+        q = q.reshape((-1,) + q.shape[-2:])
     return q, project(z, lw["wk"], lw["k_norm"]), project(z, lw["wv"])
 
 
-def _attention_out(lw, o, z):
-    """``o [heads, ..., D]`` through the head gate (``gating``: each
-    head times ``sigmoid(z W_g)``, one scalar a head) and ``W_o``."""
-    wo = lw["wo"].reshape(o.shape[0], o.shape[-1], -1)
+def _attention_out(lw, o, z, D):
+    """``o [G, ..., heads / G x D]`` through the head gate (``gating``:
+    each head times ``sigmoid(z W_g)``, one scalar a head) and ``W_o``,
+    one product over ``heads x D`` (a sum of ``G``). A stream's heads in
+    the lanes take the gate in one pass of ``ops/head_lanes.py``; heads
+    with an axis of their own (a decode step's, a narrow head's) take a
+    broadcast along it."""
+    G = o.shape[0]
     if "wg" in lw:  # a float32 product, rounded once for the next one
         gate = jax.nn.sigmoid(_dot(z, lw["wg"]))
-        o = o.astype(jnp.float32) * jnp.moveaxis(gate, -1, 0)[..., None]
+        gate = jnp.moveaxis(gate.reshape(gate.shape[:-1] + (G, -1)), -2, 0)
+        if o.shape[-1] > D:  # a stream's heads side by side
+            o = head_lanes.head_gate(
+                o.reshape(-1, o.shape[-1]), gate.reshape(-1, gate.shape[-1]),
+                head_dim=D).reshape(o.shape)
+        else:
+            o = (o.astype(jnp.float32).reshape(o.shape[:-1] + (-1, D))
+                 * gate[..., None]).reshape(o.shape)
+    if G == 1:
+        return _dot(o[0], lw["wo"])
+    wo = lw["wo"].reshape(G, o.shape[-1], -1)
     return jnp.einsum("n...d,ndh->...h", o.astype(wo.dtype), wo,
                       preferred_element_type=jnp.float32)
 
@@ -752,23 +828,19 @@ def _stream_rows(x, at, cfg):
 # -- latent attention ---------------------------------------------------------
 
 def _latent_project(lw, z, pos, cfg):
-    """``(q [heads, ..., nope + rope], kv [..., kv_lora_rank + rope])``
-    of ``z [..., H]`` at positions ``pos [...]``, in the weights' dtype.
-    ``q``: the low-rank query path, normalised at ``q_lora_rank``, each
-    head's last ``rope`` dimensions rotated. ``kv``: what a token leaves
-    in the cache, its normalised latent beside its rotated key, ONE for
-    all heads."""
-    dt = jnp.dtype(cfg.dtype)
+    """``(cq [..., q_lora_rank] float32, kv [..., kv_lora_rank + rope])``
+    of ``z [..., H]`` at positions ``pos [...]``. ``cq``: the low-rank
+    query path, normalised, before its up-projection ``W_qb`` (each
+    head's last ``rope`` columns are the rotated ones). ``kv``: what a
+    token leaves in the cache, its normalised latent beside its rotated
+    key, ONE for all heads, in the weights' dtype."""
     rope = cfg.rope(LATENT)
-    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    rkv = cfg.kv_lora_rank
     cq = _rms(_dot(z, lw["w_qa"]), lw["q_a_norm"], cfg.norm_eps)
-    q = _by_head(cq, lw["w_qb"], cfg.num_attention_heads)
-    q = jnp.concatenate([q[..., :dn], _rotary(q[..., dn:], pos, rope)],
-                        axis=-1)
     kv = _dot(z, lw["w_kva"])
     c = _rms(kv[..., :rkv], lw["kv_a_norm"], cfg.norm_eps)
-    r = _rotary(kv[None, ..., rkv:], pos, rope)[0]
-    return q.astype(dt), jnp.concatenate([c, r], axis=-1).astype(dt)
+    r = _rotary(kv[..., None, rkv:], pos, rope)[..., 0, :]
+    return cq, jnp.concatenate([c, r], axis=-1).astype(jnp.dtype(cfg.dtype))
 
 
 def _latent_up(lw, cfg):
@@ -776,13 +848,6 @@ def _latent_up(lw, cfg):
     each ``[kv_lora_rank, heads, nope or v]``."""
     w = lw["w_kvb"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads, -1)
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-
-def _latent_out(lw, o):
-    """``o [heads, ..., v]`` through ``W_o``."""
-    wo = lw["wo"].reshape(o.shape[0], o.shape[-1], -1)
-    return jnp.einsum("n...d,ndh->...h", o.astype(wo.dtype), wo,
-                      preferred_element_type=jnp.float32)
 
 
 # -- prefill ----------------------------------------------------------------
@@ -809,82 +874,135 @@ def _conv_prefill(lw, z, valid, pos, last, cfg):
     return _dot(c * y, lw["w_out"]), {"win": win}
 
 
-def _to_rows(a, at, ok=None):
-    # ptpu: allow[materialized-gather] — [heads, B, slots, D] in the
-    # weights' dtype from the stream's [heads, T, D]. Zeros where a
-    # row has no token (``ok``); the queries' pad slots hold token
-    # 0's, which no real slot reads
-    out = jnp.take(a, at.reshape(-1), axis=1)
-    if ok is not None:
-        out = jnp.where(ok.reshape(1, -1, 1), out, 0)
-    return out.reshape(a.shape[:1] + at.shape + a.shape[2:])
+def _gather(a, at):
+    """Entries ``at [...]`` of the second axis of every group of ``a [G,
+    N, ...]``: ``[G, ..., ...]`` (all in bounds, by their makers). The
+    groups as ONE ``[G N, ...]`` array: a gather along a middle axis
+    wants the groups laid inside the tokens, a copy of its operand
+    before it and of its result after (seen compiling for the v5e)."""
+    G, N = a.shape[:2]
+    at = (jnp.arange(G, dtype=at.dtype).reshape((G,) + (1,) * at.ndim) * N
+          + at)
+    # ptpu: allow[materialized-gather] — the rows (or the stream) itself
+    return a.reshape((G * N,) + a.shape[2:]).at[at].get(
+        mode="promise_in_bounds")
+
+
+def _to_rows(a, rows):
+    """The stream ``a [G, T, W]`` as right-aligned rows ``[G, B, history,
+    W]``, WHOLE TILES of ``align`` slots at a time (``src [B, history /
+    align]``: the tile of the stream each tile of a row is). A row's end
+    is a multiple of ``align`` in the stream (:func:`row_ends`) and in
+    its ``history`` slots, so a row's tiles ARE the stream's: nothing
+    moves inside a tile, where a slot-by-slot gather moves every row of
+    every tile on its own (3.1 ms against 9.4 for a sliding layer's
+    queries at 32,768 slots, PR 38's builder's chip run). What a row's
+    slots before its first hold is its neighbour's tokens or spare
+    slots: finite, which is all the kernel asks; the state zeroes
+    them."""
+    G, T, W = a.shape
+    out = _gather(a.reshape(G, T // rows.align, rows.align, W), rows.src)
+    return out.reshape(G, rows.src.shape[0], -1, W)
+
+
+def _to_stream(o, rows):
+    """The rows' ``o [G, B, history, W]`` back into the stream ``[G, T,
+    W]``, whole tiles again (``dst [T / align]``: the tile of the rows
+    each tile of the stream is; a spare slot takes what lies beside)."""
+    G, W = o.shape[0], o.shape[-1]
+    out = _gather(o.reshape(G, -1, rows.align, W), rows.dst)
+    return out.reshape(G, -1, W)
+
+
+def _cache_layout(a, D):
+    """``a [G, B, slots, heads / G x D]`` as the decode reads its state:
+    batch first, a head an axis, ``[B, heads, slots, D]``. The one
+    transpose between a prefill's projections and the cache, of the few
+    key-value heads: each head's lanes are sliced out whole and laid
+    behind the batch, nothing is reshaped across a tile."""
+    W = a.shape[-1]
+    if W == D:  # a head a group already: only the batch comes first
+        return a.swapaxes(0, 1)
+    return jnp.stack([g[..., h:h + D] for g in a for h in range(0, W, D)],
+                     axis=1)
 
 
 def _attention_prefill(lw, z, pos, rows, room, l, cfg):
     """``z [T, H]`` packed. ``q``, ``k``, ``v`` are gathered into the
-    right-aligned ``[B, heads, history]`` layout (``rows``: where each
+    right-aligned rows ``[G, B, history, lanes]`` (``rows``: where each
     of its slots lies in the stream, which of them are real, where each
-    slot of the stream lies in it, and each row's first real slot),
-    attention is taken blockwise from each row's first real slot, and
-    the output is gathered back into the stream. The state: a full
-    layer's keys and values in that layout with ``room`` behind them; a
-    sliding layer's ring, slot ``s`` holding the row's last token whose
-    position is ``s`` modulo the window (zeros where it has none)."""
-    src, real, dst, lead, last = rows
-    B, L = src.shape
+    slot of the stream lies in it, and each row's first real slot) in
+    the layout their projections have, attention is taken blockwise
+    from each row's first real slot, and the output is gathered back
+    into the stream in that layout too. The state: a full layer's keys
+    and values ``[B, kv heads, history, D]`` with ``room`` behind them;
+    a sliding layer's ring, slot ``s`` holding the row's last token
+    whose position is ``s`` modulo the window (zeros where it has
+    none)."""
+    real, lead, last = rows.real, rows.lead, rows.last
+    D = cfg.head_dim
     sliding = cfg.layer_types[l] == SLIDING
-
-    def state(k, v):  # batch first, as the decode reads it
-        return {"k": k.swapaxes(0, 1), "v": v.swapaxes(0, 1)}
-
     q, k, v = _qkv(lw, z, pos, l, cfg)
-    kr, vr = _to_rows(k, src, real), _to_rows(v, src, real)
+    kr, vr = _to_rows(k, rows), _to_rows(v, rows)
     o = window_attention(
-        _to_rows(q, src), kr, vr, lead, scale=cfg.head_dim ** -0.5,
+        _to_rows(q, rows), kr, vr, lead, scale=D ** -0.5,
         window=cfg.sliding_window if sliding else None,
-        block=ATTENTION_BLOCK)
-    # ptpu: allow[materialized-gather] — back into the stream: [heads, T, D]
-    o = jnp.take(o.reshape(o.shape[0], B * L, -1), dst, axis=1)
-    out = _attention_out(lw, o, z)
+        block=ATTENTION_BLOCK, head_dim=D)
+    out = _attention_out(lw, _to_stream(o, rows), z, D)
+
+    def state(a, ok, room=0):  # zeros where a row has no token
+        return jnp.pad(jnp.where(ok[:, None, :, None], _cache_layout(a, D),
+                                 0), ((0, 0), (0, 0), (0, room), (0, 0)))
+
     if not sliding:
-        grow = ((0, 0), (0, 0), (0, room), (0, 0))
-        return out, state(jnp.pad(kr, grow), jnp.pad(vr, grow))
+        return out, {"k": state(kr, real, room), "v": state(vr, real, room)}
     W = cfg.sliding_window
     at = jnp.arange(W, dtype=jnp.int32)[None, :]
     back = (pos[last][:, None] - at) % W  # tokens back from a row's last
     ring, held = last[:, None] - back, back <= pos[last][:, None]
-    return out, state(_to_rows(k, jnp.where(held, ring, 0), held),
-                      _to_rows(v, jnp.where(held, ring, 0), held))
+    ring = jnp.where(held, ring, 0)
+    return out, {"k": state(_gather(k, ring), held),
+                 "v": state(_gather(v, ring), held)}
 
 
 def _latent_prefill(lw, z, pos, rows, room, cfg):
     """``z [T, H]`` packed, in the EXPANDED form (the tokens are in
-    hand): every head's keys ``[k_nope | k_rope]`` (the rotated key
-    repeated for each) and values from the tokens' latents through
-    ``W_kvb``, gathered into the right-aligned rows like any attention
-    layer's, through the blockwise kernel at the family's scale. The
-    state is what the decode attends over instead: the latents
-    themselves, ``[B, history + room, kv_lora_rank + rope]``."""
-    src, real, dst, lead, _ = rows
-    B, L = src.shape
+    hand): every head's own keys ``k_nope`` and values from the tokens'
+    latents through ``W_kvb``, gathered into the right-aligned rows
+    like any attention layer's, through the blockwise kernel at the
+    family's scale with the score as TWO products: each head's
+    ``q_nope . k_nope`` plus its ``q_rope`` against the rotated key,
+    which is ONE for all heads and is never repeated (where the heads
+    lie in the lanes, both rotated halves are padded to whole lane
+    tiles with zeros, which add nothing). The state is what the decode
+    attends over instead: the latents themselves, ``[B, history + room,
+    kv_lora_rank + rope]``."""
+    real, lead = rows.real, rows.lead
     dt = jnp.dtype(cfg.dtype)
-    q, kv = _latent_project(lw, z, pos, cfg)
+    n, rkv = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    G = _groups(n, z.shape[0], dn, dv)
+    pad = -dr % LANES if G == 1 else 0
+    cq, kv = _latent_project(lw, z, pos, cfg)
+    w_q = lw["w_qb"].reshape(-1, n, dn + dr)
+    q = _project(cq, w_q[..., :dn].reshape(-1, n * dn), G).astype(dt)
+    q_r = _project(cq, jnp.pad(w_q[..., dn:], ((0, 0), (0, 0), (0, pad)))
+                   .reshape(-1, n * (dr + pad)), G)
+    q_r = _norm_rotary(q_r, None, pos, cfg.rope(LATENT), dr + pad, cfg)
     w_uk, w_uv = _latent_up(lw, cfg)
-    c, r = kv[:, :cfg.kv_lora_rank], kv[:, cfg.kv_lora_rank:]
-    k = jnp.einsum("tc,cnd->ntd", c, w_uk,
-                   preferred_element_type=jnp.float32).astype(dt)
-    k = jnp.concatenate(
-        [k, jnp.broadcast_to(r, k.shape[:2] + r.shape[-1:])], axis=-1)
-    v = jnp.einsum("tc,cnd->ntd", c, w_uv,
-                   preferred_element_type=jnp.float32).astype(dt)
+    c = kv[:, :rkv]
+    k = _project(c, w_uk.reshape(rkv, -1), G).astype(dt)
+    v = _project(c, w_uv.reshape(rkv, -1), G).astype(dt)
+    cache = _to_rows(kv[None], rows)
+    k_r = jnp.pad(cache[..., rkv:], ((0, 0),) * 3 + ((0, pad),))
     o = window_attention(
-        _to_rows(q, src), _to_rows(k, src, real), _to_rows(v, src, real),
-        lead, scale=cfg.latent_scale, block=ATTENTION_BLOCK)
-    # ptpu: allow[materialized-gather] — back into the stream: [heads, T, v]
-    o = jnp.take(o.reshape(o.shape[0], B * L, -1), dst, axis=1)
-    cache = jnp.pad(_to_rows(kv[None], src, real)[0],
-                    ((0, 0), (0, room), (0, 0)))
-    return _latent_out(lw, o), {"kv": cache}
+        _to_rows(q, rows), _to_rows(k, rows), _to_rows(v, rows), lead,
+        _to_rows(q_r, rows), k_r, scale=cfg.latent_scale,
+        block=ATTENTION_BLOCK, head_dim=dn)
+    # the state: zeros where a row has no token
+    return _attention_out(lw, _to_stream(o, rows), z, dv), \
+        {"kv": jnp.pad(jnp.where(real[..., None], cache[0], 0),
+                       ((0, 0), (0, room), (0, 0)))}
 
 
 def _head(w, x, cfg):
@@ -894,30 +1012,82 @@ def _head(w, x, cfg):
                    preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "history", "room"))
-def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
-                 cfg: DecoderConfig, history: int, room: int):
-    """``tokens [T]``: the rows' tokens one behind the other, row 0
-    first (any id in the spare slots behind the last row); ``lengths
-    [B]``, each from 1 to ``history`` and ``T`` or under together ->
-    ``(last_logits [B, V] float32, state)``, the state laid out at
-    ``history`` slots and room for ``room`` more tokens."""
-    T, B = tokens.shape[0], lengths.shape[0]
+def row_align(history: int, dtype) -> int:
+    """Slots a tile: how many slots of the stream move as one into the
+    right-aligned rows and back. The rows of one memory tile of the
+    weights' dtype (8 of float32, 16 of bfloat16), held to a divisor of
+    ``history``, so that a row that ends on a tile's edge in the stream
+    ends on one in its ``history`` slots."""
+    return math.gcd(history, 32 // jnp.dtype(dtype).itemsize)
+
+
+def row_ends(lengths, align: int):
+    """Where each row of a packed stream ENDS (one past its last token):
+    every row takes its length rounded up to whole tiles of ``align``
+    slots, its tokens at the END of them. The engine that packs a
+    stream (numpy) and the program that reads it (traced) both ask
+    here; ``row_ends(lengths, align)[-1]`` slots hold the batch."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    return xp.cumsum(-(-lengths // align) * align)
+
+
+class _Rows(NamedTuple):
+    """What the attention layers lay a packed stream out as
+    right-aligned ``[B, history]`` rows by, whole tiles of ``align``
+    slots at a time."""
+    src: jax.Array   #: [B, history / align]: the stream's tile a row's is
+    real: jax.Array  #: [B, history]: the row's slot holds a token
+    dst: jax.Array   #: [T / align]: the rows' tile the stream's is
+    lead: jax.Array  #: [B]: each row's first real slot
+    last: jax.Array  #: [B]: each row's last slot in the stream
+    align: int       #: slots a tile (:func:`row_align`)
+
+
+def _row_maps(lengths, T: int, history: int, dtype):
+    """What a packed stream of ``T`` slots is, from its rows' ``lengths
+    [B]``: ``(valid [T]``: a slot holds a row's token, ``pos [T]``: its
+    position in its row, ``rows)``, the last a :class:`_Rows`."""
+    B = lengths.shape[0]
+    align = row_align(history, dtype)
+    if T % align:
+        raise ValueError(f"a stream of {T} slots is not whole tiles of "
+                         f"{align}")
     lengths = lengths.astype(jnp.int32)
-    ends = jnp.cumsum(lengths)
+    ends = row_ends(lengths, align)
     first = ends - lengths
     slot = jnp.arange(T, dtype=jnp.int32)
     row = jnp.minimum(jnp.searchsorted(ends, slot, side="right",
                                        method="compare_all"), B - 1)
-    valid, pos = slot < ends[-1], slot - first[row]
-    # the right-aligned [B, history] layout: its slot ``a`` of row ``r``
-    # is slot ``first[r] + a - (history - lengths[r])`` of the stream
+    pos = slot - first[row]
+    valid = (pos >= 0) & (slot < ends[-1])
+    # slot ``a`` of row ``r`` is slot ``ends[r] - history + a`` of the
+    # stream, so the row's tile ``j`` is the stream's tile ``(ends[r] -
+    # history) / align + j`` (0 where that lies before the stream: a
+    # tile none of the row's tokens is in), and a tile of the stream
+    # lies in the row of its last slot
     at = jnp.arange(history, dtype=jnp.int32)[None, :]
     lead = history - lengths
-    real = at >= lead[:, None]
-    rows = (jnp.where(real, (first - lead)[:, None] + at, 0), real,
-            jnp.where(valid, row * history + lead[row] + pos, 0), lead,
-            ends - 1)
+    tile = jnp.arange(history // align, dtype=jnp.int32)[None, :]
+    in_rows = (row * history + lead[row] + pos)[align - 1::align] // align
+    return valid, pos, _Rows(
+        src=jnp.maximum((ends[:, None] - history) // align + tile, 0),
+        real=at >= lead[:, None],
+        dst=jnp.where(valid[align - 1::align], in_rows, 0), lead=lead,
+        last=ends - 1, align=align)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "history", "room"))
+def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
+                 cfg: DecoderConfig, history: int, room: int):
+    """``tokens [T]``: the rows' tokens one row behind the other, row
+    ``r``'s ENDING at slot ``row_ends(lengths, align)[r]`` (any id in
+    the spare slots: the few before a row's first token, those behind
+    the last row); ``lengths [B]``, each from 1 to ``history``; ``T`` a
+    multiple of ``align = row_align(history, cfg.dtype)`` that holds
+    them -> ``(last_logits [B, V] float32, state)``, the state laid out
+    at ``history`` slots and room for ``room`` more tokens."""
+    lengths = lengths.astype(jnp.int32)
+    valid, pos, rows = _row_maps(lengths, tokens.shape[0], history, cfg.dtype)
     # ptpu: allow[materialized-gather] — the embedding lookup itself: the
     # [T, H] it makes is the residual stream
     x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
@@ -926,7 +1096,7 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
     for l, (lw, kind) in enumerate(zip(w["layers"], cfg.layer_types)):
         def op(z, lw=lw, kind=kind, l=l):
             if kind == CONV:
-                return _conv_prefill(lw, z, valid, pos, ends - 1, cfg)
+                return _conv_prefill(lw, z, valid, pos, rows.last, cfg)
             if kind == LATENT:
                 return _latent_prefill(lw, z, pos, rows, room, cfg)
             return _attention_prefill(lw, z, pos, rows, room, l, cfg)
@@ -941,11 +1111,11 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
         gaps += [g for g in (gap_op, gap_ff) if g is not None]
     cache = jnp.arange(history + room, dtype=jnp.int32)[None, :]
     state = {"layers": states, "load": jnp.stack(loads), "pos": lengths,
-             "valid": (cache >= lead[:, None]) & (cache < history),
+             "valid": (cache >= rows.lead[:, None]) & (cache < history),
              "filled": jnp.asarray(history, jnp.int32)}
     if gaps:  # hc_mult over 1: what twenty Sinkhorn passes left
         state["sinkhorn_gap"] = jnp.max(jnp.stack(gaps))
-    last = _stream_rows(x, ends - 1, cfg)
+    last = _stream_rows(x, rows.last, cfg)
     return _head(w, _streams_out(last, cfg), cfg), state
 
 
@@ -966,7 +1136,9 @@ def _attention_step(lw, z, st, valid, pos, at, l, cfg):
     counts; a sliding layer's in slot ``pos mod window`` of its ring,
     over the oldest, and the ring's slots up to ``pos`` are the real
     ones until it has wrapped."""
-    q, k, v = (a.swapaxes(0, 1) for a in _qkv(lw, z, pos, l, cfg))
+    D = cfg.head_dim
+    q, k, v = (a.swapaxes(0, 1).reshape(a.shape[1], -1, D)
+               for a in _qkv(lw, z, pos, l, cfg))
     if cfg.layer_types[l] == SLIDING:
         W = st["k"].shape[2]
 
@@ -980,14 +1152,14 @@ def _attention_step(lw, z, st, valid, pos, at, l, cfg):
     else:
         ks = jax.lax.dynamic_update_slice_in_dim(st["k"], k[:, :, None], at, 2)
         vs = jax.lax.dynamic_update_slice_in_dim(st["v"], v[:, :, None], at, 2)
-    B, nkv, D = k.shape
+    B, nkv, _ = k.shape
     s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, nkv, -1, D), ks,
                    preferred_element_type=jnp.float32) * D ** -0.5
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
     o = jnp.einsum("bgrs,bgsd->bgrd",
                    jax.nn.softmax(s, axis=-1).astype(vs.dtype), vs,
                    preferred_element_type=jnp.float32)
-    return _attention_out(lw, o.reshape(B, -1, D).swapaxes(0, 1), z), \
+    return _attention_out(lw, o.reshape(B, -1, D).swapaxes(0, 1), z, D), \
         {"k": ks, "v": vs}
 
 
@@ -999,12 +1171,14 @@ def _latent_step(lw, z, st, valid, pos, at, cfg):
     is ever laid out over the cache."""
     dt = jnp.dtype(cfg.dtype)
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    q, kv = _latent_project(lw, z, pos, cfg)   # [heads, B, 192], [B, 576]
+    cq, kv = _latent_project(lw, z, pos, cfg)   # [B, 768], [B, 576]
+    q = _project(cq, lw["w_qb"], cfg.num_attention_heads)  # [heads, B, 192]
+    q_rope = _rotary(q[..., None, dn:], pos, cfg.rope(LATENT))[..., 0, :]
     cache = jax.lax.dynamic_update_slice_in_dim(st["kv"], kv[:, None], at, 1)
     w_uk, w_uv = _latent_up(lw, cfg)
-    q_lat = jnp.einsum("nbd,cnd->bnc", q[..., :dn], w_uk,
+    q_lat = jnp.einsum("nbd,cnd->bnc", q[..., :dn].astype(dt), w_uk,
                        preferred_element_type=jnp.float32).astype(dt)
-    qs = jnp.concatenate([q_lat, q[..., dn:].swapaxes(0, 1)], axis=-1)
+    qs = jnp.concatenate([q_lat, q_rope.astype(dt).swapaxes(0, 1)], axis=-1)
     s = jnp.einsum("bnc,bsc->bns", qs, cache,
                    preferred_element_type=jnp.float32) * cfg.latent_scale
     s = jnp.where(valid[:, None, :], s, -jnp.inf)
@@ -1015,7 +1189,7 @@ def _latent_step(lw, z, st, valid, pos, at, cfg):
                        preferred_element_type=jnp.float32)[..., :rkv]
     o = jnp.einsum("bnc,cnd->nbd", o_lat.astype(dt), w_uv,
                    preferred_element_type=jnp.float32)
-    return _latent_out(lw, o), {"kv": cache}
+    return _attention_out(lw, o, z, cfg.v_head_dim), {"kv": cache}
 
 
 def _layer_step(lw, l, x, st, valid, pos, at, cfg):

@@ -1,20 +1,42 @@
 """Blockwise causal attention over right-aligned rows, with an optional
 sliding window: the generative prefill's attention (``models/decoder.py``).
 
-``q [Hq, B, L, D]``, ``k [Hkv, B, L, D]``, ``v [Hkv, B, L, Dv]`` (heads
-first, as a projection by head makes them; ``Dv`` is ``D`` in the
-grouped-query families and 128 beside a ``D`` of 192 under latent
-attention) hold ``B`` rows of up to ``L`` tokens,
-RIGHT-aligned: row ``b``'s tokens lie in slots
-``lead[b] .. L-1`` (the layout the decode's cache keeps), so a slot's
-index is its position plus ``lead[b]`` and causal order is slot order.
-Query slot ``i`` sees key slots ``max(lead, i - window + 1) .. i``
-(``window`` ``None``: all from ``lead``). Query head ``h`` reads
-key-value head ``h // (Hq / Hkv)`` through the block index, so ``k`` and
-``v`` are never repeated in memory. Returns ``o [Hq, B, L, Dv]`` in
-``q``'s dtype; slots before ``lead[b]`` hold nothing defined (blocks of
-pad slots are not computed and not written). What ``k`` and ``v`` hold
-before ``lead[b]`` weighs exactly 0 as long as it is finite.
+Layout: HEADS LIE IN THE LANES. Every operand and the output is ``[G, B,
+L, Hi x D]``: ``B`` rows of up to ``L`` tokens, the heads in ``G`` groups
+of ``Hi``, a group's heads side by side along the minor axis, ``D`` lanes
+each (``head_dim``, static). ``q [Gq, B, L, Hiq x D]``, ``k [Gk, B, L,
+Hik x D]``, ``v [Gv, B, L, Hiv x Dv]`` (``Dv`` is ``D`` in the
+grouped-query families and 128 beside latent attention's wider keys);
+each has its own ``G`` and the output has ``q``'s: ``o [Gq, B, L, Hiq x
+Dv]`` in ``q``'s dtype. ``G = 1`` is token-major, what a projection ``[T,
+H] x [H, heads x D]`` writes and what ``W_o`` reads: nothing between the
+matrix products and this kernel changes a layout. ``G = heads`` (``Hi =
+1``) is heads first, the kernel's only layout until PR 39. Groups
+between the two are what a projection taken a few heads at a time
+stacks. The kernel's tiles are ``(block, D)`` whatever the groups: the
+block index picks head ``h`` as lanes ``(h % Hi) D ..`` of group ``h //
+Hi``. On the chip a head in the lanes must fill whole lane tiles (``D``
+a multiple of 128); a narrower head takes ``Hi = 1``, where the block is
+the whole minor axis (Pallas' interpreter does not mind either way).
+
+Rows are RIGHT-aligned: row ``b``'s tokens lie in slots ``lead[b] ..
+L-1`` (the layout the decode's cache keeps), so a slot's index is its
+position plus ``lead[b]`` and causal order is slot order. Query slot
+``i`` sees key slots ``max(lead, i - window + 1) .. i`` (``window``
+``None``: all from ``lead``). Query head ``h`` reads key-value head ``h
+// (Hq / Hkv)`` through the block index, so ``k`` and ``v`` are never
+repeated in memory. Slots before ``lead[b]`` of the output hold nothing
+defined (blocks of pad slots are not computed and not written). What
+``k`` and ``v`` hold before ``lead[b]`` weighs exactly 0 as long as it
+is finite.
+
+A score of TWO products: with ``q2 [G, B, L, Hi x D2]`` and ``k2 [G', B,
+L, Hi' x D2]`` (as many heads as ``q``; ``k2``'s a divisor of them,
+chosen by the block index like ``k``'s) the score is ``q . k + q2 . k2``,
+two float32 partial products summed before the scale. Latent attention's
+expanded prefill is that: each head's own ``q_nope . k_nope`` plus its
+rotated half against the rotated key, which is ONE for all heads
+(``k2``'s one head) and is never concatenated 32 times over.
 
 One Pallas kernel (its name in a device trace: ``window_attention``, a
 ``custom-call``), grid ``(B, Hq, L / block, key steps)``. A tile of
@@ -37,7 +59,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,8 +84,9 @@ def _interpreted() -> bool:
     off_chip = jax.default_backend() != "tpu"
     if off_chip:
         log.warning("backend %s, not tpu: the Pallas kernels "
-                    "(window_attention, touched_experts, hyper_mix) run "
-                    "in Pallas' interpreter", jax.default_backend())
+                    "(window_attention, head_lanes, touched_experts, "
+                    "hyper_mix) run in Pallas' interpreter",
+                    jax.default_backend())
     return off_chip
 
 
@@ -77,17 +100,30 @@ def key_steps(n_blocks: int, block: int, window: Optional[int]) -> int:
         for i in range(n_blocks)))
 
 
+def _div(a, n: int):
+    """``a // n`` and ``a % n`` of a traced index that is never negative
+    (``lax.div`` and ``lax.rem`` round towards zero: the same there).
+    Floor division lowers through sign arithmetic, in every index map of
+    every kernel of a program: a fifth of the seconds a prefill's
+    program took to lower at each start (profiled by PR 38's builder)."""
+    n = jnp.asarray(n, a.dtype)
+    return jax.lax.div(a, n), jax.lax.rem(a, n)
+
+
 def _first_key_block(i, lead, block: int, window: Optional[int]):
     """The first key tile query tile ``i`` of a row needs."""
-    first = lead // block
+    first = _div(lead, block)[0]
     if window is None:
         return first
-    return jnp.maximum(first, jnp.maximum(i * block - window + 1, 0)
-                       // block)
+    return jnp.maximum(first, _div(jnp.maximum(i * block - window + 1, 0),
+                                   block)[0])
 
 
-def _kernel(lead_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, window: Optional[int], block: int, steps: int):
+def _kernel(lead_ref, *refs, scale: float, window: Optional[int],
+            block: int, steps: int):
+    # q, k, v (then q2, k2 where the score is two products), o, scratch
+    *ins, o_ref, m_ref, l_ref, acc_ref = refs
+    q_ref, k_ref, v_ref = ins[:3]
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     lead = lead_ref[b]
     kb = _first_key_block(i, lead, block, window) + j
@@ -99,10 +135,16 @@ def _kernel(lead_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def tile(masked: bool):
-        s = jax.lax.dot_general(
+    def scores(q_ref, k_ref):
+        return jax.lax.dot_general(
             q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            preferred_element_type=jnp.float32)
+
+    def tile(masked: bool):
+        s = scores(q_ref, k_ref)
+        if ins[3:]:
+            s = s + scores(*ins[3:])
+        s = s * scale
         if masked:
             qs = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             ks = kb * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -141,67 +183,120 @@ def _kernel(lead_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "scale", "block"))
+def _heads(a: jax.Array, D: int, what: str) -> Tuple[int, int]:
+    """``(heads, heads a group)`` of ``a [G, B, L, Hi x D]``."""
+    G, Hi = a.shape[0], a.shape[-1] // D
+    if Hi * D != a.shape[-1]:
+        raise ValueError(f"{what}: {a.shape[-1]} lanes are not whole heads "
+                         f"of {D}")
+    return G * Hi, Hi
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "block",
+                                             "head_dim"))
 def window_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     lead: jax.Array, *, scale: float,
-                     window: Optional[int] = None,
-                     block: int = BLOCK) -> jax.Array:
+                     lead: jax.Array, q2: Optional[jax.Array] = None,
+                     k2: Optional[jax.Array] = None, *, scale: float,
+                     window: Optional[int] = None, block: int = BLOCK,
+                     head_dim: Optional[int] = None) -> jax.Array:
     """See the module's docstring. ``L`` must divide by ``block`` (which
-    is held to ``L``); ``lead [B]`` int32, each under ``L``."""
-    Hq, B, L, D = q.shape
-    Hkv, Dv = k.shape[0], v.shape[-1]
+    is held to ``L``); ``lead [B]`` int32, each under ``L``; ``head_dim``
+    the ``D`` of ``q`` and ``k`` (left out: their whole last axis, one
+    head a group)."""
+    D = head_dim or q.shape[-1]
+    B, L = q.shape[1:3]
+    (Hq, Hi), (Hkv, Hik) = _heads(q, D, "q"), _heads(k, D, "k")
     if Hq % Hkv:
         raise ValueError("query heads must divide by key-value heads")
+    Gv, Wv = v.shape[0], v.shape[-1]
+    if Hkv % Gv or Wv % (Hkv // Gv):
+        raise ValueError("v holds the key-value heads, in whole groups")
+    Hiv = Hkv // Gv
+    Dv = Wv // Hiv
+    if (q2 is None) != (k2 is None):
+        raise ValueError("a second product takes q2 and k2")
     block = min(block, L)
     if L % block:
         raise ValueError(f"{L} slots do not divide into tiles of {block}")
-    g, n = Hq // Hkv, L // block
+    n = L // block
     steps = key_steps(n, block, window)
 
     def first_real(i, lead_b):
         # a tile of pad slots maps to the row's first real tile: it is
         # neither fetched nor written back
-        return jnp.maximum(i, lead_b // block)
+        return jnp.maximum(i, _div(lead_b, block)[0])
 
-    def q_map(b, h, i, j, lead):
-        return h, b, first_real(i, lead[b]), 0
+    def queries(D, Hi):
+        # head h is lanes (h % Hi) D .. of group h // Hi
+        def at(b, h, i, j, lead):
+            group, inside = _div(h, Hi)
+            return group, b, first_real(i, lead[b]), inside
+        return pl.BlockSpec((None, None, block, D), at)
 
-    def kv_map(b, h, i, j, lead):
-        ii = first_real(i, lead[b])
-        kb = _first_key_block(ii, lead[b], block, window) + j
-        return h // g, b, jnp.minimum(kb, ii), 0
+    def keys(D, Hi, g):
+        # query head h reads key-value head h // g
+        def at(b, h, i, j, lead):
+            ii = first_real(i, lead[b])
+            kb = _first_key_block(ii, lead[b], block, window) + j
+            group, inside = _div(_div(h, g)[0], Hi)
+            return group, b, jnp.minimum(kb, ii), inside
+        return pl.BlockSpec((None, None, block, D), at)
 
-    tile, v_tile = (None, None, block, D), (None, None, block, Dv)
+    g = Hq // Hkv
+    ins = [q, k, v]
+    specs = [queries(D, Hi), keys(D, Hik, g), keys(Dv, Hiv, g)]
+    if q2 is not None:
+        D2 = q2.shape[-1] * q2.shape[0] // Hq
+        (H2, Hi2), (Hkv2, Hik2) = _heads(q2, D2, "q2"), _heads(k2, D2, "k2")
+        if H2 != Hq or Hq % Hkv2:
+            raise ValueError("q2 holds q's heads, k2 a divisor of them")
+        ins += [q2, k2]
+        specs += [queries(D2, Hi2), keys(D2, Hik2, Hq // Hkv2)]
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, window=window, block=block,
                           steps=steps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, Hq, n, steps),
-            in_specs=[pl.BlockSpec(tile, q_map), pl.BlockSpec(tile, kv_map),
-                      pl.BlockSpec(v_tile, kv_map)],
-            out_specs=pl.BlockSpec(v_tile, q_map),
+            in_specs=specs, out_specs=queries(Dv, Hi),
             scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, 1), jnp.float32),
                             pltpu.VMEM((block, Dv), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape[:-1] + (Dv,), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape[:-1] + (Hi * Dv,), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpreted(), name="window_attention",
-    )(lead.astype(jnp.int32), q, k, v)
+    )(lead.astype(jnp.int32), *ins)
 
 
-def dense_attention(q, k, v, lead, *, scale: float,
-                    window: Optional[int] = None):
-    """The same contract as dense softmax, float32: what the tests hold
-    the kernel to (and small enough to read)."""
-    Hq, B, L, D = q.shape
-    g = Hq // k.shape[0]
+def heads_first(a: jax.Array, D: int) -> jax.Array:
+    """``a [G, B, L, Hi x D]`` as ``[G x Hi, B, L, D]``: the layout with
+    one head a group (a transpose: for tests and references)."""
+    G, B, L, _ = a.shape
+    return jnp.moveaxis(a.reshape(G, B, L, -1, D), 3, 1).reshape(
+        -1, B, L, D)
+
+
+def dense_attention(q, k, v, lead, q2=None, k2=None, *, scale: float,
+                    window: Optional[int] = None,
+                    head_dim: Optional[int] = None):
+    """The same contract as dense softmax, float32, heads first
+    ``[Hq, B, L, Dv]`` whatever the operands' groups: what the tests
+    hold the kernel to (and small enough to read)."""
+    D = head_dim or q.shape[-1]
     f32 = jnp.float32
-    kk = jnp.repeat(k.astype(f32), g, axis=0)
-    vv = jnp.repeat(v.astype(f32), g, axis=0)
-    s = jnp.einsum("hbqd,hbkd->hbqk", q.astype(f32), kk,
-                   precision="highest") * scale
+    q, k = heads_first(q.astype(f32), D), heads_first(k.astype(f32), D)
+    Hq, B, L, _ = q.shape
+    v = heads_first(v.astype(f32), v.shape[-1] * v.shape[0] // k.shape[0])
+    if q2 is not None:  # one score over the concatenated operands
+        D2 = q2.shape[-1] * q2.shape[0] // Hq
+        k2 = heads_first(k2.astype(f32), D2)
+        q = jnp.concatenate([q, heads_first(q2.astype(f32), D2)], axis=-1)
+        k = jnp.concatenate(
+            [k, jnp.repeat(k2, k.shape[0] // k2.shape[0], axis=0)], axis=-1)
+    g = Hq // k.shape[0]
+    kk, vv = jnp.repeat(k, g, axis=0), jnp.repeat(v, g, axis=0)
+    s = jnp.einsum("hbqd,hbkd->hbqk", q, kk, precision="highest") * scale
     at = jnp.arange(L)
     see = (at[None, :] <= at[:, None])[None] \
         & (at[None, None, :] >= lead[:, None, None])

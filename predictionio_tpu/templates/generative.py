@@ -12,8 +12,9 @@ vocabulary are dropped.
 
 One query is a prefill and then ``max_new - 1`` decode steps, and a
 whole batch of them is ONE ``batch_predict_async``: the rows are padded
-to a row bucket and their real tokens packed one behind the other into
-a stream of ``rows x the history bucket of the batch's MEAN history``
+to a row bucket and their real tokens packed one row behind the other
+(each row ending on a memory tile's edge, ``decoder.row_ends``) into a
+stream of ``rows x the history bucket of the batch's MEAN history``
 slots, ``_gen_prefill`` and ``_gen_decode`` are enqueued back to back
 without a host sync, and the resolver blocks on the answer: the
 protocol of ``ALSAlgorithm.batch_predict_async``, so ``StagedPipeline``
@@ -231,19 +232,27 @@ class GenerativeAlgorithm(Algorithm):
         slots the prefill ran."""
         import jax
 
-        from ..models.decoder import _gen_decode, _gen_prefill
+        from ..models.decoder import (
+            _gen_decode, _gen_prefill, row_align, row_ends)
 
-        p = self.params
+        p, cfg = self.params, model.cfg
         B = _bucket(p.row_buckets, len(hists))
         lengths = np.ones((B,), np.int32)  # a pad row is one token long
         lengths[:len(hists)] = [len(h) for h in hists]
-        # the stream is sized by the batch's MEAN history: uniform rows
-        # at a history bucket (the warm ladder) give every size there is
-        T = B * _bucket(p.history_buckets, -(-int(lengths.sum()) // B))
-        flat = np.concatenate(hists)
+        # a row ends where the program's tiles do (a few spare slots
+        # before its first token), and the stream is sized by the
+        # batch's MEAN history so laid out: uniform rows at a history
+        # bucket (the warm ladder) give every size there is
+        align = row_align(p.history_buckets[-1], cfg.dtype)
+        if any(b % align for b in p.history_buckets):
+            # the warm ladder would miss the streams such a bucket gives
+            raise ValueError(f"history_buckets {p.history_buckets}: each "
+                             f"must hold whole tiles of {align} slots")
+        ends = row_ends(lengths, align)
+        T = B * _bucket(p.history_buckets, -(-int(ends[-1]) // B))
         tokens = np.zeros((T,), np.int32)
-        tokens[:len(flat)] = flat
-        cfg = model.cfg
+        for h, end in zip(hists, ends):
+            tokens[end - len(h):end] = h
         # explicit: the server's transfer guard logs an implicit one
         tokens, lengths = jax.device_put((tokens, lengths))
         with annotate("pio:gen_prefill", rows=B, slots=T):

@@ -114,16 +114,20 @@ def _ref_weights(w, cfg):
 HISTORY = 32  # the slots the state is laid out at in these tests
 
 
-def _pack(hists, rows, slots, seed=0):
-    """The engine's layout: the rows' tokens one behind the other, pad
-    rows of one token behind them, ARBITRARY ids in the spare slots."""
+def _pack(hists, rows, slots, seed=0, align=8):
+    """The engine's layout: the rows' tokens one row behind the other,
+    each row ENDING on a tile of ``align`` slots (``decoder.row_ends``;
+    8: what ``row_align`` gives these tests' float32 weights and
+    histories of 32 and 40), pad rows of one token behind them,
+    ARBITRARY ids in the spare slots."""
     lengths = np.ones((rows,), np.int32)
     lengths[:len(hists)] = [len(h) for h in hists]
+    ends = decoder.row_ends(lengths, align)
+    assert ends[-1] <= slots, "the stream does not hold its rows"
     tokens = np.random.default_rng(seed).integers(
         0, SMALL["vocab_size"], slots).astype(np.int32)
-    flat = np.concatenate([np.asarray(h, np.int32) for h in hists])
-    tokens[:len(flat)] = flat
-    tokens[len(flat):int(lengths.sum())] = 0
+    for n, end, h in zip(lengths, ends, list(hists) + [[0]] * rows):
+        tokens[end - n:end] = h
     return jnp.asarray(tokens), jnp.asarray(lengths)
 
 
@@ -132,7 +136,9 @@ def _history(cfg):
 
 
 def _prefill(w, cfg, hists, slots, rows=None, steps=STEPS):
-    tokens, lengths = _pack(hists, rows or len(hists), slots)
+    tokens, lengths = _pack(
+        hists, rows or len(hists), slots,
+        align=decoder.row_align(_history(cfg), cfg.dtype))
     return decoder._gen_prefill(w, tokens, lengths, cfg=cfg,
                                 history=_history(cfg), room=steps)
 
@@ -239,24 +245,25 @@ def test_the_ring_wraps_twice_and_still_matches_the_full_forward(
 
 
 #: (history lengths, rows, slots): the stream's sizes are the engine's
-#: ladder for 4 rows over history buckets (8, 16, 32)
+#: ladder for 4 rows over history buckets (8, 16, 32), every row (a pad
+#: row of one token too) taking whole tiles of 8 slots
 RAGGED = {
     "rows_of_one_token": ([1, 1, 1, 1], 4, 32),
     "rows_shorter_than_the_conv_window": ([2, 1, 2, 1], 4, 32),
-    "a_row_at_the_top_bucket": ([32, 3, 9, 20], 4, 64),
+    "a_row_at_the_top_bucket": ([32, 3, 9, 20], 4, 128),
     "every_row_at_the_top_bucket": ([32, 32, 32, 32], 4, 128),
-    "a_batch_under_its_row_bucket": ([7, 12], 4, 32),
+    "a_batch_under_its_row_bucket": ([7, 12], 4, 64),
     "one_row_in_a_row_bucket": ([32], 4, 64),
     "a_sum_on_the_lowest_rung": ([8, 8, 8, 8], 4, 32),
     "a_sum_just_over_the_lowest_rung": ([9, 8, 8, 8], 4, 64),
-    "a_sum_on_the_middle_rung": ([16, 30, 2, 16], 4, 64),
+    "a_sum_on_the_middle_rung": ([16, 6, 2, 16], 4, 64),
     "a_sum_on_the_top_rung": ([32, 31, 30, 29], 4, 128),
     "the_sorted_product_with_a_spare_tail": ([32, 1, 2, 32, 17, 5, 3, 9],
                                              8, 256),
     # the ``laguna`` family (history 40, window 8, tiles of 8)
     "laguna_rows_inside_one_window": ([3, 8, 1, 7], 4, 32),
     "laguna_rows_of_several_windows": ([40, 9, 25, 16], 4, 160),
-    "laguna_a_batch_under_its_row_bucket": ([33, 12], 4, 64),
+    "laguna_a_batch_under_its_row_bucket": ([33, 12], 4, 160),
     "laguna_every_row_at_the_top_bucket": ([40, 40, 40, 40], 4, 160),
 }
 
@@ -293,6 +300,40 @@ def test_the_stream_and_neighbours_do_not_move_a_row(
     np.testing.assert_allclose(f2[-1], first[1], atol=TOL32)
     np.testing.assert_array_equal(t2[-1], toks[1])
     np.testing.assert_allclose(s2[-1], scores[1], atol=TOL32)
+
+
+@pytest.mark.parametrize("family,lanes,head_parts", [
+    ("lfm2", 16, 1), ("laguna", 16, 1), ("laguna", 16, 3), ("laguna", 8, 2),
+    ("xing4", 16, 1), ("xing4", 8, 1)])
+def test_the_heads_layout_moves_nothing(small, lag, xing, monkeypatch, family,
+                                        lanes, head_parts):
+    """A head that fills whole lane tiles stays in its projection's
+    lanes (``[G, T, heads / G x D]``, what ``window_attention`` reads and
+    writes), a narrower one gets an axis of its own: logits, state and a
+    decode step are the same numbers either way. These families' heads
+    are 16 wide: heads first at 128 lanes, in the lanes at 16 or 8 (the
+    latent family's 8 rotated dimensions are then padded to 16, or not
+    at all), alone or with the queries in ``head_parts`` groups."""
+    d, cfg, w = {"lfm2": small, "laguna": lag, "xing4": xing}[family]
+    hists = _hists(np.random.default_rng(14), [29, 7, 18, 1])
+    tokens, lengths = _pack(hists, 4, 96)
+
+    def run():
+        first, st = decoder._gen_prefill.__wrapped__(
+            w, tokens, lengths, cfg=cfg, history=_history(cfg), room=2)
+        tok = jnp.argmax(first, axis=-1).astype(jnp.int32)
+        return first, st, decoder._decode_step(w, st, tok, cfg)
+
+    assert decoder._groups(4, 96, cfg.head_dim) == 4  # heads first as it is
+    want = run()
+    monkeypatch.setattr(decoder, "LANES", lanes)
+    monkeypatch.setattr(decoder, "STREAM_TOKENS", 32)  # 96 slots are one
+    monkeypatch.setattr(decoder, "HEAD_GROUP_ELEMENTS",
+                        96 * 16 * 6 // head_parts)
+    assert decoder._groups(4, 96, 16) == 1 and decoder._groups(4, 4, 16) == 4
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(run())):
+        np.testing.assert_allclose(a, b, atol=2e-5)
 
 
 def _row_state(state, r):

@@ -112,8 +112,7 @@ def test_rotary_by_kind_is_the_formula_written_out(kind):
     x1, x2 = x[..., :R // 2], x[..., R // 2:R]
     want = np.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
                            x[..., R:]], axis=-1)
-    got = decoder._rotary(jnp.asarray(x).swapaxes(0, 1), jnp.arange(12),
-                          cfg.rope(kind)).swapaxes(0, 1)
+    got = decoder._rotary(jnp.asarray(x), jnp.arange(12), cfg.rope(kind))
     np.testing.assert_allclose(got, want, atol=2e-6)
     np.testing.assert_allclose(ref.rotary(jnp.asarray(x), p), want,
                                atol=2e-6)

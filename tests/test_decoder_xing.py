@@ -164,7 +164,7 @@ RAGGED = {
     "rows_of_one_token": ([1, 1, 1, 1], 4, 32),
     "a_row_at_the_top_bucket": ([32, 3, 9, 20], 4, 128),
     "every_row_at_the_top_bucket": ([32, 32, 32, 32], 4, 128),
-    "a_batch_under_its_row_bucket": ([7, 12], 4, 32),
+    "a_batch_under_its_row_bucket": ([7, 12], 4, 64),
 }
 
 
@@ -182,7 +182,7 @@ def test_a_packed_ragged_batch_matches_the_reference(xing, case):
 
 
 @pytest.mark.parametrize("slots,beside", [(192, []), (192, [30, 32]),
-                                          (96, [3, 32, 20])])
+                                          (128, [3, 32, 20])])
 def test_the_stream_and_neighbours_do_not_move_a_row(xing, xing_served,
                                                      slots, beside):
     """The same history alone and behind other rows in larger streams
@@ -242,7 +242,10 @@ def test_the_absorbed_step_is_the_expanded_one_on_the_same_state(xing):
     valid = st["valid"].at[:, at].set(True)
     got, new = decoder._latent_step(lw, z, st["layers"][1], valid, pos, at,
                                     cfg)
-    q, kv = decoder._latent_project(lw, z, pos, cfg)
+    cq, kv = decoder._latent_project(lw, z, pos, cfg)
+    q = decoder._dot(cq, lw["w_qb"]).reshape(B, 4, 24)
+    q = jnp.concatenate([q[..., :16], decoder._rotary(
+        q[..., 16:], pos, cfg.rope(decoder.LATENT))], axis=-1)
     cache = np.asarray(new["kv"], np.float64)
     np.testing.assert_array_equal(np.asarray(new["kv"][:, at]), kv)
     up = np.asarray(lw["w_kvb"], np.float64).reshape(16, 4, 32)
@@ -250,7 +253,7 @@ def test_the_absorbed_step_is_the_expanded_one_on_the_same_state(xing):
                                   up[..., :16]),
                         np.repeat(cache[:, None, :, 16:], 4, axis=1)], -1)
     v = np.einsum("bsc,cnd->bnsd", cache[..., :16], up[..., 16:])
-    s = np.einsum("nbd,bnsd->bns", np.asarray(q, np.float64), k) \
+    s = np.einsum("bnd,bnsd->bns", np.asarray(q, np.float64), k) \
         * cfg.latent_scale
     s = np.where(np.asarray(valid)[:, None, :], s, -np.inf)
     p = np.exp(s - s.max(-1, keepdims=True))

@@ -132,18 +132,20 @@ def _post_get(port, path):
 
 
 #: history lengths of one batch -> the slots its prefill runs: the row
-#: bucket x the history bucket of the batch's MEAN history (a pad row
-#: is one token long), with row buckets (4, 8) and history buckets
+#: bucket x the history bucket of the batch's MEAN history, every row (a
+#: pad row is one token long) taking whole tiles of 8 slots
+#: (``decoder.row_ends``), with row buckets (4, 8) and history buckets
 #: (16, 32)
 MIXES = {
     "one_short_row": ([1], 64),
     "every_row_at_the_top_bucket": ([32, 32, 32, 32], 128),
-    "long_rows_beside_short_ones": ([32, 1, 1, 30], 64),
+    "long_rows_beside_short_ones": ([32, 1, 1, 14], 64),
     "a_mean_just_over_a_bucket": ([32, 32, 1], 128),
     "five_rows_in_the_bucket_of_eight": ([16] * 5, 128),
     "eight_rows_at_the_top_bucket": ([32] * 8, 256),
-    "seven_ragged_rows": ([32, 31, 2, 9, 17, 1, 25], 128),
-    "eight_rows_on_the_lowest_rung": ([32, 32, 32, 9, 1, 1, 1, 1], 128),
+    "seven_ragged_rows": ([32, 31, 2, 9, 17, 1, 25], 256),
+    "seven_ragged_rows_on_the_lowest_rung": ([24, 31, 2, 9, 8, 1, 16], 128),
+    "eight_rows_on_the_lowest_rung": ([32, 32, 24, 8, 1, 1, 1, 1], 128),
 }
 
 
@@ -365,6 +367,18 @@ def test_four_streams_and_a_latent_cache_through_the_same_engine():
     assert gap["count"] == 1 and 0 < gap["sum"] < 5e-3
     touched = registry.export()["pio_moe_experts_touched"]["children"][0]
     assert [b[0] for b in touched["buckets"]][-2:] == [8.0, "+Inf"]
+
+
+def test_a_history_bucket_that_is_not_whole_tiles_is_refused():
+    """Rows end on a tile's edge (``decoder.row_ends``), so the ladder's
+    uniform rows at a bucket of 12 would be sized as rows of 16 and the
+    stream a ragged batch can give there would never be warmed: the
+    engine says so at the first dispatch, the warm-up's."""
+    params = GenerativeParams(model=SMALL, seed=3, max_new=4,
+                              row_buckets=(2,), history_buckets=(12, 32))
+    model = GenerativeModel(config=dict(SMALL), seed=3)
+    with pytest.raises(ValueError, match="whole tiles of 8"):
+        GenerativeAlgorithm(params)._dispatch(model, [[1, 2, 3]])
 
 
 def test_experts_touched_bounds_follow_the_model():

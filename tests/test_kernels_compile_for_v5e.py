@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from predictionio_tpu.ops import hyper_mix, moe, window_attention as wa
+from predictionio_tpu.ops import (
+    head_lanes, hyper_mix, moe, window_attention as wa)
 
 
 @pytest.fixture(scope="module")
@@ -31,6 +32,7 @@ def for_the_chip(monkeypatch):
     monkeypatch.setattr(moe, "_interpreted", lambda: False)
     monkeypatch.setattr(wa, "_interpreted", lambda: False)
     monkeypatch.setattr(hyper_mix, "_interpreted", lambda: False)
+    monkeypatch.setattr(head_lanes, "_interpreted", lambda: False)
 
 
 def _compiled(fn, one_chip, *shapes):
@@ -59,21 +61,224 @@ def test_the_touched_experts_kernel_compiles_at_the_cells_widths(
     assert "tpu_custom_call" in text and "touched_experts" in text
 
 
-def test_the_attention_kernel_compiles_with_values_narrower_than_keys(
+#: the attention kernel's operands at the three generative cells' widths:
+#: (head width, window, [q, k, v(, q2, k2)] as ``[G, rows, slots, lanes]``)
+ATTENTION = {
+    # laguna-xs2-l5: 128-wide heads in the projections' lanes, the
+    # queries in two groups of 24 (a full layer's 48) or 32 (a sliding
+    # layer's 64), the 8 key-value heads in one
+    "laguna-full-48-over-8": (128, None, [
+        (2, 16, 4096, 24 * 128), (1, 16, 4096, 1024), (1, 16, 4096, 1024)]),
+    "laguna-sliding-64-over-8": (128, 512, [
+        (2, 16, 4096, 32 * 128), (1, 16, 4096, 1024), (1, 16, 4096, 1024)]),
+    # xing4-29b-a4b-l6, latent attention expanded: 32 heads' nope halves
+    # and values 128 wide in the lanes, the rotated halves (64) padded
+    # to a lane tile against ONE rotated key for all heads
+    "xing4-128-and-64-rope-x32": (128, None, [
+        (1, 4, 4096, 4096), (1, 4, 4096, 4096), (1, 4, 4096, 4096),
+        (1, 4, 4096, 4096), (1, 4, 4096, 128)]),
+    # the same heads first, the rotated halves at their own 64: what a
+    # model whose nope half does not fill a lane tile takes
+    "xing4-heads-first-rope-64": (128, None, [
+        (32, 4, 4096, 128), (32, 4, 4096, 128), (32, 4, 4096, 128),
+        (32, 4, 4096, 64), (1, 4, 4096, 64)]),
+    # lfm2-8b-a1b-l14: 64-wide heads do not fill a lane tile: a head a
+    # group, the layout the kernel had until PR 38
+    "lfm2-64-x32-over-8": (64, None, [
+        (32, 64, 512, 64), (8, 64, 512, 64), (8, 64, 512, 64)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTENTION))
+def test_the_attention_kernel_compiles_at_the_cells_widths(
+        one_chip, for_the_chip, case):
+    import functools
+
+    D, window, shapes = ATTENTION[case]
+    bf16 = jnp.bfloat16
+    compiled = _compiled(
+        functools.partial(wa.window_attention.__wrapped__, scale=0.125,
+                          window=window, block=wa.BLOCK, head_dim=D),
+        one_chip, *((s, bf16) for s in shapes[:3]),
+        ((shapes[0][1],), jnp.int32), *((s, bf16) for s in shapes[3:]))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "window_attention" in text
+
+
+def test_heads_narrower_than_a_lane_tile_cannot_lie_in_the_lanes(
         one_chip, for_the_chip):
-    """Latent attention expanded, at the xing4 cell's widths: 32 heads
-    on both sides, queries and keys 192 wide (not a multiple of 128
-    lanes) against values 128 wide, 4 rows of 4,096 slots."""
+    """Why ``models/decoder.py::_groups`` asks the width: 64-wide heads
+    side by side in the lanes are blocks of half a lane tile, which the
+    chip's compiler refuses (and Pallas' interpreter does not)."""
     import functools
 
     bf16 = jnp.bfloat16
-    compiled = _compiled(
-        functools.partial(wa.window_attention.__wrapped__, scale=0.14468,
-                          window=None, block=wa.BLOCK),
-        one_chip, ((32, 4, 4096, 192), bf16), ((32, 4, 4096, 192), bf16),
-        ((32, 4, 4096, 128), bf16), ((4,), jnp.int32))
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text and "window_attention" in text
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        _compiled(
+            functools.partial(wa.window_attention.__wrapped__, scale=0.125,
+                              window=None, block=wa.BLOCK, head_dim=64),
+            one_chip, ((1, 64, 512, 32 * 64), bf16),
+            ((1, 64, 512, 8 * 64), bf16), ((1, 64, 512, 8 * 64), bf16),
+            ((64,), jnp.int32))
+
+
+@pytest.mark.parametrize("heads,rotated", [(16, 128), (24, 64), (8, 128)],
+                         ids=["sliding-16-of-64", "full-24-of-48-half",
+                              "the-8-key-heads"])
+def test_the_head_kernels_compile_at_the_laguna_cells_widths(
+        one_chip, for_the_chip, heads, rotated):
+    """128-wide heads side by side in the lanes of a 32,768-slot
+    stream: a group of a layer's queries (16 of a sliding layer's 64,
+    24 of a full layer's 48, which rotates half a head) or its 8 keys
+    through ``head_norm_rotary``, the attention's output through
+    ``head_gate``."""
+    import functools
+
+    import numpy as np
+
+    T, D, f32 = 32768, 128, jnp.float32
+    rope = (tuple(1.0 / 1e4 ** (np.arange(0, rotated, 2) / rotated)), 1.0)
+    text = _compiled(
+        functools.partial(head_lanes.head_norm_rotary.__wrapped__, rope=rope,
+                          head_dim=D, eps=1e-6, dtype="bfloat16"),
+        one_chip, ((T, heads * D), f32), ((D,), f32),
+        ((T,), jnp.int32)).as_text()
+    assert "tpu_custom_call" in text and "head_norm_rotary" in text
+    text = _compiled(
+        functools.partial(head_lanes.head_gate.__wrapped__, head_dim=D),
+        one_chip, ((T, heads * D), jnp.bfloat16), ((T, heads), f32)).as_text()
+    assert "tpu_custom_call" in text and "head_gate" in text
+
+
+#: a dispatch's prefill at the generative cells' most frequent shapes:
+#: (configuration, rows, stream slots, history, the most MiB that
+#: ``copy`` instructions outside fusions may write in all). By this
+#: file's count (an instruction once, whatever the trips of the loop it
+#: stands in) the parent of PR 39 wrote 12,654 MiB at laguna's 32,768
+#: rung (88 % of its cell's batches), 6,471 at xing4's 12,288 and 1,517
+#: at lfm2's 16,384; this tree 370, 2,076 (the streams' prefetches into
+#: the memory space the gathers read from, the weights) and 545. The
+#: ceilings of the two cells whose heads take the lanes are under a
+#: third and a half of the parent's; lfm2's, whose 64-wide heads keep a
+#: head a group, is the parent's reading
+PREFILLS = {
+    "laguna-16x32768": ("laguna-xs2-l5", 16, 32768, 4096, 2048),
+    "xing4-4x12288": ("xing4-29b-a4b-l6", 4, 12288, 4096, 3072),
+    "lfm2-64x16384": ("lfm2-8b-a1b-l14", 64, 16384, 512, 1517),
+}
+#: MiB that ``copy`` / ``transpose`` instructions beside a
+#: ``window_attention`` call may write in all. The parent of PR 39 wrote
+#: over 10,000 (laguna) and 3,840 (xing4) there; this tree writes 0
+BESIDE_THE_KERNEL_MB = 64
+_PREFILL = {}  # case -> (cfg, the optimised text): one compile a case
+
+
+def _instructions(text):
+    """``{name: (opcode, result bytes, operand names)}`` of the optimised
+    HLO's instructions outside fusions' bodies."""
+    import re
+
+    size = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1}
+    out, inside = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\((.*)$", line)
+        if not m or (inside or "").startswith("fused_"):
+            continue
+        name, dtype, dims, opcode, rest = m.groups()
+        n = size.get(dtype, 4)
+        for x in filter(None, dims.split(",")):
+            n *= int(x)
+        if opcode == "custom-call" and "window_attention" in rest:
+            opcode = "window_attention"
+        out[name] = (opcode, n, re.findall(r"%([\w.\-]+)",
+                                           rest.split("), ")[0]))
+    return out
+
+
+def _prefill(one_chip, case):
+    """``(cfg, instructions)`` of ``_gen_prefill`` compiled for the
+    described v5e at ``PREFILLS[case]`` (under ``for_the_chip``)."""
+    import json
+    import os
+
+    from predictionio_tpu.models import decoder
+
+    if case not in _PREFILL:
+        name, rows, slots, history, _ = PREFILLS[case]
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "cellbench", "configs",
+                name + ".json")) as f:
+            cfg = decoder.DecoderConfig.from_dict(json.load(f))
+        w = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(
+                lambda: decoder.init_weights(jax.random.key(0), cfg)))
+        text = decoder._gen_prefill.lower(
+            w, jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+            cfg=cfg, history=history, room=32).compile().as_text()
+        _PREFILL[case] = cfg, _instructions(text)
+    return _PREFILL[case]
+
+
+@pytest.mark.parametrize("case", sorted(PREFILLS))
+def test_a_prefill_s_copies_stay_under_their_ceiling(
+        one_chip, for_the_chip, case):
+    """What ``prefill_copy_device_ms`` reads on the chip, held without
+    one: the bytes the ``copy`` instructions of the optimised
+    ``_gen_prefill`` write (layout changes XLA puts between operations;
+    a fusion computes). A projection written by head, a gather along a
+    middle axis or a reshape across a memory tile on the attention's
+    path shows here as gigabytes (docs/kernels.md)."""
+    _, ins = _prefill(one_chip, case)
+    copied = sum(n for op, n, _ in ins.values() if op == "copy")
+    assert 0 < copied <= PREFILLS[case][4] << 20, copied >> 20
+
+
+@pytest.mark.parametrize("case", ["laguna-16x32768", "xing4-4x12288"])
+def test_nothing_changes_a_layout_beside_the_attention_kernel(
+        one_chip, for_the_chip, case):
+    """``_gen_prefill`` compiled for the described v5e: between the
+    gathers into rows and the kernel, and between the kernel and the
+    gather back into the stream, no ``copy`` or ``transpose`` stands
+    (through bitcasts and tuple plumbing): the kernel reads and writes
+    the layout the projections have. The state's transpose into the
+    decode's ``[B, kv heads, slots, D]`` is a fusion off the gathered
+    keys and values (``_cache_layout``), not on the kernel's path, and
+    is exempt. (``lfm2-8b-a1b-l14``'s 64-wide heads keep a head a
+    group and the copies that come with it.)"""
+    cfg, ins = _prefill(one_chip, case)
+    users = {}
+    for name, (_, _, operands) in ins.items():
+        for o in operands:
+            users.setdefault(o, []).append(name)
+    plumbing = ("bitcast", "get-tuple-element", "tuple", "reshape")
+
+    def through(name, step):  # the nearest instructions that do work
+        found, todo = set(), list(step(name))
+        while todo:
+            n = todo.pop()
+            if ins.get(n, ("?",))[0] in plumbing:
+                todo += step(n)
+            else:
+                found.add(n)
+        return found
+
+    kernels = [n for n, (op, _, _) in ins.items() if op == "window_attention"]
+    assert len(kernels) == cfg.num_hidden_layers
+    beside = set()
+    for k in kernels:
+        beside |= through(k, lambda n: ins[n][2] if n in ins else [])
+        beside |= through(k, lambda n: users.get(n, []))
+    moved = {n: ins[n][1] for n in beside
+             if n in ins and ins[n][0] in ("copy", "transpose")}
+    assert sum(moved.values()) <= BESIDE_THE_KERNEL_MB << 20, moved
 
 
 @pytest.mark.parametrize("slots", [4096, 16384])
