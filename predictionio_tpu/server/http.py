@@ -12,13 +12,16 @@ from __future__ import annotations
 
 import json
 import logging
+import os
 import random
 import re
-import secrets
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from http import HTTPStatus
+from http.server import ThreadingHTTPServer
+from socketserver import StreamRequestHandler
 from typing import Any, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
@@ -51,7 +54,7 @@ class RequestStamps:
     reached (a request handed to :meth:`HTTPApp.handle` directly has
     no ``t_recv``/``t_sent``).
 
-    - ``t_recv``: the request line has arrived (``parse_request``)
+    - ``t_recv``: the request line has arrived (``_Handler.handle``)
     - ``t_enter``: headers and body read, ids minted, trace begun;
       routing starts (``pio_http_request_duration_seconds`` starts here)
     - ``t_enq`` / ``t_done`` / ``t_wake``: written by a handler that
@@ -303,6 +306,9 @@ class HTTPApp:
         #: ``json.dumps`` is real money — sample the successes, but
         #: errors and 503s ALWAYS log (they are why the log exists)
         self.access_log_sample = 1.0
+        #: request ids: a handle for logs and traces, not a secret, so
+        #: a generator seeded once and no system call a request
+        self._id_bits = random.Random(os.urandom(16)).getrandbits
 
     def route(self, method: str, pattern: str) -> Callable[[Handler], Handler]:
         compiled = re.compile(f"^{pattern}$")
@@ -378,7 +384,7 @@ class HTTPApp:
 
     def handle(self, req: Request) -> Response:
         req.request_id = (req.headers.get("X-Request-ID")
-                          or secrets.token_hex(8))
+                          or "%016x" % self._id_bits(64))
         tracer = self.tracer
         if tracer is not None:
             # W3C context propagation (ISSUE 12): continue the caller's
@@ -441,6 +447,14 @@ class HTTPApp:
                 st.t_sent - st.t_recv)
         if self.on_sent is not None:
             self.on_sent(req)
+
+    def refused(self, status: int) -> None:
+        """The server's request loop answered a request itself (it
+        never parsed into a :class:`Request`): counted, under a route
+        label of its own and no method (a client's token is unbounded)."""
+        if self.metrics is not None:
+            self._child(self._http_count, route=LOOP_ROUTE, method="-",
+                        status=str(status)).inc()
 
     def _log_this(self, status: int) -> bool:
         """Access-log admission: errors/503s always; successes at the
@@ -582,59 +596,191 @@ def mount_trace_routes(app: HTTPApp, tracer) -> None:
         return json_response(tracer.status())
 
 
-class _Handler(BaseHTTPRequestHandler):
-    app: HTTPApp  # bound by AppServer
-    protocol_version = "HTTP/1.1"
-    # response header + body go out in separate writes; without
-    # TCP_NODELAY, Nagle + the peer's delayed ACK stalls every
-    # keep-alive response ~40ms (measured: host-path p50 10ms → 44ms
-    # the moment clients reused connections)
-    disable_nagle_algorithm = True
+#: route label of the answers the request loop gives itself (400, 414,
+#: 431, 501, 505) in ``pio_http_requests_total``
+LOOP_ROUTE = "(http-loop)"
 
-    def log_message(self, fmt, *args):  # quiet by default
-        pass
+#: the methods a route table can hold; any other is answered 501
+_METHODS = frozenset(("GET", "POST", "PUT", "DELETE"))
+#: the standard library's limits, kept: bytes of a request line or of
+#: one header line, and header lines of one request
+_MAX_LINE = 65536
+_MAX_HEADERS = 100
+_REASONS = {s.value: s.phrase for s in HTTPStatus}
+_SERVER = "PredictionIO-TPU Python/" + sys.version.split()[0]
+_DAYS = ("Mon", "Tue", "Wed", "Thu", "Fri", "Sat", "Sun")
+_MONTHS = ("Jan", "Feb", "Mar", "Apr", "May", "Jun",
+           "Jul", "Aug", "Sep", "Oct", "Nov", "Dec")
+
+
+def _is_http11(version: str) -> bool:
+    """Whether a request's ``HTTP/x.y`` is 1.1 or a later 1.y (its
+    connection stays open unless it says otherwise) and not 1.0;
+    :class:`HTTPError` 400 for a token that is no version, 505 for a
+    major version other than 1."""
+    if version == "HTTP/1.1":
+        return True
+    if version == "HTTP/1.0":
+        return False
+    major, dot, minor = version[5:].partition(".")
+    try:
+        if not version.startswith("HTTP/") or not dot:
+            raise ValueError(version)
+        major, minor = int(major), int(minor)
+    except ValueError:
+        raise HTTPError(400, f"Bad request version ({version!r})")
+    if major != 1:
+        raise HTTPError(505, f"Invalid HTTP version ({version[5:]})")
+    return minor >= 1
+
+
+class _Handler(StreamRequestHandler):
+    """One connection's thread: reads requests off the socket, hands
+    each to the app and writes its response, until either side closes.
+    HTTP/1.0 and 1.1 as the servers' clients speak them
+    (docs/observability.md, "The HTTP the servers speak"); the framing
+    is split by hand and a response leaves in one ``sendall``, because
+    in a full interpreter every system call is a turn in the queue for
+    the interpreter lock (PERF.md finding 42.1)."""
+
+    app: HTTPApp  # bound by AppServer
+    # a response is one small write: with Nagle on, it would wait for
+    # the peer's delayed ACK of the one before (~40 ms a keep-alive
+    # request, measured)
+    disable_nagle_algorithm = True
 
     def setup(self) -> None:
         # once a connection, on the thread ThreadingHTTPServer started
         # for it (``Thread-N (process_request_thread)`` until here)
         name_os_thread("http-handler")
         super().setup()
+        self._date_at = 0
+        self._date = ""
 
-    def parse_request(self) -> bool:
-        # the request line has arrived: the request's first stamp
-        self._stamps = st = RequestStamps(time.monotonic())
-        st.open_span("http_read")  # closed where routing starts
-        ok = super().parse_request()
-        if not ok or not hasattr(self, "do_" + self.command):
-            st.close_span()  # answered by the base class, not by us
-        return ok
-
-    def _dispatch(self) -> None:
-        st = self._stamps
+    def handle(self) -> None:
         try:
-            parsed = urlparse(self.path)
-            query = {k: v[0] for k, v in parse_qs(parsed.query).items()}
-            length = int(self.headers.get("Content-Length") or 0)
-            body = self.rfile.read(length) if length else b""
-            req = Request(method=self.command, path=parsed.path,
-                          query=query,
-                          headers={k: v for k, v in self.headers.items()},
-                          body=body, stamps=st)
-            resp = self.app.handle(req)
-        finally:
-            st.close_span()  # a read that failed never reached handle
-        with stage_span("http_write"):
-            payload = resp.encoded()
-            self.send_response(resp.status)
-            self.send_header("Content-Type", resp.content_type)
-            self.send_header("Content-Length", str(len(payload)))
-            for k, v in resp.headers.items():
-                self.send_header(k, v)
-            self.end_headers()
-            self.wfile.write(payload)
-        self.app.sent(req)
+            try:
+                self._serve()
+            except HTTPError as e:  # _read_request's: answered and counted
+                self.request.sendall(self._wire(
+                    json_response({"message": e.message}, e.status),
+                    "close"))
+                self.app.refused(e.status)
+        except OSError:
+            pass  # the peer went away mid-request: nobody to answer
 
-    do_GET = do_POST = do_DELETE = do_PUT = _dispatch
+    def _serve(self) -> None:
+        app, rfile, sock = self.app, self.rfile, self.request
+        while True:
+            line = rfile.readline(_MAX_LINE + 1)
+            if not line:
+                return  # the peer closed between requests
+            # the request line has arrived: the request's first stamp
+            st = RequestStamps(time.monotonic())
+            st.open_span("http_read")  # closed where routing starts
+            try:
+                req, connection = self._read_request(line, st)
+                if req is None:
+                    return
+                resp = app.handle(req)
+            finally:
+                st.close_span()  # a read that failed never got there
+            with stage_span("http_write"):
+                sock.sendall(self._wire(resp, connection))
+            app.sent(req)
+            if connection == "close":
+                return
+
+    def _read_request(self, line: bytes, st: RequestStamps
+                      ) -> Tuple[Optional[Request], str]:
+        """The request whose first line is ``line``, and its response's
+        ``Connection``: ``close`` (the loop ends with it), ``keep-alive``
+        where the client asked for that, nothing where HTTP/1.1's
+        default holds. No request where the peer closed before it was
+        whole; :class:`HTTPError` where the loop answers it itself."""
+        if len(line) > _MAX_LINE:
+            raise HTTPError(414, "Request-URI Too Long")
+        if not line.endswith(b"\n"):
+            return None, "close"  # the stream ended inside the line
+        words = line.decode("iso-8859-1").split()
+        if len(words) != 3:
+            raise HTTPError(400, f"Bad request syntax ({line[:80]!r})")
+        method, target, version = words
+        http11 = _is_http11(version)
+        connection = "" if http11 else "close"
+        headers: Dict[str, str] = {}
+        length, expect = 0, False
+        rfile = self.rfile
+        for _ in range(_MAX_HEADERS + 1):
+            line = rfile.readline(_MAX_LINE + 1)
+            if line in (b"\r\n", b"\n"):
+                break
+            if len(line) > _MAX_LINE:
+                raise HTTPError(431, "Header line too long")
+            if not line.endswith(b"\n"):
+                return None, "close"
+            name, colon, value = line.decode("iso-8859-1").partition(":")
+            if not colon or not name or name != name.strip():
+                # no name, a folded line (obsolete since RFC 7230) or
+                # space around the name: refused, never guessed at
+                raise HTTPError(400, f"Bad header line ({line[:80]!r})")
+            headers[name] = value = value.strip()
+            name = name.lower()
+            if name == "content-length":
+                try:
+                    length = int(value)
+                except ValueError:
+                    length = -1
+                if length < 0:
+                    raise HTTPError(400, f"Bad Content-Length ({value!r})")
+            elif name == "connection":
+                value = value.lower()
+                if value in ("close", "keep-alive"):
+                    connection = value
+            elif name == "expect":
+                expect = value.lower() == "100-continue"
+            elif name == "transfer-encoding":
+                # a body is read by Content-Length alone; a chunked
+                # one would be taken for the next request
+                raise HTTPError(501, f"Unsupported Transfer-Encoding "
+                                     f"({value!r})")
+        else:
+            raise HTTPError(431, f"More than {_MAX_HEADERS} headers")
+        if method not in _METHODS:
+            raise HTTPError(501, f"Unsupported method ({method!r})")
+        if expect and http11:
+            # curl sends it with any body over 1,024 bytes and waits
+            self.request.sendall(b"HTTP/1.1 100 Continue\r\n\r\n")
+        body = rfile.read(length) if length else b""
+        if len(body) < length:
+            return None, "close"
+        if "?" in target:
+            parsed = urlparse(target)
+            target = parsed.path
+            query = {k: v[0] for k, v in parse_qs(parsed.query).items()}
+        else:
+            query = {}
+        return Request(method=method, path=target, query=query,
+                       headers=headers, body=body, stamps=st), connection
+
+    def _wire(self, resp: Response, connection: str) -> bytes:
+        """A whole response as the bytes of one write."""
+        payload = resp.encoded()
+        now = int(time.time())
+        if now != self._date_at:  # formatted once a second it is true of
+            y, mo, d, h, mi, sec, wd = time.gmtime(now)[:7]
+            self._date = "%s, %02d %s %04d %02d:%02d:%02d GMT" % (
+                _DAYS[wd], d, _MONTHS[mo - 1], y, h, mi, sec)
+            self._date_at = now
+        head = [f"HTTP/1.1 {resp.status} {_REASONS.get(resp.status, '')}\r\n"
+                f"Server: {_SERVER}\r\nDate: {self._date}\r\n"
+                f"Content-Type: {resp.content_type}\r\n"
+                f"Content-Length: {len(payload)}\r\n"]
+        head += [f"{k}: {v}\r\n" for k, v in resp.headers.items()]
+        if connection:
+            head.append(f"Connection: {connection}\r\n")
+        head.append("\r\n")
+        return "".join(head).encode("latin-1") + payload
 
 
 class _AppHTTPServer(ThreadingHTTPServer):
