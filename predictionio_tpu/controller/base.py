@@ -84,6 +84,12 @@ class Algorithm(abc.ABC, Generic[PD, M, Q, P]):
     so serving stays low-latency.
     """
 
+    #: batches of this algorithm's that the serving surface keeps on the
+    #: device at once: the engine server writes its pipeline's depth here
+    #: before ``warm_serving``, for an algorithm whose batches hold state
+    #: there to size it (the generative template's check of residency)
+    batches_in_flight: int = 1
+
     @abc.abstractmethod
     def train(self, ctx: Context, prepared_data: PD) -> M:
         ...

@@ -1,8 +1,10 @@
 """A config-driven decoder block stack with generation, driven by the
-keys of a published ``config.json``. Three families' names are read,
+keys of a published ``config.json``. Four families' names are read,
 into ONE stack: ``lfm2_moe`` (short-convolution and grouped-query
 attention layers side by side, dense and sparse-expert feed-forwards, a
-tied head), ``laguna`` (full and sliding-window attention layers side by
+tied head), ``granitemoehybrid`` (Mamba-2 state-space layers beside
+grouped-query layers without rotary, dense feed-forwards, four scalar
+multipliers, a tied head), ``laguna`` (full and sliding-window attention layers side by
 side with their own head counts and rotary, a sigmoid gate a head, a
 shared expert beside the routed ones, an untied head) and ``xing4_0``
 (latent attention in every layer: low-rank queries, a cache that holds a
@@ -18,8 +20,9 @@ passes in one program (the first token is the prefill's). The host
 sees one dispatch of two programs and syncs once, on the answer.
 
 Layer ``l``: ``h = x + op_l(n(x))``, ``y = h + ff_l(n(h))`` with RMSNorm
-``n``; ``op_l`` by ``layer_types[l]``, one of four kinds (``conv``,
-``full_attention``, ``sliding_attention``, ``latent_attention``: every
+``n``; ``op_l`` by ``layer_types[l]``, one of five kinds (``conv``,
+``mamba``, ``full_attention`` (``attention`` in one family's words),
+``sliding_attention``, ``latent_attention``: every
 layer of a family that gives ``kv_lora_rank`` and no ``layer_types``),
 ``ff_l`` dense where ``mlp_layer_types[l]`` is ``dense`` (``lfm2_moe``:
 for ``l < num_dense_layers``; ``xing4_0``: ``first_k_dense_replace``)
@@ -62,7 +65,10 @@ and a row's tokens move between the stream and the rows whole tiles at
 a time (:func:`_to_rows`: a row ends on a tile's edge in both). A spare
 slot joins no expert's group and no row reads it: a row's logits do
 not depend on
-where in the stream it lies or on what lies beside it. State of four
+where in the stream it lies or on what lies beside it. A ``mamba``
+layer's recurrence runs over the packed stream in chunks
+(``ops/ssm_scan.py``) and restarts at each row's first token by the
+rows' ids, wherever in a chunk that falls. State of five
 kinds is carried from one program to the next: keys and values that
 grow (full-attention layers), right-aligned at ``history`` slots
 whatever the batch so that every row appends at the same slot and the
@@ -77,7 +83,11 @@ key, ``[B, history + room, kv_lora_rank + rope]``, right-aligned like a
 full cache; the prefill lays keys and values of every head out from the
 tokens it has in hand, a decode step attends over the latents
 themselves with the up-projections absorbed into the query and the
-output, :func:`_latent_step`).
+output, :func:`_latent_step`); and a RECURRENT state (``mamba`` layers:
+each row's ``S [N, heads x head_dim]`` float32 after its last token
+beside its last ``conv_L_cache - 1`` raw ``xBC``: 2 MiB a row and layer
+at the published sizes WHATEVER the history, rewritten whole by every
+decode step, :func:`_mamba_step`).
 
 Precision. Weights in ``cfg.dtype`` (bfloat16 as served). Every matrix
 product takes operands in that dtype and accumulates in float32
@@ -86,7 +96,10 @@ them), the hyper-connections' coefficients (their projection at
 ``highest``, the sigmoids, the exponential and the Sinkhorn passes),
 norms (the latents' too), rotary, softmax (running maximum, sum and
 accumulator), the gates' sigmoids and sums, and the conv window are
-float32; the latent cache is kept in the weights' dtype.
+float32; the latent cache is kept in the weights' dtype. A ``mamba``
+layer's conv, ``dt``, decays, state, skip and gated norm are float32;
+``x``, ``B`` and ``C`` enter the chunked scan's products in the
+weights' dtype, and a decode step's update is float32 throughout.
 
 Every layer holds its own arrays and the stack is unrolled. (Stacking
 the periods of the layer pattern and scanning them compiles the period
@@ -107,24 +120,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import head_lanes, hyper_mix, moe
+from ..ops import head_lanes, hyper_mix, moe, ssm_scan
 from ..ops.window_attention import BLOCK as ATTENTION_BLOCK, window_attention
 
 CONV, ATTENTION, SLIDING = "conv", "full_attention", "sliding_attention"
-LATENT = "latent_attention"
+LATENT, MAMBA = "latent_attention", "mamba"
 #: published names of one family that mean a field named by the other
 ALIASES = {"rms_norm_eps": "norm_eps",
            "moe_routed_scaling_factor": "routed_scaling_factor",
            "n_routed_experts": "num_experts",
-           "first_k_dense_replace": "num_dense_layers"}
+           "first_k_dense_replace": "num_dense_layers",
+           "mamba_d_conv": "conv_L_cache", "mamba_conv_bias": "conv_bias"}
+#: a layer kind by another family's name for it
+KINDS = {"attention": ATTENTION}
 #: keys that switch on mathematics nobody has written here: they are
 #: accepted at the value that switches it off, and raise otherwise
-UNWRITTEN = {"conv_bias": False, "attention_bias": False,
+#: (``conv_bias`` is written for ``mamba`` layers and raises beside a
+#: ``conv`` one)
+UNWRITTEN = {"attention_bias": False,
              "moe_apply_router_weight_on_input": False,
              "moe_router_logit_softcapping": 0,
              "n_group": 1, "topk_group": 1, "ep_size": 1,
              "moe_layer_freq": 1, "topk_method": "noaux_tc",
-             "scoring_func": "sigmoid"}
+             "scoring_func": "sigmoid", "num_local_experts": 0,
+             "mamba_proj_bias": False, "mamba_n_groups": 1}
 
 
 def _freeze(v):
@@ -153,18 +172,27 @@ class DecoderConfig:
     ``first_k_dense_replace``, ``n_shared_experts`` (times
     ``moe_intermediate_size``: the shared width) and the residual
     path's ``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
-    ``mhc_h_res_clamp_min`` / ``_max``. ``UNWRITTEN`` lists the keys
-    that raise at any value but the one that switches them off."""
+    ``mhc_h_res_clamp_min`` / ``_max``. ``granitemoehybrid`` gives
+    ``layer_types`` of ``mamba`` and ``attention``, the ``mamba_*`` sizes
+    (``mamba_d_conv`` and ``mamba_conv_bias`` are read as ``conv_L_cache``
+    and ``conv_bias``), no routed experts (``num_local_experts`` 0: every
+    layer's feed-forward is dense, ``shared_intermediate_size`` wide),
+    ``position_embedding_type`` ``nope`` and four scalars:
+    ``embedding_multiplier``, ``residual_multiplier`` (on both
+    sub-blocks' outputs), ``attention_multiplier`` (the softmax scale, in
+    place of ``head_dim ** -0.5``) and ``logits_scaling`` (a divisor).
+    ``UNWRITTEN`` lists the keys that raise at any value but the one
+    that switches them off."""
 
     hidden_size: int
     intermediate_size: int
-    moe_intermediate_size: int
     num_hidden_layers: int
     num_attention_heads: int
     num_key_value_heads: int
-    num_experts: int
-    num_experts_per_tok: int
     vocab_size: int
+    moe_intermediate_size: int = 0
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
     layer_types: Optional[Tuple[str, ...]] = None
     num_dense_layers: Optional[int] = None
     mlp_layer_types: Optional[Tuple[str, ...]] = None
@@ -203,6 +231,20 @@ class DecoderConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    mamba_n_heads: Optional[int] = None
+    mamba_d_head: Optional[int] = None
+    mamba_d_state: Optional[int] = None
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    mamba_n_groups: int = 1
+    mamba_proj_bias: bool = False
+    num_local_experts: int = 0
+    shared_intermediate_size: Optional[int] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    position_embedding_type: str = "rope"
     dtype: str = "bfloat16"
     experts_held: Optional[Tuple[int, ...]] = None
 
@@ -217,7 +259,7 @@ class DecoderConfig:
                                  "layer has (only a family with "
                                  "kv_lora_rank has one kind throughout)")
             put("layer_types", (LATENT,) * n)
-        put("layer_types", tuple(self.layer_types))
+        put("layer_types", tuple(KINDS.get(k, k) for k in self.layer_types))
         if LATENT in self.layer_types:
             if None in latent:
                 raise ValueError(
@@ -237,13 +279,14 @@ class DecoderConfig:
         if self.head_dim is None:
             put("head_dim", self.hidden_size // self.num_attention_heads)
         if self.mlp_layer_types is None:
-            if self.num_dense_layers is None:
+            if self.num_dense_layers is None and self.num_experts:
                 raise ValueError("one of num_dense_layers and "
                                  "mlp_layer_types says which layers are "
                                  "dense")
-            put("mlp_layer_types",
-                tuple("dense" if l < self.num_dense_layers else "sparse"
-                      for l in range(n)))
+            dense = n if self.num_dense_layers is None \
+                else self.num_dense_layers  # no experts: every layer
+            put("mlp_layer_types", tuple(
+                "dense" if l < dense else "sparse" for l in range(n)))
         put("mlp_layer_types", tuple(self.mlp_layer_types))
         put("num_attention_heads_per_layer", tuple(
             self.num_attention_heads_per_layer
@@ -254,23 +297,45 @@ class DecoderConfig:
             if len(getattr(self, name)) != n:
                 raise ValueError(f"{name} must name num_hidden_layers "
                                  f"layers")
-        if set(self.layer_types) - {CONV, ATTENTION, SLIDING, LATENT}:
+        if set(self.layer_types) - {CONV, ATTENTION, SLIDING, LATENT, MAMBA}:
             raise ValueError(f"layer types {set(self.layer_types)}: only "
-                             f"{CONV!r}, {ATTENTION!r}, {SLIDING!r} and "
-                             f"{LATENT!r} are written")
+                             f"{CONV!r}, {ATTENTION!r}, {SLIDING!r}, "
+                             f"{LATENT!r} and {MAMBA!r} are written")
         if set(self.mlp_layer_types) - {"dense", "sparse"}:
             raise ValueError(f"mlp layer types {set(self.mlp_layer_types)}")
         for key, off in UNWRITTEN.items():
             if getattr(self, key) != off:
                 raise ValueError(f"{key}={getattr(self, key)!r}: not "
                                  f"written here (only {off!r} is)")
+        if self.conv_bias and CONV in self.layer_types:
+            raise ValueError("conv_bias=True: not written for conv layers "
+                             "(only for mamba ones)")
+        if MAMBA in self.layer_types:
+            if None in (self.mamba_n_heads, self.mamba_d_head,
+                        self.mamba_d_state) or self.mamba_n_heads \
+                    * self.mamba_d_head != self.mamba_expand * self.hidden_size:
+                raise ValueError(
+                    "mamba layers need mamba_d_state and mamba_n_heads x "
+                    "mamba_d_head = mamba_expand x hidden_size")
+        if self.position_embedding_type not in ("rope", "nope"):
+            raise ValueError(f"position_embedding_type "
+                             f"{self.position_embedding_type!r}: only 'rope' "
+                             f"and 'nope' are written")
+        if self.nope and set(self.layer_types) & {SLIDING, LATENT}:
+            raise ValueError("attention without rotary and without a "
+                             "per-head norm ('nope') is written for full "
+                             "layers only")
+        if self.hc_mult > 1 and self.residual_multiplier != 1:
+            raise ValueError("residual_multiplier under hyper-connections: "
+                             "not written here")
         if SLIDING in self.layer_types and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
         if any(h % self.num_key_value_heads
                for h in self.num_attention_heads_per_layer):
             raise ValueError("query heads must divide by key-value heads")
-        for kind in set(self.layer_types) - {CONV}:
-            self.rope(kind)  # raises on a rope_type nobody has written
+        if not self.nope:
+            for kind in set(self.layer_types) - {CONV, MAMBA}:
+                self.rope(kind)  # raises on a rope_type nobody has written
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "DecoderConfig":
@@ -286,6 +351,26 @@ class DecoderConfig:
                     raise ValueError(f"{k} is given twice, differently")
                 out[k] = v
         return cls(**out)
+
+    @property
+    def nope(self) -> bool:
+        """Attention layers without rotary and without a per-head norm
+        (``position_embedding_type`` ``nope``)."""
+        return self.position_embedding_type == "nope"
+
+    @property
+    def dense_width(self) -> int:
+        """A dense feed-forward's width: ``intermediate_size``, or the
+        shared feed-forward's where a family names that one."""
+        return self.shared_intermediate_size or self.intermediate_size
+
+    @property
+    def attention_scale(self) -> float:
+        """The softmax scale of the grouped-query layers."""
+        if self.attention_multiplier is not None:
+            return float(self.attention_multiplier)
+        # ptpu: allow[unguarded-domain] — a head is 1 wide or more
+        return self.head_dim ** -0.5
 
     @property
     def n_held(self) -> int:
@@ -405,15 +490,27 @@ def _inverse_frequencies(rotated: int, theta: float, rope_type: str,
 #: that of the static biases, ``hc_diag`` what ``b_res`` has on its
 #: diagonal beside them, and every ``a`` is 1: ``H_res`` then leans on
 #: the diagonal without being the identity and differs token to token.
+#: A ``mamba`` layer's three vectors take the Mamba-2 conventions and no
+#: factor: ``A_log = log(uniform(1, 16))``, ``dt_bias`` the inverse
+#: softplus of a step drawn log-uniformly from 0.001 to 0.1, ``D`` 1 (a
+#: head's decay a token then spans about 0.2 to 0.999); its conv's bias
+#: is normal x ``conv_bias``, its conv's taps take ``conv_taps`` and the
+#: ``dt`` columns of its in-projection ``dt_in``. At 1 and 1 the skip ``D
+#: x`` carries half of a mixer's output and a token's own projection
+#: moves its step ``e^+-1`` around the drawn one; a benchmark
+#: configuration sets them so that the STATE carries the output and the
+#: drawn steps stand (its file has the readings).
 INIT = {"embed": 0.02, "op_out": 0.08, "dense_out": 0.67,
         "expert_out": 2.0, "shared_out": 1.0, "gate_bias": 0.01,
-        "hc_phi": 0.5, "hc_bias": 0.5, "hc_diag": 1.5}
+        "hc_phi": 0.5, "hc_bias": 0.5, "hc_diag": 1.5, "conv_bias": 0.1,
+        "conv_taps": 1.0, "dt_in": 1.0}
 
 
 def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
     """``{name: (shape, fan_in, factor)}`` of layer ``l``; fan-in 0 marks
     a gain (ones); ``factor`` names the entry of :data:`INIT` a matrix
-    is scaled by beside 1/sqrt(fan-in) (``None``: none)."""
+    is scaled by beside 1/sqrt(fan-in) (``None``: none; ``(entry, first
+    column)``: its columns from that one on)."""
     H, D = cfg.hidden_size, cfg.head_dim
     nq, nkv = cfg.num_attention_heads_per_layer[l], cfg.num_key_value_heads
     out = {"op_norm": ((H,), 0, None), "ff_norm": ((H,), 0, None)}
@@ -432,6 +529,16 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
         out.update(w_in=((H, 3 * H), H, None),
                    w_out=((H, H), H, "op_out"),
                    conv_w=((H, cfg.conv_L_cache), cfg.conv_L_cache, None))
+    elif cfg.layer_types[l] == MAMBA:
+        I, N, nh = _mamba_sizes(cfg)
+        C, K = I + 2 * N, cfg.conv_L_cache
+        out.update(w_in=((H, 2 * I + 2 * N + nh), H,   # z | xBC | dt
+                         ("dt_in", 2 * I + 2 * N)),
+                   conv_w=((C, K), K, "conv_taps"),
+                   conv_b=((C,), 1, "conv_bias"),
+                   A_log=((nh,), 1, None), dt_bias=((nh,), 1, None),
+                   D=((nh,), 0, None), ssm_norm=((I,), 0, None),
+                   w_out=((I, H), I, "op_out"))
     elif cfg.layer_types[l] == LATENT:
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -445,12 +552,13 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
     else:
         out.update(wq=((H, nq * D), H, None), wk=((H, nkv * D), H, None),
                    wv=((H, nkv * D), H, None),
-                   wo=((nq * D, H), nq * D, "op_out"),
-                   q_norm=((D,), 0, None), k_norm=((D,), 0, None))
+                   wo=((nq * D, H), nq * D, "op_out"))
+        if not cfg.nope:
+            out.update(q_norm=((D,), 0, None), k_norm=((D,), 0, None))
         if cfg.gating:
             out["wg"] = ((H, nq), H, None)
     if cfg.mlp_layer_types[l] == "dense":
-        I = cfg.intermediate_size
+        I = cfg.dense_width
         out.update(w1=((H, I), H, None), w3=((H, I), H, None),
                    w2=((I, H), I, "dense_out"))
     else:
@@ -477,12 +585,27 @@ def _draw(key, init: dict, *, shapes: tuple, dtype: str) -> dict:
         if fan == 0:
             out[name] = jnp.ones(shape, jnp.float32)
             continue
+        if name == "A_log":
+            # ptpu: allow[unguarded-domain] — drawn from [1, 16)
+            out[name] = jnp.log(jax.random.uniform(
+                k, shape, jnp.float32, 1.0, 16.0))
+            continue
+        if name == "dt_bias":  # softplus(dt_bias) is the step drawn
+            step = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, math.log(1e-3), math.log(0.1)))
+            # ptpu: allow[unguarded-domain] — a step of 0.001 or more:
+            # 1 - exp(-step) is positive
+            out[name] = step + jnp.log(-jnp.expm1(-step))
+            continue
         scale = jnp.asarray(fan ** -0.5, jnp.float32)
-        if factor is not None:
+        if isinstance(factor, tuple):  # some columns' own factor
+            scale = scale * jnp.where(jnp.arange(shape[-1]) >= factor[1],
+                                      init[factor[0]], 1.0)
+        elif factor is not None:
             scale = scale * init[factor]
         # drawn in the target dtype: a float32 draw of one layer's
         # experts would be 1.4 GB of scratch beside 9 GB of weights
-        kind = jnp.float32 if name in ("conv_w", "gate_bias") \
+        kind = jnp.float32 if name in ("conv_w", "conv_b", "gate_bias") \
             or name.startswith("hc_") else dtype
         out[name] = jax.random.normal(k, shape, kind) * scale.astype(kind)
         if name.endswith("_b_res"):
@@ -634,7 +757,7 @@ def _qkv(lw, z, pos, l, cfg):
     ``HEAD_GROUP_ELEMENTS``: those groups are ``G``, nothing stacks or
     transposes them."""
     dt = jnp.dtype(cfg.dtype)
-    rope = cfg.rope(cfg.layer_types[l])
+    rope = None if cfg.nope else cfg.rope(cfg.layer_types[l])
     nq, D = cfg.num_attention_heads_per_layer[l], cfg.head_dim
 
     def project(z, w, norm=None):
@@ -645,18 +768,18 @@ def _qkv(lw, z, pos, l, cfg):
 
     n = moe.equal_parts(nq, z[..., 0].size * D, HEAD_GROUP_ELEMENTS)
     if n == 1:
-        q = project(z, lw["wq"], lw["q_norm"])
+        q = project(z, lw["wq"], lw.get("q_norm"))
     else:
         zb = z.astype(lw["wq"].dtype)  # read once a group: half the bytes
 
         def part(w):  # [T, lanes] where the part's heads are one group
-            a = project(zb, w, lw["q_norm"])
+            a = project(zb, w, lw.get("q_norm"))
             return a[0] if a.shape[0] == 1 else a
 
         q = jax.lax.map(
             part, lw["wq"].reshape(-1, n, nq // n * D).swapaxes(0, 1))
         q = q.reshape((-1,) + q.shape[-2:])
-    return q, project(z, lw["wk"], lw["k_norm"]), project(z, lw["wv"])
+    return q, project(z, lw["wk"], lw.get("k_norm")), project(z, lw["wv"])
 
 
 def _attention_out(lw, o, z, D):
@@ -684,6 +807,12 @@ def _attention_out(lw, o, z, D):
                       preferred_element_type=jnp.float32)
 
 
+def _mamba_sizes(cfg) -> Tuple[int, int, int]:
+    """``(inner width, state size, heads)`` of a ``mamba`` layer."""
+    return (cfg.mamba_n_heads * cfg.mamba_d_head, cfg.mamba_d_state,
+            cfg.mamba_n_heads)
+
+
 def _swiglu(z, w1, w3, w2):
     return _dot(jax.nn.silu(_dot(z, w1)) * _dot(z, w3), w2)
 
@@ -697,7 +826,7 @@ def _feed_forward(lw, z, valid, cfg):
     if "gate" not in lw:
         return _token_blocks(
             lambda z: _swiglu(z, lw["w1"], lw["w3"], lw["w2"]),
-            cfg.intermediate_size, z), None
+            cfg.dense_width, z), None
     sel, wts = moe.route(z, lw["gate"], lw.get("gate_bias"),
                          top_k=cfg.num_experts_per_tok,
                          norm_topk=cfg.norm_topk_prob,
@@ -779,6 +908,8 @@ def _sub_block(lw, sub, x, fn, cfg, valid=None):
     norm = lw[sub + "_norm"]
     if cfg.hc_mult == 1:
         out, aux = fn(_rms(x, norm, cfg.norm_eps))
+        if cfg.residual_multiplier != 1:
+            out = cfg.residual_multiplier * out
         return x + out, aux, None
     n = cfg.hc_mult
     if x.shape[1] >= hyper_mix.TILE:
@@ -857,21 +988,76 @@ def _conv_prefill(lw, z, valid, pos, last, cfg):
     before in the stream where the token is at least ``s`` into its row,
     and zero where it is not. ``last [B]``: each row's last slot, for
     the window the decode goes on from."""
-    K = cfg.conv_L_cache
     b, c, u = jnp.split(_dot(z, lw["w_in"]), 3, axis=-1)
     v = jnp.where(valid[:, None], b * u, 0.0)
-    T = v.shape[0]
-    y = lw["conv_w"][:, K - 1] * v
+    y = _causal_taps(v, lw["conv_w"], pos)
+    win = _row_tail(v, last, pos, cfg.conv_L_cache)
+    return _dot(c * y, lw["w_out"]), {"win": win}
+
+
+def _causal_taps(v, w, pos):
+    """The depthwise causal conv of a packed stream ``v [T, C]`` with
+    taps ``w [C, K]`` (the last one the token's own): a tap ``s`` slots
+    back adds nothing where the token is fewer than ``s`` into its
+    row."""
+    T, K = v.shape[0], w.shape[1]
+    y = w[:, K - 1] * v
     for s in range(1, K):
         back = jnp.pad(v, ((s, 0), (0, 0)))[:T]
-        y = y + lw["conv_w"][:, K - 1 - s] * jnp.where(
-            (pos >= s)[:, None], back, 0.0)
-    ago = jnp.arange(K - 1, -1, -1, dtype=jnp.int32)
-    # ptpu: allow[materialized-gather] — [B, K, H]: the K last slots of
-    # each row, the state itself
+        y = y + w[:, K - 1 - s] * jnp.where((pos >= s)[:, None], back, 0.0)
+    return y
+
+
+def _row_tail(v, last, pos, n: int):
+    """``[B, n, C]``: the ``n`` last slots of each row of the stream ``v
+    [T, C]``, oldest first, zeros where a row is shorter."""
+    ago = jnp.arange(n - 1, -1, -1, dtype=jnp.int32)
+    # ptpu: allow[materialized-gather] — the last slots of each row, the
+    # state itself
     win = jnp.take(v, jnp.maximum(last[:, None] - ago, 0), axis=0)
-    win = jnp.where((pos[last][:, None] >= ago)[..., None], win, 0.0)
-    return _dot(c * y, lw["w_out"]), {"win": win}
+    return jnp.where((pos[last][:, None] >= ago)[..., None], win, 0.0)
+
+
+def _mamba_inputs(lw, z, cfg):
+    """``(gate [.., I], raw xBC [.., I + 2 N], dt [.., heads])`` float32
+    of ``z [.., H]``: the in-projection's three parts."""
+    I, N, _ = _mamba_sizes(cfg)
+    zxd = _dot(z, lw["w_in"])
+    return zxd[..., :I], zxd[..., I:2 * I + 2 * N], zxd[..., 2 * I + 2 * N:]
+
+
+def _mamba_out(lw, y, x, gate, cfg):
+    """From the scan's ``y`` to the layer's output: the skip ``D x`` a
+    head, the gate ``silu(z)``, the RMSNorm over ALL the inner channels
+    (one group) and ``W_out``; float32 up to the product."""
+    y = y + jnp.repeat(lw["D"], cfg.mamba_d_head) * x
+    return _dot(_rms(y * jax.nn.silu(gate), lw["ssm_norm"], cfg.norm_eps),
+                lw["w_out"])
+
+
+def _mamba_prefill(lw, z, valid, pos, rows, cfg):
+    """``z [T, H]`` packed. The depthwise conv over ``xBC`` as
+    :func:`_conv_prefill` has its taps (a tap that would reach before a
+    row's first token adds zero), with a bias and a ``silu``; then the
+    recurrence over the stream (``ops/ssm_scan.py``: chunks of
+    ``mamba_chunk_size``, restarted at each row's first token by the
+    rows' ids), whose inputs are zeroed in the spare slots. The state:
+    each row's ``S`` after its last token, ``[B, N, heads x head_dim]``
+    float32, beside its last ``conv_L_cache - 1`` raw ``xBC``."""
+    I, N, _ = _mamba_sizes(cfg)
+    dt_ = jnp.dtype(cfg.dtype)
+    gate, raw, dt = _mamba_inputs(lw, z, cfg)
+    y = lw["conv_b"] + _causal_taps(raw, lw["conv_w"], pos)
+    xbc = jnp.where(valid[:, None], jax.nn.silu(y), 0.0)
+    dt = jnp.where(valid[:, None], jax.nn.softplus(dt + lw["dt_bias"]), 0.0)
+    x = xbc[:, :I]
+    y, final = ssm_scan.ssm_scan(
+        x.astype(dt_), xbc[:, I:I + N].astype(dt_),
+        xbc[:, I + N:].astype(dt_), dt, -jnp.exp(lw["A_log"]), rows.row,
+        rows.last, chunk=cfg.mamba_chunk_size)
+    return _mamba_out(lw, y, x, gate, cfg), {
+        "ssm": final,
+        "win": _row_tail(raw, rows.last, pos, cfg.conv_L_cache - 1)}
 
 
 def _gather(a, at):
@@ -945,7 +1131,7 @@ def _attention_prefill(lw, z, pos, rows, room, l, cfg):
     q, k, v = _qkv(lw, z, pos, l, cfg)
     kr, vr = _to_rows(k, rows), _to_rows(v, rows)
     o = window_attention(
-        _to_rows(q, rows), kr, vr, lead, scale=D ** -0.5,
+        _to_rows(q, rows), kr, vr, lead, scale=cfg.attention_scale,
         window=cfg.sliding_window if sliding else None,
         block=ATTENTION_BLOCK, head_dim=D)
     out = _attention_out(lw, _to_stream(o, rows), z, D)
@@ -1005,11 +1191,30 @@ def _latent_prefill(lw, z, pos, rows, room, cfg):
                        ((0, 0), (0, room), (0, 0)))}
 
 
+def _embed(w, tokens, cfg):
+    """The residual stream's entry: a token's row of the embedding,
+    float32, times ``embedding_multiplier``."""
+    # ptpu: allow[materialized-gather] — the embedding lookup itself: the
+    # [T, H] it makes is the residual stream
+    x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
+    return x if cfg.embedding_multiplier == 1 \
+        else x * cfg.embedding_multiplier
+
+
+def _stack(loads, cfg):
+    """The expert layers' loads ``[expert layers, E]`` (none: ``[0,
+    E]``)."""
+    return jnp.stack(loads) if loads \
+        else jnp.zeros((0, cfg.num_experts), jnp.int32)
+
+
 def _head(w, x, cfg):
     z = _rms(x, w["norm_out"], cfg.norm_eps)
     table = w["embed"] if cfg.tie_word_embeddings else w["head"]
-    return jnp.dot(z.astype(table.dtype), table.T,
-                   preferred_element_type=jnp.float32)
+    logits = jnp.dot(z.astype(table.dtype), table.T,
+                     preferred_element_type=jnp.float32)
+    return logits if cfg.logits_scaling == 1 \
+        else logits / cfg.logits_scaling
 
 
 def row_align(history: int, dtype) -> int:
@@ -1040,6 +1245,7 @@ class _Rows(NamedTuple):
     dst: jax.Array   #: [T / align]: the rows' tile the stream's is
     lead: jax.Array  #: [B]: each row's first real slot
     last: jax.Array  #: [B]: each row's last slot in the stream
+    row: jax.Array   #: [T]: the row a slot of the stream belongs to
     align: int       #: slots a tile (:func:`row_align`)
 
 
@@ -1073,7 +1279,7 @@ def _row_maps(lengths, T: int, history: int, dtype):
         src=jnp.maximum((ends[:, None] - history) // align + tile, 0),
         real=at >= lead[:, None],
         dst=jnp.where(valid[align - 1::align], in_rows, 0), lead=lead,
-        last=ends - 1, align=align)
+        last=ends - 1, row=row, align=align)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "history", "room"))
@@ -1088,15 +1294,14 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
     at ``history`` slots and room for ``room`` more tokens."""
     lengths = lengths.astype(jnp.int32)
     valid, pos, rows = _row_maps(lengths, tokens.shape[0], history, cfg.dtype)
-    # ptpu: allow[materialized-gather] — the embedding lookup itself: the
-    # [T, H] it makes is the residual stream
-    x = jnp.take(w["embed"], tokens, axis=0).astype(jnp.float32)
-    x = _streams_in(x, cfg)
+    x = _streams_in(_embed(w, tokens, cfg), cfg)
     states, loads, gaps = [], [], []
     for l, (lw, kind) in enumerate(zip(w["layers"], cfg.layer_types)):
         def op(z, lw=lw, kind=kind, l=l):
             if kind == CONV:
                 return _conv_prefill(lw, z, valid, pos, rows.last, cfg)
+            if kind == MAMBA:
+                return _mamba_prefill(lw, z, valid, pos, rows, cfg)
             if kind == LATENT:
                 return _latent_prefill(lw, z, pos, rows, room, cfg)
             return _attention_prefill(lw, z, pos, rows, room, l, cfg)
@@ -1110,7 +1315,7 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
             loads.append(load)
         gaps += [g for g in (gap_op, gap_ff) if g is not None]
     cache = jnp.arange(history + room, dtype=jnp.int32)[None, :]
-    state = {"layers": states, "load": jnp.stack(loads), "pos": lengths,
+    state = {"layers": states, "load": _stack(loads, cfg), "pos": lengths,
              "valid": (cache >= rows.lead[:, None]) & (cache < history),
              "filled": jnp.asarray(history, jnp.int32)}
     if gaps:  # hc_mult over 1: what twenty Sinkhorn passes left
@@ -1128,6 +1333,27 @@ def _conv_step(lw, z, st, cfg):
     # through the MXU at one bfloat16 pass
     y = sum(lw["conv_w"][:, j] * win[:, j] for j in range(win.shape[1]))
     return _dot(c * y, lw["w_out"]), {"win": win}
+
+
+def _mamba_step(lw, z, st, cfg):
+    """One token a row: the conv over the window and the new ``xBC``,
+    then ``S' = exp(dt A) S + dt x B^T`` and ``y = S' C``
+    (``ops/ssm_scan.py::ssm_step``: the state goes through once, in its
+    own buffer), float32 throughout."""
+    I, N, _ = _mamba_sizes(cfg)
+    gate, raw, dt = _mamba_inputs(lw, z, cfg)
+    win = st["win"]
+    y = lw["conv_b"] + lw["conv_w"][:, -1] * raw + sum(
+        lw["conv_w"][:, j] * win[:, j] for j in range(win.shape[1]))
+    xbc = jax.nn.silu(y)
+    dt = jax.nn.softplus(dt + lw["dt_bias"])
+    x = xbc[:, :I]
+    new, y = ssm_scan.ssm_step(
+        st["ssm"], x, xbc[:, I:I + N], xbc[:, I + N:],
+        jnp.exp(-dt * jnp.exp(lw["A_log"])), dt)
+    return _mamba_out(lw, y, x, gate, cfg), {
+        "ssm": new,
+        "win": jnp.concatenate([win[:, 1:], raw[:, None]], axis=1)}
 
 
 def _attention_step(lw, z, st, valid, pos, at, l, cfg):
@@ -1154,7 +1380,7 @@ def _attention_step(lw, z, st, valid, pos, at, l, cfg):
         vs = jax.lax.dynamic_update_slice_in_dim(st["v"], v[:, :, None], at, 2)
     B, nkv, _ = k.shape
     s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, nkv, -1, D), ks,
-                   preferred_element_type=jnp.float32) * D ** -0.5
+                   preferred_element_type=jnp.float32) * cfg.attention_scale
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
     o = jnp.einsum("bgrs,bgsd->bgrd",
                    jax.nn.softmax(s, axis=-1).astype(vs.dtype), vs,
@@ -1196,6 +1422,8 @@ def _layer_step(lw, l, x, st, valid, pos, at, cfg):
     def op(z):
         if cfg.layer_types[l] == CONV:
             return _conv_step(lw, z, st, cfg)
+        if cfg.layer_types[l] == MAMBA:
+            return _mamba_step(lw, z, st, cfg)
         if cfg.layer_types[l] == LATENT:
             return _latent_step(lw, z, st, valid, pos, at, cfg)
         return _attention_step(lw, z, st, valid, pos, at, l, cfg)
@@ -1212,8 +1440,7 @@ def _decode_step(w, state, tok, cfg):
     at, pos = state["filled"], state["pos"]
     valid = jax.lax.dynamic_update_slice_in_dim(
         state["valid"], jnp.ones((tok.shape[0], 1), bool), at, 1)
-    x = _streams_in(
-        jnp.take(w["embed"], tok, axis=0).astype(jnp.float32), cfg)
+    x = _streams_in(_embed(w, tok, cfg), cfg)
     states, loads = [], []
     for l, (lw, st) in enumerate(zip(w["layers"], state["layers"])):
         x, st, load = _layer_step(lw, l, x, st, valid, pos, at, cfg)
@@ -1222,7 +1449,7 @@ def _decode_step(w, state, tok, cfg):
             loads.append(load)
     new = {**state, "layers": states, "valid": valid, "filled": at + 1,
            "pos": pos + 1}
-    return _head(w, _streams_out(x, cfg), cfg), new, jnp.stack(loads)
+    return _head(w, _streams_out(x, cfg), cfg), new, _stack(loads, cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "steps"),

@@ -1,7 +1,7 @@
 """The plain reference of ``models/decoder.py``: the full forward pass of
 the block stack in straightforward ``jax.numpy``, float32, at
 ``highest`` matmul precision, one sequence at a time. No cache, no
-batching, no padding, no kernels; the experts one after the other. Three
+batching, no padding, no kernels; the experts one after the other. Four
 families' equations, told apart by the keys a configuration has.
 
 ``cfg`` is the published ``config.json`` as a dict (plus ``head_dim``
@@ -71,6 +71,23 @@ The equations (``H`` hidden size, ``n`` RMSNorm with ``norm_eps`` or
   then its columns likewise: ``H_res``. ``u = H_pre X``; ``y = F(n(u))``;
   ``X' = H_res X + H_post^T y``.
 
+- ``granitemoehybrid``: ``x_0 = embedding_multiplier E[tok]``; both
+  sub-blocks' outputs times ``residual_multiplier`` before they are
+  added; ``logits / logits_scaling``; no routed experts
+  (``num_local_experts`` 0): every feed-forward is the dense one,
+  ``shared_intermediate_size`` wide. ``attention`` layers
+  (``position_embedding_type`` ``nope``): no rotary and no per-head
+  norm, softmax at scale ``attention_multiplier``. ``mamba`` layers
+  (``I = mamba_n_heads x mamba_d_head``, ``N = mamba_d_state``, ONE
+  group): ``[z | xBC | dt] = u W_in`` (``I | I + 2 N | heads``); ``xBC_t
+  = silu(b + sum_j w[:, j] xBC_{t-K+1+j})`` depthwise, causal, ``K =
+  mamba_d_conv``, zeros before the sequence; ``[x | B | C] = xBC`` (``B``
+  and ``C`` shared by the heads); ``dt = softplus(dt + dt_bias)``, ``A =
+  -exp(A_log)`` a head; ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``
+  from ``S = 0``, TOKEN BY TOKEN (``lax.scan``), ``y_t = S_t C_t + D
+  x_t``; ``g = y * silu(z)``; ``out = (g / rms(g over all I) * gain)
+  W_out``.
+
 Departures from the published implementations, all listed in the
 benchmark configurations' ``assumed``. ``lfm2_moe``: ``head_dim = hidden
 / heads`` (the source gives null), the tied head, a conv kernel exactly
@@ -89,7 +106,10 @@ under the norm's root and in the divisions; rotate-half inside the 64
 rotated dimensions (the published interleaving is a permutation of
 seeded columns); the routing's ``1e-6`` where the family writes
 ``1e-20``; the next-next-token module (``num_nextn_predict_layers``) is
-not part of the forward pass. All: seeded weights in
+not part of the forward pass. ``granitemoehybrid``: ``head_dim = hidden
+/ heads`` (the source gives null), no clamp on ``dt`` (the family's
+default limits are 0 and infinity), the gated norm over all ``I``
+channels (one group). All: seeded weights in
 place of trained ones. ``cellbench/reference_lfm2.py`` and
 ``cellbench/reference_laguna.py`` are the benchmark's copies;
 ``tests/test_decoder.py`` holds them to identical outputs.
@@ -120,6 +140,8 @@ def _heads(cfg, l):
 
 
 def _is_dense(cfg, l):
+    if "num_local_experts" in cfg:  # granitemoehybrid: 0 is all there is
+        return int(cfg["num_local_experts"]) == 0
     kinds = cfg.get("mlp_layer_types")
     return kinds[l] == "dense" if kinds \
         else l < int(cfg["num_dense_layers"] if "num_dense_layers" in cfg
@@ -223,10 +245,13 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
     q = (z @ _f(lw["wq"])).reshape(T, nq, D)
     k = (z @ _f(lw["wk"])).reshape(T, nkv, D)
     v = (z @ _f(lw["wv"])).reshape(T, nkv, D)
-    if qk_norm:
-        q = rms(q, lw["q_norm"], _eps(cfg))
-        k = rms(k, lw["k_norm"], _eps(cfg))
-    q, k = rotary(q, rope), rotary(k, rope)
+    # ptpu: allow[unguarded-domain] — D is the static head size, never 0
+    scale = float(cfg.get("attention_multiplier") or D ** -0.5)
+    if cfg.get("position_embedding_type") != "nope":
+        if qk_norm:
+            q = rms(q, lw["q_norm"], _eps(cfg))
+            k = rms(k, lw["k_norm"], _eps(cfg))
+        q, k = rotary(q, rope), rotary(k, rope)
     at = jnp.arange(T)
 
     def seen(i):  # which keys queries at positions ``i`` see
@@ -237,7 +262,7 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
     if query_block is None:
         kk = jnp.repeat(k, nq // nkv, axis=1)
         vv = jnp.repeat(v, nq // nkv, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", q, kk) * D ** -0.5
+        s = jnp.einsum("qhd,khd->hqk", q, kk) * scale
         p = jax.nn.softmax(jnp.where(seen(at)[None], s, -jnp.inf), axis=-1)
         o = jnp.einsum("hqk,khd->qhd", p, vv)
     else:
@@ -248,7 +273,7 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
 
         def block(a):
             qs, i = a  # [bq, kv heads, heads of one, D], [bq]
-            s = jnp.einsum("qgrd,kgd->grqk", qs, k) * D ** -0.5
+            s = jnp.einsum("qgrd,kgd->grqk", qs, k) * scale
             p = jax.nn.softmax(jnp.where(seen(i), s, -jnp.inf), axis=-1)
             return jnp.einsum("grqk,kgd->qgrd", p, v)
 
@@ -259,6 +284,40 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
         o = o * gate[..., None] if head_gate == "scalar" \
             else o * gate.reshape(T, nq, D)
     return o.reshape(T, nq * D) @ _f(lw["wo"])
+
+
+def mamba_op(lw, z, cfg, *, round_state=None):
+    """A ``mamba`` layer over one sequence ``z [T, H]``, the recurrence
+    token by token. ``round_state``: a function every token's new state
+    goes through (the benchmark's ``state_bf16`` control rounds it to
+    bfloat16; ``None``: float32 as it is)."""
+    T = z.shape[0]
+    nh, dh, N = (int(cfg[k]) for k in ("mamba_n_heads", "mamba_d_head",
+                                       "mamba_d_state"))
+    I, K = nh * dh, int(cfg["mamba_d_conv"])
+    zxd = z @ _f(lw["w_in"])
+    gate, raw, dt = zxd[:, :I], zxd[:, I:2 * I + 2 * N], zxd[:, 2 * I + 2 * N:]
+    rp = jnp.concatenate([jnp.zeros((K - 1, raw.shape[1]), F32), raw])
+    w = _f(lw["conv_w"])
+    xbc = jax.nn.silu(_f(lw["conv_b"])
+                      + sum(w[:, j] * rp[j:j + T] for j in range(K)))
+    x = xbc[:, :I].reshape(T, nh, dh)
+    dt = jax.nn.softplus(dt + _f(lw["dt_bias"]))
+    a = -jnp.exp(_f(lw["A_log"]))
+
+    def token(S, t):  # S [heads, head_dim, N]
+        x_t, b_t, c_t, dt_t = t
+        S = jnp.exp(dt_t * a)[:, None, None] * S \
+            + (dt_t[:, None] * x_t)[:, :, None] * b_t
+        if round_state is not None:
+            S = round_state(S)
+        return S, jnp.sum(S * c_t, axis=-1)
+
+    _, y = jax.lax.scan(token, jnp.zeros((nh, dh, N), F32),
+                        (x, xbc[:, I:I + N], xbc[:, I + N:], dt))
+    y = y + _f(lw["D"])[:, None] * x
+    g = y.reshape(T, I) * jax.nn.silu(gate)
+    return rms(g, lw["ssm_norm"], _eps(cfg)) @ _f(lw["w_out"])
 
 
 def yarn_mscale(scaling, key):
@@ -357,7 +416,7 @@ def _around(lw, sub, x, fn, cfg, iters=None):
     """A sub-block on the residual path: a plain sum, or the ``n``
     streams' read, write and mix."""
     if int(cfg.get("hc_mult") or 1) == 1:
-        return x + fn(x)
+        return x + float(cfg.get("residual_multiplier", 1.0)) * fn(x)
     return hyper_connection(lw, sub, x, fn, cfg, iters=iters)
 
 
@@ -411,6 +470,8 @@ def operator(lw, l, x, cfg, *, sinkhorn_iters=None, **how):
         z = rms(u, lw["op_norm"], _eps(cfg))
         if _kind(cfg, l) == "conv":
             return conv_op(lw, z, cfg)
+        if _kind(cfg, l) == "mamba":
+            return mamba_op(lw, z, cfg)
         if _kind(cfg, l) == "latent_attention":
             return latent_attention_op(lw, z, cfg, **how)
         return attention_op(lw, z, cfg, l, **how)
@@ -440,8 +501,9 @@ def layer(lw, l, x, cfg):
     return feed_forward(lw, l, operator(lw, l, x, cfg), cfg)
 
 
-def embed(weights, tokens):
-    return _f(weights["embed"])[jnp.asarray(tokens)]
+def embed(weights, tokens, cfg=None):
+    return _f(weights["embed"])[jnp.asarray(tokens)] \
+        * float((cfg or {}).get("embedding_multiplier", 1.0))
 
 
 def streams_in(x, cfg):
@@ -459,12 +521,13 @@ def head(weights, x, cfg):
     table = weights["embed"] if cfg.get("tie_word_embeddings", True) \
         else weights["head"]
     with jax.default_matmul_precision("highest"):
-        return rms(x, weights["norm_out"], _eps(cfg)) @ _f(table).T
+        return rms(x, weights["norm_out"], _eps(cfg)) @ _f(table).T \
+            / float(cfg.get("logits_scaling", 1.0))
 
 
 def forward(weights, tokens, cfg):
     """Logits ``[T, V]`` of one sequence of token ids."""
-    x = streams_in(embed(weights, tokens), cfg)
+    x = streams_in(embed(weights, tokens, cfg), cfg)
     for l, lw in enumerate(weights["layers"]):
         x = layer(lw, l, x, cfg)
     return head(weights, streams_out(x, cfg), cfg)

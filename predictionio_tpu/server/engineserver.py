@@ -832,6 +832,9 @@ class QueryServer:
             lane_models = list(self.lane_models)
         max_b = self.config.max_batch \
             if (self.config.batching or lane_models) else 1
+        for algo in algorithms:  # a lane's depth: what one device holds
+            algo.batches_in_flight = \
+                self.batcher.depth if self.batcher is not None else 1
         aot.reset_stats()
         t0 = time.perf_counter()
         store = None

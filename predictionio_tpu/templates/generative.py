@@ -30,6 +30,7 @@ repository yet. What persists is the configuration and the seed.
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -50,6 +51,9 @@ IMBALANCE_BOUNDS = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0)
 #: |row sum - 1| of a Sinkhorn-normalised mix: float32's rounding at the
 #: low end, what a single pass leaves at the high one
 SINKHORN_GAP_BOUNDS = (1e-7, 1e-6, 1e-5, 1e-4, 1e-3, 1e-2, 0.1, 1.0)
+#: the share of the device's memory that weights and the states of the
+#: batches in flight may take; the rest is the prefill's temporaries
+RESIDENT_SHARE = 0.8
 
 
 @dataclass(frozen=True)
@@ -146,13 +150,15 @@ def _bucket(buckets: Sequence[int], n: int) -> int:
 
 def _state_bytes(cfg, state) -> Dict[str, int]:
     """Bytes of a batch's per-sequence state by kind, from the shapes
-    (no sync): ``full``, ``window``, ``conv``, ``latent``."""
-    from ..models.decoder import ATTENTION, CONV, LATENT
+    (no sync): ``full``, ``window``, ``conv``, ``latent``, ``ssm`` (a
+    state-space layer's recurrent state and its conv window: bytes that
+    follow the ROWS, whatever their histories)."""
+    from ..models.decoder import ATTENTION, CONV, LATENT, MAMBA
 
     out: Dict[str, int] = {}
     for kind, st in zip(cfg.layer_types, state["layers"]):
-        name = {ATTENTION: "full", CONV: "conv",
-                LATENT: "latent"}.get(kind, "window")
+        name = {ATTENTION: "full", CONV: "conv", LATENT: "latent",
+                MAMBA: "ssm"}.get(kind, "window")
         out[name] = out.get(name, 0) + sum(a.nbytes for a in st.values())
     return out
 
@@ -166,6 +172,10 @@ class GenerativeAlgorithm(Algorithm):
         self.params = params
         self._tokens = self._touched = self._read = self._imbalance = None
         self._state_bytes = self._sinkhorn_gap = None
+        self._ssm_bytes = self._ssm_tokens = self._ssm_chunks = None
+        # bytes of recurrent state the batches in flight hold (dispatch
+        # and readback threads both move it); only a registry reads it
+        self._ssm_resident, self._ssm_lock = 0, threading.Lock()
 
     def train(self, ctx: Context, td: TrainingData) -> GenerativeModel:
         return GenerativeModel(config=dict(self.params.model),
@@ -210,7 +220,22 @@ class GenerativeAlgorithm(Algorithm):
             "prefill to its decode, by kind: full (keys and values that "
             "grow), window (rings of sliding_window), conv (windows of "
             "conv_L_cache), latent (normalised latents beside their rotated "
-            "shared key)")
+            "shared key), ssm (state-space layers' recurrent states and conv "
+            "windows: they follow the rows, not the histories)")
+        self._ssm_bytes = registry.gauge(
+            "pio_ssm_state_bytes",
+            "Bytes of state-space layers' recurrent state resident on the "
+            "device: every batch in flight holds its own from its prefill's "
+            "dispatch to its answer's readback")
+        self._ssm_tokens = registry.counter(
+            "pio_ssm_scan_tokens_total",
+            "Real history tokens the prefills' chunked scans ran over (a "
+            "layer's scan; pad slots count for nothing)")
+        self._ssm_chunks = registry.counter(
+            "pio_ssm_scan_chunks_total",
+            "Chunks of mamba_chunk_size slots the prefills' scans ran (a "
+            "layer's scan): tokens over chunk size x chunks is the share of "
+            "a chunk's slots that held a token")
         self._sinkhorn_gap = registry.histogram(
             "pio_mhc_sinkhorn_gap",
             "Largest |row sum - 1| of any hyper-connection mixing matrix "
@@ -230,6 +255,13 @@ class GenerativeAlgorithm(Algorithm):
     def _dispatch(self, model: GenerativeModel, hists: List[List[int]]):
         """Enqueue one packed batch: ``(device outputs, slots)``, the
         slots the prefill ran."""
+        return self._enqueue(model, hists)[:2]
+
+    def _enqueue(self, model: GenerativeModel, hists: List[List[int]],
+                 sized: bool = False):
+        """:meth:`_dispatch` and, third, the bytes of state the batch
+        holds on the device by kind (:func:`_state_bytes`): empty unless
+        a registry reads them or the caller asks (``sized``)."""
         import jax
 
         from ..models.decoder import (
@@ -259,8 +291,11 @@ class GenerativeAlgorithm(Algorithm):
             first, state = _gen_prefill(
                 model.weights, tokens, lengths, cfg=cfg,
                 history=p.history_buckets[-1], room=p.max_new)
-        if self._state_bytes is not None:  # once a batch, from shapes
-            for kind, nbytes in _state_bytes(cfg, state).items():
+        held: Dict[str, int] = {}
+        if sized or self._state_bytes is not None:
+            held = _state_bytes(cfg, state)  # once a batch, from shapes
+        if self._state_bytes is not None:
+            for kind, nbytes in held.items():
                 self._state_bytes.labels(kind=kind).set(nbytes)
         # a scalar beside the state, not of it: the decode is not given
         # it, and it rides behind the answer's arrays where there is one
@@ -268,7 +303,17 @@ class GenerativeAlgorithm(Algorithm):
         with annotate("pio:gen_decode", rows=B, steps=p.max_new):
             toks, scores, load, _ = _gen_decode(
                 model.weights, state, first, cfg=cfg, steps=p.max_new)
-        return (toks, scores, load) + (() if gap is None else (gap,)), T
+        return ((toks, scores, load) + (() if gap is None else (gap,)), T,
+                held)
+
+    def _ssm_moves(self, nbytes: int) -> None:
+        """A served batch's recurrent state arrives on the device
+        (``nbytes`` over 0) or leaves it with its answer."""
+        if not nbytes:
+            return  # no state-space layers, or no registry: no series
+        with self._ssm_lock:
+            self._ssm_resident += nbytes
+            self._ssm_bytes.set(self._ssm_resident)
 
     def _observe(self, cfg, hists, rows: int, slots: int, load,
                  gap=None) -> None:
@@ -284,6 +329,9 @@ class GenerativeAlgorithm(Algorithm):
             self._sinkhorn_gap.observe(float(gap))
 
         prompt = sum(len(h) for h in hists)
+        if cfg.mamba_n_heads:  # a layer's scan: its tokens, its chunks
+            self._ssm_tokens.inc(prompt)
+            self._ssm_chunks.inc(-(-slots // cfg.mamba_chunk_size))
         self._tokens.labels(kind="prompt").inc(prompt)
         self._tokens.labels(kind="pad").inc(slots - prompt)
         self._tokens.labels(kind="generated").inc(
@@ -317,7 +365,35 @@ class GenerativeAlgorithm(Algorithm):
             if b > top:
                 break
             for L in p.history_buckets:
-                self._dispatch(model, [[0] * L] * b)[0][0].block_until_ready()
+                arrays, _, held = self._enqueue(model, [[0] * L] * b,
+                                                sized=True)
+                arrays[0].block_until_ready()
+        self._check_residency(model, sum(held.values()))
+
+    def _check_residency(self, model: GenerativeModel, state: int) -> None:
+        """Weights and ``batches_in_flight`` batches' ``state`` bytes (a
+        batch of the ladder's top: what the last warm dispatch held)
+        against the device's memory, where the backend reports one. A
+        state-space model's state follows the rows and not the histories
+        (2 MiB a row and layer at the published sizes), so it is the rows
+        a batch may have, and not the cache a history needs, that a
+        deployment sizes here: a ladder that does not fit fails the
+        deploy, before traffic finds out."""
+        import jax
+
+        leaves = jax.tree_util.tree_leaves(model.weights)
+        limit = (leaves[0].devices().pop().memory_stats() or {}).get(
+            "bytes_limit") if leaves else None
+        if not limit:
+            return
+        resident = sum(a.nbytes for a in leaves) \
+            + self.batches_in_flight * state
+        if resident > RESIDENT_SHARE * limit:
+            raise RuntimeError(
+                f"weights and {self.batches_in_flight} batches' state are "
+                f"{resident / 1e9:.2f} GB of the device's "
+                f"{limit / 1e9:.2f}: lower row_buckets, history_buckets or "
+                f"the server's pipeline_depth")
 
     def batch_predict_async(self, model: GenerativeModel,
                             queries: Sequence[Query]):
@@ -332,12 +408,17 @@ class GenerativeAlgorithm(Algorithm):
         out: List[PredictedResult] = [PredictedResult()] * len(queries)
         top = self.params.row_buckets[-1]
         chunks = [live[s:s + top] for s in range(0, len(live), top)]
-        pending = [(chunk,) + self._dispatch(
-            model, [hists[i] for i in chunk]) for chunk in chunks]
+        pending = []
+        for chunk in chunks:
+            pending.append((chunk,) + self._enqueue(
+                model, [hists[i] for i in chunk]))
+            # in flight from here to its readback, its state with it
+            self._ssm_moves(pending[-1][-1].get("ssm", 0))
 
         def resolve() -> List[PredictedResult]:
-            for chunk, arrays, slots in pending:
+            for chunk, arrays, slots, held in pending:
                 toks, scores, load, *gap = jax.device_get(arrays)
+                self._ssm_moves(-held.get("ssm", 0))
                 self._observe(model.cfg, [hists[i] for i in chunk],
                               len(toks), slots, load, *gap)
                 for row, i in enumerate(chunk):

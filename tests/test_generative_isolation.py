@@ -27,6 +27,7 @@ GENERATIVE = ("predictionio_tpu.models.decoder",
               "predictionio_tpu.ops.window_attention",
               "predictionio_tpu.ops.head_lanes",
               "predictionio_tpu.ops.hyper_mix",
+              "predictionio_tpu.ops.ssm_scan",
               "predictionio_tpu.ops.moe")
 
 

@@ -11,7 +11,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from predictionio_tpu.ops import (
-    head_lanes, hyper_mix, moe, window_attention as wa)
+    head_lanes, hyper_mix, moe, ssm_scan, window_attention as wa)
 
 
 @pytest.fixture(scope="module")
@@ -316,3 +316,34 @@ def test_the_write_kernel_compiles_onto_its_input(one_chip, for_the_chip,
     assert "tpu_custom_call" in text and "hyper_mix_write" in text
     assert compiled.memory_analysis().alias_size_in_bytes \
         == n * slots * H * 4
+
+
+@pytest.mark.parametrize("slots", [2048, 16384])
+def test_the_scan_kernel_compiles_at_the_granite_cells_rungs(one_chip,
+                                                             slots):
+    """64 heads of 64 over a state of 128 in chunks of 256, 16 rows: the
+    shortest and the longest stream of the cell's ladder."""
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    compiled = _compiled(
+        lambda *a: ssm_scan.scan_kernel(*a, chunk=256), one_chip,
+        ((slots, 4096), bf16), ((slots, 128), bf16), ((slots, 128), bf16),
+        ((slots, 64), f32), ((64,), f32), ((slots,), i32), ((16,), i32))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_scan" in text
+
+
+def test_the_step_kernel_compiles_onto_its_state(one_chip):
+    """16 rows' float32 states ``[128, 4096]``: the new state lands in
+    the buffer the old one came in (donated: no second 32 MiB)."""
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one_chip) for s in (
+        (16, 128, 4096), (16, 4096), (16, 128), (16, 128), (16, 64),
+        (16, 64))]
+    compiled = jax.jit(ssm_scan.step_kernel, donate_argnums=(0,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_step" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 16 * 128 * 4096 * 4
