@@ -1714,13 +1714,148 @@ def quantize_serving_model(model: "ALSModel", quant: str, *,
 
 # -- serving ----------------------------------------------------------------
 
+#: items a chunk of :func:`_select_topk` holds. Set from a sweep on one
+#: TPU v5e (PERF.md, finding 44.2: a dispatch of 32 rows over 4.85 M
+#: items took 5.95 / 6.14 / 6.29 ms at 512 / 1024 / 2048, of 128 rows
+#: 13.90 / 13.93 / 14.47, of 4 rows 3.44 / 3.41 / 3.42): the pass over
+#: the scores reads the same bytes whatever the chunk, and the gather
+#: and the last top-k grow with it.
+SELECT_CHUNK = 512
+_LANES = 128
+
+
+def _chunk_maxima_kernel(scores: jax.Array, L: int, *,
+                         interpret: bool = False) -> jax.Array:
+    """``[B, ceil(n / L)]``: the maximum of each run of ``L`` scores of
+    a row, the ragged tail as if it went on at ``-inf``. ONE pass over
+    ``scores`` where it lies (no padded or re-laid-out copy: every
+    plain-XLA form of this reduction copied ``[B, n]`` at some batch),
+    eight rows and 128 chunks a grid step: a chunk's ``L / 128`` lane
+    tiles fold elementwise, one lane reduction, one lane of the output
+    tile. ``chunk_maxima`` in a device trace. The chip takes an ``L``
+    of whole lane tiles; Pallas' interpreter (the tests) takes any."""
+    from jax.experimental import pallas as pl
+
+    B, n = scores.shape
+    width = _LANES * L
+    rows = min(B, 8)
+    blocks = -(-n // width)
+
+    def kernel(x_ref, o_ref):
+        left = n - pl.program_id(1) * width  # real columns from here on
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, L), 1)
+
+        def eight_chunks(g, out):
+            for c in (8 * g + j for j in range(8)):
+                x = x_ref[:, pl.ds(pl.multiple_of(c * L, L), L)]
+                x = jnp.where(col < left - c * L, x, -jnp.inf)
+                out = jnp.where(lane == c,
+                                jnp.max(x, axis=1, keepdims=True), out)
+            return out
+
+        # a loop of sixteen, not 128 copies of the body: a program is
+        # traced and lowered at every start of the process, once a
+        # batch shape (the compile cache keeps only what comes after),
+        # and 128 copies cost a third of a second each time
+        o_ref[...] = jax.lax.fori_loop(
+            0, _LANES // 8, eight_chunks,
+            jnp.full((rows, _LANES), -jnp.inf, jnp.float32))
+
+    out = pl.pallas_call(
+        kernel, grid=(-(-B // rows), blocks),
+        in_specs=[pl.BlockSpec((rows, width), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((rows, _LANES), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, blocks * _LANES), jnp.float32),
+        interpret=interpret, name="chunk_maxima")(scores)
+    return out[:, :-(-n // L)]
+
+
+def _chunk_maxima(scores: jax.Array, L: int) -> jax.Array:
+    """The chunk maxima on the backend in hand: the kernel on a TPU,
+    the same reduction as plain ``jax.numpy`` elsewhere (a CPU has no
+    tiled layout for a reshape to break; the tests hold the two to each
+    other)."""
+    B, n = scores.shape
+    if jax.default_backend() == "tpu":
+        return _chunk_maxima_kernel(scores, L)
+    full, tail = divmod(n, L)
+    maxima = scores[:, :full * L].reshape(B, full, L).max(axis=-1)
+    if tail:
+        maxima = jnp.concatenate(
+            [maxima, scores[:, full * L:].max(axis=-1, keepdims=True)],
+            axis=1)
+    return maxima
+
+
+def _select_topk(scores: jax.Array, k: int, *,
+                 L: int = SELECT_CHUNK) -> Tuple[jax.Array, jax.Array]:
+    """``jax.lax.top_k(scores, k)`` over ``[B, n]`` float32, exactly
+    (values, indices, ties to the lowest index), without a selection
+    over ``n``-wide rows:
+
+    1. the maximum of each of a row's ``C = ceil(n / L)`` chunks of
+       ``L`` consecutive scores (:func:`_chunk_maxima`);
+    2. ``top_k`` of the maxima picks ``k`` chunks a row, and the picked
+       chunk numbers are sorted ascending;
+    3. ``top_k`` of those chunks' ``k * L`` scores, positions mapped
+       back to indices.
+
+    Every one of the true ``k`` lies in a picked chunk: were a winner
+    ``e`` in an unpicked chunk ``c``, each of the ``k`` picked chunks
+    would hold a score that beats ``e`` (a larger maximum, or an equal
+    one in a chunk of lower number, hence at a lower index). With the
+    picked chunks in ascending order a candidate's position ascends
+    with its index, so the stable ``top_k`` of step 3 breaks ties as
+    one ``top_k`` over the row would. The ragged last chunk is read as
+    the row's last ``L`` scores and shifted to the window's front with
+    ``-inf`` behind it: no copy of ``[B, n]`` is made, padded or not.
+
+    The ONE parameter adapts to the shape when the program is traced:
+    with no more chunks than ``k`` (``C <= k``: small catalogs, large
+    ``k``) every chunk would be picked and the function is the single
+    ``top_k``. ``L`` is a keyword for the tests' small arrays; nothing
+    else passes it."""
+    B, n = scores.shape
+    if -(-n // L) <= k:
+        return jax.lax.top_k(scores, k)
+    full, tail = divmod(n, L)
+    _, chunks = jax.lax.top_k(_chunk_maxima(scores, L), k)
+    chunks = jnp.sort(chunks, axis=-1)
+    # a chunk of a row is one [1, L] window of the scores as they lie;
+    # the ragged one (number ``full``) comes as the row's LAST L scores
+    ragged = chunks == full
+    rows = jnp.broadcast_to(
+        jnp.arange(B, dtype=chunks.dtype)[:, None], chunks.shape)
+    picked = jax.lax.gather(
+        scores,
+        jnp.stack([rows, jnp.where(ragged, n - L, chunks * L)], axis=-1),
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(2,), collapsed_slice_dims=(0,),
+            start_index_map=(0, 1)),
+        slice_sizes=(1, L),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    if tail:
+        # ... whose own ``tail`` scores end that window: to its front,
+        # -inf behind them
+        own = jnp.where(jnp.arange(L) < tail,
+                        jnp.roll(picked, tail - L, axis=-1), -jnp.inf)
+        picked = jnp.where(ragged[:, :, None], own, picked)
+    values, at = jax.lax.top_k(picked.reshape(B, k * L), k)
+    index = jnp.take_along_axis(chunks, at // L, axis=1) * L + at % L
+    return values, index
+
+
 @functools.partial(jax.jit, static_argnames=("k", "n_items"))
 def _serve_topk(user_factors, item_factors, idx: jax.Array, *, k: int,
                 n_items: int) -> Tuple[jax.Array, jax.Array]:
     """The WHOLE serving dispatch as one compiled program: user-row
-    gather + [B, r]×[n_pad, r]ᵀ matmul + pad mask + top_k. Eagerly these
-    were 4-5 separate dispatches — fused, a query pays one dispatch and
-    one fetch.
+    gather + [B, r]×[n_pad, r]ᵀ matmul + pad mask + the ``k`` best of a
+    row (:func:`_select_topk`: exactly ``top_k``'s answer, in two
+    stages wherever the catalog has more than ``k`` chunks of
+    ``SELECT_CHUNK`` items, so nothing sorts an ``n_pad``-wide row).
+    Eagerly these were 4-5 separate dispatches — fused, a query pays
+    one dispatch and one fetch.
 
     Tables may be :class:`QuantizedFactors`: rows upcast to f32 (and
     per-row scales apply) INSIDE the program, so the dot accumulates
@@ -1752,7 +1887,7 @@ def _serve_topk(user_factors, item_factors, idx: jax.Array, *, k: int,
         mask = jnp.arange(n_pad) < n_items
         scores = jnp.where(mask[None, :], scores, -jnp.inf)
     with jax.named_scope("pio_select"):
-        return jax.lax.top_k(scores, k)
+        return _select_topk(scores, k)
 
 
 def _device_topk(user_table, item_table, idx: np.ndarray, k_dev: int,

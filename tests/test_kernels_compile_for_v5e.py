@@ -5,6 +5,8 @@ does here and not on the chip: a block that does not fit the tiling,
 more VMEM than a kernel may use. The topology is described inside a
 fixture of this one file (on-chip-measurement guide, section 2)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -347,3 +349,35 @@ def test_the_step_kernel_compiles_onto_its_state(one_chip):
     assert "ssm_step" in text
     assert compiled.memory_analysis().alias_size_in_bytes \
         == 16 * 128 * 4096 * 4
+
+
+@pytest.mark.parametrize("batch", [1, 2, 4, 8, 16, 32, 64, 128])
+def test_the_serving_program_holds_one_array_of_scores(one_chip, monkeypatch,
+                                                       batch):
+    """``_serve_topk`` over the ALS cells' float32 tables (4,847,571
+    items, a multiple of no chunk, rank 128, ``k_dev`` 16) for the
+    described v5e: the chunk maxima are the ``chunk_maxima`` kernel, the
+    only ``top_k``s left are the two small ones, and the program's
+    temporaries are the ``[B, n_pad]`` scores once: no padded, sliced or
+    re-laid-out second copy (every plain-XLA form of the maxima made
+    one at ``B`` 32 or 128, PERF.md finding 44.1)."""
+    from predictionio_tpu.models import als
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, r, k = 4_847_571, 128, 16
+    table = jax.ShapeDtypeStruct((n, r), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    compiled = als._serve_topk.lower(
+        table, table, idx, k=k, n_items=n).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "chunk_maxima" in text
+    shape = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    selected = re.findall(
+        r'custom-call\(%([\w.\-]+)\), custom_call_target="TopK"', text)
+    widths = sorted(int(re.findall(r"\d+", shape[o])[-1]) for o in selected)
+    assert widths == sorted([-(-n // als.SELECT_CHUNK),
+                             k * als.SELECT_CHUNK]), widths
+    scores = batch * n * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= scores + (32 << 20)

@@ -3,7 +3,11 @@ against an independent float32 numpy ranking: ragged shapes, the pad
 mask and the int8/bf16 tables; its host mirror ``_host_topk``; the
 serving entries that launch it (``recommend_batch`` / ``_products`` /
 ``_pinned``), the sharded ranker and a ``StagedPipeline`` answer; the
-``_compiled_k`` clamp; and the removed selectors staying removed."""
+``_compiled_k`` clamp; the removed selectors staying removed; and the
+two-stage selection (``_select_topk``: chunk maxima, ``k`` chunks, a
+top-k of those) held to ``lax.top_k`` on the same scores, id for id and
+score for score, at chunks small enough for arrays of a few hundred
+items."""
 
 from datetime import datetime, timezone
 
@@ -11,6 +15,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.models import als
@@ -93,6 +98,206 @@ def test_host_topk_mirrors_serve_topk(B, n_pad, n_items, k):
     assert np.array_equal(i_host, np.asarray(i_dev))
     np.testing.assert_allclose(s_host, np.asarray(s_dev),
                                rtol=1e-6, atol=1e-6)
+
+
+# -- the two-stage selection ------------------------------------------------
+
+def _ints(B, n, seed, lo=-3, hi=4):
+    """Integer scores, so that ties abound."""
+    return np.random.default_rng(seed).integers(
+        lo, hi, size=(B, n)).astype(np.float32)
+
+
+def _ties_across_a_border():
+    s = np.zeros((3, 40), np.float32)
+    s[:, [7, 8, 15, 16, 23, 24]] = 5.0   # L = 8: either side of a border
+    return s
+
+
+def _all_in_one_chunk():
+    s = _ints(4, 96, 1, lo=-9, hi=0)
+    s[:, 16:24] = np.arange(8, 0, -1)    # the whole answer in chunk 2
+    return s
+
+
+def _several_winners_a_chunk():
+    s = _ints(5, 96, 2, lo=-9, hi=0)
+    s[:, [3, 5, 6]] = [4.0, 4.0, 9.0]    # three in chunk 0
+    s[:, [90, 95]] = [9.0, 4.0]          # two in the last
+    return s
+
+
+def _pads(n, n_items, seed):
+    s = _ints(6, n, seed)
+    s[:, n_items:] = -np.inf
+    return s
+
+
+#: name -> (scores, k, L)
+SELECT_CASES = {
+    "ties-across-a-border": (_ties_across_a_border(), 4, 8),
+    "ties-everywhere": (np.ones((2, 50), np.float32), 5, 4),
+    "all-k-in-one-chunk": (_all_in_one_chunk(), 8, 8),
+    "several-winners-a-chunk": (_several_winners_a_chunk(), 4, 8),
+    "ragged-tail": (_ints(7, 203, 3), 8, 16),
+    "ragged-tail-of-one": (_ints(3, 65, 4), 4, 16),
+    "tail-holds-the-answer": (
+        np.concatenate([_ints(3, 64, 5, -9, 0), _ints(3, 5, 6, 1, 9)], 1),
+        4, 16),
+    "pads-inside-the-last-real-chunk": (_pads(120, 83, 7), 8, 8),
+    "whole-chunks-of-pads": (_pads(128, 41, 8), 4, 8),
+    "pads-and-a-ragged-tail": (_pads(131, 70, 9), 8, 16),
+    "fewer-real-items-than-k": (_pads(90, 5, 10), 8, 8),
+    "k-1": (_ints(9, 77, 11), 1, 8),
+    "k-16": (_ints(5, 300, 12, -50, 50), 16, 16),
+    "one-row": (_ints(1, 100, 13), 4, 8),
+    "rows-off-the-ladder": (_ints(13, 150, 14), 8, 16),
+    "one-chunk-more-than-k": (_ints(4, 72, 15), 8, 8),
+    "normal-scores": (np.random.default_rng(16).normal(
+        size=(11, 260)).astype(np.float32), 16, 16),
+}
+
+
+@pytest.mark.parametrize("name", list(SELECT_CASES))
+def test_select_topk_is_top_k(name):
+    scores, k, L = SELECT_CASES[name]
+    assert -(-scores.shape[1] // L) > k  # the chunked path
+    want_s, want_i = jax.lax.top_k(jnp.asarray(scores), k)
+    got_s, got_i = als._select_topk(jnp.asarray(scores), k, L=L)
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert np.array_equal(np.asarray(got_s), np.asarray(want_s))
+    # and the semantics, not only the primitive: descending, ties to
+    # the lowest index
+    order = np.argsort(-scores, axis=1, kind="stable")[:, :k]
+    assert np.array_equal(np.asarray(got_i), order)
+
+
+def test_a_nan_score_is_named_as_top_k_names_it():
+    """A NaN in the table is a fault the answer must show, not hide
+    (``obs/numerics`` probes the served scores): its chunk's maximum is
+    NaN, the chunk is picked, and the NaN ranks where ``top_k`` ranks
+    it."""
+    scores = _ints(3, 100, 17).astype(np.float32)
+    scores[0, 37] = scores[1, 99] = np.nan
+    want_s, want_i = jax.lax.top_k(jnp.asarray(scores), 4)
+    got_s, got_i = als._select_topk(jnp.asarray(scores), 4, L=8)
+    assert np.isnan(np.asarray(got_s)[:2, 0]).all()
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert np.array_equal(np.asarray(got_s), np.asarray(want_s),
+                          equal_nan=True)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_select_topk_on_random_shapes(seed):
+    """The issue's scratch check, kept: integer scores, pads at -inf,
+    chunks of 4, 8 and 16, every shape past the rule."""
+    rng = np.random.default_rng(44 + seed)
+    for _ in range(5):
+        L, k = int(rng.choice([4, 8, 16])), int(rng.choice([1, 2, 5, 8]))
+        B, n = int(rng.integers(1, 6)), int(rng.integers(k * L + 1, 200))
+        s = _ints(B, n, int(rng.integers(1 << 30)))
+        s[:, int(rng.integers(1, n + 1)):] = -np.inf
+        want = jax.lax.top_k(jnp.asarray(s), k)
+        got = als._select_topk(jnp.asarray(s), k, L=L)
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), (L, k, B, n)
+
+
+def _serve_with_chunks(monkeypatch, L):
+    """``_serve_topk``'s own lines (untraced, so that the patched chunk
+    is read) selecting in chunks of ``L``."""
+    chunked = als._select_topk
+    monkeypatch.setattr(
+        als, "_select_topk", lambda scores, k: chunked(scores, k, L=L))
+    return als._serve_topk.__wrapped__
+
+
+@pytest.mark.parametrize("quant", QUANTS)
+@pytest.mark.parametrize("B,n_pad,n_items,k,L", [
+    (1, 200, 200, 8, 8),       # the exact float32 multiply-reduce
+    (13, 203, 203, 16, 8),     # off the pow2 ladder, a ragged tail
+    (8, 140, 100, 12, 8),      # pads inside a chunk, then chunks of pads
+    (5, 97, 97, 1, 16),
+], ids=["one-row", "ragged", "padded", "k-1"])
+def test_serve_topk_in_chunks_answers_as_whole(monkeypatch, B, n_pad,
+                                               n_items, k, L, quant):
+    """The program with its selection in small chunks against the same
+    program with ONE ``top_k`` (the parent's last line) and against the
+    numpy ranking, for all three table kinds."""
+    U, V, idx = case(B, n_pad)
+    qU, qV = as_quant(U, quant), as_quant(V, quant)
+    whole_s, whole_i = als._serve_topk(qU, qV, idx, k=k, n_items=n_items)
+    assert -(-n_pad // L) > k >= -(-n_pad // als.SELECT_CHUNK)
+    s, i = _serve_with_chunks(monkeypatch, L)(
+        qU, qV, jnp.asarray(idx), k=k, n_items=n_items)
+    assert np.array_equal(np.asarray(i), np.asarray(whole_i))
+    np.testing.assert_allclose(np.asarray(s), np.asarray(whole_s),
+                               rtol=1e-6, atol=1e-6)
+    ids, _ = numpy_topk(table_host_f32(qU)[idx], table_host_f32(qV),
+                        k, n_items)
+    assert np.array_equal(np.asarray(i), ids)
+
+
+def test_duplicated_item_rows_in_different_chunks_tie_to_the_lowest_id(
+        monkeypatch):
+    """The same factor row at ids in different chunks scores the same
+    to the bit: the answer names the lowest ids first."""
+    U, V, idx = case(6, 120)
+    V[[5, 37, 38, 77, 119]] = 4.0 * U[idx[0]]  # row 0's five best, tied
+    s, i = _serve_with_chunks(monkeypatch, 8)(
+        U, V, jnp.asarray(idx), k=8, n_items=120)
+    assert np.asarray(i)[0, :5].tolist() == [5, 37, 38, 77, 119]
+    want_s, want_i = als._serve_topk(U, V, idx, k=8, n_items=120)
+    assert np.array_equal(np.asarray(i), np.asarray(want_i))
+
+
+@pytest.mark.parametrize("B,n,L", [(1, 100, 8), (4, 300, 128), (8, 1000, 8),
+                                   (13, 77, 4), (19, 2051, 16)])
+def test_chunk_maxima_kernel_and_twin_agree(B, n, L):
+    """The Pallas pass (in its interpreter here) against the plain
+    reduction the CPU takes and against numpy: rows short of a sublane
+    tile, a row block past the batch, a ragged last chunk."""
+    scores = np.random.default_rng(n).normal(size=(B, n)).astype(np.float32)
+    padded = np.full((B, -(-n // L) * L), -np.inf, np.float32)
+    padded[:, :n] = scores
+    want = padded.reshape(B, -1, L).max(-1)
+    twin = als._chunk_maxima(jnp.asarray(scores), L)
+    kernel = als._chunk_maxima_kernel(jnp.asarray(scores), L,
+                                      interpret=True)
+    assert np.array_equal(np.asarray(twin), want)
+    assert np.array_equal(np.asarray(kernel), want)
+
+
+def _widest_selection(jaxpr):
+    """Widths of the operands of every ``top_k`` and ``sort`` of a
+    jaxpr, nested jaxprs included."""
+    widths = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("top_k", "sort"):
+            widths.append(max(v.aval.shape[-1] for v in eqn.invars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            widths.extend(_widest_selection(sub))
+    return widths
+
+
+def test_no_selection_is_as_wide_as_the_catalog():
+    """Past the rule (more chunks than ``k``) no ``top_k`` or ``sort``
+    of the program takes an operand ``n_pad`` wide; under it exactly
+    one does. The full-width selection cannot come back unnoticed."""
+    k, r = 16, 8
+    past = (k + 1) * als.SELECT_CHUNK + 3
+    under = k * als.SELECT_CHUNK
+
+    def widths(n_pad):
+        tables = [jax.ShapeDtypeStruct((n_pad, r), jnp.float32)] * 2
+        idx = jax.ShapeDtypeStruct((4,), jnp.int32)
+        closed = jax.make_jaxpr(
+            lambda u, v, i: als._serve_topk(u, v, i, k=k, n_items=n_pad)
+        )(*tables, idx)
+        return _widest_selection(closed.jaxpr)
+
+    assert widths(past) and max(widths(past)) == k * als.SELECT_CHUNK
+    assert [w for w in widths(under) if w >= under] == [under]
 
 
 def make_model(quant="off", r=16, nu=150, ni=180, seed=0, device=True):
