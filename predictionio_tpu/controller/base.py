@@ -165,7 +165,13 @@ class Serving(abc.ABC, Generic[Q, P]):
     (``core/BaseServing.scala:41,53``)."""
 
     def supplement(self, query: Q) -> Q:
-        """Pre-predict query enrichment (``BaseServing.supplementBase``)."""
+        """Pre-predict query enrichment (``BaseServing.supplementBase``).
+
+        The batched paths never call this identity
+        (``workflow/batch_predict.py::supplement_batch`` recognises it
+        on a serving that does not override it). An override runs once
+        a query, concurrently on a thread pool where a batch holds more
+        than one, so it may block on storage."""
         return query
 
     @abc.abstractmethod

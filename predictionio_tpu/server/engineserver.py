@@ -62,6 +62,7 @@ from ..rollout.registry import ReleaseRegistry
 from ..rollout.splitter import ARM_CANDIDATE, ARM_STABLE
 from ..utils.jsonutil import from_jsonable, to_jsonable
 from ..workflow.batch_predict import (
+    SUPPLEMENT_WAYS,
     PendingBatch,
     dispatch_batch,
     supplement_batch,
@@ -449,6 +450,14 @@ class QueryServer:
             "pio_pipeline_overlapped_dispatches_total",
             "Batch launches that found an earlier batch still in "
             "flight on the device — direct evidence of stage overlap")
+        self._pipeline_supplemented = self.metrics.counter(
+            "pio_pipeline_supplement_batches_total",
+            "Assembled batches by the way their queries were "
+            "supplemented: identity (the serving inherits "
+            "Serving.supplement: nothing is called), serial (an "
+            "overriding supplement, one query, on the assemble "
+            "thread), pool (the same, a call a query on the shared "
+            "thread pool)")
         self.overlap = OverlapTracker()
         state_seconds = self.metrics.counter(
             "pio_pipeline_state_seconds_total",
@@ -3288,8 +3297,10 @@ class StagedPipeline:
     - **assemble** (host pool, ``assemble_workers`` threads): greedy
       adaptive batch formation (``_form_batch``), JSON→query parse —
       per-query 400s complete IMMEDIATELY, a malformed query never
-      waits on a device round trip — and
-      concurrent supplement. All of it runs while the device chews on
+      waits on a device round trip — and supplement
+      (``supplement_batch``: nothing for a serving that inherits
+      ``Serving.supplement``, concurrent on the shared pool for one
+      that overrides it). All of it runs while the device chews on
       earlier batches.
     - **dispatch** (one thread per lane): takes the next assembled
       batch and ENQUEUES its device executables via
@@ -3360,6 +3371,9 @@ class StagedPipeline:
                        for st in ("assemble", "dispatch", "readback")}
         self._qdepth = {q: server._pipeline_qdepth.labels(queue=q)
                         for q in ("submit", "dispatch", "readback")}
+        self._supplemented = {
+            way: server._pipeline_supplemented.labels(way=way)
+            for way in SUPPLEMENT_WAYS}
         # per-stage rosters so close() can stop the stages in pipeline
         # order (assemble first, readback last)
         self._assemble_threads: List[threading.Thread] = []
@@ -3513,9 +3527,11 @@ class StagedPipeline:
             live: List[int] = []
             supplemented: List[Any] = []
             if entries:
-                with server._transfer_guard():
-                    supplemented, live = supplement_batch(
-                        serving, queries, out)
+                # the guard is entered only around a supplement that
+                # runs: an inherited identity is a list copy
+                supplemented, live, way = supplement_batch(
+                    serving, queries, out, guard=server._transfer_guard)
+                self._supplemented[way].inc()
             ab = _AssembledBatch(
                 entries=entries, queries=queries, out=out, live=live,
                 supplemented=supplemented, algorithms=algorithms,

@@ -12,8 +12,18 @@ query per dispatch.
 from __future__ import annotations
 
 import json
-from typing import Any, Iterable, Iterator, List, Optional
+from contextlib import nullcontext
+from typing import (
+    Any,
+    Callable,
+    ContextManager,
+    Iterable,
+    Iterator,
+    List,
+    Optional,
+)
 
+from ..controller.base import Serving
 from ..controller.context import Context
 from ..controller.engine import Engine
 from ..controller.params import EngineParams
@@ -40,36 +50,69 @@ def _algo_pool():
     return _dispatch_pool
 
 
-def supplement_batch(serving: Any, queries: List[Any],
-                     out: List[Any]) -> tuple:
+#: the ways a batch is supplemented: what :func:`supplement_batch`
+#: returns third, and the label values of
+#: ``pio_pipeline_supplement_batches_total{way}``
+SUPPLEMENT_WAYS = ("identity", "serial", "pool")
+
+
+def _inherits_supplement(serving: Any) -> bool:
+    """Whether ``serving.supplement`` is the one :class:`Serving`
+    defines (``return query``): true of an instance of any subclass
+    that does not override it; false for an override, for a duck-typed
+    serving that is no ``Serving`` and for an instance whose attribute
+    was replaced."""
+    return getattr(getattr(serving, "supplement", None),
+                   "__func__", None) is Serving.supplement
+
+
+def supplement_batch(serving: Any, queries: List[Any], out: List[Any],
+                     guard: Callable[[], ContextManager] = nullcontext
+                     ) -> tuple:
     """Supplement each query (the assemble-stage host work). Returns
-    ``(supplemented, live)``; per-query supplement failures land as the
-    raised exception in that query's ``out`` slot. With more than one
+    ``(supplemented, live, way)``, ``way`` one of
+    :data:`SUPPLEMENT_WAYS`; per-query supplement failures land as the
+    raised exception in that query's ``out`` slot.
+
+    ``identity``: the serving inherits ``Serving.supplement``
+    (:func:`_inherits_supplement`), so the queries ARE the supplemented
+    queries: a new list of the same objects, every index live, no call,
+    no pool, ``guard`` not entered. (A pool round trip a query to copy
+    a list was the largest host part of a saturated 21-query batch:
+    PERF.md finding 46.1.)
+
+    Any other supplement runs inside ``guard()``. ``serial``: at most
+    one query, on the calling thread. ``pool``: with more than one
     query the supplements run CONCURRENTLY on the shared dispatch pool:
     for templates whose supplement reads the event store (seen/
     constraint lookups), a serial loop made a 128-query batch pay 128
     sequential storage round trips before the device saw anything.
     Futures are drained in query order, so result order and per-query
     error slots are exactly the serial loop's."""
+    if _inherits_supplement(serving):
+        return list(queries), list(range(len(queries))), "identity"
     supplemented: List[Any] = []
     live: List[int] = []
-    if len(queries) > 1:
-        pool = _algo_pool()
-        futures = [pool.submit(serving.supplement, q) for q in queries]
-        for i, f in enumerate(futures):
-            try:
-                supplemented.append(f.result())
-                live.append(i)
-            except Exception as e:  # noqa: BLE001 — isolate per query
-                out[i] = e
-    else:
-        for i, q in enumerate(queries):
-            try:
-                supplemented.append(serving.supplement(q))
-                live.append(i)
-            except Exception as e:  # noqa: BLE001 — isolate per query
-                out[i] = e
-    return supplemented, live
+    with guard():
+        if len(queries) > 1:
+            way = "pool"
+            pool = _algo_pool()
+            futures = [pool.submit(serving.supplement, q) for q in queries]
+            for i, f in enumerate(futures):
+                try:
+                    supplemented.append(f.result())
+                    live.append(i)
+                except Exception as e:  # noqa: BLE001 — isolate per query
+                    out[i] = e
+        else:
+            way = "serial"
+            for i, q in enumerate(queries):
+                try:
+                    supplemented.append(serving.supplement(q))
+                    live.append(i)
+                except Exception as e:  # noqa: BLE001 — isolate per query
+                    out[i] = e
+    return supplemented, live, way
 
 
 def dispatch_batch(algorithms: List[Any], models: List[Any],
@@ -158,7 +201,7 @@ def predict_serve_batch(algorithms: List[Any], models: List[Any],
     :func:`dispatch_batch`, :class:`PendingBatch`), resolved at once,
     so the job and the server can never diverge."""
     out: List[Any] = [None] * len(queries)
-    supplemented, live = supplement_batch(serving, queries, out)
+    supplemented, live, _ = supplement_batch(serving, queries, out)
     resolvers: List[Any] = []
     if live:
         try:

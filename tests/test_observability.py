@@ -565,13 +565,28 @@ class TestHostClocks:
     """The host's seconds by thread role (ISSUE 37): one pass over
     /proc/self/task per export, every server thread under a role."""
 
-    def test_every_engine_server_thread_has_a_role(self):
+    def test_every_engine_server_thread_has_a_role(self, monkeypatch):
         import http.client
 
-        from predictionio_tpu.obs.runtime import thread_role
+        from conftest import serve_staged_batch
 
+        from predictionio_tpu.obs.runtime import thread_role
+        from predictionio_tpu.workflow import batch_predict
+
+        # a pool of this test's own: an earlier test's idle threads
+        # would take the submissions and no new thread would show
+        monkeypatch.setattr(batch_predict, "_dispatch_pool", None)
         before = set(threading.enumerate())
         qs, srv = _deploy_synthetic(batching=True)
+
+        class Supplementing(type(qs.serving)):
+            """The shipped serving inherits ``Serving.supplement``,
+            which never reaches the pool (ISSUE 46): an override does."""
+
+            def supplement(self, query):
+                return query
+
+        qs.serving = Supplementing()
         conns = [http.client.HTTPConnection("127.0.0.1", srv.port,
                                             timeout=60) for _ in range(6)]
         try:
@@ -580,15 +595,18 @@ class TestHostClocks:
                     {"user": f"u{i}", "num": 3}))
                 assert conn.getresponse().read()
 
-            # concurrently, so that a batch holds several queries and
-            # the supplement pool starts; keep-alive, so that the
-            # handler threads are still there to be looked at
+            # keep-alive, so that the handler threads are still there
+            # to be looked at
             clients = [threading.Thread(target=fire, args=(c, i))
                        for i, c in enumerate(conns)]
             for t in clients:
                 t.start()
             for t in clients:
                 t.join(60)
+            # one batch that holds several queries whatever the load
+            # made of the six above, so that the supplement pool starts
+            serve_staged_batch(qs, [{"user": f"u{i}", "num": 3}
+                                    for i in range(3)])
             roles = {}
             for t in set(threading.enumerate()) - before:
                 roles.setdefault(thread_role(t.name), set()).add(t.name)

@@ -318,6 +318,52 @@ class TestPipelineTelemetry:
         assert readback_ms <= sum(per_query) + 1e-6
         assert readback_ms >= max(per_query) * 0.5 - 1e-6
 
+    @staticmethod
+    def _supplemented(qs):
+        """``pio_pipeline_supplement_batches_total`` by ``way``."""
+        fam = qs.metrics.export()["pio_pipeline_supplement_batches_total"]
+        return {c["labels"]["way"]: c["value"] for c in fam["children"]}
+
+    def test_default_serving_batch_is_supplemented_by_identity(
+            self, monkeypatch):
+        """ISSUE 46: a batch of several queries whose serving inherits
+        ``Serving.supplement`` counts once under ``identity``, records
+        phase ``supplement`` once and starts no pool thread."""
+        from predictionio_tpu.workflow import batch_predict as bp
+
+        # as a fresh process has it: no executor made
+        monkeypatch.setattr(bp, "_dispatch_pool", None)
+        qs = _mk_server(ServerConfig(batching=True, max_batch=8,
+                                     warm_start=False))
+        assert self._supplemented(qs) == {
+            "identity": 0, "serial": 0, "pool": 0}
+        before = set(threading.enumerate())
+        slots, _ = serve_staged_batch(
+            qs, [{"user": f"u{i}", "num": 3} for i in range(6)])
+        assert all(len(_items(r)) == 3 for r in slots)
+        assert self._supplemented(qs) == {
+            "identity": 1, "serial": 0, "pool": 0}
+        phases = qs.metrics.snapshot()["pio_query_phase_seconds"]
+        assert phases["phase=supplement"]["count"] == 1
+        assert bp._dispatch_pool is None
+        assert not [t.name for t in set(threading.enumerate()) - before
+                    if t.name.startswith("algo-batch-dispatch")]
+
+    @pytest.mark.parametrize("n,way", [(1, "serial"), (4, "pool")])
+    def test_overriding_serving_batch_counts_by_its_size(self, n, way):
+        """The wedge's duck-typed serving keeps the behaviour it had:
+        one query on the assemble thread, more on the shared pool."""
+        qs = _mk_server(ServerConfig(batching=True, max_batch=8,
+                                     warm_start=False))
+        TestDeadline()._wedge(qs, 0.0)
+        slots, _ = serve_staged_batch(
+            qs, [{"user": f"u{i}", "num": 2} for i in range(n)])
+        assert all(len(_items(r)) == 2 for r in slots)
+        want = {"identity": 0, "serial": 0, "pool": 0, way: 1}
+        assert self._supplemented(qs) == want
+        phases = qs.metrics.snapshot()["pio_query_phase_seconds"]
+        assert phases["phase=supplement"]["count"] == 1
+
 
 class TestInflightDepth:
     """PR 26: ``pipeline_depth`` 0 resolves to 2 on every backend, and
