@@ -24,35 +24,10 @@ Honesty model (BASELINE.md "bench accounting"):
 
 import json
 import os
-import re
 import sys
 import time
 
 import numpy as np
-
-#: CSI/SGR escape sequences (jax's colored tracebacks) — stripped from
-#: error strings before they land in BENCH JSON, which must stay
-#: greppable plain text
-_ANSI_RE = re.compile(r"\x1b\[[0-9;]*[A-Za-z]")
-#: stray escape FRAGMENTS a mid-sequence truncation leaves behind
-_ANSI_FRAG_RE = re.compile(r"\x1b\[?[0-9;]*")
-#: log-line timestamps (ISO dates, times) — noise in a recorded error
-_TS_RE = re.compile(
-    r"\d{4}-\d{2}-\d{2}[T ]?(\d{2}:\d{2}(:\d{2}(\.\d+)?)?)?Z?")
-
-
-def _strip_ansi(s: str) -> str:
-    return _ANSI_FRAG_RE.sub("", _ANSI_RE.sub("", s))
-
-
-def _clean_err(s: str, limit: int = 160) -> str:
-    """One BENCH-safe line out of an arbitrary exception string: ANSI
-    escapes (and truncation fragments) stripped, log timestamps
-    dropped, whitespace collapsed, bounded length."""
-    s = _strip_ansi(str(s))
-    s = _TS_RE.sub("", s)
-    s = " ".join(s.split())
-    return s[:limit].rstrip()
 
 #: Headline peak matmul FLOP/s by TPU generation (bf16; public spec
 #: sheets). MFU is reported against this even though the bench runs f32 —
@@ -201,7 +176,6 @@ def main():
     n_items = int(27_000 * scale)
     nnz = int(20_000_000 * scale)
     rank = int(os.environ.get("BENCH_RANK", "64"))
-    gram_mode = os.environ.get("BENCH_GRAM", "auto")
     iterations = 5
     alpha, reg = 40.0, 0.01
 
@@ -231,8 +205,7 @@ def main():
 
     # bucketed layout: every rating trains, whatever the skew (0 drops)
     params = ALSParams(rank=rank, num_iterations=1, implicit_prefs=True,
-                       alpha=alpha, reg=reg, seed=3,
-                       gram_mode=gram_mode)
+                       alpha=alpha, reg=reg, seed=3)
 
     # pack once (the COO→device transfer + sort; sweeps amortize this),
     # then warm up the compiled half-steps
@@ -246,126 +219,46 @@ def main():
     dropped = 2 * nnz - kept_entries(packed[0]) - kept_entries(packed[1])
     assert dropped == 0, f"bench must train on all ratings; dropped={dropped}"
 
-    # gram-mode race: the packed layouts are gram-independent, so under
-    # "auto" the bench times BOTH realizations (baseline einsum vs the
-    # pair-packed MXU tiling) and reports the winner honestly
     peak = device_peak_flops()
 
-    gather_env = os.environ.get("BENCH_GATHER", "auto")
+    gather = os.environ.get("BENCH_GATHER", "float32")
 
-    def race(rank_r: int, repeats: int = 3):
-        """Time the training run at ``rank_r`` across the gram-mode ×
-        gather-dtype candidates; return the winner's numbers. The
-        gather axis: gathering factor rows from a bf16 shadow halves
-        the gather traffic, but the
-        winner must be MEASURED, not assumed, and its quality flows
-        into the ndcg10 the bench reports (the holdout retrain uses
-        the winning params). A failed candidate is skipped, surfaced
-        in the result's ``race_errors``, and BLOCKS the persistent
-        gram_autotune record if it was an f32 candidate (a partial f32
-        race must not write a winner the unmeasured mode might beat)."""
-        if gram_mode == "auto":
-            # the fused gather+gram kernel is not a candidate: the v5e
-            # compiler refuses it at every shape training runs
-            # (CHANGES.md PR 21); BENCH_GRAM=fused asks for it by name
-            gram_cands = ["einsum", "pair"]
-        else:
-            gram_cands = [gram_mode]
-        gather_cands = ["float32", "bfloat16"] if gather_env == "auto" \
-            else [gather_env]
-        cands = [(gm, gd) for gm in gram_cands for gd in gather_cands]
-        # normalize to (gram, gather, block_rows); rank 128 adds the
-        # small-blocks candidate (block_rows=1024) beside the
-        # auto-tiled ones
-        cands = [(*c, None) for c in cands]
-        if rank_r == 128 \
-                and gram_mode == "auto" \
-                and gather_env in ("auto", "bfloat16"):
-            # honor a forced-f32 sweep: this candidate is bf16-only,
-            # so it must not smuggle bf16 into a BENCH_GATHER=float32
-            # run
-            cands.append(("einsum", "bfloat16", 1024))
-        best_dt, best_gm, best_params = float("inf"), cands[0][0], None
-        best_f32_dt, best_f32_gm = float("inf"), cands[0][0]
-        cand_errors = []
-        f32_failed = False
-        for gm, gd, br in cands:
-            p_run = ALSParams(rank=rank_r, num_iterations=iterations,
-                              implicit_prefs=True, alpha=alpha, reg=reg,
-                              seed=3, gram_mode=gm, gather_dtype=gd,
-                              block_rows=br)
-            try:
-                U, V = train_als(ratings, p_run, packed=packed)  # warm
-                V.block_until_ready()
-                for _ in range(repeats):  # best-of-N
-                    t0 = time.monotonic()
-                    U, V = train_als(ratings, p_run, packed=packed)
-                    V.block_until_ready()
-                    d = time.monotonic() - t0
-                    if d < best_dt:
-                        best_dt, best_gm, best_params = d, gm, p_run
-                    if gd == "float32" and d < best_f32_dt:
-                        best_f32_dt, best_f32_gm = d, gm
-            except Exception as ce:  # noqa: BLE001 — one candidate the
-                # compiler refuses must not kill candidates that work;
-                # it is named in race_errors, and a race in which every
-                # candidate fails raises below
-                cand_errors.append(
-                    f"{gm}/{gd}{f'/br{br}' if br else ''}: "
-                    f"{_clean_err(ce, 120)}")
-                f32_failed = f32_failed or gd == "float32"
-        if best_params is None:
-            raise RuntimeError("every race candidate failed: "
-                               + " | ".join(cand_errors))
-        if gram_mode == "auto" and len(gram_cands) > 1 \
-                and best_f32_dt < float("inf") and not f32_failed:
-            # persist the gram winner measured AT THE DEFAULT gather
-            # dtype — gram_autotune consumers run gather_dtype=float32
-            # unless told otherwise, so storing the global (possibly
-            # bf16-combined) winner could hand them the slower mode.
-            # Skipped when any f32 candidate FAILED: a partial race
-            # must not cache a winner the unmeasured mode might beat.
-            # (Writes only where PIO_GRAM_AUTOTUNE_CACHE names a file.)
-            from predictionio_tpu.ops.gram_autotune import record
-            record(rank_r, best_f32_gm,
-                   device_kind=jax.devices()[0].device_kind,
-                   measured={"source": "bench_race",
-                             "best_s": round(best_f32_dt, 3)})
-        fl = als_flops_per_iter(packed[0], packed[1], best_params)
+    def timed_run(rank_r: int, repeats: int = 3):
+        """One training configuration at ``rank_r`` over the shared
+        packing (the layouts are rank-independent): a warm run, then
+        the best of ``repeats`` timed ones."""
+        p_run = ALSParams(rank=rank_r, num_iterations=iterations,
+                          implicit_prefs=True, alpha=alpha, reg=reg,
+                          seed=3, gather_dtype=gather)
+        U, V = train_als(ratings, p_run, packed=packed)  # warm
+        V.block_until_ready()
+        best_dt = float("inf")
+        for _ in range(repeats):
+            t0 = time.monotonic()
+            U, V = train_als(ratings, p_run, packed=packed)
+            V.block_until_ready()
+            best_dt = min(best_dt, time.monotonic() - t0)
+        fl = als_flops_per_iter(packed[0], packed[1], p_run)
         ach = fl * iterations / best_dt  # raw; display-rounded once
-        # what gram_mode="auto" RESOLVES to for this rank (persistent
-        # shape-keyed table → defaults → heuristic) — reported beside
-        # the race's measured winner so a stale autotune entry is
-        # visible in the BENCH line, not silently trained against
-        from predictionio_tpu.ops.gram_autotune import best_mode
-        autotune_pick = best_mode(
-            rank_r, device_kind=jax.devices()[0].device_kind)
         out = {
             "value": round(nnz * iterations / best_dt, 1),
             "achieved_tflops": round(ach / 1e12, 2),
             "mfu": round(ach / peak, 4) if peak else None,
-            "gram_mode": best_gm,
-            "autotune_pick": autotune_pick,
-            "gather_dtype": best_params.gather_dtype,
+            "gather_dtype": gather,
             "_achieved_flops_raw": ach,
         }
-        if best_params.block_rows is not None:
-            out["block_rows"] = best_params.block_rows
-        if cand_errors:
-            out["race_errors"] = cand_errors
-        return out, best_dt, best_params
+        return out, best_dt, p_run
 
-    r64, dt, params_run = race(rank)
+    r64, dt, params_run = timed_run(rank)
     ratings_per_sec = nnz * iterations / dt
     achieved_flops = r64.pop("_achieved_flops_raw")
     mfu = r64["mfu"]
-    gram_used = r64["gram_mode"]
 
     # rank-128 datapoint: the layouts are rank-independent, so the same
     # packing times a rank where the MXU is naturally fuller
     rank128 = None
     if os.environ.get("BENCH_RANK128", "1") == "1" and rank != 128:
-        rank128, _, _ = race(128, repeats=2)
+        rank128, _, _ = timed_run(128, repeats=2)
         rank128.pop("_achieved_flops_raw", None)
 
     cpu_rps = cpu_als_baseline(
@@ -506,8 +399,6 @@ def main():
         "dropped_entries": dropped,
         "ndcg10": ndcg10,
         "rank": rank,
-        "gram_mode": gram_used,
-        "autotune_pick": r64.get("autotune_pick"),
         "gather_dtype": r64.get("gather_dtype"),
         "rank128": rank128,
         "device_scaling": device_scaling,

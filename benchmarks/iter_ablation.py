@@ -2,11 +2,10 @@
 
 iter_scaling (round 4) split the iteration into a rank-independent
 ~0.4s component and an r² math term — but per-stage microbenches
-(gram_profile) show every stage at multi-TF/s on small batches, so the
+show every stage at multi-TF/s on small batches, so the
 bound hides at FULL problem scale. This probe times the real iteration
 body (both halves, real bucketed layout, 20M entries) with stages
-successively disabled, using gram_profile's DCE-proof fori_loop
-technique. The difference between adjacent stages is that stage's true
+successively disabled, inside a DCE-proof fori_loop. The difference between adjacent stages is that stage's true
 full-scale cost, dispatch overhead excluded.
 
 Stages (cumulative): gather → gram → +rhs → +solve → full (+scatter).
@@ -44,7 +43,7 @@ def main() -> None:
         _auto_block_rows,
         pack_ratings,
     )
-    from predictionio_tpu.ops.gram import gram_dispatch
+    from predictionio_tpu.ops.gram import gram_weighted
     from predictionio_tpu.ops.ragged import BucketedHistories
     from predictionio_tpu.ops.solve import gramian, solve_spd_batch
 
@@ -116,7 +115,7 @@ def main() -> None:
                     acc += jnp.sum(F)
                     continue
                 c1 = params.alpha * val * valid
-                A = G[None, None] + gram_dispatch(F, c1, mode="einsum")
+                A = G[None, None] + gram_weighted(F, c1)
                 if stage == "gram":
                     acc += jnp.sum(A)
                     continue

@@ -20,10 +20,6 @@ The output places the iteration on the DUAL roofline (ISSUE 7):
   model over the measured steady-state time) say how close the run
   sits to that roof.
 
-``PROBE_GRAM`` selects the gram realization (einsum | pair | fused |
-auto), so the bench can emit one block per mode and the fused kernel's
-bytes-accessed drop is visible next to the einsum baseline.
-
 With ``PROBE_SERVE=1`` the probe runs the SERVING roofline instead
 (ISSUE 13): it lowers the batched top-k dispatch (`_serve_topk`) over
 an f32 model and over the row-quantized (``PROBE_QUANT``, default
@@ -35,7 +31,7 @@ cost model; its effect shows up in serving_bench's measured lane).
 
 Usage: python benchmarks/roofline_probe.py   (from the repo root)
 Env:   BENCH_SCALE, BENCH_RANK as for bench.py; PROBE_ITERS (default 1);
-       PROBE_GRAM (default auto); PROBE_GATHER (float32|bfloat16);
+       PROBE_GATHER (float32|bfloat16);
        PROBE_REPEATS (default 3); PROBE_SERVE=1 (+ PROBE_QUANT,
        PROBE_SERVE_ITEMS, PROBE_SERVE_BATCH) for the serving block
 """
@@ -174,7 +170,6 @@ def main() -> None:
     scale = float(os.environ.get("BENCH_SCALE", "1.0"))
     rank = int(os.environ.get("BENCH_RANK", "64"))
     iters = int(os.environ.get("PROBE_ITERS", "1"))
-    gram = os.environ.get("PROBE_GRAM", "auto")
     gather = os.environ.get("PROBE_GATHER", "float32")
     n_users = int(138_000 * scale)
     n_items = int(27_000 * scale)
@@ -192,7 +187,7 @@ def main() -> None:
     ratings = als.RatingsCOO(users, items, vals, n_users, n_items)
     params = als.ALSParams(rank=rank, num_iterations=iters,
                            implicit_prefs=True, alpha=40.0, reg=0.01,
-                           seed=3, gram_mode=gram, gather_dtype=gather)
+                           seed=3, gather_dtype=gather)
 
     captured: dict = {}
     orig = als._train_fused
@@ -245,7 +240,6 @@ def main() -> None:
     out = {
         "metric": "als_fused_roofline",
         "device": device,
-        "gram_mode": gram,
         "gather_dtype": gather,
         "rank": rank, "nnz": nnz, "iters_in_program": iters,
         "xla_flops": flops,

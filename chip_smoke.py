@@ -12,8 +12,8 @@ implicit ALS, rank 64, every entity of the ML-20M surrogate
 26,744 titles that its 20,000,263 ratings touch — a factor table has a
 row per entity seen in the events, so these are the tables a full-scale
 ``ptpu train`` builds: [138493, 64] and [25279, 64]), default
-``ALSParams`` otherwise (``gram_mode``, ``history_mode`` and the solver
-all ``auto``).
+``ALSParams`` otherwise (``history_mode`` and the solver both
+``auto``).
 
 What is CUT is depth only, to fit the 1200 s limit with compilation:
 
@@ -238,9 +238,7 @@ def train(engine_json: Path) -> dict:
             f"train ran on {found['build']}, not on a TPU")
     k = found["kernels"]
     say(f"train backend: {found['build']}")
-    say(f"train resolved: gram {k['gram']['requested']} -> "
-        f"{k['gram']['resolved']}, solver {k['solver']}, layout "
-        f"{k['layout']}, refused {k['refused'] or 'nothing'}")
+    say(f"train resolved: solver {k['solver']}, layout {k['layout']}")
     say(f"train stages: {found['stages']}")
     return found
 
@@ -574,8 +572,7 @@ def run(device: dict, cache_dir: str, cache_before: int) -> int:
         "shapes": {"user_factors": [N_USERS, RANK],
                    "item_factors": [N_ITEMS, RANK],
                    "ratings": N_RATINGS, "iterations": ITERATIONS},
-        "resolved": {"gram": trained["kernels"]["gram"]["resolved"],
-                     "solver": trained["kernels"]["solver"],
+        "resolved": {"solver": trained["kernels"]["solver"],
                      "serving_quant": served["quant"]},
         "replicated_lanes": None if replicated is None
         else len(replicated["lanes"]),

@@ -41,9 +41,6 @@ shape-independent, which is exactly why a golden manifest works):
   O(k·n_dev) candidate all-gather (einsum realization).
 - ``lhs_einsum`` — ``_lhs_fn`` under GSPMD with row-sharded
   table/indices: the half-step's derived gather collective.
-- ``lhs_fused`` — ``_lhs_fn`` routed through the shard_map'd fused
-  kernel (interpret mode on CPU): the replicated-table boundary's
-  all-gather, and nothing else.
 - ``train_update_block`` — ``_update_block``: one whole training
   block (gather + Gramian + solve) under GSPMD.
 - ``seqrec_train_step`` — ``models/seqrec.py::_train_step`` with
@@ -210,22 +207,7 @@ def _entry_lhs_einsum():
 
     mesh = _training_mesh()
     table, idx, w = _lhs_inputs(mesh)
-    fn = jax.jit(functools.partial(_lhs_fn, gram="einsum", bf16=False,
-                                   mesh=None))
-    return fn.lower(table, idx, w, w).compile()
-
-
-def _entry_lhs_fused():
-    import functools
-
-    import jax
-
-    from ..models.als import _lhs_fn
-
-    mesh = _training_mesh()
-    table, idx, w = _lhs_inputs(mesh)
-    fn = jax.jit(functools.partial(_lhs_fn, gram="fused", bf16=False,
-                                   mesh=mesh))
+    fn = jax.jit(functools.partial(_lhs_fn, bf16=False))
     return fn.lower(table, idx, w, w).compile()
 
 
@@ -243,7 +225,7 @@ def _entry_train_update_block():
     G = np.zeros((16, 16), np.float32)
     fn = jax.jit(functools.partial(
         _update_block.__wrapped__, implicit=True, scale_reg=True,
-        bf16=False, gram="einsum", mesh=None))
+        bf16=False, mesh=None))
     return fn.lower(table, G, idx, w, counts, 0.1, 40.0).compile()
 
 
@@ -299,10 +281,6 @@ ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
     "lhs_einsum": (
         _entry_lhs_einsum,
         "_lhs_fn normal-equation build under GSPMD row sharding"),
-    "lhs_fused": (
-        _entry_lhs_fused,
-        "_lhs_fn through the shard_map'd fused kernel "
-        "(replicated-table boundary)"),
     "train_update_block": (
         _entry_train_update_block,
         "one ALS training block (gather+Gramian+solve) under GSPMD"),

@@ -1,8 +1,8 @@
 """The Pallas kernel-safety rule family behind ``ptpu check``.
 
-PR 7 put hand-written Pallas kernels on the training hot path
-(``ops/fused_gram.py``; ``ops/solve.py`` and ``ops/gram.py`` were
-already there), and the failure classes that silently corrupt or OOM a
+Hand-written Pallas kernels sit on the training and serving hot paths
+(``ops/solve.py`` and the generative engine's ``ops/``), and the
+failure classes that silently corrupt or OOM a
 kernel are invisible to both ``ruff`` and the JAX rules: a VMEM
 working set that only blows up at rank 128, a DMA started and never
 waited (reads garbage from the in-flight buffer), an accumulator that
@@ -16,9 +16,8 @@ pure AST like everything else in this package:
 - ``vmem-overbudget`` — statically evaluate every ``pallas_call``'s
   VMEM working set (BlockSpec tiles — doubled when a grid pipelines
   them — plus VMEM scratch) against the ~16 MiB/core budget, across
-  the rank grid declared by ``ops/gram_autotune_defaults.json`` and
-  the module's own chunk constants: the static sibling of
-  ``fused_gram.fused_vmem_bytes``. Symbolic dims resolve through
+  the rank grid :data:`RANKS` and the module's own chunk constants.
+  Symbolic dims resolve through
   local assignments, module constants, and parameter defaults; rank-
   like / chunk-like / history-like free names bind to the scenario
   grid; enclosing ``if``/``assert`` bounds (``if rp <= _RP_SCRATCH:``)
@@ -27,9 +26,9 @@ pure AST like everything else in this package:
   over-reports).
 - ``dma-unwaited`` — a ``make_async_copy`` ``.start()`` with no
   matching ``.wait()`` anywhere in the kernel (matched by copy
-  variable or by semaphore expression, so the split
-  issue-in-one-helper / drain-in-another pipeline idiom of
-  ``fused_gram`` matches), or the same semaphore slot restarted
+  variable or by semaphore expression, so a split
+  issue-in-one-helper / drain-in-another pipeline matches), or the
+  same semaphore slot restarted
   within a straight-line block before its wait.
 - ``low-precision-accumulator`` — ``+=`` / read-modify-write / dot
   results accumulated into bf16/f16 VMEM scratch refs. Accumulators
@@ -37,7 +36,7 @@ pure AST like everything else in this package:
   so the wire can be bf16 while the sum is not).
 - ``missing-interpret-fallback`` — a ``pallas_call`` with no
   ``interpret=`` escape hatch: every kernel must be routable through
-  a support-gated dispatcher (``fused_gram_dispatch`` is the model)
+  a support-gated dispatcher (``ops/solve.py::solve_spd_batch``)
   so CPU hosts and Mosaic versions that can't lower it degrade
   instead of raising mid-train.
 
@@ -50,8 +49,6 @@ budget math the first rule encodes).
 from __future__ import annotations
 
 import ast
-import json
-import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -333,44 +330,14 @@ def _memory_space_of(mod: ModuleInfo, call: ast.Call) -> Optional[str]:
 # rule: vmem-overbudget
 # ---------------------------------------------------------------------------
 
-_ranks_cache: Dict[str, Tuple[int, ...]] = {}
-
-
-def autotune_ranks(mod_path: str) -> Tuple[int, ...]:
-    """The rank grid ``vmem-overbudget`` evaluates: the autotuner's
-    rank buckets (32, 64, 128 — ``gram_autotune._rank_bucket``) plus
-    any further ``r<N>`` bucket declared by
-    ``gram_autotune_defaults.json`` next to the scanned module (falling
-    back to the packaged table), so the checker and the autotuner
-    always argue over the same ranks however few entries the table
-    holds."""
-    for candidate in (
-            os.path.join(os.path.dirname(mod_path) or ".",
-                         "gram_autotune_defaults.json"),
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "ops",
-                "gram_autotune_defaults.json")):
-        cached = _ranks_cache.get(candidate)
-        if cached is not None:
-            return cached
-        try:
-            with open(candidate, "r", encoding="utf-8") as fh:
-                doc = json.load(fh)
-        except (OSError, ValueError):
-            continue
-        ranks = sorted({int(m.group(1))
-                        for key in doc
-                        for m in [re.search(r"\|r(\d+)\|", key)]
-                        if m})
-        out = tuple(sorted({32, 64, 128, *ranks}))
-        _ranks_cache[candidate] = out
-        return out
-    return (32, 64, 128)
+#: the rank grid ``vmem-overbudget`` evaluates a kernel's free
+#: rank-like names at
+RANKS: Tuple[int, ...] = (32, 64, 128)
 
 
 def _module_chunks(scope: _Scope) -> Tuple[int, ...]:
     """Chunk-size scenario values: every module constant whose name
-    contains CHUNK (``_L_CHUNK = 512``), else the fused-gram default."""
+    contains CHUNK (``_L_CHUNK = 512``), else 512."""
     out: Set[int] = set()
     for name, value in scope.consts.items():
         if "CHUNK" in name.upper():
@@ -416,13 +383,12 @@ def rule_vmem_overbudget(mod: ModuleInfo,
     if not _uses_pallas(mod):
         return []
     findings: List[Finding] = []
-    ranks = autotune_ranks(mod.path)
     for site in _pallas_sites(mod):
         scope = _Scope(mod, site.fn)
         chunks = _module_chunks(scope)
         pipelined = "grid" in site.kwargs
         worst: Optional[Tuple[int, int, int, List[str]]] = None
-        for rank in ranks:
+        for rank in RANKS:
             for chunk in chunks:
                 scope.bind(rank, chunk)
                 if not scope.feasible(site.constraints):
@@ -492,7 +458,7 @@ def rule_vmem_overbudget(mod: ModuleInfo,
                 f"{total / (1 << 20):.1f} MiB at rank {rank} / chunk "
                 f"{chunk} exceeds the ~16 MiB/core budget "
                 f"({' + '.join(parts)}); shrink the block/scratch "
-                f"tiles, stream via ANY+DMA like fused_gram, or "
+                f"tiles, stream via ANY+DMA, or "
                 f"pragma with the measured budget argument "
                 f"(docs/kernels.md)"))
     return findings
@@ -745,8 +711,7 @@ def rule_low_precision_accumulator(mod: ModuleInfo,
                     f"`{tgt}` — every partial sum rounds to "
                     f"{low[tgt]} and the Gramian drifts; declare the "
                     f"accumulator f32 (upcast after the wire, "
-                    f"contract with preferred_element_type=f32, like "
-                    f"ops/fused_gram.py)"))
+                    f"contract with preferred_element_type=f32)"))
     return findings
 
 
@@ -771,11 +736,11 @@ def rule_missing_interpret_fallback(mod: ModuleInfo,
                 site.call.lineno, site.call.col_offset,
                 "pallas_call is hard-wired to compiled mode; thread "
                 "an interpret= parameter through and route callers "
-                "via a support-gated dispatcher (the "
-                "fused_gram_dispatch pattern: compiled kernel on "
-                "TPU, interpret-mode elsewhere, XLA reference where "
-                "Mosaic can't lower) so a CPU host or an older "
-                "Mosaic degrades instead of raising mid-train"))
+                "via a support-gated dispatcher (compiled kernel on "
+                "TPU, interpret-mode or the XLA reference elsewhere, "
+                "as ops/solve.py::solve_spd_batch does) so a CPU host "
+                "or an older Mosaic degrades instead of raising "
+                "mid-train"))
     return findings
 
 
@@ -783,7 +748,7 @@ def rule_missing_interpret_fallback(mod: ModuleInfo,
 __all__: Iterable[str] = (
     "VMEM_BUDGET_BYTES",
     "MAX_HISTORY_L",
-    "autotune_ranks",
+    "RANKS",
     "rule_dma_unwaited",
     "rule_low_precision_accumulator",
     "rule_missing_interpret_fallback",

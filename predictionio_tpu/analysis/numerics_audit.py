@@ -228,20 +228,7 @@ def _entry_lhs_einsum():
     from ..models.als import _lhs_fn
 
     table, idx, w = _lhs_arrays(AUDIT_DEVICE_COUNT)
-    fn = functools.partial(_lhs_fn, gram="einsum", bf16=False, mesh=None)
-    return jax.make_jaxpr(fn)(table, idx, w, w)
-
-
-def _entry_lhs_fused():
-    import functools
-
-    import jax
-
-    from ..models.als import _lhs_fn
-
-    mesh = _training_mesh()
-    table, idx, w = _lhs_arrays(mesh.devices.size)
-    fn = functools.partial(_lhs_fn, gram="fused", bf16=False, mesh=mesh)
+    fn = functools.partial(_lhs_fn, bf16=False)
     return jax.make_jaxpr(fn)(table, idx, w, w)
 
 
@@ -258,7 +245,7 @@ def _entry_train_update_block():
     G = np.zeros((16, 16), np.float32)
     fn = functools.partial(
         _update_block.__wrapped__, implicit=True, scale_reg=True,
-        bf16=False, gram="einsum", mesh=None)
+        bf16=False, mesh=None)
     return jax.make_jaxpr(fn)(table, G, idx, w, counts, 0.1, 40.0)
 
 
@@ -307,7 +294,7 @@ def _entry_foldin_update_bf16():
     G = np.zeros((16, 16), np.float32)
     inner = functools.partial(
         _update_block.__wrapped__, implicit=True, scale_reg=True,
-        bf16=True, gram="einsum", mesh=None)
+        bf16=True, mesh=None)
 
     def fold_block(table, G, idx, w, counts):
         # the fold_in_rows seam verbatim: gather_dtype="bfloat16"
@@ -545,10 +532,7 @@ ENTRY_POINTS: Dict[str, Tuple[Callable[[], object], str]] = {
         "per-shard top-k + candidate all-gather (einsum ranker)"),
     "lhs_einsum": (
         _entry_lhs_einsum,
-        "_lhs_fn normal-equation build (einsum lane)"),
-    "lhs_fused": (
-        _entry_lhs_fused,
-        "_lhs_fn through the shard_map'd fused kernel"),
+        "_lhs_fn normal-equation build"),
     "train_update_block": (
         _entry_train_update_block,
         "one ALS training block (gather+Gramian+solve)"),

@@ -44,9 +44,8 @@ The JAX rules:
   index array inside ``models/``/``ops/``/``server/`` functions
   (directly, or through a helper the traced index flows into): XLA
   materializes the gathered rows as an HBM temp sized by the index
-  shape (the ``[B, L, r]`` ALS gather temp behind BENCH_r05's
-  75%-HBM/0.6%-MFU roofline); fuse it (``gram_mode="fused"``), bound
-  it, or pragma a size case.
+  shape (the ``[B, L, r]`` ALS gather temp); bound it, or pragma a
+  size case.
 - ``config-drift`` — ``jax.config.update`` outside
   ``utils/platform.py``: scattered config flips make process behavior
   depend on import order (exactly the class of bug
@@ -669,8 +668,7 @@ def _gather_finding(mod: ModuleInfo, node: ast.AST, desc: str,
         "materialized-gather", mod.path, node.lineno, node.col_offset,
         f"{desc} by the index array `{idx_name}` in hot function "
         f"`{fname}` materializes the gathered rows as an HBM temp of "
-        f"unbounded size; bound it (row blocks), fuse it "
-        f"(gram_mode='fused', ops/fused_gram.py), or pragma with a "
+        f"unbounded size; bound it (row blocks), or pragma with a "
         f"size justification")
 
 
@@ -681,10 +679,8 @@ def _module_materialized_gather(mod: ModuleInfo,
 
     XLA materializes the gathered rows as an HBM temp whose size is the
     full index shape times the row width — ``fixed[indices]`` in the
-    ALS half-step was ``[B, L, r]``, written once and read back at
-    least once, which is exactly the 75%-HBM/0.6%-MFU bound BENCH_r05
-    measured. Bound the gather (row blocks), fuse it
-    (``gram_mode="fused"`` / ``ops/fused_gram.py``), or pragma it with
+    ALS half-step is ``[B, L, r]``, written once and read back at
+    least once. Bound the gather (row blocks), or pragma it with
     a size justification (a ``[B, r]`` serving row-fetch is fine; an
     unbounded ``[B, L, r]`` training temp is not).
 
@@ -798,7 +794,7 @@ def _module_materialized_gather(mod: ModuleInfo,
                     f"flows into a gather one call away: "
                     f"{chain_text(hops)} — the helper hides the "
                     f"subscript but the call site pays the HBM temp; "
-                    f"bound it, fuse it (gram_mode='fused'), or "
+                    f"bound it, or "
                     f"pragma the helper's gather with a size "
                     f"justification",
                     related=chain_related(hops)))
@@ -1049,8 +1045,7 @@ RULES: Dict[str, Rule] = {r.name: r for r in (
          rule_low_precision_accumulator),
     Rule("missing-interpret-fallback",
          "pallas_call hard-wired to compiled mode (no interpret= "
-         "escape) instead of riding a support-gated dispatcher like "
-         "fused_gram_dispatch",
+         "escape) instead of riding a support-gated dispatcher",
          rule_missing_interpret_fallback),
     Rule("unguarded-shared-state",
          "reads/writes of a class's lock-guarded attributes outside "

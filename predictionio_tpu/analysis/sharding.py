@@ -23,8 +23,7 @@ committed golden manifest.
   carries the interprocedural chain down to the shard_map site.
   Constraints are collected as per-function **spec sinks**
   (:class:`~.core.ProjectIndex` effect summaries) so a pragma at the
-  shard_map boundary blesses every caller at once (the
-  ``_fused_lhs`` replicated-table contract is the canonical case).
+  shard_map boundary blesses every caller at once.
 - ``shard-map-spec-mismatch`` — ``shard_map`` / ``shard_map_compat`` /
   ``sharded`` sites whose ``in_specs`` arity disagrees with the wrapped
   function's parameter count, whose ``out_specs`` arity disagrees with
@@ -420,8 +419,7 @@ def collect_spec_sinks(fn_info) -> Dict[int, Tuple[str, Witness]]:
     this function feeds into a shard_map boundary: the direct sites of
     ``implicit-reshard``. A ``# ptpu: allow[implicit-reshard]`` pragma
     at the boundary kills the sink — blessing the one documented
-    boundary (e.g. ``_fused_lhs``'s replicated table) blesses every
-    caller."""
+    boundary blesses every caller."""
     mod: ModuleInfo = fn_info.mod
     fn = fn_info.node
     params: List[str] = fn_info.params

@@ -38,6 +38,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import numerics as _numerics
+from ..ops.gram import gram_weighted
 from ..ops.ragged import BucketedHistories, PaddedHistories, SplitHistories
 from ..ops.solve import gramian, solve_spd_batch
 from ..parallel.mesh import rows_spec
@@ -77,15 +78,6 @@ class ALSParams:
     #: of the normal-equation INPUTS (quality-checked by
     #: tests/test_als.py::TestGatherDtype).
     gather_dtype: str = "float32"
-    #: Weighted-gram realization: "einsum" (baseline batched matmul),
-    #: "pair" (two rank-r systems packed per 128x128 MXU tile —
-    #: ``ops/gram.py``), "fused" (the Pallas gather+Gramian kernel,
-    #: ``ops/fused_gram.py`` — the gathered [B, L, r] temp never lands
-    #: in HBM; on non-TPU backends this runs the kernel in interpret
-    #: mode, a debugging path), or "auto" (the persistent shape-keyed
-    #: autotune table, support-gated so "fused" never resolves where
-    #: the kernel cannot lower).
-    gram_mode: str = "auto"
     #: History layout. "pad": one [n_rows, L] padded matrix per side
     #: (entries beyond L are DROPPED — round-1 semantics). "bucket":
     #: power-of-two length buckets, drop-free at ≤2× padding with MXU-deep
@@ -109,10 +101,6 @@ class ALSParams:
             raise ValueError(
                 f"history_mode must be 'auto', 'pad', 'split' or "
                 f"'bucket', got {self.history_mode!r}")
-        if self.gram_mode not in ("auto", "einsum", "pair", "fused"):
-            raise ValueError(
-                f"gram_mode must be 'auto', 'einsum', 'pair' or "
-                f"'fused', got {self.gram_mode!r}")
 
 
 @jax.tree_util.register_dataclass
@@ -153,134 +141,26 @@ class RatingsCOO:
     n_items: int
 
 
-def _table_names_fused(gram: str, rank: int, bf16: bool) -> bool:
-    """Whether ``gram`` asks for the fused Pallas kernel: explicitly, or
-    through the autotune table's entry for this rank."""
-    if gram == "fused":
-        return True
-    if gram != "auto":
-        return False
-    from ..ops.gram_autotune import best_mode
-
-    return best_mode(rank, bf16=bf16) == "fused"
-
-
-def _resolve_gram(gram: str, rank: int, bf16: bool, wire_dtype,
-                  hist_lens: Sequence[int]) -> str:
-    """The concrete gram realization for tables of ``wire_dtype`` and
-    the padded history lengths about to run.
-
-    An explicit mode is returned as asked: ``"fused"`` compiles the
-    kernel or raises the compiler's message where it is dispatched.
-    ``"auto"`` reads the autotune table; where that names the fused
-    kernel it is compiled at these shapes first
-    (``fused_gram_refusal``) and, if the compiler refuses any of them,
-    the whole run trains on einsum and the refusal stays on record for
-    the train log line (``fused_gram.refusals``)."""
-    if gram != "auto":
-        return gram
-    from ..ops.gram_autotune import best_mode
-
-    pick = best_mode(rank, bf16=bf16)
-    if pick == "fused":
-        from ..ops.fused_gram import fused_gram_refusal
-
-        if any(fused_gram_refusal(rank, wire_dtype, L) is not None
-               for L in hist_lens):
-            return "einsum"
-    return pick
-
-
-def _wire_dtype(params: "ALSParams") -> str:
-    return "bfloat16" if params.gather_dtype == "bfloat16" else "float32"
-
-
-def resolved_gram_mode(params: "ALSParams",
-                       hist_lens: Optional[Sequence[int]] = None) -> str:
-    """The concrete gram realization ``params`` trains with on the
-    attached backend at the padded history lengths ``hist_lens`` (the
-    fused kernel's steady-state chunk when not given) — what the train
-    log line reports and the label value of the ``pio_gram_mode`` info
-    gauge (docs/observability.md). Raises the compiler's message for an
-    explicit ``gram_mode="fused"`` the attached TPU cannot compile: a
-    gauge must never read ``fused`` for a kernel that does not run."""
-    from ..ops import _probe, fused_gram
-    from ..ops.fused_gram import fused_gram_refusal
-
-    lens = tuple(hist_lens or (fused_gram._L_CHUNK,))
-    bf16 = params.matmul_dtype == "bfloat16"
-    wire = _wire_dtype(params)
-    if params.gram_mode == "fused" and _probe.tpu_attached():
-        for L in lens:
-            why = fused_gram_refusal(params.rank, wire, L)
-            if why is not None:
-                raise RuntimeError(
-                    f"gram_mode='fused' does not compile on this backend "
-                    f"at rank {params.rank}, {wire} wire, history length "
-                    f"{L}: {why}")
-    return _resolve_gram(params.gram_mode, params.rank, bf16, wire, lens)
-
-
-def _fused_lhs(table: jax.Array, indices: jax.Array, wa: jax.Array,
-               wb: jax.Array, mesh: Optional[Mesh]):
-    """The fused-kernel realization of :func:`_lhs_fn`: gather and
-    Gramian in one Pallas launch (``ops/fused_gram.py``) — the
-    ``[d, B, L, r]`` temp never exists. Under a mesh the kernel runs on
-    each device's LOCAL rows via shard_map: the fixed table enters
-    replicated (the same all-gather the GSPMD gather pays), the
-    index/weight blocks and both outputs stay row-sharded."""
-    from ..ops.fused_gram import fused_gram_dispatch
-
-    r = table.shape[-1]
-    L = indices.shape[-1]
-
-    def flat(tab, idx, a, b2):
-        A, bb = fused_gram_dispatch(tab, idx.reshape(-1, L),
-                                    a.reshape(-1, L), b2.reshape(-1, L))
-        return (A.reshape(idx.shape[:-1] + (r, r)),
-                bb.reshape(idx.shape[:-1] + (r,)))
-
-    if mesh is None:
-        return flat(table, indices, wa, wb)
-    spec = rows_spec(mesh)
-    fn = jax.shard_map(flat, mesh=mesh,
-                       in_specs=(P(), spec, spec, spec),
-                       out_specs=(spec, spec), check_vma=False)
-    return fn(table, indices, wa, wb)
-
-
 def _lhs_fn(table: jax.Array, indices: jax.Array, wa: jax.Array,
-            wb: jax.Array, *, gram: str, bf16: bool,
-            mesh: Optional[Mesh] = None):
+            wb: jax.Array, *, bf16: bool):
     """Per-row normal-equation build — the ONE place the factor gather
     exists: ``A = Σ_l wa·f fᵀ`` and the fused RHS ``b = Σ_l wb·f`` over
     ``f = table[indices]``. ``table`` is the f32 factors or the bf16
     shadow (:func:`_shadow_lhs_fn` casts for callers that have not);
     weights arrive pre-masked so padding slots contribute exactly zero.
 
-    ``gram_mode="fused"`` (and "auto" resolving to it at these shapes,
-    :func:`_resolve_gram`) routes to the Pallas fused gather+Gramian
-    kernel and never materializes the ``[d, B, L, r]`` gather temp in
-    HBM. Every other mode gathers and dispatches to ``ops/gram.py``.
-    Under a mesh the kernel covers row-sharded blocks; L-axis-sharded
-    skinny buckets keep the einsum path, whose contraction over L GSPMD
-    turns into per-device partial Gramians + an all-reduce."""
-    gram = _resolve_gram(gram, table.shape[-1], bf16, table.dtype,
-                         (indices.shape[-1],))
-    if gram == "fused" \
-            and (mesh is None or indices.shape[0] == mesh.devices.size):
-        return _fused_lhs(table, indices, wa, wb, mesh)
-    from ..ops.gram import gram_dispatch
-
+    Under a mesh GSPMD places both einsums: row-sharded blocks stay
+    local, and the contraction over L of an L-axis-sharded skinny
+    bucket becomes per-device partial Gramians + an all-reduce."""
     # gather_dtype="bfloat16": F stays bf16 INTO the einsums — the
     # upcast to f32 happens inside each dot's fusion (exact: the values
     # are already bf16-quantized) instead of as a standalone convert
     # materializing a second full-size F (measured 5.2ms per block in
     # the round-4 trace). Accumulation/solve stay f32 via promotion.
     # ptpu: allow[materialized-gather] — bounded by _auto_block_rows'
-    # ~1GB block budget, and eliminated entirely under gram_mode="fused"
+    # ~1GB block budget
     F = table[indices]  # [d, B, L, r] — cross-shard gather under a mesh
-    A = gram_dispatch(F, wa, mode=gram, bf16=bf16)
+    A = gram_weighted(F, wa, bf16=bf16)
     # F can be the bf16 shadow: keep the RHS accumulation f32, matching
     # the Gramian side (ops/gram.py contract) — without this the Σ_l
     # wb·f sum runs at bf16 and fold-in solves drift
@@ -290,24 +170,23 @@ def _lhs_fn(table: jax.Array, indices: jax.Array, wa: jax.Array,
 
 
 def _shadow_lhs_fn(table_f32: jax.Array, indices: jax.Array,
-                   wa: jax.Array, wb: jax.Array, *, gram: str,
-                   bf16: bool, mesh: Optional[Mesh] = None):
+                   wa: jax.Array, wb: jax.Array, *, bf16: bool):
     """:func:`_lhs_fn` over the bf16 SHADOW of an f32 table (the
     ``ALSParams.gather_dtype="bfloat16"`` wire): rows travel HBM→MXU
-    (or HBM→VMEM, fused) as bf16, accumulation stays f32. The
+    as bf16, accumulation stays f32. The
     half-iteration impls pre-cast ONCE per half-step so every block
     shares one shadow buffer; this entry is for callers without that
     amortization (tests, one-shot solves)."""
     return _lhs_fn(table_f32.astype(jnp.bfloat16), indices, wa, wb,
-                   gram=gram, bf16=bf16, mesh=mesh)
+                   bf16=bf16)
 
 
 @functools.partial(jax.jit, static_argnames=("implicit", "scale_reg",
-                                             "bf16", "gram", "mesh"))
+                                             "bf16", "mesh"))
 def _update_block(fixed: jax.Array, G, indices: jax.Array,
                   values: jax.Array, counts: jax.Array, reg: float,
                   alpha: float, implicit: bool, scale_reg: bool,
-                  bf16: bool = False, gram: str = "auto",
+                  bf16: bool = False,
                   mesh: Optional[Mesh] = None) -> jax.Array:
     """Recompute one block of rows, holding ``fixed`` constant.
 
@@ -315,8 +194,8 @@ def _update_block(fixed: jax.Array, G, indices: jax.Array,
     for implicit); indices/values: [d, B, L]; counts: [d, B] with leading
     axis sharded across all devices → new factors [d, B, r], same sharding.
     Padding entries carry value 0 and index 0; masks keep them inert.
-    ``mesh`` (static) lets the fused path run its kernel per device on
-    local rows; the einsum/pair paths ignore it (GSPMD places them).
+    ``mesh`` (static) is the SPD solve's (``ops/solve.py``); GSPMD
+    places the normal-equation einsums.
     """
     r = fixed.shape[-1]
     L = indices.shape[-1]
@@ -331,10 +210,9 @@ def _update_block(fixed: jax.Array, G, indices: jax.Array,
     else:
         wa = valid
         wb = values * valid
-    A, b = _lhs_fn(fixed, indices, wa, wb, gram=gram, bf16=bf16,
-                   mesh=mesh)
+    A, b = _lhs_fn(fixed, indices, wa, wb, bf16=bf16)
     if implicit:
-        # G is added AFTER the kernel/einsum output on purpose: the
+        # G is added AFTER the einsum output on purpose: the
         # blocks' normal-equation build has no data dependence on the
         # fixed-side Gramian, so its (mesh) all-reduce overlaps the
         # first block's gather instead of gating it
@@ -346,39 +224,20 @@ def _update_block(fixed: jax.Array, G, indices: jax.Array,
     return solve_spd_batch(A, b, mesh=mesh)
 
 
-_gramian_jit = jax.jit(gramian)
+#: Implicit-path baseline Gramian FᵀF of the fixed side: the plain
+#: einsum, whose collective GSPMD derives under a mesh. Jitted
+#: (compile-once) for the eager split path; inlined when traced inside
+#: a half-step program.
+_fixed_gramian = jax.jit(gramian)
 
 
-def _fixed_gramian(fixed: jax.Array, mesh: Optional[Mesh], gram: str,
-                   bf16: bool):
-    """Implicit-path baseline Gramian FᵀF of the fixed side. Under a
-    mesh on the fused path it is computed as an EXPLICIT per-shard
-    partial + ICI psum (``parallel/collectives.gramian_allreduce``)
-    that nothing in any block's kernel depends on: blocks add G to
-    their kernel output last (:func:`_update_block`), so the all-reduce
-    rides under the next virtual-row block's gather/kernel launch
-    instead of serializing the half-iteration on it — the ALX overlap
-    (arXiv 2112.02194). Elsewhere it stays the plain einsum whose
-    collective GSPMD derives."""
-    if mesh is not None \
-            and _table_names_fused(gram, fixed.shape[-1], bf16):
-        from ..parallel.collectives import gramian_allreduce
-
-        return gramian_allreduce(fixed, mesh)
-    # jitted (compile-once) for the eager split path; inlined like the
-    # plain einsum when traced inside a half-step program
-    return _gramian_jit(fixed)
-
-
-@functools.partial(jax.jit, static_argnames=("implicit", "bf16",
-                                             "gram", "mesh"),
+@functools.partial(jax.jit, static_argnames=("implicit", "bf16"),
                    donate_argnums=(5, 6))
 def _partials_block(fixed: jax.Array, indices: jax.Array,
                     values: jax.Array, counts: jax.Array,
                     row_ids: jax.Array, A_acc: jax.Array,
                     b_acc: jax.Array, alpha: float, implicit: bool,
-                    bf16: bool = False, gram: str = "auto",
-                    mesh: Optional[Mesh] = None):
+                    bf16: bool = False):
     """Split-mode half of :func:`_update_block`: per-VIRTUAL-row partials
     Σ w·ffᵀ and Σ w·f, scatter-added onto the owning real rows.
     Sentinel/padding virtual rows contribute exactly zero (their valid
@@ -393,8 +252,7 @@ def _partials_block(fixed: jax.Array, indices: jax.Array,
     else:
         wa = valid
         wb = values * valid
-    A_v, b_v = _lhs_fn(fixed, indices, wa, wb, gram=gram, bf16=bf16,
-                       mesh=mesh)
+    A_v, b_v = _lhs_fn(fixed, indices, wa, wb, bf16=bf16)
     ids = row_ids.reshape(-1)
     A_acc = A_acc.at[ids].add(A_v.reshape(-1, r, r), mode="drop")
     b_acc = b_acc.at[ids].add(b_v.reshape(-1, r), mode="drop")
@@ -446,8 +304,7 @@ def _update_side_split(fixed: jax.Array, sh: dict, params: "ALSParams",
     exactly as the pad path does."""
     implicit = params.implicit_prefs
     bf16 = params.matmul_dtype == "bfloat16"
-    G = _fixed_gramian(fixed, sh["mesh"], params.gram_mode, bf16) \
-        if implicit else None
+    G = _fixed_gramian(fixed) if implicit else None
     gsrc = fixed.astype(jnp.bfloat16) \
         if params.gather_dtype == "bfloat16" else fixed
     d, n_vper, L = sh["idx"].shape
@@ -461,8 +318,7 @@ def _update_side_split(fixed: jax.Array, sh: dict, params: "ALSParams",
         A_acc, b_acc = _partials_block(
             gsrc, sh["idx"][:, s:e], sh["val"][:, s:e],
             sh["cnt"][:, s:e], sh["rid"][:, s:e], A_acc, b_acc,
-            params.alpha, implicit, bf16=bf16,
-            gram=params.gram_mode, mesh=sh["mesh"])
+            params.alpha, implicit, bf16=bf16)
     if G is None:
         G = jnp.zeros((r, r), jnp.float32)  # static arg shape filler
     return _solve_accumulated(A_acc, b_acc, G, sh["real_cnt"], params.reg,
@@ -473,14 +329,13 @@ def _update_side_split(fixed: jax.Array, sh: dict, params: "ALSParams",
 def _bucket_half_impl(fixed: jax.Array, out0: jax.Array, buckets,
                       reg, alpha, implicit: bool, scale_reg: bool,
                       bf16: bool, block_rows_opt,
-                      gram: str = "auto",
                       gather_bf16: bool = False,
                       mesh: Optional[Mesh] = None) -> jax.Array:
     """Trace-level body of a bucketed half-iteration (jit-wrapped by
     :func:`_bucket_half_step` and inlined whole-training by
     :func:`_train_bucket_fused`)."""
     r = fixed.shape[-1]
-    G = _fixed_gramian(fixed, mesh, gram, bf16) if implicit else None
+    G = _fixed_gramian(fixed) if implicit else None
     # the bf16 shadow (ALSParams.gather_dtype): gram/rhs/solve stay f32.
     # The barrier shares ONE materialized shadow across every bucket's
     # gather instead of letting XLA re-fuse the cast per bucket
@@ -498,7 +353,7 @@ def _bucket_half_impl(fixed: jax.Array, out0: jax.Array, buckets,
             parts.append(_update_block(
                 gsrc, G, b["idx"][:, s:e], b["val"][:, s:e],
                 b["cnt"][:, s:e], reg, alpha, implicit, scale_reg,
-                bf16=bf16, gram=gram, mesh=mesh))
+                bf16=bf16, mesh=mesh))
         new = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
                                                                axis=1)
         # each real row lives in exactly one bucket → unique indices (the
@@ -511,13 +366,12 @@ def _bucket_half_impl(fixed: jax.Array, out0: jax.Array, buckets,
 
 @functools.partial(jax.jit,
                    static_argnames=("implicit", "scale_reg", "bf16",
-                                    "block_rows_opt", "gram",
+                                    "block_rows_opt",
                                     "gather_bf16", "mesh"),
                    donate_argnums=(1,))
 def _bucket_half_step(fixed: jax.Array, out0: jax.Array, buckets,
                       reg, alpha, *, implicit: bool, scale_reg: bool,
                       bf16: bool, block_rows_opt,
-                      gram: str = "auto",
                       gather_bf16: bool = False,
                       mesh: Optional[Mesh] = None) -> jax.Array:
     """One ENTIRE bucketed half-iteration as a single compiled program —
@@ -529,7 +383,7 @@ def _bucket_half_step(fixed: jax.Array, out0: jax.Array, buckets,
     compilation; the bucket STRUCTURE (shapes) is the cache key.
     """
     return _bucket_half_impl(fixed, out0, buckets, reg, alpha, implicit,
-                             scale_reg, bf16, block_rows_opt, gram,
+                             scale_reg, bf16, block_rows_opt,
                              gather_bf16, mesh)
 
 
@@ -547,20 +401,20 @@ def _update_side_bucket(fixed: jax.Array, bk: dict, params: "ALSParams"
         implicit=params.implicit_prefs,
         scale_reg=params.scale_reg_by_count,
         bf16=(params.matmul_dtype == "bfloat16"),
-        block_rows_opt=params.block_rows, gram=params.gram_mode,
+        block_rows_opt=params.block_rows,
         gather_bf16=(params.gather_dtype == "bfloat16"),
         mesh=bk["mesh"])
 
 
 def _pad_half_impl(fixed: jax.Array, lay: dict, block: int, reg, alpha,
                    implicit: bool, scale_reg: bool, bf16: bool,
-                   gram: str, gather_bf16: bool = False,
+                   gather_bf16: bool = False,
                    mesh: Optional[Mesh] = None) -> jax.Array:
     """One pad-layout half-iteration (trace-level body): Gramian, row
     blocks through :func:`_update_block`, flat reshape. SHARED by the
     per-step path (:func:`_update_side`) and the fused whole-run
     trainer — the two must never diverge."""
-    G = _fixed_gramian(fixed, mesh, gram, bf16) if implicit else None
+    G = _fixed_gramian(fixed) if implicit else None
     gsrc = jax.lax.optimization_barrier(
         fixed.astype(jnp.bfloat16)) if gather_bf16 else fixed
     d, n_per, L = lay["idx"].shape
@@ -570,7 +424,7 @@ def _pad_half_impl(fixed: jax.Array, lay: dict, block: int, reg, alpha,
         parts.append(_update_block(
             gsrc, G, lay["idx"][:, st:e], lay["val"][:, st:e],
             lay["cnt"][:, st:e], reg, alpha, implicit, scale_reg,
-            bf16=bf16, gram=gram, mesh=mesh))
+            bf16=bf16, mesh=mesh))
     out = parts[0] if len(parts) == 1 \
         else jnp.concatenate(parts, axis=1)
     return out.reshape(d * n_per, out.shape[-1])
@@ -578,14 +432,14 @@ def _pad_half_impl(fixed: jax.Array, lay: dict, block: int, reg, alpha,
 
 @functools.partial(jax.jit,
                    static_argnames=("implicit", "scale_reg", "bf16",
-                                    "gram", "kind_u", "kind_i",
+                                    "kind_u", "kind_i",
                                     "block_u", "block_i",
                                     "block_rows_opt", "nu", "ni",
                                     "shard_u", "shard_i",
                                     "gather_bf16"))
 def _train_fused(U: jax.Array, V: jax.Array, lay_u, lay_i, reg, alpha,
                  iters, *, implicit: bool, scale_reg: bool, bf16: bool,
-                 gram: str, kind_u: str, kind_i: str, block_u: int,
+                 kind_u: str, kind_i: str, block_u: int,
                  block_i: int, block_rows_opt, nu: int, ni: int,
                  shard_u, shard_i,
                  gather_bf16: bool = False) -> Tuple[jax.Array, jax.Array]:
@@ -607,10 +461,9 @@ def _train_fused(U: jax.Array, V: jax.Array, lay_u, lay_i, reg, alpha,
                 out0 = jax.lax.with_sharding_constraint(out0, shard)
             return _bucket_half_impl(fixed, out0, lay, reg, alpha,
                                      implicit, scale_reg, bf16,
-                                     block_rows_opt, gram, gather_bf16,
-                                     mesh)
+                                     block_rows_opt, gather_bf16, mesh)
         out = _pad_half_impl(fixed, lay, block, reg, alpha, implicit,
-                             scale_reg, bf16, gram, gather_bf16, mesh)
+                             scale_reg, bf16, gather_bf16, mesh)
         if shard is not None:
             out = jax.lax.with_sharding_constraint(out, shard)
         return out
@@ -639,7 +492,6 @@ def _update_side(fixed: jax.Array, indices: jax.Array, values: jax.Array,
         block_rows, params.reg, params.alpha, params.implicit_prefs,
         params.scale_reg_by_count,
         bf16=(params.matmul_dtype == "bfloat16"),
-        gram=params.gram_mode,
         gather_bf16=(params.gather_dtype == "bfloat16"),
         mesh=mesh)
 
@@ -1229,7 +1081,7 @@ def _pack_side_bucket_multihost(read_row_mask, counts: np.ndarray,
 
 def _layout_hist_lens(lay: dict) -> Tuple[int, ...]:
     """Padded history lengths (the L axis) of one side's blocked device
-    layout — every shape the gram realization is about to run at."""
+    layout."""
     if lay.get("mode") == "bucket":
         return tuple(int(b["idx"].shape[-1]) for b in lay["buckets"])
     return (int(lay["idx"].shape[-1]),)
@@ -1238,11 +1090,9 @@ def _layout_hist_lens(lay: dict) -> Tuple[int, ...]:
 def training_report(params: ALSParams, packed: "PackedRatings",
                     mesh: Optional[Mesh] = None) -> dict:
     """What a :func:`train_als` run over ``packed`` resolves to on the
-    attached backend: the gram realization ``auto`` picked, the SPD
-    solver variant, the layouts, and the compiler's message for every
-    kernel that was skipped. One JSON-able dict — the train log line
-    (``ptpu train`` prints it as ``Train kernels:``)."""
-    from ..ops import fused_gram
+    attached backend: the SPD solver variant and the layouts. One
+    JSON-able dict — the train log line (``ptpu train`` prints it as
+    ``Train kernels:``)."""
     from ..ops.solve import solver_variant
 
     n_dev = 1 if mesh is None else mesh.devices.size
@@ -1252,14 +1102,11 @@ def training_report(params: ALSParams, packed: "PackedRatings",
                    for L in _layout_hist_lens(lay)})
     return {
         "rank": params.rank,
-        "gram": {"requested": params.gram_mode,
-                 "resolved": resolved_gram_mode(params, lens)},
         "solver": solver_variant(params.rank),
         "gatherDtype": params.gather_dtype,
         "layout": {side: lay.get("mode", "pad")
                    for side, lay in lays.items()},
         "historyLens": lens,
-        "refused": fused_gram.refusals(),
     }
 
 
@@ -1418,11 +1265,6 @@ def train_als(ratings: RatingsCOO, params: ALSParams,
         return "split"
 
     kind_u, kind_i = _kind(user_h), _kind(item_h)
-    # "auto" becomes ONE concrete realization for the whole run, chosen
-    # against every padded history length the layouts hold — the
-    # compiled programs below never see "auto"
-    params = dataclasses.replace(params, gram_mode=resolved_gram_mode(
-        params, _layout_hist_lens(uh) + _layout_hist_lens(ih)))
     if ckpt is None and "split" not in (kind_u, kind_i) \
             and start < params.num_iterations:
         # checkpoint-free runs compile the WHOLE training loop into one
@@ -1444,7 +1286,7 @@ def train_als(ratings: RatingsCOO, params: ALSParams,
             implicit=params.implicit_prefs,
             scale_reg=params.scale_reg_by_count,
             bf16=(params.matmul_dtype == "bfloat16"),
-            gram=params.gram_mode, kind_u=kind_u, kind_i=kind_i,
+            kind_u=kind_u, kind_i=kind_i,
             block_u=block_u, block_i=block_i,
             block_rows_opt=params.block_rows,
             nu=u_rows_pad, ni=i_rows_pad,
@@ -1545,8 +1387,7 @@ class QuantizedFactors:
     pytree (so device placement, sharding and ``nbytes`` accounting
     reach the leaves); ``quant`` is static metadata. Serving paths
     dequantize after the wire — upcast + scale inside the compiled
-    program (or the fused kernel's VMEM), never as a materialized f32
-    copy of the table."""
+    program, never as a materialized f32 copy of the table."""
 
     data: jax.Array = field(metadata=dict(static=False))
     scale: Optional[jax.Array] = field(default=None,
@@ -2580,11 +2421,10 @@ def fixed_gramian(fixed, params: "ALSParams"):
     # sharding preserved): fold-in math stays f32 against the same
     # values serving scores with
     arr = jnp.asarray(dequantize_table(fixed))
-    bf16 = params.matmul_dtype == "bfloat16"
     if _is_row_sharded(arr):
         with _mesh_dispatch_lock:  # the reduction launches collectives
-            return _fixed_gramian(arr, None, params.gram_mode, bf16)
-    return _fixed_gramian(arr, None, params.gram_mode, bf16)
+            return _fixed_gramian(arr)
+    return _fixed_gramian(arr)
 
 
 def _pow2_ceil(n: int, lo: int = 1) -> int:
@@ -2600,7 +2440,7 @@ def fold_in_rows(fixed, indices: np.ndarray, values: np.ndarray,
     """Batched per-row fold-in: solve ``[B]`` rows' normal equations
     against the fixed opposite factor table — the streaming increment's
     device path. Routes through :func:`_update_block` (and therefore
-    :func:`_lhs_fn`), so it shares the fused gather+Gramian kernel, the
+    :func:`_lhs_fn`), so it shares the normal-equation build, the
     bf16 gather shadow and the implicit/explicit weighting with the
     batch trainer — the two solvers can never drift apart.
 
@@ -2638,7 +2478,7 @@ def fold_in_rows(fixed, indices: np.ndarray, values: np.ndarray,
     def _solve():
         nonlocal G
         if implicit and G is None:
-            G = _fixed_gramian(table, None, params.gram_mode, bf16)
+            G = _fixed_gramian(table)
         if not implicit:
             # static-arg shape filler, exactly like _update_side_split
             G = jnp.zeros((table.shape[-1],) * 2, jnp.float32)
@@ -2650,8 +2490,7 @@ def fold_in_rows(fixed, indices: np.ndarray, values: np.ndarray,
         new = _numerics.checked_call(
             "fold_in_rows", _update_block, gsrc, G, idx, val, cnt,
             params.reg, params.alpha, implicit,
-            params.scale_reg_by_count, bf16=bf16,
-            gram=params.gram_mode, mesh=None)
+            params.scale_reg_by_count, bf16=bf16, mesh=None)
         return np.asarray(jax.device_get(new[0][:B]), dtype=np.float32)
 
     if _is_row_sharded(table):

@@ -33,17 +33,14 @@ def gramian_allreduce(x: jax.Array, mesh: Mesh) -> jax.Array:
     """``xᵀx`` of a row-sharded ``[n, r]`` table as an EXPLICIT
     per-shard partial + ICI psum, replicated out.
 
-    The fused-gram training path (``models/als.py::_fixed_gramian``)
-    uses this instead of the plain einsum so the all-reduce is a
-    structurally independent node: every update block's Pallas kernel
-    builds its observed-entry system without touching G (the baseline
-    Gramian is added to the kernel OUTPUT), which frees XLA's
-    latency-hiding scheduler to run this collective on ICI underneath
-    the next virtual-row block's gather DMAs and kernel launch rather
-    than serializing each half-iteration behind it — the compute/
-    collective overlap ALX builds its sharded trainer around
-    (arXiv 2112.02194). Axis names come from the mesh, so the same
-    program runs over a ``(data, model)`` training mesh and a
+    The all-reduce is a structurally independent node, which frees
+    XLA's latency-hiding scheduler to run it on ICI underneath other
+    work — the compute/collective overlap ALX builds its sharded
+    trainer around (arXiv 2112.02194). No production path calls it
+    (ALS training takes the plain einsum, whose collective GSPMD
+    derives); it is the example entry of ``analysis/hlo_audit.py`` and
+    ``analysis/numerics_audit.py``. Axis names come from the mesh, so
+    the same program runs over a ``(data, model)`` training mesh and a
     ``(batch, model)`` serving mesh."""
     axes = tuple(mesh.axis_names)
 

@@ -652,9 +652,7 @@ class QueryServer:
         #: per generation ({"artifact": bool, "seconds": {...}, ...})
         self._warm_report: dict = {}
         # the initial _bind ran before this registry existed; record
-        # the resolved gram + serving-kernel modes now (rebinds
-        # re-record inside _bind)
-        self._record_gram_mode()
+        # the serving-kernel mode now (rebinds re-record inside _bind)
         self._record_serving_kernel()
         self._record_sharding_findings()
         for algo in self.algorithms:
@@ -1007,12 +1005,6 @@ class QueryServer:
             self.models = [a.prepare_serving_model(m, bind_batch)
                            for a, m in zip(self.algorithms, models)]
             self.serving = self.engine.make_serving(engine_params)
-            # ptpu: allow[blocking-under-lock] — bind-time only
-            # (deploy/reload/promote, never a query): the gram-mode
-            # resolution may one-shot-probe the fused kernel's
-            # lowering, and the result must be recorded inside the
-            # same swap that installs the binding it describes
-            self._record_gram_mode()
             self._record_serving_kernel()
             # mesh-wide placement (ISSUE 6): resolve the serving mode
             # against the live devices and the model's resident bytes,
@@ -1028,48 +1020,13 @@ class QueryServer:
 
     # ptpu: guarded-by[_lock] — only ever called from _bind under the
     # binding lock (the gauge family itself is thread-safe)
-    def _record_gram_mode(self) -> None:
-        """Refresh the ``pio_gram_mode`` info gauge (ISSUE 7) from the
-        bound algorithms' ALS params: the weighted-gram realization
-        they resolve to on THIS backend (autotune table + Pallas
-        lowering support, ``models/als.resolved_gram_mode``) reads 1;
-        a label a rebind left behind drops to 0 — a retrain/deploy
-        that silently fell off the fused kernel is visible on
-        /metrics, not just in bench lines. The very first _bind runs
-        before __init__ creates the registry — __init__ re-records
-        right after; rebinds find it in place."""
-        if getattr(self, "metrics", None) is None:
-            return  # constructor's initial _bind; __init__ re-records
-        try:
-            from ..models.als import resolved_gram_mode
-
-            mode = None
-            for algo in self.algorithms:
-                p = getattr(algo, "params", None)
-                if p is not None and hasattr(p, "gram_mode"):
-                    mode = resolved_gram_mode(p)
-                    break
-            if mode is None:
-                return
-            fam = self.metrics.gauge(
-                "pio_gram_mode",
-                "Resolved ALS gram realization of the bound engine "
-                "params (info gauge: 1 at the active mode label)")
-            self._gram_mode_gauge = fam
-            for _, child in fam.children():
-                child.set(0.0)
-            fam.labels(mode=mode).set(1.0)
-        except Exception:  # noqa: BLE001 — telemetry must not block a
-            pass           # deploy/reload/promote
-
-    # ptpu: guarded-by[_lock] — only ever called from _bind under the
-    # binding lock (the gauge family itself is thread-safe)
     def _record_serving_kernel(self) -> None:
         """Note the quantization the bound ALS tables ended up with
         (the parity probe may have refused the configured one) and
         refresh the ``pio_serving_kernel`` info gauge (ISSUE 13) from
         it: that label reads 1, a label from a prior bind drops to 0.
-        Sits next to ``pio_gram_mode``."""
+        The very first _bind runs before __init__ creates the registry
+        — __init__ re-records right after; rebinds find it in place."""
         from ..models.als import ALSModel, serving_quant_of
 
         self._serving_quant = next(
@@ -1094,7 +1051,7 @@ class QueryServer:
         accepted-and-justified sharding debt the static pass would
         otherwise flag. A deploy that ships new suppressed sharding
         findings moves this gauge, so the debt is visible on /metrics
-        next to ``pio_gram_mode``/``pio_serving_kernel``, not only in
+        next to ``pio_serving_kernel``, not only in
         code review. Source-text census (no jax, no AST), run once at
         server construction — the installed sources don't change under
         a live process."""

@@ -7,7 +7,7 @@ bus-woken, catch-up-correct), folds micro-batches of fresh events into
 the deployed ALS model via per-entity regularized least-squares solves
 against the fixed opposite factors
 (:func:`~predictionio_tpu.models.als.fold_in_rows` — the same
-``_lhs_fn``/fused-Gramian device path the batch trainer uses), canaries
+``_lhs_fn`` device path the batch trainer uses), canaries
 every delta with a :class:`~predictionio_tpu.rollout.HealthPolicy`
 probe, and hot-swaps updated rows into the live serving binding. A
 :class:`DriftMonitor` demotes full retrains to a drift-triggered

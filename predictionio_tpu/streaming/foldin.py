@@ -15,8 +15,8 @@ Turns a batch of freshly consumed events into a NEW serving model:
    (:func:`~predictionio_tpu.models.als.dedupe_pairs`) so bursts don't
    multiply implicit confidence;
 5. solve through :func:`~predictionio_tpu.models.als.fold_in_rows` —
-   the jitted device path sharing ``_lhs_fn``/the fused-Gramian
-   machinery with the batch trainer — and assemble the updated model
+   the jitted device path sharing ``_lhs_fn`` with the batch
+   trainer — and assemble the updated model
    functionally (the old binding keeps serving until the swap).
 
 New items solve first (against known users), then user rows solve

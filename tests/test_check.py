@@ -2017,7 +2017,7 @@ class TestMissingInterpretFallback:
             code, path="ops/k.py",
             rule_names=["missing-interpret-fallback"])
         assert rules_of(findings) == ["missing-interpret-fallback"]
-        assert "fused_gram_dispatch" in findings[0].message
+        assert "support-gated dispatcher" in findings[0].message
 
     def test_interpret_param_clean(self):
         code = ksrc("""

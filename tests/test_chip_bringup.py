@@ -1,7 +1,6 @@
 """CPU-side checks of the chip bring-up rules (PR 21): where the compile
-cache lives, that nothing stands in for a requested kernel on a TPU
-backend, that a failed warm-up fails the deploy, that ``chip_smoke.py``
-has no CPU mode, and that the autotuner keeps no state outside git."""
+cache lives, that a failed warm-up fails the deploy, and that
+``chip_smoke.py`` has no CPU mode."""
 
 import json
 import os
@@ -17,13 +16,7 @@ import pytest
 from predictionio_tpu.controller.context import Context
 from predictionio_tpu.data.storage.base import App
 from predictionio_tpu.data.storage.registry import Storage
-from predictionio_tpu.models.als import (
-    ALSModel,
-    ALSParams,
-    resolved_gram_mode,
-)
-from predictionio_tpu.ops import _probe, fused_gram
-from predictionio_tpu.ops import gram_autotune as ga
+from predictionio_tpu.models.als import ALSModel, ALSParams
 from predictionio_tpu.utils import platform
 
 REPO = Path(__file__).resolve().parent.parent
@@ -112,18 +105,7 @@ class TestCompileCachePlacement:
         assert updates == {}
 
 
-# -- no stand-in for a kernel ------------------------------------------------
-
-@pytest.fixture()
-def fake_tpu_attach(monkeypatch):
-    """The kernel modules believe a TPU is attached; their compile
-    probes then really run the TPU lowering, which this CPU process
-    refuses — a stand-in for a kernel the chip's compiler refuses."""
-    monkeypatch.setattr(_probe, "tpu_attached", lambda: True)
-    fused_gram.reset_support_cache_for_tests()
-    yield
-    fused_gram.reset_support_cache_for_tests()
-
+# -- a failed warm-up fails the deploy --------------------------------------
 
 def _server(cfg):
     from predictionio_tpu.data.storage.base import (
@@ -157,36 +139,6 @@ def _server(cfg):
     return QueryServer(ctx, recommendation_engine(),
                        default_engine_params("bringup", rank=8), [model],
                        inst, cfg)
-
-
-class TestNoStandInForAKernel:
-    def test_explicit_gram_request_raises_and_gauge_stays_off_fused(
-            self, fake_tpu_attach):
-        from predictionio_tpu.server.engineserver import ServerConfig
-
-        with pytest.raises(RuntimeError, match="gram_mode='fused' does "
-                                               "not compile"):
-            resolved_gram_mode(ALSParams(rank=8, gram_mode="fused"))
-        server = _server(ServerConfig(warm_start=False))
-        server.algorithms[0].params = ALSParams(rank=8, gram_mode="fused")
-        server._record_gram_mode()
-        assert 'pio_gram_mode{mode="fused"} 1' \
-            not in server.metrics.render()
-
-    def test_auto_gram_skips_a_named_kernel_and_keeps_the_message(
-            self, fake_tpu_attach, monkeypatch, tmp_path):
-        table = tmp_path / "tune.json"
-        table.write_text(json.dumps(
-            {"cpu|r32|f32": {"mode": "fused", "source": "test"}}))
-        monkeypatch.setenv("PIO_GRAM_AUTOTUNE_CACHE", str(table))
-        ga.reset_for_tests()
-        try:
-            assert resolved_gram_mode(ALSParams(rank=8), (32, 64)) \
-                == "einsum"
-            # the first refusal decides; its message is kept by shape
-            assert set(fused_gram.refusals()) == {"r8/float32/L32"}
-        finally:
-            ga.reset_for_tests()
 
 
 class TestFailedWarmupFailsTheDeploy:
@@ -240,19 +192,3 @@ def test_chip_smoke_result_line_has_the_contract_keys_and_no_others():
     assert "\n" not in line
     assert json.loads(line) == {"ok": True, "device": {
         "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
-
-
-# -- nothing from outside git ------------------------------------------------
-
-def test_autotune_touches_nothing_under_home(monkeypatch, tmp_path):
-    monkeypatch.delenv("PIO_GRAM_AUTOTUNE_CACHE", raising=False)
-    monkeypatch.setenv("HOME", str(tmp_path))
-    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / ".cache"))
-    ga.reset_for_tests()
-    try:
-        assert ga.best_mode(64, device_kind="TPU v5 lite0") == "einsum"
-        assert not ga.record(64, "pair", device_kind="TPU v5 lite0",
-                             measured={"source": "bench_race"})
-        assert list(tmp_path.iterdir()) == []
-    finally:
-        ga.reset_for_tests()

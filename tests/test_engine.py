@@ -123,6 +123,13 @@ def make_engine():
     )
 
 
+def _recommendation_engine():
+    from predictionio_tpu.templates.recommendation import (
+        recommendation_engine,
+    )
+    return recommendation_engine()
+
+
 def ep(ds=0, prep=0, algos=(("a1", 0),)):
     return EngineParams(
         datasource=("", DSParams(id=ds)),
@@ -244,10 +251,19 @@ class TestVariantParsing:
         assert parsed.algorithms == (("a1", AParams(id=9)),
                                      ("a2", AParams(id=1)))
 
-    def test_unknown_param_rejected(self):
-        variant = {"datasource": {"params": {"nope": 1}}}
-        with pytest.raises(ValueError, match="unknown field"):
-            make_engine().params_from_variant(variant)
+    @pytest.mark.parametrize("engine, variant, named", [
+        (make_engine, {"datasource": {"params": {"nope": 1}}}, "nope"),
+        # a removed ALSParams option (PR 47) is an unknown key like any
+        # other: refused by name, never accepted and ignored
+        (_recommendation_engine,
+         {"algorithms": [{"name": "als",
+                          "params": {"rank": 8, "gramMode": "einsum"}}]},
+         "gramMode"),
+    ], ids=["datasource", "als-gramMode"])
+    def test_unknown_param_rejected(self, engine, variant, named):
+        with pytest.raises(ValueError,
+                           match=f"unknown field.*{named}"):
+            engine().params_from_variant(variant)
 
     def test_simple_engine(self):
         se = SimpleEngine(datasource_class=DS, algorithm_class=Algo)

@@ -262,29 +262,7 @@ class TestShardingFindingsGauge:
         assert doc["shardingFindings"] == sf
 
 
-class TestGramModeGauge:
-    def test_bind_records_resolved_gram_mode(self, trained_ctx):
-        """The pio_gram_mode info gauge (ISSUE 7): binding an ALS
-        engine sets 1 on the resolved realization's label; a rebind
-        zeroes the stale label."""
-        from predictionio_tpu.models.als import resolved_gram_mode
-        from predictionio_tpu.server.engineserver import QueryServer
-        from predictionio_tpu.workflow import (
-            get_latest_completed,
-            load_models_for_deploy,
-        )
-
-        ctx, engine, ep = trained_ctx
-        inst = get_latest_completed(ctx, engine_id="srv")
-        models = load_models_for_deploy(ctx, engine, inst, ep)
-        server = QueryServer(ctx, engine, ep, models, inst)
-        expect = resolved_gram_mode(server.algorithms[0].params)
-        children = dict(server._gram_mode_gauge.children())
-        active = {labels: child.value
-                  for labels, child in children.items()}
-        assert active[(("mode", expect),)] == 1.0
-        assert f'pio_gram_mode{{mode="{expect}"}} 1' \
-            in server.metrics.render()
+class TestServeErrorIsolation:
     def test_serve_error_isolated_in_mixed_batch(self, trained_ctx):
         """A serve-time exception for one query must not poison its
         batch-mates (one genuinely mixed batch through the staged

@@ -118,73 +118,3 @@ def test_gramian():
     F = np.arange(12, dtype=np.float32).reshape(4, 3)
     np.testing.assert_allclose(np.asarray(gramian(jnp.asarray(F))),
                                F.T @ F, rtol=1e-6)
-
-
-class TestGramVariants:
-    """ops/gram.py: the pair-packed MXU gram must equal the baseline."""
-
-    def test_pair_matches_einsum(self):
-        import jax.numpy as jnp
-
-        from predictionio_tpu.ops.gram import gram_pairs, gram_weighted
-        rng = np.random.default_rng(0)
-        F = jnp.asarray(rng.standard_normal((2, 6, 17, 8)), jnp.float32)
-        w = jnp.asarray(rng.random((2, 6, 17)), jnp.float32)
-        np.testing.assert_allclose(np.asarray(gram_pairs(F, w)),
-                                   np.asarray(gram_weighted(F, w)),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_pair_bf16_close(self):
-        import jax.numpy as jnp
-
-        from predictionio_tpu.ops.gram import gram_pairs, gram_weighted
-        rng = np.random.default_rng(1)
-        F = jnp.asarray(rng.standard_normal((1, 4, 9, 16)), jnp.float32)
-        w = jnp.asarray(rng.random((1, 4, 9)), jnp.float32)
-        np.testing.assert_allclose(
-            np.asarray(gram_pairs(F, w, bf16=True)),
-            np.asarray(gram_weighted(F, w)), rtol=3e-2, atol=3e-2)
-
-    def test_train_als_pair_mode_matches(self):
-        from predictionio_tpu.models.als import (
-            ALSParams, RatingsCOO, train_als)
-        rng = np.random.default_rng(3)
-        coo = RatingsCOO(rng.integers(0, 30, 600).astype(np.int32),
-                         rng.integers(0, 20, 600).astype(np.int32),
-                         rng.random(600).astype(np.float32) * 4 + 1,
-                         30, 20)
-        base = ALSParams(rank=8, num_iterations=3, seed=5,
-                         implicit_prefs=True, alpha=20.0)
-        import dataclasses
-        pair = dataclasses.replace(base, gram_mode="pair")
-        U1, V1 = train_als(coo, base)
-        U2, V2 = train_als(coo, pair)
-        # the pair layout reassociates the f32 contraction; per-iteration
-        # divergence is ~5e-5 rel and compounds through the solves
-        np.testing.assert_allclose(np.asarray(U1), np.asarray(U2),
-                                   rtol=5e-2, atol=2e-3)
-        np.testing.assert_allclose(np.asarray(V1), np.asarray(V2),
-                                   rtol=5e-2, atol=2e-3)
-
-    def test_gram_table_pallas_interpret(self):
-        """Fused VMEM-table gather+gram kernel vs the einsum reference
-        (interpret mode — Mosaic lowering is probed at runtime on TPU)."""
-        import jax.numpy as jnp
-
-        from predictionio_tpu.ops.gram import gram_table_pallas
-        rng = np.random.default_rng(4)
-        m, r, B, L = 200, 16, 21, 24
-        tab = rng.standard_normal((m, r)).astype(np.float32)
-        idx = rng.integers(0, m, (B, L)).astype(np.int32)
-        wa = rng.random((B, L)).astype(np.float32)
-        wb = rng.random((B, L)).astype(np.float32)
-        A, b = gram_table_pallas(jnp.asarray(tab), jnp.asarray(idx),
-                                 jnp.asarray(wa), jnp.asarray(wb),
-                                 interpret=True)
-        F = tab[idx]
-        np.testing.assert_allclose(
-            np.asarray(A), np.einsum("blr,bls,bl->brs", F, F, wa),
-            rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(
-            np.asarray(b), np.einsum("blr,bl->br", F, wb),
-            rtol=1e-4, atol=1e-4)
