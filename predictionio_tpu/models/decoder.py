@@ -1,8 +1,13 @@
 """A config-driven decoder block stack with generation, driven by the
-keys of a published ``config.json``. Four families' names are read,
-into ONE stack: ``lfm2_moe`` (short-convolution and grouped-query
-attention layers side by side, dense and sparse-expert feed-forwards, a
-tied head), ``granitemoehybrid`` (Mamba-2 state-space layers beside
+keys of a published ``config.json``. Five families' names are read,
+into ONE stack: ``nemotron_h`` (layers of ONE sub-block each, by the
+letters of ``hybrid_override_pattern``: a Mamba-2 mixer over several
+groups, grouped-query attention without rotary, or sparse experts with
+a squared ReLU and no gate matrix that work in a LATENT narrower than
+the stream, beside a shared expert; an untied head), ``lfm2_moe``
+(short-convolution and grouped-query attention layers side by side,
+dense and sparse-expert feed-forwards, a tied head),
+``granitemoehybrid`` (Mamba-2 state-space layers beside
 grouped-query layers without rotary, dense feed-forwards, four scalar
 multipliers, a tied head), ``laguna`` (full and sliding-window attention layers side by
 side with their own head counts and rotary, a sigmoid gate a head, a
@@ -20,7 +25,10 @@ passes in one program (the first token is the prefill's). The host
 sees one dispatch of two programs and syncs once, on the answer.
 
 Layer ``l``: ``h = x + op_l(n(x))``, ``y = h + ff_l(n(h))`` with RMSNorm
-``n``; ``op_l`` by ``layer_types[l]``, one of five kinds (``conv``,
+``n`` (a layer of a ``hybrid_override_pattern`` is ONE of the two, with
+the one norm and the one sub-block's weights it has: ``none`` stands in
+``layer_types`` or ``mlp_layer_types`` for the sub-block a layer lacks);
+``op_l`` by ``layer_types[l]``, one of five kinds (``conv``,
 ``mamba``, ``full_attention`` (``attention`` in one family's words),
 ``sliding_attention``, ``latent_attention``: every
 layer of a family that gives ``kv_lora_rank`` and no ``layer_types``),
@@ -85,9 +93,9 @@ tokens it has in hand, a decode step attends over the latents
 themselves with the up-projections absorbed into the query and the
 output, :func:`_latent_step`); and a RECURRENT state (``mamba`` layers:
 each row's ``S [N, heads x head_dim]`` float32 after its last token
-beside its last ``conv_L_cache - 1`` raw ``xBC``: 2 MiB a row and layer
-at the published sizes WHATEVER the history, rewritten whole by every
-decode step, :func:`_mamba_step`).
+beside its last ``conv_L_cache - 1`` raw ``xBC``: 2 or 4 MiB a row and
+layer at the published sizes WHATEVER the history, rewritten whole by
+every decode step, :func:`_mamba_step`).
 
 Precision. Weights in ``cfg.dtype`` (bfloat16 as served). Every matrix
 product takes operands in that dtype and accumulates in float32
@@ -125,12 +133,28 @@ from ..ops.window_attention import BLOCK as ATTENTION_BLOCK, window_attention
 
 CONV, ATTENTION, SLIDING = "conv", "full_attention", "sliding_attention"
 LATENT, MAMBA = "latent_attention", "mamba"
+#: in ``layer_types`` or ``mlp_layer_types``: the layer has no such
+#: sub-block (and no norm or weight of one)
+NONE = "none"
+#: a ``hybrid_override_pattern``'s letters: the ONE sub-block of a layer.
+#: ``-`` (the family's dense feed-forward layer) is not written
+PATTERN = {"M": (MAMBA, NONE), "*": (ATTENTION, NONE),
+           "E": (NONE, "sparse")}
 #: published names of one family that mean a field named by the other
 ALIASES = {"rms_norm_eps": "norm_eps",
            "moe_routed_scaling_factor": "routed_scaling_factor",
            "n_routed_experts": "num_experts",
            "first_k_dense_replace": "num_dense_layers",
-           "mamba_d_conv": "conv_L_cache", "mamba_conv_bias": "conv_bias"}
+           "mamba_d_conv": "conv_L_cache", "mamba_conv_bias": "conv_bias",
+           # nemotron_h's names
+           "layer_norm_epsilon": "norm_eps",
+           "mamba_num_heads": "mamba_n_heads",
+           "mamba_head_dim": "mamba_d_head",
+           "ssm_state_size": "mamba_d_state", "n_groups": "mamba_n_groups",
+           "conv_kernel": "conv_L_cache", "use_conv_bias": "conv_bias",
+           "chunk_size": "mamba_chunk_size", "expand": "mamba_expand",
+           "moe_shared_expert_intermediate_size":
+               "shared_expert_intermediate_size"}
 #: a layer kind by another family's name for it
 KINDS = {"attention": ATTENTION}
 #: keys that switch on mathematics nobody has written here: they are
@@ -143,7 +167,8 @@ UNWRITTEN = {"attention_bias": False,
              "n_group": 1, "topk_group": 1, "ep_size": 1,
              "moe_layer_freq": 1, "topk_method": "noaux_tc",
              "scoring_func": "sigmoid", "num_local_experts": 0,
-             "mamba_proj_bias": False, "mamba_n_groups": 1}
+             "mamba_proj_bias": False, "mlp_bias": False, "use_bias": False,
+             "mamba_hidden_act": "silu"}
 
 
 def _freeze(v):
@@ -181,6 +206,19 @@ class DecoderConfig:
     ``embedding_multiplier``, ``residual_multiplier`` (on both
     sub-blocks' outputs), ``attention_multiplier`` (the softmax scale, in
     place of ``head_dim ** -0.5``) and ``logits_scaling`` (a divisor).
+    ``nemotron_h`` gives ``hybrid_override_pattern`` (a letter a layer,
+    :data:`PATTERN`; its attention takes no rotary and no per-head norm:
+    ``rope_theta`` is read by nothing), the Mamba-2 sizes by its own
+    names (``mamba_num_heads``, ``mamba_head_dim``, ``ssm_state_size``,
+    ``n_groups``, ``conv_kernel``, ``use_conv_bias``, ``chunk_size``),
+    ``mlp_hidden_act`` ``relu2`` (a feed-forward ``relu(z W_1)^2 W_2``,
+    two matrices and no gate), ``moe_latent_size`` (the routed experts'
+    rows are ``z W_down``, one down- and one up-projection a layer
+    around all of them) and ``moe_shared_expert_intermediate_size``.
+    ``router_experts``: the router's outputs where the key that counts
+    the experts gives this chip's SHARE (a benchmark configuration cut
+    as the model-configs guide says: ``n_routed_experts`` 128 held of
+    ``router_experts`` 512, ``experts_held`` naming which).
     ``UNWRITTEN`` lists the keys that raise at any value but the one
     that switches them off."""
 
@@ -238,6 +276,13 @@ class DecoderConfig:
     mamba_chunk_size: int = 256
     mamba_n_groups: int = 1
     mamba_proj_bias: bool = False
+    mamba_hidden_act: str = "silu"
+    hybrid_override_pattern: Optional[str] = None
+    mlp_hidden_act: str = "silu"
+    mlp_bias: bool = False
+    use_bias: bool = False
+    moe_latent_size: int = 0
+    router_experts: Optional[int] = None
     num_local_experts: int = 0
     shared_intermediate_size: Optional[int] = None
     embedding_multiplier: float = 1.0
@@ -253,6 +298,27 @@ class DecoderConfig:
         n = self.num_hidden_layers
         latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
                   self.qk_rope_head_dim, self.v_head_dim)
+        if self.hybrid_override_pattern is not None:
+            if self.layer_types or self.mlp_layer_types:
+                raise ValueError("hybrid_override_pattern beside "
+                                 "layer_types: the layers are given twice")
+            for letter in self.hybrid_override_pattern:
+                if letter not in PATTERN:
+                    raise ValueError(
+                        f"hybrid_override_pattern letter {letter!r}: not "
+                        f"written here (only {sorted(PATTERN)} are; '-' is "
+                        f"the family's dense feed-forward layer)")
+            kinds = [PATTERN[c] for c in self.hybrid_override_pattern]
+            put("layer_types", tuple(k for k, _ in kinds))
+            put("mlp_layer_types", tuple(k for _, k in kinds))
+            put("position_embedding_type", "nope")
+        if self.router_experts is not None:
+            if self.experts_held is None \
+                    or len(self.experts_held) != self.num_experts:
+                raise ValueError(
+                    "router_experts: the key that counts the experts then "
+                    "gives the share held, and experts_held names as many")
+            put("num_experts", int(self.router_experts))
         if self.layer_types is None:
             if self.kv_lora_rank is None:
                 raise ValueError("layer_types says which operator each "
@@ -297,12 +363,30 @@ class DecoderConfig:
             if len(getattr(self, name)) != n:
                 raise ValueError(f"{name} must name num_hidden_layers "
                                  f"layers")
-        if set(self.layer_types) - {CONV, ATTENTION, SLIDING, LATENT, MAMBA}:
+        if set(self.layer_types) - {CONV, ATTENTION, SLIDING, LATENT, MAMBA,
+                                    NONE}:
             raise ValueError(f"layer types {set(self.layer_types)}: only "
                              f"{CONV!r}, {ATTENTION!r}, {SLIDING!r}, "
                              f"{LATENT!r} and {MAMBA!r} are written")
-        if set(self.mlp_layer_types) - {"dense", "sparse"}:
+        if set(self.mlp_layer_types) - {"dense", "sparse", NONE}:
             raise ValueError(f"mlp layer types {set(self.mlp_layer_types)}")
+        if (NONE, NONE) in zip(self.layer_types, self.mlp_layer_types):
+            raise ValueError("a layer has one sub-block at least")
+        if NONE in self.layer_types + self.mlp_layer_types \
+                and self.hc_mult > 1:
+            raise ValueError("layers of one sub-block under "
+                             "hyper-connections: not written here")
+        if self.mlp_hidden_act not in ("silu", "relu2"):
+            raise ValueError(f"mlp_hidden_act {self.mlp_hidden_act!r}: only "
+                             f"'silu' (gated) and 'relu2' are written")
+        if self.mlp_hidden_act == "relu2" and "dense" in self.mlp_layer_types:
+            raise ValueError("mlp_hidden_act 'relu2' in a dense "
+                             "feed-forward: not written here (only in the "
+                             "experts and the shared expert)")
+        if self.moe_latent_size and self.mlp_hidden_act != "relu2":
+            raise ValueError("moe_latent_size around gated experts: not "
+                             "written here (only with mlp_hidden_act "
+                             "'relu2')")
         for key, off in UNWRITTEN.items():
             if getattr(self, key) != off:
                 raise ValueError(f"{key}={getattr(self, key)!r}: not "
@@ -317,6 +401,11 @@ class DecoderConfig:
                 raise ValueError(
                     "mamba layers need mamba_d_state and mamba_n_heads x "
                     "mamba_d_head = mamba_expand x hidden_size")
+            if self.mamba_n_groups < 1 \
+                    or self.mamba_n_heads % self.mamba_n_groups:
+                raise ValueError(
+                    f"mamba_n_groups {self.mamba_n_groups}: the "
+                    f"{self.mamba_n_heads} mamba heads come in equal groups")
         if self.position_embedding_type not in ("rope", "nope"):
             raise ValueError(f"position_embedding_type "
                              f"{self.position_embedding_type!r}: only 'rope' "
@@ -334,7 +423,7 @@ class DecoderConfig:
                for h in self.num_attention_heads_per_layer):
             raise ValueError("query heads must divide by key-value heads")
         if not self.nope:
-            for kind in set(self.layer_types) - {CONV, MAMBA}:
+            for kind in set(self.layer_types) - {CONV, MAMBA, NONE}:
                 self.rope(kind)  # raises on a rope_type nobody has written
 
     @classmethod
@@ -513,7 +602,9 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
     column)``: its columns from that one on)."""
     H, D = cfg.hidden_size, cfg.head_dim
     nq, nkv = cfg.num_attention_heads_per_layer[l], cfg.num_key_value_heads
-    out = {"op_norm": ((H,), 0, None), "ff_norm": ((H,), 0, None)}
+    out = {sub + "_norm": ((H,), 0, None)
+           for sub, kind in (("op", cfg.layer_types[l]),
+                             ("ff", cfg.mlp_layer_types[l])) if kind != NONE}
     n = cfg.hc_mult
     if n > 1:  # float32 all: the residual path's own coefficients
         for sub in ("op", "ff"):
@@ -531,9 +622,10 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
                    conv_w=((H, cfg.conv_L_cache), cfg.conv_L_cache, None))
     elif cfg.layer_types[l] == MAMBA:
         I, N, nh = _mamba_sizes(cfg)
-        C, K = I + 2 * N, cfg.conv_L_cache
-        out.update(w_in=((H, 2 * I + 2 * N + nh), H,   # z | xBC | dt
-                         ("dt_in", 2 * I + 2 * N)),
+        GN = cfg.mamba_n_groups * N  # a B and a C a group
+        C, K = I + 2 * GN, cfg.conv_L_cache
+        out.update(w_in=((H, 2 * I + 2 * GN + nh), H,   # z | xBC | dt
+                         ("dt_in", 2 * I + 2 * GN)),
                    conv_w=((C, K), K, "conv_taps"),
                    conv_b=((C,), 1, "conv_bias"),
                    A_log=((nh,), 1, None), dt_bias=((nh,), 1, None),
@@ -549,7 +641,7 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
                    kv_a_norm=((rkv,), 0, None),
                    w_kvb=((rkv, nq * (dn + dv)), rkv, None),
                    wo=((nq * dv, H), nq * dv, "op_out"))
-    else:
+    elif cfg.layer_types[l] != NONE:
         out.update(wq=((H, nq * D), H, None), wk=((H, nkv * D), H, None),
                    wv=((H, nkv * D), H, None),
                    wo=((nq * D, H), nq * D, "op_out"))
@@ -561,17 +653,23 @@ def _layer_shapes(cfg: DecoderConfig, l: int) -> dict:
         I = cfg.dense_width
         out.update(w1=((H, I), H, None), w3=((H, I), H, None),
                    w2=((I, H), I, "dense_out"))
-    else:
+    elif cfg.mlp_layer_types[l] == "sparse":
         E, F = cfg.n_held, cfg.moe_intermediate_size
+        L = cfg.moe_latent_size or H  # what an expert's rows are wide
+        gated = cfg.mlp_hidden_act != "relu2"  # a third matrix, the gate's
         out.update(gate=((H, cfg.num_experts), H, None),
-                   w1=((E, H, F), H, None), w3=((E, H, F), H, None),
-                   w2=((E, F, H), F, "expert_out"))
+                   w1=((E, L, F), L, None), w2=((E, F, L), F, "expert_out"))
+        if gated:
+            out["w3"] = ((E, L, F), L, None)
+        if cfg.moe_latent_size:  # once a layer, around all its experts
+            out.update(w_down=((H, L), H, None), w_up=((L, H), L, None))
         if cfg.use_expert_bias:
             out["gate_bias"] = ((cfg.num_experts,), 1, "gate_bias")
         S = cfg.shared_expert_intermediate_size
         if S:
-            out.update(s1=((H, S), H, None), s3=((H, S), H, None),
-                       s2=((S, H), S, "shared_out"))
+            out.update(s1=((H, S), H, None), s2=((S, H), S, "shared_out"))
+            if gated:
+                out["s3"] = ((H, S), H, None)
     return out
 
 
@@ -817,12 +915,20 @@ def _swiglu(z, w1, w3, w2):
     return _dot(jax.nn.silu(_dot(z, w1)) * _dot(z, w3), w2)
 
 
+def _relu2(z, w1, w2):
+    """A feed-forward of two matrices: ``relu(z W_1)^2 W_2``."""
+    return _dot(jnp.square(jax.nn.relu(_dot(z, w1))), w2)
+
+
 def _feed_forward(lw, z, valid, cfg):
     """Dense or expert feed-forward of ``z [T, H]``; ``(out, load)``
     with ``load [E]`` (``None`` for a dense layer). The shared expert,
     where the family has one, is a dense feed-forward that every token
     takes at weight 1: added once, here, whatever share of the routed
-    experts this chip holds."""
+    experts this chip holds. Experts in a latent (``w_down``, ``w_up``:
+    ``moe_latent_size``) take ``z W_down`` for their rows, and the sum
+    of what the held ones give goes through ``W_up`` ONCE; the router
+    and the shared expert read ``z`` itself."""
     if "gate" not in lw:
         return _token_blocks(
             lambda z: _swiglu(z, lw["w1"], lw["w3"], lw["w2"]),
@@ -831,12 +937,17 @@ def _feed_forward(lw, z, valid, cfg):
                          top_k=cfg.num_experts_per_tok,
                          norm_topk=cfg.norm_topk_prob,
                          scale=cfg.routed_scaling_factor)
+    rows = _dot(z, lw["w_down"]) if "w_down" in lw else z
     out = moe.expert_product(
-        z.astype(jnp.dtype(cfg.dtype)), sel, wts, lw["w1"], lw["w3"],
+        rows.astype(jnp.dtype(cfg.dtype)), sel, wts, lw["w1"], lw.get("w3"),
         lw["w2"], n_experts=cfg.num_experts, held=cfg.experts_held,
         valid=valid)
-    if "s1" in lw:
+    if "w_up" in lw:
+        out = _dot(out, lw["w_up"])
+    if "s3" in lw:
         out = out + _swiglu(z, lw["s1"], lw["s3"], lw["s2"])
+    elif "s1" in lw:
+        out = out + _relu2(z, lw["s1"], lw["s2"])
     return out, moe.expert_load(sel, cfg.num_experts, valid)
 
 
@@ -1019,20 +1130,27 @@ def _row_tail(v, last, pos, n: int):
 
 
 def _mamba_inputs(lw, z, cfg):
-    """``(gate [.., I], raw xBC [.., I + 2 N], dt [.., heads])`` float32
-    of ``z [.., H]``: the in-projection's three parts."""
+    """``(gate [.., I], raw xBC [.., I + 2 G N], dt [.., heads])``
+    float32 of ``z [.., H]``: the in-projection's three parts (``xBC``:
+    ``x``, then a ``B`` a group, then a ``C`` a group)."""
     I, N, _ = _mamba_sizes(cfg)
+    C = I + 2 * cfg.mamba_n_groups * N
     zxd = _dot(z, lw["w_in"])
-    return zxd[..., :I], zxd[..., I:2 * I + 2 * N], zxd[..., 2 * I + 2 * N:]
+    return zxd[..., :I], zxd[..., I:I + C], zxd[..., I + C:]
 
 
 def _mamba_out(lw, y, x, gate, cfg):
     """From the scan's ``y`` to the layer's output: the skip ``D x`` a
-    head, the gate ``silu(z)``, the RMSNorm over ALL the inner channels
-    (one group) and ``W_out``; float32 up to the product."""
+    head, the gate ``silu(z)``, the RMSNorm of each of the
+    ``mamba_n_groups`` groups of inner channels over ITS OWN mean square
+    (one group: over all of them) and ``W_out``; float32 up to the
+    product."""
     y = y + jnp.repeat(lw["D"], cfg.mamba_d_head) * x
-    return _dot(_rms(y * jax.nn.silu(gate), lw["ssm_norm"], cfg.norm_eps),
-                lw["w_out"])
+    g, G = y * jax.nn.silu(gate), cfg.mamba_n_groups
+    if G == 1:  # the lines (and the compiled program) one group always had
+        return _dot(_rms(g, lw["ssm_norm"], cfg.norm_eps), lw["w_out"])
+    by_group = _rms(g.reshape(g.shape[:-1] + (G, -1)), 1.0, cfg.norm_eps)
+    return _dot(by_group.reshape(g.shape) * lw["ssm_norm"], lw["w_out"])
 
 
 def _mamba_prefill(lw, z, valid, pos, rows, cfg):
@@ -1045,6 +1163,7 @@ def _mamba_prefill(lw, z, valid, pos, rows, cfg):
     each row's ``S`` after its last token, ``[B, N, heads x head_dim]``
     float32, beside its last ``conv_L_cache - 1`` raw ``xBC``."""
     I, N, _ = _mamba_sizes(cfg)
+    GN = cfg.mamba_n_groups * N
     dt_ = jnp.dtype(cfg.dtype)
     gate, raw, dt = _mamba_inputs(lw, z, cfg)
     y = lw["conv_b"] + _causal_taps(raw, lw["conv_w"], pos)
@@ -1052,9 +1171,9 @@ def _mamba_prefill(lw, z, valid, pos, rows, cfg):
     dt = jnp.where(valid[:, None], jax.nn.softplus(dt + lw["dt_bias"]), 0.0)
     x = xbc[:, :I]
     y, final = ssm_scan.ssm_scan(
-        x.astype(dt_), xbc[:, I:I + N].astype(dt_),
-        xbc[:, I + N:].astype(dt_), dt, -jnp.exp(lw["A_log"]), rows.row,
-        rows.last, chunk=cfg.mamba_chunk_size)
+        x.astype(dt_), xbc[:, I:I + GN].astype(dt_),
+        xbc[:, I + GN:].astype(dt_), dt, -jnp.exp(lw["A_log"]), rows.row,
+        rows.last, chunk=cfg.mamba_chunk_size, groups=cfg.mamba_n_groups)
     return _mamba_out(lw, y, x, gate, cfg), {
         "ssm": final,
         "win": _row_tail(raw, rows.last, pos, cfg.conv_L_cache - 1)}
@@ -1306,10 +1425,14 @@ def _gen_prefill(w: dict, tokens: jax.Array, lengths: jax.Array, *,
                 return _latent_prefill(lw, z, pos, rows, room, cfg)
             return _attention_prefill(lw, z, pos, rows, room, l, cfg)
 
-        h, st, gap_op = _sub_block(lw, "op", x, op, cfg, valid)
-        x, load, gap_ff = _sub_block(
-            lw, "ff", h, lambda z, lw=lw: _feed_forward(lw, z, valid, cfg),
-            cfg, valid)
+        st, load, gap_op, gap_ff = {}, None, None, None
+        if kind != NONE:
+            x, st, gap_op = _sub_block(lw, "op", x, op, cfg, valid)
+        if cfg.mlp_layer_types[l] != NONE:
+            x, load, gap_ff = _sub_block(
+                lw, "ff", x,
+                lambda z, lw=lw: _feed_forward(lw, z, valid, cfg), cfg,
+                valid)
         states.append(st)
         if load is not None:
             loads.append(load)
@@ -1341,6 +1464,7 @@ def _mamba_step(lw, z, st, cfg):
     (``ops/ssm_scan.py::ssm_step``: the state goes through once, in its
     own buffer), float32 throughout."""
     I, N, _ = _mamba_sizes(cfg)
+    GN = cfg.mamba_n_groups * N
     gate, raw, dt = _mamba_inputs(lw, z, cfg)
     win = st["win"]
     y = lw["conv_b"] + lw["conv_w"][:, -1] * raw + sum(
@@ -1349,7 +1473,7 @@ def _mamba_step(lw, z, st, cfg):
     dt = jax.nn.softplus(dt + lw["dt_bias"])
     x = xbc[:, :I]
     new, y = ssm_scan.ssm_step(
-        st["ssm"], x, xbc[:, I:I + N], xbc[:, I + N:],
+        st["ssm"], x, xbc[:, I:I + GN], xbc[:, I + GN:],
         jnp.exp(-dt * jnp.exp(lw["A_log"])), dt)
     return _mamba_out(lw, y, x, gate, cfg), {
         "ssm": new,
@@ -1428,9 +1552,12 @@ def _layer_step(lw, l, x, st, valid, pos, at, cfg):
             return _latent_step(lw, z, st, valid, pos, at, cfg)
         return _attention_step(lw, z, st, valid, pos, at, l, cfg)
 
-    h, st, _ = _sub_block(lw, "op", x, op, cfg)
-    x, load, _ = _sub_block(
-        lw, "ff", h, lambda z: _feed_forward(lw, z, None, cfg), cfg)
+    load = None
+    if cfg.layer_types[l] != NONE:
+        x, st, _ = _sub_block(lw, "op", x, op, cfg)
+    if cfg.mlp_layer_types[l] != NONE:
+        x, load, _ = _sub_block(
+            lw, "ff", x, lambda z: _feed_forward(lw, z, None, cfg), cfg)
     return x, st, load
 
 

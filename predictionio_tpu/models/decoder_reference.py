@@ -1,7 +1,7 @@
 """The plain reference of ``models/decoder.py``: the full forward pass of
 the block stack in straightforward ``jax.numpy``, float32, at
 ``highest`` matmul precision, one sequence at a time. No cache, no
-batching, no padding, no kernels; the experts one after the other. Four
+batching, no padding, no kernels; the experts one after the other. Five
 families' equations, told apart by the keys a configuration has.
 
 ``cfg`` is the published ``config.json`` as a dict (plus ``head_dim``
@@ -88,6 +88,23 @@ The equations (``H`` hidden size, ``n`` RMSNorm with ``norm_eps`` or
   x_t``; ``g = y * silu(z)``; ``out = (g / rms(g over all I) * gain)
   W_out``.
 
+- ``nemotron_h`` (a configuration with ``hybrid_override_pattern``):
+  layer ``l`` is ONE sub-block by the pattern's letter, ``x' = x +
+  F_l(n_l(x))`` with the one norm it has; logits through the untied
+  head. ``*``: grouped-query attention, no rotary, no per-head norm,
+  no bias, softmax at ``head_dim ** -0.5``. ``M``: the Mamba-2 mixer
+  above with the family's own key names and ``G = n_groups`` groups:
+  ``xBC = [x | B (G x N) | C (G x N)]``, head ``h`` reads ``B`` and ``C``
+  of group ``h // (heads / G)``, and the gated ``g`` is normalised in
+  ``G`` groups of ``I / G`` channels, each by ITS OWN rms, times the
+  gain ``[I]``. ``E``: ``s = sigmoid(z W_g)`` over all the router's
+  experts; the top ``k`` by ``s + b``, weighted by ``s`` without it over
+  their sum, times ``routed_scaling_factor``; ``u = z W_down``
+  (``moe_latent_size``); expert ``e``: ``relu(u W1_e)^2 W2_e`` (no gate
+  matrix); ``out = (sum_e w_e f_e(u)) W_up + relu(z S_1)^2 S_2``. Under a
+  share (``experts_held`` of ``router_experts``) the sum runs over the
+  selected experts held; the weights stay normalised over all ``k``.
+
 Departures from the published implementations, all listed in the
 benchmark configurations' ``assumed``. ``lfm2_moe``: ``head_dim = hidden
 / heads`` (the source gives null), the tied head, a conv kernel exactly
@@ -109,8 +126,12 @@ seeded columns); the routing's ``1e-6`` where the family writes
 not part of the forward pass. ``granitemoehybrid``: ``head_dim = hidden
 / heads`` (the source gives null), no clamp on ``dt`` (the family's
 default limits are 0 and infinity), the gated norm over all ``I``
-channels (one group). All: seeded weights in
-place of trained ones. ``cellbench/reference_lfm2.py`` and
+channels (one group). ``nemotron_h``: attention without rotary (the
+row's ``rope_theta`` is read by nothing), the routing's ``1e-6``, no
+clamp on ``dt``, the gate before the grouped norm, ``in_proj``'s column
+order ``[z | x | B | C | dt]``; multi-token prediction
+(``num_nextn_predict_layers``) is not part of the forward pass. All:
+seeded weights in place of trained ones. ``cellbench/reference_lfm2.py`` and
 ``cellbench/reference_laguna.py`` are the benchmark's copies;
 ``tests/test_decoder.py`` holds them to identical outputs.
 """
@@ -130,8 +151,15 @@ def _f(a):
 
 
 def _eps(cfg):
-    return float(cfg["rms_norm_eps"] if "rms_norm_eps" in cfg
-                 else cfg["norm_eps"])
+    return float(next(cfg[k] for k in ("rms_norm_eps", "norm_eps",
+                                       "layer_norm_epsilon") if k in cfg))
+
+
+def _letter(cfg, l):
+    """Layer ``l``'s letter of a ``hybrid_override_pattern`` (``None``:
+    a family whose layers have both sub-blocks)."""
+    pattern = cfg.get("hybrid_override_pattern")
+    return pattern[l] if pattern else None
 
 
 def _heads(cfg, l):
@@ -140,6 +168,8 @@ def _heads(cfg, l):
 
 
 def _is_dense(cfg, l):
+    if _letter(cfg, l):  # "E": sparse experts; no other letter has one
+        return False
     if "num_local_experts" in cfg:  # granitemoehybrid: 0 is all there is
         return int(cfg["num_local_experts"]) == 0
     kinds = cfg.get("mlp_layer_types")
@@ -149,19 +179,28 @@ def _is_dense(cfg, l):
 
 
 def _kind(cfg, l):
+    if _letter(cfg, l):
+        return {"M": "mamba", "*": "full_attention"}[_letter(cfg, l)]
     kinds = cfg.get("layer_types")
     return kinds[l] if kinds else "latent_attention"
 
 
 def _n_experts(cfg):
-    return int(cfg["num_experts"] if "num_experts" in cfg
-               else cfg["n_routed_experts"])
+    """The router's outputs (``router_experts`` where the counting key
+    gives a chip's share)."""
+    return int(next(cfg[k] for k in ("router_experts", "num_experts",
+                                     "n_routed_experts") if k in cfg))
 
 
 def _shared_width(cfg):
     return int(cfg.get("shared_expert_intermediate_size")
+               or cfg.get("moe_shared_expert_intermediate_size")
                or int(cfg.get("n_shared_experts") or 0)
                * int(cfg["moe_intermediate_size"]))
+
+
+def _relu2(cfg):
+    return cfg.get("mlp_hidden_act") == "relu2"
 
 
 def rms(x, gain, eps):
@@ -234,7 +273,7 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
     that many at a time (the same numbers; scores of ``[heads of one
     key-value head, block, T]`` and never ``[heads, T, T]``)."""
     T = z.shape[0]
-    kind = cfg["layer_types"][l]
+    kind = _kind(cfg, l)
     nq, nkv = _heads(cfg, l), int(cfg["num_key_value_heads"])
     D = int(cfg.get("head_dim") or cfg["hidden_size"] // nq)
     rope = (cfg.get("rope_parameters") or {}).get(
@@ -247,7 +286,8 @@ def attention_op(lw, z, cfg, l=0, *, head_gate="scalar", qk_norm=True,
     v = (z @ _f(lw["wv"])).reshape(T, nkv, D)
     # ptpu: allow[unguarded-domain] — D is the static head size, never 0
     scale = float(cfg.get("attention_multiplier") or D ** -0.5)
-    if cfg.get("position_embedding_type") != "nope":
+    if cfg.get("position_embedding_type") != "nope" \
+            and not cfg.get("hybrid_override_pattern"):
         if qk_norm:
             q = rms(q, lw["q_norm"], _eps(cfg))
             k = rms(k, lw["k_norm"], _eps(cfg))
@@ -292,11 +332,18 @@ def mamba_op(lw, z, cfg, *, round_state=None):
     goes through (the benchmark's ``state_bf16`` control rounds it to
     bfloat16; ``None``: float32 as it is)."""
     T = z.shape[0]
-    nh, dh, N = (int(cfg[k]) for k in ("mamba_n_heads", "mamba_d_head",
-                                       "mamba_d_state"))
-    I, K = nh * dh, int(cfg["mamba_d_conv"])
+
+    def size(*names):  # one family's name for it or the other's
+        return int(next(cfg[k] for k in names if k in cfg))
+
+    nh, dh = size("mamba_n_heads", "mamba_num_heads"), \
+        size("mamba_d_head", "mamba_head_dim")
+    N, G = size("mamba_d_state", "ssm_state_size"), \
+        int(cfg.get("mamba_n_groups", cfg.get("n_groups", 1)))
+    I, K = nh * dh, size("mamba_d_conv", "conv_kernel")
+    C = I + 2 * G * N
     zxd = z @ _f(lw["w_in"])
-    gate, raw, dt = zxd[:, :I], zxd[:, I:2 * I + 2 * N], zxd[:, 2 * I + 2 * N:]
+    gate, raw, dt = zxd[:, :I], zxd[:, I:I + C], zxd[:, I + C:]
     rp = jnp.concatenate([jnp.zeros((K - 1, raw.shape[1]), F32), raw])
     w = _f(lw["conv_w"])
     xbc = jax.nn.silu(_f(lw["conv_b"])
@@ -305,19 +352,25 @@ def mamba_op(lw, z, cfg, *, round_state=None):
     dt = jax.nn.softplus(dt + _f(lw["dt_bias"]))
     a = -jnp.exp(_f(lw["A_log"]))
 
+    def by_head(v):  # [T, G x N] -> [T, heads, N]: a head's group's
+        return jnp.repeat(v.reshape(T, G, N), nh // G, axis=1)
+
     def token(S, t):  # S [heads, head_dim, N]
         x_t, b_t, c_t, dt_t = t
         S = jnp.exp(dt_t * a)[:, None, None] * S \
-            + (dt_t[:, None] * x_t)[:, :, None] * b_t
+            + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
         if round_state is not None:
             S = round_state(S)
-        return S, jnp.sum(S * c_t, axis=-1)
+        return S, jnp.sum(S * c_t[:, None, :], axis=-1)
 
-    _, y = jax.lax.scan(token, jnp.zeros((nh, dh, N), F32),
-                        (x, xbc[:, I:I + N], xbc[:, I + N:], dt))
+    _, y = jax.lax.scan(
+        token, jnp.zeros((nh, dh, N), F32),
+        (x, by_head(xbc[:, I:I + G * N]), by_head(xbc[:, I + G * N:]), dt))
     y = y + _f(lw["D"])[:, None] * x
     g = y.reshape(T, I) * jax.nn.silu(gate)
-    return rms(g, lw["ssm_norm"], _eps(cfg)) @ _f(lw["w_out"])
+    # each group of channels over its own rms, then the gain [I]
+    g = rms(g.reshape(T, G, I // G), 1.0, _eps(cfg)).reshape(T, I)
+    return (g * _f(lw["ssm_norm"])) @ _f(lw["w_out"])
 
 
 def yarn_mscale(scaling, key):
@@ -425,6 +478,11 @@ def dense_ff(lw, z, names=("w1", "w3", "w2")):
     return (jax.nn.silu(z @ w1) * (z @ w3)) @ w2
 
 
+def relu2_ff(w1, w2, z):
+    """A feed-forward of two matrices: ``relu(z W_1)^2 W_2``."""
+    return jnp.square(jax.nn.relu(z @ _f(w1))) @ _f(w2)
+
+
 def route(lw, z, cfg, *, scores="sigmoid"):
     """The dense ``[T, E]`` matrix of routing weights (zero where an
     expert is not selected)."""
@@ -432,7 +490,8 @@ def route(lw, z, cfg, *, scores="sigmoid"):
     logits = z @ _f(lw["gate"])
     s = jax.nn.sigmoid(logits) if scores == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    biased = cfg.get("use_expert_bias", cfg.get("topk_method") == "noaux_tc")
+    biased = cfg.get("use_expert_bias", cfg.get("topk_method") == "noaux_tc"
+                     or bool(cfg.get("hybrid_override_pattern")))
     pick = s + _f(lw["gate_bias"]) if biased else s
     _, sel = jax.lax.top_k(pick, k)
     w = jnp.take_along_axis(s, sel, axis=-1)
@@ -451,6 +510,16 @@ def expert_ff(lw, z, cfg, *, scores="sigmoid"):
     added by :func:`feed_forward`."""
     held = cfg.get("experts_held") or range(_n_experts(cfg))
     weights = route(lw, z, cfg, scores=scores)[:, jnp.asarray(list(held))]
+    if _relu2(cfg):  # the experts' rows are the layer's latents
+        u = z @ _f(lw["w_down"]) if "w_down" in lw else z
+
+        def plain(out, expert):
+            w1, w2, w = expert
+            return out + w[:, None] * relu2_ff(w1, w2, u), None
+
+        out, _ = jax.lax.scan(plain, jnp.zeros_like(u),
+                              (lw["w1"], lw["w2"], weights.T))
+        return out @ _f(lw["w_up"]) if "w_up" in lw else out
 
     def one(out, expert):
         w1, w3, w2, w = expert
@@ -466,6 +535,9 @@ def operator(lw, l, x, cfg, *, sinkhorn_iters=None, **how):
     """``h = x + op_l(n_op(x))`` over one sequence ``x [T, H]`` (``[T, n,
     H]`` and the hyper-connection under ``hc_mult``); ``how`` goes to
     the attention (:func:`attention_op`, :func:`latent_attention_op`)."""
+    if _letter(cfg, l) == "E":  # the layer is its feed-forward alone
+        return x
+
     def op(u):
         z = rms(u, lw["op_norm"], _eps(cfg))
         if _kind(cfg, l) == "conv":
@@ -483,13 +555,17 @@ def operator(lw, l, x, cfg, *, sinkhorn_iters=None, **how):
 def feed_forward(lw, l, h, cfg, *, scores="sigmoid", sinkhorn_iters=None):
     """``y = h + ff_l(n_ff(h))`` over tokens ``h [T, H]`` (``[T, n, H]``
     under ``hc_mult``); every token on its own."""
+    if _letter(cfg, l) in ("M", "*"):  # the layer is its mixer alone
+        return h
+
     def ff(u):
         z = rms(u, lw["ff_norm"], _eps(cfg))
         if _is_dense(cfg, l):
             return dense_ff(lw, z)
         out = expert_ff(lw, z, cfg, scores=scores)
         if _shared_width(cfg):
-            out = out + dense_ff(lw, z, ("s1", "s3", "s2"))
+            out = out + (relu2_ff(lw["s1"], lw["s2"], z) if _relu2(cfg)
+                         else dense_ff(lw, z, ("s1", "s3", "s2")))
         return out
 
     with jax.default_matmul_precision("highest"):

@@ -8,9 +8,20 @@ bias ``b`` that balances load, and WEIGHTED by ``s`` without it;
 ``norm_topk_prob`` divides the weights by their sum (plus ``1e-6``).
 No token is dropped and there is no capacity limit.
 
+An expert is one of two blocks, told by the weights it is given: the
+gated one, ``W2 (silu(W1 x) * W3 x)`` (three matrices), or the plain
+one with a squared ReLU, ``W2 relu(W1 x)^2`` (two matrices, ``w3`` is
+``None``: the ``nemotron_h`` family's, whose rows are the family's
+LATENTS, narrower than the stream; the projections into and out of the
+latent belong to the layer and are the caller's, once a layer).
+
 The product (:func:`expert_product`) has three forms and picks among
 them from shapes it can see (:func:`product_form`): the tokens ``T``,
-the experts a token ``k`` and the experts held ``E_held``. MANY tokens
+the experts a token ``k``, the experts held ``E_held`` and the experts
+routed over ``E``. Under a share only ``E_held / E`` of a step's ``T x
+k`` assignments can be expected to land here, and that many are what
+the rule weighs (16 rows x 22 over 512 experts of which 128 are held:
+88 assignments here, which touch about 64 of the 128). MANY tokens
 (a prefill, over ``DENSE_MAX_ROWS``): the (token,
 expert) assignments are sorted by expert and each weight takes one
 grouped matrix product (``jax.lax.ragged_dot``: a Mosaic grouped-matmul
@@ -25,16 +36,17 @@ more than ``BLOCK_ASSIGNMENTS`` assignments goes through all of that in
 equal blocks of tokens, one after the other (``lax.map``), so the
 temporaries are a block's whatever the stream. FEW tokens (a decode
 step, ``DENSE_MAX_ROWS`` or under) stream expert weights past the rows,
-and the question is WHICH experts' weights. Where the step's ``T x k``
-assignments outnumber the held experts (``TOUCHED_REACH x T x k >
-E_held``: 64 rows x 4 over 32 experts touch 31.5 of them), every held
+and the question is WHICH experts' weights. Where the step's
+assignments here outnumber the held experts (``TOUCHED_REACH x T x k x
+E_held / E > E_held``: 64 rows x 4 over 32 experts touch 31.5 of
+them), every held
 expert is computed for every token in one
 batched product and the routing weights, zero where an expert was not
 selected, do the selecting (:func:`_every_expert`): each expert's
 weights are read once either way, and the batched product reads them at
 14.2 ms a step of 64 rows where the sorted one took 24.5 (my chip run,
-PR 27). Where they do not (``TOUCHED_REACH x T x k <= E_held``: 16 rows
-x 8 over 256 experts touch 94), only the experts that at least one row
+PR 27). Where they do not (16 rows x 8 over 256 experts touch 94),
+only the experts that at least one row
 selected are fetched and multiplied (:func:`_touched_experts`: one Pallas
 kernel, ``touched_experts`` in a device trace, whose grid walks the
 ascending list of distinct selected experts through a prefetched
@@ -54,10 +66,12 @@ Off the TPU the kernel runs in Pallas' interpreter (tests, rehearsals).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -132,10 +146,12 @@ def local_index(sel: jax.Array, n_experts: int,
     the last group) for an expert that lives on another chip."""
     if held is None:
         return sel, n_experts
-    table = jnp.full((n_experts,), len(held), jnp.int32).at[
-        jnp.asarray(held, jnp.int32)].set(
-        jnp.arange(len(held), dtype=jnp.int32))
-    return table[sel], len(held)
+    # a constant of the program (``held`` is static): built on the device
+    # by a scatter, the table crashed the v5e compiler's fusion pass in
+    # the decode's loop (scatter_emitter.cc, PR 48)
+    table = np.full((n_experts,), len(held), np.int32)
+    table[list(held)] = np.arange(len(held))
+    return jnp.asarray(table)[sel], len(held)
 
 
 def expert_load(sel: jax.Array, n_experts: int,
@@ -153,32 +169,51 @@ def expert_load(sel: jax.Array, n_experts: int,
 SORTED, TOUCHED, EVERY = "sorted_groups", "touched_experts", "every_expert"
 
 
-def product_form(tokens: int, top_k: int, n_held: int) -> str:
+def product_form(tokens: int, top_k: int, n_held: int,
+                 n_experts: Optional[int] = None) -> str:
     """Which of the three forms :func:`expert_product` takes for
-    ``tokens`` rows routed ``top_k`` ways over ``n_held`` held experts:
-    shapes alone decide (the module's docstring says why)."""
+    ``tokens`` rows routed ``top_k`` ways over ``n_experts`` experts of
+    which ``n_held`` are held here (``None``: all of them): shapes alone
+    decide (the module's docstring says why). The assignments that can
+    be expected HERE are ``tokens x top_k x n_held / n_experts``."""
     if tokens > DENSE_MAX_ROWS:
         return SORTED
-    if TOUCHED_REACH * tokens * top_k <= n_held:
+    n_experts = n_held if n_experts is None else n_experts
+    # T k E_held / E <= E_held, in whole numbers
+    if TOUCHED_REACH * tokens * top_k * n_held <= n_held * n_experts:
         return TOUCHED
     return EVERY
 
 
 def expert_product(x: jax.Array, sel: jax.Array, wts: jax.Array,
-                   w1: jax.Array, w3: jax.Array, w2: jax.Array, *,
+                   w1: jax.Array, w3: Optional[jax.Array], w2: jax.Array, *,
                    n_experts: int, held: Optional[Sequence[int]] = None,
                    valid: Optional[jax.Array] = None) -> jax.Array:
-    """``sum_e w_e W2_e (silu(W1_e x) * W3_e x)`` over the held experts,
-    ``[T, H]`` float32. ``x [T, H]`` in the weights' dtype, ``sel`` /
-    ``wts [T, k]`` from :func:`route`, ``w1`` / ``w3 [E_held, H, F]``,
-    ``w2 [E_held, F, H]``; products accumulate in float32."""
+    """``sum_e w_e W2_e (silu(W1_e x) * W3_e x)`` over the held experts
+    (``w3`` ``None``: ``sum_e w_e W2_e relu(W1_e x)^2``), ``[T, H]``
+    float32. ``x [T, H]`` in the weights' dtype, ``sel`` / ``wts [T,
+    k]`` from :func:`route`, ``w1`` / ``w3 [E_held, H, F]``, ``w2
+    [E_held, F, H]``; products accumulate in float32."""
     T, k = sel.shape
     local, n_held = local_index(sel, n_experts, held)
     if valid is not None:
         local = jnp.where(valid[:, None], local, n_held)
     form = {SORTED: _sorted_groups, TOUCHED: _touched_experts,
-            EVERY: _every_expert}[product_form(T, k, n_held)]
+            EVERY: _every_expert}[product_form(T, k, n_held, n_experts)]
     return form(x, local, wts, w1, w3, w2)
+
+
+def _hidden(a, gate=None):
+    """An expert's hidden activations from its first product, float32:
+    ``silu(a) * gate()``, or ``relu(a)^2`` where the expert has no gate
+    matrix. ``gate`` is CALLED for the gate matrix's product after
+    ``silu(a)`` is written: the statement order the gated experts had
+    before experts of two matrices (PR 48), which is the order a Pallas
+    kernel's body is scheduled in (the traced programs of the gated
+    families are the parent's, statement for statement)."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(a))
+    return jax.nn.silu(a) * gate()
 
 
 def _every_expert(x, local, wts, w1, w3, w2):
@@ -188,9 +223,11 @@ def _every_expert(x, local, wts, w1, w3, w2):
     f32 = jnp.float32
     dense = jnp.sum(jax.nn.one_hot(local, w1.shape[0], dtype=f32)
                     * wts[..., None], axis=1)
-    h = jax.nn.silu(jnp.einsum("th,ehf->etf", x, w1,
-                               preferred_element_type=f32)) \
-        * jnp.einsum("th,ehf->etf", x, w3, preferred_element_type=f32)
+
+    def into(w):
+        return jnp.einsum("th,ehf->etf", x, w, preferred_element_type=f32)
+
+    h = _hidden(into(w1), None if w3 is None else lambda: into(w3))
     y = jnp.einsum("etf,efh->eth", h.astype(x.dtype), w2,
                    preferred_element_type=f32)
     # float32 x float32: at the default precision the MXU would round
@@ -215,20 +252,22 @@ def touched_list(local: jax.Array, n_held: int, length: int
     return jnp.where(n > 0, ids, 0), n
 
 
-def _f_tiles(H: int, F: int, itemsize: int) -> int:
+def _f_tiles(H: int, F: int, itemsize: int, matrices: int = 3) -> int:
     """The fewest equal tiles of ``F`` (the whole, or multiples of 128
-    lanes) that keep a step's three weight blocks at
-    ``EXPERT_BLOCK_BYTES`` or under; the finest there is where none
-    does."""
+    lanes) that keep a step's weight blocks (``matrices`` of them: three
+    of a gated expert, two of a plain one) at ``EXPERT_BLOCK_BYTES`` or
+    under; the finest there is where none does."""
     ways = [n for n in range(1, F + 1)
             if F % n == 0 and (n == 1 or F // n % 128 == 0)]
     return next((n for n in ways
-                 if 3 * H * (F // n) * itemsize <= EXPERT_BLOCK_BYTES),
-                ways[-1])
+                 if matrices * H * (F // n) * itemsize
+                 <= EXPERT_BLOCK_BYTES), ways[-1])
 
 
-def _touched_kernel(ids_ref, n_ref, x_ref, dense_ref, w1_ref, w3_ref,
-                    w2_ref, o_ref):
+def _touched_kernel(ids_ref, n_ref, x_ref, dense_ref, *refs):
+    """``refs``: the expert's first matrices (``w1`` and, where it is
+    gated, ``w3``), its ``w2`` and the output block."""
+    *into_refs, w2_ref, o_ref = refs
     g, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((g == 0) & (j == 0))
@@ -239,8 +278,12 @@ def _touched_kernel(ids_ref, n_ref, x_ref, dense_ref, w1_ref, w3_ref,
     def _():
         f32 = jnp.float32
         x = x_ref[...]
-        h = jax.nn.silu(jnp.dot(x, w1_ref[...], preferred_element_type=f32)) \
-            * jnp.dot(x, w3_ref[...], preferred_element_type=f32)
+
+        def into(w_ref):
+            return jnp.dot(x, w_ref[...], preferred_element_type=f32)
+
+        first, *gate = into_refs
+        h = _hidden(into(first), *(functools.partial(into, w) for w in gate))
         y = jnp.dot(h.astype(x.dtype), w2_ref[...],
                     preferred_element_type=f32)
         # the expert's column of the routing matrix, float32 on the VPU
@@ -263,7 +306,9 @@ def _touched_experts(x, local, wts, w1, w3, w2):
     in VMEM from the first step (zeroed there) to the last (written
     once). A later step maps to the block already held: no DMA, no
     product. Only the float32 order of the sum over experts differs
-    from :func:`_every_expert`."""
+    from :func:`_every_expert`. An expert of two matrices (``w3``
+    ``None``) goes through the same grid with two weight blocks a
+    step."""
     T, k = local.shape
     n_held, H, F = w1.shape
     f32 = jnp.float32
@@ -274,7 +319,8 @@ def _touched_experts(x, local, wts, w1, w3, w2):
     rows = -(-T // 16) * 16  # whole sublane tiles of either dtype
     x = jnp.pad(x, ((0, rows - T), (0, 0)))
     dense = jnp.pad(dense, ((0, rows - T), (0, 0)))
-    nf = _f_tiles(H, F, w1.dtype.itemsize)
+    into = (w1,) if w3 is None else (w1, w3)
+    nf = _f_tiles(H, F, w1.dtype.itemsize, len(into) + 1)
     tile = F // nf
 
     def whole(g, j, ids, n):
@@ -296,8 +342,7 @@ def _touched_experts(x, local, wts, w1, w3, w2):
             num_scalar_prefetch=2, grid=(G, nf),
             in_specs=[pl.BlockSpec((rows, H), whole),
                       pl.BlockSpec((rows, n_held), whole),
-                      pl.BlockSpec((None, H, tile), in_map),
-                      pl.BlockSpec((None, H, tile), in_map),
+                      *(pl.BlockSpec((None, H, tile), in_map) for _ in into),
                       pl.BlockSpec((None, tile, H), out_map)],
             out_specs=pl.BlockSpec((rows, H), whole)),
         out_shape=jax.ShapeDtypeStruct((rows, H), f32),
@@ -305,7 +350,7 @@ def _touched_experts(x, local, wts, w1, w3, w2):
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=2 * EXPERT_BLOCK_BYTES + (16 << 20)),
         interpret=_interpreted(), name="touched_experts",
-    )(ids, n.reshape(1), x, dense, w1, w3, w2)
+    )(ids, n.reshape(1), x, dense, *into, w2)
     return out[:T]
 
 
@@ -340,9 +385,11 @@ def _sorted_block(x, local, wts, w1, w3, w2):
     sizes = jnp.diff(ends, prepend=0)
     xs = x.at[order // k].get(mode="promise_in_bounds")
     f32 = jnp.float32
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes,
-                                       preferred_element_type=f32)) \
-        * jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=f32)
+
+    def into(w):
+        return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=f32)
+
+    h = _hidden(into(w1), None if w3 is None else lambda: into(w3))
     y = jax.lax.ragged_dot(h.astype(x.dtype), w2, sizes,
                            preferred_element_type=f32)
     # the inverse permutation: assignment (t, j) is row back[t, j] of y

@@ -5,9 +5,12 @@ one-token update.
 
 The recurrence, a head ``h`` with ``head_dim`` channels ``x_t``, a
 scalar step ``dt_t > 0`` and decay rate ``A < 0``, and ``B_t``, ``C_t
-[N]`` shared by the heads: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
-B_t^T`` (``S`` zero before a row's first token), ``y_t = S_t C_t``. The
-skip ``D x_t`` is the caller's.
+[N]`` shared by the heads of ``h``'s GROUP (``groups`` equal runs of
+consecutive heads; one group: by all): ``S_t = exp(dt_t A) S_{t-1} +
+dt_t x_t B_t^T`` (``S`` zero before a row's first token), ``y_t = S_t
+C_t``. The skip ``D x_t`` is the caller's. ``B`` and ``C`` come as the
+in-projection lays them, ``[T, groups x N]``, a group's ``N`` behind
+the last one's.
 
 :func:`ssm_scan` takes the prefill's stream as ``models/decoder.py``
 packs it: the rows' tokens one row behind the other, ``row [T]`` saying
@@ -17,8 +20,8 @@ slots. In chunks of ``chunk`` tokens (``mamba_chunk_size``), with ``cum_t
 
 - inside a chunk, ``y_t += sum_{s <= t} (C_t . B_s) exp(cum_t - cum_s)
   dt_s x_s`` over the ``s`` of ``t``'s OWN row: ``C B^T`` once a chunk
-  for all heads, masked to same-row causal pairs, then a head's decay
-  and one product with its channels;
+  and group for all its heads, masked to same-row causal pairs, then a
+  head's decay and one product with its channels;
 - between chunks, ``y_t += exp(cum_t) C_t S_in`` where ``t``'s row is
   the row of the last slot before the chunk (else nothing: the state is
   another row's), and ``S_out = exp(cum_end) S_in [same row] + sum_s
@@ -69,14 +72,17 @@ def _on_chip() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def kernel_takes(heads: int, head_dim: int, chunk: int) -> bool:
+def kernel_takes(heads: int, head_dim: int, chunk: int,
+                 groups: int = 1) -> bool:
     """Whether the Pallas scan is written for these sizes: heads that
-    tile the lanes (``head_dim`` a divisor of 128), groups of whole lane
-    tiles, chunks of whole sublane tiles. Anything else takes the
-    twin."""
+    tile the lanes (``head_dim`` a divisor of 128), grid steps of whole
+    lane tiles whose heads lie inside ONE of the ``groups`` that share a
+    ``B`` and a ``C``, chunks of whole sublane tiles. Anything else
+    takes the twin."""
     group = min(HEAD_GROUP, heads)
     return LANES % head_dim == 0 and heads % group == 0 \
-        and (group * head_dim) % LANES == 0 and chunk % 16 == 0
+        and (group * head_dim) % LANES == 0 and chunk % 16 == 0 \
+        and heads % groups == 0 and heads // groups % group == 0
 
 
 # -- what both forms of the scan share -----------------------------------------
@@ -114,35 +120,50 @@ def _plan(x, b, c, dt, a, row, last, chunk: int):
 
 # -- the twin ------------------------------------------------------------------
 
-def scan_chunked(x, b, c, dt, a, row, last, *, chunk: int):
+def scan_chunked(x, b, c, dt, a, row, last, *, chunk: int, groups: int = 1):
     """:func:`ssm_scan` as batched einsums over the chunks (and a
     ``lax.scan`` over them for the carried state): the same algebra and
     the same roundings as the kernel, for the CPU (whose batched
     products take no bfloat16: an operand is rounded to ``x``'s dtype
-    and widened again)."""
+    and widened again). A group at a time where ``B`` and ``C`` differ
+    by group: one group is the lines it always was."""
     f32, dtp = jnp.float32, x.dtype
 
     def rounded(v):
         return v.astype(dtp).astype(f32)
 
     T0, heads = dt.shape
-    N, D = b.shape[1], x.shape[1] // heads
+    N, D = b.shape[1] // groups, x.shape[1] // heads
+    per = heads // groups  # heads that share a B and a C
     (x, b, c, dt), (cum, seg, prev, _, _, end, of) = _plan(
         x, b, c, dt, a, row, last, chunk)
     T = dt.shape[0]
     n, Q = T // chunk, chunk
     xs = rounded(x).reshape(n, Q, heads, D)
-    bs, cs = rounded(b).reshape(n, Q, N), rounded(c).reshape(n, Q, N)
+    bs = rounded(b).reshape(n, Q, groups, N)
+    cs = rounded(c).reshape(n, Q, groups, N)
+
+    def by_group(fn):
+        """``fn(group, its heads)`` of every group, side by side along
+        the heads' axis (the third of what ``fn`` returns)."""
+        parts = [fn(k, slice(k * per, (k + 1) * per)) for k in range(groups)]
+        return parts[0] if groups == 1 else jnp.concatenate(parts, axis=2)
+
     cum, dts, seg = (v.reshape((n, Q) + v.shape[1:])
                      for v in (cum, dt, seg))
     prev = prev.astype(f32)
 
     pairs = (seg[:, :, None] == seg[:, None, :]) \
         & (jnp.arange(Q)[None, :] <= jnp.arange(Q)[:, None])
-    g = jnp.where(pairs, jnp.einsum("cqn,csn->cqs", cs, bs), 0.0)
     decay = jnp.exp(jnp.minimum(cum[:, :, None] - cum[:, None, :], 0.0))
-    m = g[..., None] * decay * dts[:, None]              # [n, q, s, heads]
-    y = jnp.einsum("cqsh,cshd->cqhd", rounded(m), xs)
+
+    def inside(k, mine):  # [n, q, heads of the group, D]
+        g = jnp.where(pairs, jnp.einsum("cqn,csn->cqs", cs[:, :, k],
+                                        bs[:, :, k]), 0.0)
+        m = g[..., None] * decay[..., mine] * dts[:, None, :, mine]
+        return jnp.einsum("cqsh,cshd->cqhd", rounded(m), xs[:, :, mine])
+
+    y = by_group(inside)
 
     def state_at(cum_e, seg_e, chunks, s_in):
         """The state after the slot whose running sum is ``cum_e [k,
@@ -152,8 +173,10 @@ def scan_chunked(x, b, c, dt, a, row, last, *, chunk: int):
             * dts[chunks] * (seg[chunks] == seg_e[:, None])[..., None]
         keep = jnp.where((seg_e == prev[chunks])[:, None], jnp.exp(cum_e),
                          0.0)
-        return keep[:, None, :, None] * s_in + jnp.einsum(
-            "ksn,kshd->knhd", bs[chunks], rounded(xs[chunks] * w[..., None]))
+        xw, bk = rounded(xs[chunks] * w[..., None]), bs[chunks]
+        return keep[:, None, :, None] * s_in + by_group(
+            lambda k, mine: jnp.einsum("ksn,kshd->knhd", bk[:, :, k],
+                                       xw[:, :, mine]))
 
     every = jnp.arange(n)
 
@@ -163,8 +186,10 @@ def scan_chunked(x, b, c, dt, a, row, last, *, chunk: int):
 
     _, s_in = jax.lax.scan(carry, jnp.zeros((N, heads, D), f32), every)
     seen = jnp.where((seg == prev[:, None])[..., None], jnp.exp(cum), 0.0)
-    y = y + seen[..., None] * jnp.einsum("cqn,cnhd->cqhd", cs,
-                                         rounded(s_in))
+    s_r = rounded(s_in)
+    y = y + seen[..., None] * by_group(
+        lambda k, mine: jnp.einsum("cqn,cnhd->cqhd", cs[:, :, k],
+                                   s_r[:, :, mine]))
     rows = jnp.arange(last.shape[0])
     final = state_at(cum[of, end], rows.astype(f32), of, s_in[of])
     return (y.reshape(T, heads * D)[:T0],
@@ -173,10 +198,16 @@ def scan_chunked(x, b, c, dt, a, row, last, *, chunk: int):
 
 def step_plain(state, x, b, c, decay, dt):
     """:func:`ssm_step` as plain lines."""
-    D = state.shape[-1] // dt.shape[-1]
+    rows, N, W = state.shape
+    D = W // dt.shape[-1]
+
+    def lanes(v):  # [B, groups x N] -> [B, N, lanes]: a group's lanes its own
+        v = v.reshape(rows, -1, N).swapaxes(1, 2)
+        return jnp.repeat(v, W // v.shape[-1], axis=-1)
+
     new = jnp.repeat(decay, D, axis=-1)[:, None, :] * state \
-        + b[:, :, None] * (jnp.repeat(dt, D, axis=-1) * x)[:, None, :]
-    return new, jnp.sum(new * c[:, :, None], axis=1)
+        + lanes(b) * (jnp.repeat(dt, D, axis=-1) * x)[:, None, :]
+    return new, jnp.sum(new * lanes(c), axis=1)
 
 
 # -- the kernels ---------------------------------------------------------------
@@ -261,17 +292,24 @@ def _scan_kernel(lo_ref, hi_ref, end_ref, prev_ref, x_ref, bt_ref, c_ref,
         s_ref[:, t * LANES:(t + 1) * LANES] = s
 
 
-def scan_kernel(x, b, c, dt, a, row, last, *, chunk: int,
+def scan_kernel(x, b, c, dt, a, row, last, *, chunk: int, groups: int = 1,
                 interpret: bool = False):
     """:func:`ssm_scan` as the Pallas kernel (``interpret``: in Pallas'
-    interpreter, for the tests)."""
+    interpreter, for the tests). A grid step's heads read the ``N``
+    rows of ``B^T`` and columns of ``C`` that are their group's."""
     f32 = jnp.float32
     T0, heads = dt.shape
-    N, D, rows = b.shape[1], x.shape[1] // heads, last.shape[0]
+    N, D, rows = b.shape[1] // groups, x.shape[1] // heads, last.shape[0]
     group = min(HEAD_GROUP, heads)
-    if not kernel_takes(heads, D, chunk):
-        raise ValueError(f"{heads} heads of {D} in chunks of {chunk}: "
-                         f"not a shape the scan kernel is written for")
+    if not kernel_takes(heads, D, chunk, groups):
+        raise ValueError(f"{heads} heads of {D} in {groups} group(s), "
+                         f"chunks of {chunk}: not a shape the scan kernel "
+                         f"is written for")
+    steps = heads // groups // group  # grid steps a group of B and C
+
+    def of(g):  # the B-and-C group of grid step g's heads
+        return 0 if groups == 1 else g // steps
+
     (x, b, c, dt), (cum, seg, prev, lo, hi, end, _) = _plan(
         x, b, c, dt, a, row, last, chunk)
     T = dt.shape[0]
@@ -289,8 +327,8 @@ def scan_kernel(x, b, c, dt, a, row, last, *, chunk: int,
             num_scalar_prefetch=4, grid=(ng, T // chunk),
             in_specs=[
                 pl.BlockSpec((chunk, W), lambda g, i, *_: (i, g)),
-                pl.BlockSpec((N, chunk), lambda g, i, *_: (0, i)),
-                pl.BlockSpec((chunk, N), lambda g, i, *_: (i, 0)),
+                pl.BlockSpec((N, chunk), lambda g, i, *_: (of(g), i)),
+                pl.BlockSpec((chunk, N), lambda g, i, *_: (i, of(g))),
                 pl.BlockSpec((None, chunk, col.shape[-1]),
                              lambda g, i, *_: (g, i, 0)),
                 pl.BlockSpec((None, wide, chunk),
@@ -322,10 +360,15 @@ def step_kernel(state, x, b, c, decay, dt, *, interpret: bool = False):
     f32 = jnp.float32
     rows, N, W = state.shape
     D = W // dt.shape[-1]
-    lanes = next(n for n in (STEP_LANES, 512, 256, LANES, W) if W % n == 0)
+    groups = b.shape[-1] // N
+    # a grid step's lanes lie inside one group's
+    lanes = next(n for n in (STEP_LANES, 512, 256, LANES, W)
+                 if W // groups % n == 0)
+    steps = W // groups // lanes  # grid steps a group of B and C
     wide = lambda v: jnp.repeat(v, D, axis=-1)  # noqa: E731
     row = pl.BlockSpec((1, 1, lanes), lambda r, i: (r, 0, i))
-    col = pl.BlockSpec((1, N, 1), lambda r, i: (r, 0, 0))
+    col = pl.BlockSpec((1, N, 1), lambda r, i: (
+        r, 0 if groups == 1 else i // steps, 0))
     block = pl.BlockSpec((1, N, lanes), lambda r, i: (r, 0, i))
     new, y = pl.pallas_call(
         _step_kernel, grid=(rows, W // lanes),
@@ -345,27 +388,32 @@ def step_kernel(state, x, b, c, decay, dt, *, interpret: bool = False):
 
 def ssm_scan(x: jax.Array, b: jax.Array, c: jax.Array, dt: jax.Array,
              a: jax.Array, row: jax.Array, last: jax.Array, *,
-             chunk: int) -> Tuple[jax.Array, jax.Array]:
+             chunk: int, groups: int = 1) -> Tuple[jax.Array, jax.Array]:
     """The recurrence over a packed stream. ``x [T, heads x D]``, ``b``,
-    ``c [T, N]`` in the products' dtype and ``dt [T, heads]`` float32,
-    all ZERO in the spare slots; ``a [heads]`` float32, negative; ``row
-    [T]`` the row each slot belongs to (a spare slot: a row beside it);
-    ``last [B]`` each row's last slot -> ``(y [T, heads x D] float32,
-    final [B, N, heads x D] float32)``: ``y_t = S_t C_t`` and each row's
-    state after its last token."""
+    ``c [T, groups x N]`` in the products' dtype and ``dt [T, heads]``
+    float32, all ZERO in the spare slots; ``a [heads]`` float32,
+    negative; ``row [T]`` the row each slot belongs to (a spare slot: a
+    row beside it); ``last [B]`` each row's last slot -> ``(y [T, heads
+    x D] float32, final [B, N, heads x D] float32)``: ``y_t = S_t C_t``
+    and each row's state after its last token."""
     heads = dt.shape[1]
-    if _on_chip() and kernel_takes(heads, x.shape[1] // heads, chunk):
-        return scan_kernel(x, b, c, dt, a, row, last, chunk=chunk)
-    return scan_chunked(x, b, c, dt, a, row, last, chunk=chunk)
+    if _on_chip() and kernel_takes(heads, x.shape[1] // heads, chunk,
+                                   groups):
+        return scan_kernel(x, b, c, dt, a, row, last, chunk=chunk,
+                           groups=groups)
+    return scan_chunked(x, b, c, dt, a, row, last, chunk=chunk,
+                        groups=groups)
 
 
 def ssm_step(state: jax.Array, x: jax.Array, b: jax.Array, c: jax.Array,
              decay: jax.Array, dt: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """One token a row: ``state [B, N, heads x D]`` float32, ``x [B,
-    heads x D]``, ``b``, ``c [B, N]``, ``decay = exp(dt A)`` and ``dt [B,
-    heads]``, all float32 -> ``(state', y [B, heads x D])`` with
-    ``state' = decay state + b (dt x)^T`` and ``y = state' c``."""
-    if _on_chip() and state.shape[-1] % LANES == 0 \
+    heads x D]``, ``b``, ``c [B, groups x N]``, ``decay = exp(dt A)``
+    and ``dt [B, heads]``, all float32 -> ``(state', y [B, heads x D])``
+    with ``state' = decay state + b (dt x)^T`` and ``y = state' c``, a
+    head against its group's ``b`` and ``c``."""
+    groups = b.shape[-1] // state.shape[1]
+    if _on_chip() and state.shape[-1] // groups % LANES == 0 \
             and state.shape[1] % 8 == 0:
         return step_kernel(state, x, b, c, decay, dt)
     return step_plain(state, x, b, c, decay, dt)

@@ -153,10 +153,12 @@ def _state_bytes(cfg, state) -> Dict[str, int]:
     (no sync): ``full``, ``window``, ``conv``, ``latent``, ``ssm`` (a
     state-space layer's recurrent state and its conv window: bytes that
     follow the ROWS, whatever their histories)."""
-    from ..models.decoder import ATTENTION, CONV, LATENT, MAMBA
+    from ..models.decoder import ATTENTION, CONV, LATENT, MAMBA, NONE
 
     out: Dict[str, int] = {}
     for kind, st in zip(cfg.layer_types, state["layers"]):
+        if kind == NONE:  # a layer that is its feed-forward alone
+            continue
         name = {ATTENTION: "full", CONV: "conv", LATENT: "latent",
                 MAMBA: "ssm"}.get(kind, "window")
         out[name] = out.get(name, 0) + sum(a.nbytes for a in st.values())
@@ -171,6 +173,7 @@ class GenerativeAlgorithm(Algorithm):
     def __init__(self, params: GenerativeParams = GenerativeParams()):
         self.params = params
         self._tokens = self._touched = self._read = self._imbalance = None
+        self._assigned = None
         self._state_bytes = self._sinkhorn_gap = None
         self._ssm_bytes = self._ssm_tokens = self._ssm_chunks = None
         # bytes of recurrent state the batches in flight hold (dispatch
@@ -197,12 +200,16 @@ class GenerativeAlgorithm(Algorithm):
             "Token slots of generative batches by kind: prompt (real "
             "history tokens), pad (the rest of the slots the prefill ran), "
             "generated")
-        bounds = experts_touched_bounds(self.params.model.get(
-            "num_experts", self.params.model.get("n_routed_experts", 32)))
+        # the experts HELD here: a chip's share counts its own
+        model = self.params.model
+        bounds = experts_touched_bounds(
+            len(model.get("experts_held") or ()) or model.get(
+                "num_experts", model.get("n_routed_experts", 32)))
         self._touched = registry.histogram(
             "pio_moe_experts_touched",
-            "Distinct experts a decode step's rows selected, mean over "
-            "the expert layers and the steps of a batch", bounds=bounds)
+            "Distinct HELD experts a decode step's rows selected, mean "
+            "over the expert layers and the steps of a batch (a chip that "
+            "holds a share of the experts counts its own)", bounds=bounds)
         self._read = registry.histogram(
             "pio_moe_experts_read",
             "Experts whose weights a decode step fetched, mean over the "
@@ -211,9 +218,16 @@ class GenerativeAlgorithm(Algorithm):
             "its rows selected where it took another form", bounds=bounds)
         self._imbalance = registry.histogram(
             "pio_moe_load_imbalance",
-            "Largest over mean tokens per expert of an expert layer in "
-            "a batch's prefill",
+            "Largest over mean tokens per HELD expert of an expert layer "
+            "in a batch's prefill",
             bounds=IMBALANCE_BOUNDS)
+        self._assigned = registry.counter(
+            "pio_moe_assignments_total",
+            "The router's assignments (a token's selected expert, every "
+            "expert layer, prefill and decode) by where the expert lives: "
+            "held (its weights are on this chip: the product is computed) "
+            "or absent (another chip's share: left out here). A prefill "
+            "counts its real tokens, a decode step the rows it ran")
         self._state_bytes = registry.gauge(
             "pio_gen_state_bytes",
             "Bytes of per-sequence state the last batch carried from its "
@@ -337,21 +351,28 @@ class GenerativeAlgorithm(Algorithm):
         self._tokens.labels(kind="generated").inc(
             len(hists) * self.params.max_new)
         prefill, decode = (np.asarray(a) for a in load)
+        # the loads are over ALL the router's experts; a chip's own
+        # series count the experts it holds
+        held = slice(None) if cfg.experts_held is None \
+            else list(cfg.experts_held)
+        mine, steps = prefill[:, held], decode[..., held]
         if decode.size:
-            self._touched.observe(float((decode > 0).sum(axis=-1).mean()))
             # from the loads the program returns anyway and the form
             # ops/moe.py takes for a step of that many rows: no sync
-            held = slice(None) if cfg.experts_held is None \
-                else list(cfg.experts_held)
-            mine = decode[..., held] > 0
-            n_held = mine.shape[-1]
-            every = moe.product_form(rows, cfg.num_experts_per_tok,
-                                     n_held) == moe.EVERY
-            self._read.observe(float(n_held) if every
-                               else float(mine.sum(axis=-1).mean()))
-        for layer in prefill:
+            n_held = steps.shape[-1]
+            touched = float((steps > 0).sum(axis=-1).mean())
+            self._touched.observe(touched)
+            every = moe.product_form(rows, cfg.num_experts_per_tok, n_held,
+                                     cfg.num_experts) == moe.EVERY
+            self._read.observe(float(n_held) if every else touched)
+        for layer in mine:
             if layer.sum() > 0:
                 self._imbalance.observe(float(layer.max() / layer.mean()))
+        if prefill.size:
+            here = int(mine.sum() + steps.sum())
+            self._assigned.labels(where="held").inc(here)
+            self._assigned.labels(where="absent").inc(
+                int(prefill.sum() + decode.sum()) - here)
 
     def warm_serving(self, model: GenerativeModel,
                      max_batch: int = 1) -> None:
@@ -373,9 +394,12 @@ class GenerativeAlgorithm(Algorithm):
     def _check_residency(self, model: GenerativeModel, state: int) -> None:
         """Weights and ``batches_in_flight`` batches' ``state`` bytes (a
         batch of the ladder's top: what the last warm dispatch held)
-        against the device's memory, where the backend reports one. A
-        state-space model's state follows the rows and not the histories
-        (2 MiB a row and layer at the published sizes), so it is the rows
+        against the device's memory, where the backend reports one. The
+        weights are what this chip HOLDS (a share of the experts, a
+        slice of the vocabulary: ``experts_held`` and ``vocab_size`` of
+        the configuration). A state-space model's state follows the rows
+        and not the histories (2 or 4 MiB a row and layer at the
+        published sizes), so it is the rows
         a batch may have, and not the cache a history needs, that a
         deployment sizes here: a ladder that does not fit fails the
         deploy, before traffic finds out."""

@@ -95,7 +95,7 @@ def test_config_reads_the_published_keys():
 
 @pytest.mark.parametrize("key,value", [
     ("num_local_experts", 8), ("mamba_proj_bias", True),
-    ("attention_bias", True), ("mamba_n_groups", 2),
+    ("attention_bias", True), ("mamba_n_groups", 3),
     ("position_embedding_type", "alibi"), ("mamba_d_head", 16),
 ])
 def test_an_unwritten_key_of_the_family_raises(key, value):

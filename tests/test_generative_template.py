@@ -212,13 +212,16 @@ def test_per_batch_series_are_on_the_servers_registry(served):
 @pytest.mark.parametrize("rows,held,want", [
     (4, None, 2.5),         # 8 assignments over 8 experts: the touched
     (8, None, 8.0),         # 16 over 8: every held expert
-    (2, (0, 1, 2, 5), 1.5),  # 4 over the 4 held here: those touched HERE
-    (4, (0, 1, 2, 5), 4.0),  # 8 over 4 held: every one of the 4
+    (2, (0, 1, 2, 5), 1.5),  # 4 over 8, half of them held: 2 land on the
+    (4, (0, 1, 2, 5), 1.5),  # 4 held here, then 4: those touched HERE
+    (5, (0, 1, 2, 5), 4.0),  # 10 over 8, 5 here over 4 held: every one
 ])
 def test_experts_read_follows_the_form_the_step_took(rows, held, want):
     """``pio_moe_experts_read`` off the loads the decode returns and the
     form ``ops/moe.py`` takes for that many rows: the touched count
-    (among the experts held) or all that are held."""
+    (among the experts held) or all that are held; under a share both
+    series count the experts HELD, and the form reckons with the share
+    of the assignments that can land here."""
     import dataclasses
 
     import numpy as np
@@ -241,7 +244,8 @@ def test_experts_read_follows_the_form_the_step_took(rows, held, want):
     read = export["pio_moe_experts_read"]["children"][0]
     assert (read["count"], read["sum"]) == (1, want)
     touched = export["pio_moe_experts_touched"]["children"][0]
-    assert (touched["count"], touched["sum"]) == (1, 2.5)
+    assert (touched["count"], touched["sum"]) == (
+        1, 2.5 if held is None else 1.5)
 
 
 def test_unknown_items_and_empty_histories():

@@ -351,6 +351,57 @@ def test_the_step_kernel_compiles_onto_its_state(one_chip):
         == 16 * 128 * 4096 * 4
 
 
+@pytest.mark.parametrize("slots", [2048, 16384])
+def test_the_grouped_scan_kernel_compiles_at_the_nemotron_cells_rungs(
+        one_chip, slots):
+    """128 heads of 64 in 8 groups over a state of 128 in chunks of 128,
+    16 rows: a grid step's 8 heads read their own group's ``B`` and
+    ``C`` (two steps a group)."""
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    assert ssm_scan.kernel_takes(128, 64, 128, 8)
+    compiled = _compiled(
+        lambda *a: ssm_scan.scan_kernel(*a, chunk=128, groups=8), one_chip,
+        ((slots, 8192), bf16), ((slots, 1024), bf16), ((slots, 1024), bf16),
+        ((slots, 128), f32), ((128,), f32), ((slots,), i32), ((16,), i32))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_scan" in text
+
+
+def test_the_grouped_step_kernel_compiles_onto_its_state(one_chip):
+    """16 rows' float32 states ``[128, 8192]`` in 8 groups: a grid
+    step's 1,024 lanes are one group's, and the new state lands in the
+    buffer the old one came in (no second 64 MiB)."""
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one_chip) for s in (
+        (16, 128, 8192), (16, 8192), (16, 1024), (16, 1024), (16, 128),
+        (16, 128))]
+    compiled = jax.jit(ssm_scan.step_kernel, donate_argnums=(0,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_step" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 16 * 128 * 8192 * 4
+
+
+def test_the_plain_experts_kernel_compiles_at_the_nemotron_cells_widths(
+        one_chip, for_the_chip):
+    """The cell's decode step: 16 rows of the 1,024-wide latent, 22 of
+    512 experts a token of which 128 are held, an expert's two matrices
+    1024 x 2688 in 3 tiles of 896."""
+    bf16 = jnp.bfloat16
+    assert moe.product_form(16, 22, 128, 512) == moe.TOUCHED
+    assert moe._f_tiles(1024, 2688, 2, 2) == 3
+    compiled = _compiled(
+        lambda x, local, wts, w1, w2: moe._touched_experts(
+            x, local, wts, w1, None, w2), one_chip,
+        ((16, 1024), bf16), ((16, 22), jnp.int32), ((16, 22), jnp.float32),
+        ((128, 1024, 2688), bf16), ((128, 2688, 1024), bf16))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "touched_experts" in text
+
+
 @pytest.mark.parametrize("batch", [1, 2, 4, 8, 16, 32, 64, 128])
 def test_the_serving_program_holds_one_array_of_scores(one_chip, monkeypatch,
                                                        batch):

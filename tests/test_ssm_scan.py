@@ -165,3 +165,106 @@ def test_the_kernel_says_which_shapes_it_takes():
             jnp.zeros((16, 96)), jnp.zeros((16, 8)), jnp.zeros((16, 8)),
             jnp.zeros((16, 2)), jnp.zeros((2,)), jnp.zeros((16,), jnp.int32),
             jnp.asarray([15], jnp.int32), chunk=16)
+
+
+# -- several groups: a B and a C for each run of consecutive heads ---------
+
+G_D = 16  # heads of 16: eight of them a lane tile, one grid step's
+
+
+def _grouped(groups, lengths, slots, align, seed):
+    """A packed stream of ``8 x groups`` heads of 16 in ``groups``
+    groups (a grid step's 8 heads are exactly one group's: the fewest
+    the kernel takes), ``B`` and ``C`` ``[T, groups x N]`` as the
+    in-projection lays them, and the token-by-token recurrence's ``y``
+    and final states, float64."""
+    heads = 8 * groups
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths)
+    ends = np.cumsum(-(-lengths // align) * align)
+    slot = np.arange(slots)
+    row = np.minimum(np.searchsorted(ends, slot, side="right"),
+                     len(lengths) - 1)
+    valid = (slot >= (ends - lengths)[row]) & (slot < ends[-1])
+    x = rng.normal(size=(slots, heads * G_D))
+    b, c = rng.normal(size=(2, slots, groups * N))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.5), (slots, heads)))
+    a = -np.exp(rng.uniform(0, np.log(16), heads))
+    x, b, c, dt = (np.where(valid[:, None], v, 0.0) for v in (x, b, c, dt))
+    of = np.arange(heads) // 8  # a head's group
+    y = np.zeros((slots, heads, G_D))
+    finals = []
+    for n, end in zip(lengths, ends):
+        S = np.zeros((heads, G_D, N))
+        for t in range(end - n, end):
+            bt, ct = (v[t].reshape(groups, N)[of] for v in (b, c))
+            S = np.exp(dt[t] * a)[:, None, None] * S \
+                + dt[t][:, None, None] * x[t].reshape(heads, G_D)[:, :, None] \
+                * bt[:, None, :]
+            y[t] = np.einsum("hdn,hn->hd", S, ct)
+        finals.append(S)
+    return (x, b, c, dt, a, row, ends - 1), valid, \
+        y.reshape(slots, -1), np.stack(finals)
+
+
+@pytest.mark.parametrize("form", ["twin", "kernel"])
+@pytest.mark.parametrize("groups", [2, 8])
+def test_the_grouped_scan_is_the_recurrence(groups, form):
+    """Rows that start mid-chunk, span three chunks or are shorter than
+    a tile, every head against ITS group's ``B`` and ``C``."""
+    lengths, slots, align, chunk = CASES["ragged"]
+    args, valid, want_y, want_s = _grouped(groups, lengths, slots, align, 7)
+    dev = [jnp.asarray(v, jnp.float32) for v in args[:5]] \
+        + [jnp.asarray(v, jnp.int32) for v in args[5:]]
+    assert ssm_scan.kernel_takes(8 * groups, G_D, chunk, groups)
+    if form == "twin":
+        y, final = ssm_scan.scan_chunked(*dev, chunk=chunk, groups=groups)
+    else:
+        y, final = jax.jit(lambda *a: ssm_scan.scan_kernel(
+            *a, chunk=chunk, groups=groups, interpret=True))(*dev)
+    scale = np.abs(want_y[valid]).max()
+    assert np.abs(np.asarray(y)[valid] - want_y[valid]).max() < TOL * scale
+    got = np.asarray(final).reshape(len(lengths), N, 8 * groups, G_D)
+    want = want_s.transpose(0, 3, 1, 2)
+    assert np.abs(got - want).max() < TOL * np.abs(want).max()
+    # one B and one C for all the heads is another answer
+    y1, _ = ssm_scan.scan_chunked(dev[0], dev[1][:, :N], dev[2][:, :N],
+                                  *dev[3:], chunk=chunk)
+    assert np.abs(np.asarray(y1)[valid] - want_y[valid]).max() > 0.1 * scale
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+@pytest.mark.parametrize("groups", [2, 8])
+def test_a_grouped_step_is_one_token_of_the_recurrence(groups, form):
+    rng = np.random.default_rng(5)
+    rows, heads = 3, 8 * groups
+    state = rng.normal(size=(rows, N, heads * G_D)).astype(np.float32)
+    x = rng.normal(size=(rows, heads * G_D)).astype(np.float32)
+    b, c = rng.normal(size=(2, rows, groups * N)).astype(np.float32)
+    dt = rng.uniform(1e-3, 0.3, (rows, heads)).astype(np.float32)
+    a = -rng.uniform(1, 16, heads).astype(np.float32)
+    decay = np.exp(dt * a)
+    fn = ssm_scan.step_plain if form == "plain" else (
+        lambda *v: ssm_scan.step_kernel(*v, interpret=True))
+    new, y = fn(*(jnp.asarray(v) for v in (state, x, b, c, decay, dt)))
+    of = np.arange(heads) // 8
+    bh, ch = (v.reshape(rows, groups, N)[:, of] for v in (b, c))  # [r, h, N]
+    S = state.reshape(rows, N, heads, G_D).astype(np.float64)
+    want = decay[:, None, :, None] * S + bh.transpose(0, 2, 1)[..., None] \
+        * (dt[:, :, None] * x.reshape(rows, heads, G_D))[:, None]
+    want_y = np.einsum("rnhd,rhn->rhd", want, ch).reshape(rows, -1)
+    assert np.abs(np.asarray(new).reshape(want.shape) - want).max() < 1e-5
+    assert np.abs(np.asarray(y) - want_y).max() < 1e-4
+
+
+def test_the_kernel_takes_groups_of_whole_grid_steps():
+    assert ssm_scan.kernel_takes(128, 64, 128, 8)    # 16 heads a group
+    assert ssm_scan.kernel_takes(16, 16, 32, 2)
+    assert not ssm_scan.kernel_takes(16, 16, 32, 4)  # 4 heads a group
+    assert not ssm_scan.kernel_takes(64, 64, 256, 3)
+    with pytest.raises(ValueError, match="4 group"):
+        ssm_scan.scan_kernel(
+            jnp.zeros((32, 256)), jnp.zeros((32, 4 * N)),
+            jnp.zeros((32, 4 * N)), jnp.zeros((32, 16)), jnp.zeros((16,)),
+            jnp.zeros((32,), jnp.int32), jnp.asarray([31], jnp.int32),
+            chunk=32, groups=4)
